@@ -42,7 +42,7 @@ Methodology notes:
   its batch while continuous overcommits and pays with counted
   evictions (recompute-style, stream-lossless).
 - jit warmup (all bucketed shapes) happens before the clock starts;
-  with MXNET_COMPILE_CACHE_DIR set the warmup is a disk load (PR 6).
+  with the persistent jit cache warm the warmup is a disk load (PR 6).
 
 Env knobs: BENCH_SERVE_{DMODEL,LAYERS,HEADS,DFF,VOCAB,REQUESTS,SEED,
 BLOCK_SIZE,KV_BLOCKS,MAX_BATCH,PREFILL_CHUNK,LOAD,TIMEOUT}.

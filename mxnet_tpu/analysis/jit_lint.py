@@ -26,10 +26,10 @@ schedule/layout decisions) over every jit-dispatching module:
     returned buffer back (the pool.swap discipline).  warning: a
     steady-state loop dispatching pool-like buffers through a program
     built with *no* donation at all — every step pays a device-side
-    copy that donation would elide.  The PR 6 cache+CPU carve-out
-    (``donate = () if jit_cache.donation_unsafe() else (...)``) is
-    donation for analysis purposes, never a finding: the buffers ARE
-    donated on TPU, so caller reuse is still an error.
+    copy that donation would elide.  A conditional carve-out
+    (``donate = () if <cond> else (...)``) is donation for analysis
+    purposes, never a finding: the buffers ARE donated on one branch,
+    so caller reuse is still an error.
 
 ``hot-d2h`` (error)
     ``.asnumpy()`` / ``np.asarray`` / ``float()`` / ``.item()`` /
@@ -431,9 +431,9 @@ def _tuple_ints(node):
 
 def _donation_spec(call, fn):
     """(donated positions, conditional?, donate kwarg present?) for a
-    jax.jit call — resolving the repo's PR 6 carve-out ternary
-    (``() if jit_cache.donation_unsafe() else (1, 2)``) to the donating
-    branch: on TPU the buffers ARE donated."""
+    jax.jit call — resolving a carve-out ternary
+    (``() if <cond> else (1, 2)``) to the donating branch: where the
+    condition is false the buffers ARE donated."""
     kw = next((k for k in call.keywords if k.arg == "donate_argnums"), None)
     if kw is None:
         return (), False, False
@@ -619,9 +619,8 @@ def _detect_donation(mod, findings):
                     "%s:%d" % (mod.relpath, call.lineno),
                     "steady-state loop dispatches %s through a program "
                     "built with no donate_argnums — every step pays a "
-                    "device-side copy donation would elide (gate the "
-                    "carve-out with jit_cache.donation_unsafe() if CPU "
-                    "cache safety is the concern)" % (poolish,)))
+                    "device-side copy donation would elide"
+                    % (poolish,)))
 
 
 def _rebound_targets(stmt, call):

@@ -17,18 +17,18 @@ gap (ROADMAP "Compilation layer"; ground: PAPERS.md TVM):
    (op, shapes, dtype, backend).
 3. **A persistent compilation cache** (jit_cache.py): traced/lowered
    executables survive process restarts via jax's compilation cache,
-   keyed to include the rewrite-pass configuration.
+   placed by ``JAX_COMPILATION_CACHE_DIR``.
 
 Enablement contract (off by default, the repo's established style)::
 
     MXNET_COMPILE_OPT=1               # master switch for the passes
     MXNET_COMPILE_PASSES=...          # subset of fold,layout,fuse,precision
-    MXNET_COMPILE_CACHE_DIR=/path     # persistent jit cache + tuning db
+    JAX_COMPILATION_CACHE_DIR=/path   # persistent jit cache + tuning db
     MXNET_COMPILE_TUNE=1              # allow on-device tuning trials
     MXNET_COMPILE_VERIFY=1            # golden-check every optimize()
     MXNET_COMPILE_MATMUL_PREC=auto    # auto | f32 | fast
 
-The cache is independent of the passes: ``MXNET_COMPILE_CACHE_DIR``
+The cache is independent of the passes: ``JAX_COMPILATION_CACHE_DIR``
 alone turns cold-start jit builds into loads with zero graph changes.
 Off, the only cost at bind time is one module attribute test.
 mxtel counters: ``compile.passes_applied_total``,
@@ -106,9 +106,9 @@ def active_passes():
 
 
 def config_key():
-    """Stable string describing the rewrite configuration — folded into
-    the jit-cache directory key so executables compiled under different
-    pass configurations never share entries."""
+    """Stable string describing the rewrite configuration — the prefix
+    of mxprof's program-record keys, so records taken under different
+    pass configurations never alias."""
     return "v1|opt=%d|passes=%s|prec=%s" % (
         int(ENABLED), ",".join(_passes) if ENABLED else "-", _matmul_prec)
 
@@ -133,14 +133,13 @@ def optimize(sym, input_shapes=None, input_types=None, frozen_params=None):
 
 
 def ensure_jit_cache():
-    """Enable the persistent jit cache when configured; safe no-op
-    otherwise. Every compile entry point calls this before building
-    programs."""
-    if os.environ.get("MXNET_COMPILE_CACHE_DIR", "").strip():
-        from . import jit_cache
+    """Wire the persistent jit cache when one is placed
+    (JAX_COMPILATION_CACHE_DIR, or an entry point's jit_cache.enable());
+    safe no-op otherwise. Every compile entry point calls this before
+    building programs."""
+    from . import jit_cache
 
-        return jit_cache.ensure(config_key())
-    return None
+    return jit_cache.ensure()
 
 
 def last_report():

@@ -1,20 +1,27 @@
 """Persistent compilation cache: jit builds survive process restarts.
 
-Every process today pays every XLA compile from scratch — mxtel's
+Every process pays every XLA compile from scratch — mxtel's
 ``executor.jit_builds_total`` counts them, and for a serving cold start
-they ARE the latency floor. This module wires jax's persistent
-compilation-cache machinery (``jax_compilation_cache_dir``) through the
-framework's compile entry points (Executor, the scanned trainers,
-Predictor): with ``MXNET_COMPILE_CACHE_DIR`` set, compiled executables
-land on disk keyed by their HLO + compile options, and the next process
-that builds the same program LOADS instead of compiling.
+or a chip call they ARE the latency floor. jax's persistent compilation
+cache keeps compiled executables on disk keyed by their HLO + compile
+options, so the next process that builds the same program LOADS instead
+of compiling. This module does not place that cache; it makes the most
+of one that is placed:
 
-Keying: entries live under ``<dir>/jit-<config-hash>/`` where the hash
-covers the rewrite-pass configuration (pass set, layout/precision
-modes, cache format version). The HLO itself already differs when a
-pass rewrites the graph, but the subdir keying also isolates
-configurations whose effect is not visible in the HLO (and makes
-``rm -r`` per-config cleanup trivial).
+Placement: ``JAX_COMPILATION_CACHE_DIR`` says where, and jax's own
+wiring is then the only thing that places it — nothing here overrides
+the directory or keys a sub-directory under it (the path is part of the
+cache key, so a directory that moves never hits). With the variable
+unset the library runs without a cache; the repo's entry points
+(``chip_smoke.py``, ``bench*.py``, ``serving.fleet.replica``) call
+:func:`enable`, which then places it at one fixed path inside the
+checkout (:data:`REPO_CACHE_DIR`). The tuning database
+(``compile/autotune.py``) lives beside the entries.
+
+:func:`ensure` — called from every compile entry point (Executor, the
+scanned trainers, Predictor, the serving engine) — lifts jax's
+size/time thresholds so the small programs a cold start is made of are
+cached too, and wires the accounting below.
 
 Robustness: a truncated or bit-flipped cache entry must cost a
 recompile, never a crash. jax's own read path already demotes
@@ -22,23 +29,32 @@ undecodable entries to a miss (``_cache_read`` catches and warns);
 ``verify_cache_dir`` goes further and sweeps the directory at ensure()
 time, deleting entries whose compressed payload no longer decodes and
 counting them via ``compile.cache_corrupt_total`` — so one poisoned
-entry costs exactly one recompile and disappears.
+entry costs exactly one recompile and disappears. (The frames carry no
+content checksum: the sweep catches truncation and damage to a frame's
+structure; a flip inside a literal run still decodes and is left to
+jax's own fallback.)
 
 Hit/miss accounting rides jax's monitoring events
 (``/jax/compilation_cache/cache_hits`` / ``cache_misses``) into both
 mxtel counters (``compile.cache_hits_total`` / ``misses_total``) and
-module-level plain ints readable without telemetry (bench.py's
-cold-start leg reports them from a bare subprocess).
+module-level plain ints readable without telemetry (chip_smoke.py and
+bench.py's cold-start leg report them from a bare process).
 """
 from __future__ import annotations
 
-import hashlib
 import os
 import zlib
 
 from .. import telemetry as _tel
 
-__all__ = ["ensure", "verify_cache_dir", "cache_dir", "stats"]
+__all__ = ["enable", "ensure", "verify_cache_dir", "cache_dir", "stats",
+           "REPO_CACHE_DIR"]
+
+#: where :func:`enable` puts the cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: fixed, inside the checkout, listed in .gitignore
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: process-lifetime counters (mirrors of the mxtel counters; plain ints
 #: so subprocesses can report them without enabling telemetry)
@@ -46,35 +62,27 @@ HITS = 0
 MISSES = 0
 CORRUPT = 0
 
-_configured_dir = None
+_ensured_dir = None
 _listener_on = False
 
 
 def cache_dir():
-    """MXNET_COMPILE_CACHE_DIR, or None (cache off)."""
-    return os.environ.get("MXNET_COMPILE_CACHE_DIR", "").strip() or None
+    """The directory jax's persistent cache is using, or None (off)."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
 
 
-def donation_unsafe():
-    """True when donated executables may load from the persistent cache
-    on the CPU backend. jaxlib 0.4.3x CPU executables deserialized from
-    the cache corrupt the heap when run with donated buffers (verified
-    in this container: the warm-process scanned-fit loop segfaults with
-    `malloc_consolidate(): invalid chunk size`; with donation stripped
-    the same cached executable runs clean — and the bug reproduces with
-    jax's own JAX_COMPILATION_CACHE_DIR env wiring, so it is not this
-    module's doing). Donating entry points (parallel/fit_trainer.py,
-    parallel/symbol_trainer.py) consult this and keep their buffers;
-    TPU backends keep donation (different serialization path, and the
-    HBM headroom matters there)."""
-    if cache_dir() is None:
-        return False
-    try:
+def enable(path=REPO_CACHE_DIR):
+    """Turn the persistent cache on for this process; entry points call
+    it before their first compile. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set jax has already placed the cache and ``path`` is ignored.
+    Returns the active directory."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
         import jax
 
-        return jax.default_backend() == "cpu"
-    except Exception:
-        return False
+        jax.config.update("jax_compilation_cache_dir", path)
+    return ensure()
 
 
 def stats():
@@ -97,13 +105,10 @@ def _register_listener():
     global _listener_on
     if _listener_on:
         return
-    try:
-        from jax._src import monitoring
+    import jax.monitoring
 
-        monitoring.register_event_listener(_on_event)
-        _listener_on = True
-    except Exception:  # monitoring API moved: counters stay at 0, cache
-        pass           # itself still works
+    jax.monitoring.register_event_listener(_on_event)
+    _listener_on = True
 
 
 def _decompress_ok(payload):
@@ -162,51 +167,26 @@ def verify_cache_dir(path):
     return checked, removed
 
 
-def keyed_dir(base, config_key):
-    h = hashlib.sha256(config_key.encode()).hexdigest()[:16]
-    return os.path.join(base, "jit-%s" % h)
-
-
-def ensure(config_key=""):
-    """Idempotently enable the persistent jit cache when
-    MXNET_COMPILE_CACHE_DIR is set. Returns the active entry directory
-    or None. Called from every compile entry point (Executor bind, the
-    scanned trainers, Predictor) — the first caller configures jax,
-    later calls are one string compare."""
-    global _configured_dir
-    base = cache_dir()
-    if base is None:
-        return None
-    target = keyed_dir(base, config_key)
-    if _configured_dir == target:
-        return target
-    os.makedirs(target, exist_ok=True)
-    verify_cache_dir(target)
+def ensure():
+    """Make the most of a persistent cache that is placed (by
+    ``JAX_COMPILATION_CACHE_DIR`` or :func:`enable`): sweep it for
+    corrupt entries, cache small and fast-to-build programs too, count
+    hits and misses. Returns the active directory, or None when the
+    cache is off. Called from every compile entry point (Executor bind,
+    the scanned trainers, Predictor, the serving engine) — after the
+    first call it is one string compare."""
+    global _ensured_dir
+    path = cache_dir()
+    if path is None or path == _ensured_dir:
+        return path
+    os.makedirs(path, exist_ok=True)
+    verify_cache_dir(path)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", target)
     # default thresholds skip exactly the small fast-to-build programs
-    # a cold start is made of; cache everything (each knob guarded: the
-    # spelling differs across jax versions and a missing threshold knob
-    # must degrade to default gating, not crash every bind)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
-    # jax memoizes cache-usability at the FIRST compile of the process
-    # (_cache_checked in compilation_cache.py): any jit dispatched
-    # before this ensure() — an autotuning trial, a warmup program —
-    # would otherwise freeze the cache off for the process lifetime
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass  # private API moved: configuring before first jit still works
+    # a cold start is made of; cache everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _register_listener()
-    _configured_dir = target
-    return target
+    _ensured_dir = path
+    return path

@@ -3,8 +3,9 @@
 Re-design of the reference Context (ref: python/mxnet/context.py:1-126,
 include/mxnet/base.h:85-118). `mx.tpu(i)` slots in alongside `cpu()` per
 SURVEY.md §7 step 1. `gpu(i)` is kept so reference-era scripts run
-unmodified: it resolves to the i-th accelerator device (TPU here), falling
-back to CPU when no accelerator exists.
+unmodified: it resolves to the i-th accelerator device (TPU here). A
+`tpu`/`gpu` context with no accelerator attached raises: code that wants
+"the chip if there is one" asks `num_devices("tpu")` and says so.
 
 Device resolution maps a Context onto a concrete `jax.Device`. Multiple
 `cpu(i)` contexts map onto the virtual CPU devices created by
@@ -76,8 +77,12 @@ class Context:
 
         if self.device_type in ("cpu", "cpu_pinned"):
             devs = _local_cpu_devices()
-        else:  # tpu / gpu -> accelerator backend if present, else cpu fallback
-            devs = _accelerator_devices() or _local_cpu_devices()
+        else:  # tpu / gpu -> the accelerator backend, never the host
+            devs = _accelerator_devices()
+            if not devs:
+                raise MXNetError(
+                    "%s: no accelerator attached (jax.local_devices() = %s)"
+                    % (self, jax.local_devices()))
         if self.device_id >= len(devs):
             raise MXNetError(
                 "%s: device_id %d out of range (%d %s device(s) visible)"
@@ -95,17 +100,26 @@ class Context:
         Context._default.stack.pop()
 
 
+def default_jax_device():
+    """The jax.Device that uncommitted computation lands on: the pinned
+    ``jax_default_device`` (a Device or a platform name; tests pin the
+    CPU while the TPU plugin is loaded), else the default backend's
+    first device."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None or isinstance(dev, str):
+        dev = jax.devices(dev)[0]
+    return dev
+
+
 def _accelerator_devices():
     """Local accelerator devices: under multi-process jax.distributed,
     jax.devices() is global and other processes' chips are not
     addressable — Context device ids index this process's hardware."""
     import jax
 
-    try:
-        devs = jax.local_devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"]
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def _local_cpu_devices():

@@ -210,7 +210,7 @@ class Executor:
             # across rebinds
             self._host_op_cache = {}
 
-        # persistent jit cache (MXNET_COMPILE_CACHE_DIR): compiled
+        # persistent jit cache (JAX_COMPILATION_CACHE_DIR): compiled
         # programs from this bind land on disk and the next process
         # loads them instead of rebuilding — no-op when unconfigured
         _compile.ensure_jit_cache()

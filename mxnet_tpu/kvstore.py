@@ -259,7 +259,16 @@ class KVStore:
                     # another rank owns this key's optimizer update;
                     # its weight arrives in the all-gather below
                     continue
-                self._updater(_key_int(k), merged, self._store[k])
+                stored = self._store[k]
+                if stored.context != merged.context:
+                    # the stored weight follows the merged gradient to
+                    # its device and stays there (ref kvstore_local.h
+                    # Push: "local = local.Copy(merged.ctx())") — init()
+                    # keeps it where the caller's parameters were, the
+                    # host, while gradients are reduced on the first
+                    # pushing device
+                    stored = self._store[k] = stored.copyto(merged.context)
+                self._updater(_key_int(k), merged, stored)
             else:
                 self._store[k] = merged
         if shard:
@@ -1969,15 +1978,7 @@ def _maybe_init_distributed():
 
     # NB: must not touch jax.process_count()/devices() here — that would
     # initialize the local backend and make distributed init impossible.
-    # jax.distributed.is_initialized() only exists on newer jax; on older
-    # releases (0.4.x) the coordination-service client being present is
-    # the same fact — and _coordination_client reads it without touching
-    # the backend.
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        if is_init():
-            return
-    elif _coordination_client() is not None:
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=os.environ.get("MXNET_COORDINATOR", "127.0.0.1:9876"),

@@ -29,6 +29,7 @@ class TransformerConfig:
     use_ring_attention: bool = False
     seq_axis: str = "seq"  # mesh axis for sequence parallelism
     tensor_axis: str = "model"  # mesh axis for tensor parallelism
+    data_axis: str = "data"  # mesh axis the batch is sharded on
 
     @property
     def head_dim(self):
@@ -131,8 +132,38 @@ def _attention(q, k, v, causal=True):
     return flash_attention(q, k, v, causal=causal)
 
 
+def _sharded_attention(mesh, cfg):
+    """``_attention`` run per shard of ``mesh``. XLA cannot partition a
+    Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"),
+    and attention is independent across batch rows and heads, so each
+    device runs the kernel on the rows of its data shard and the heads
+    of its tensor shard; an axis that does not divide its dimension is
+    left replicated."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def attn(q, k, v):
+        def axis(name, dim):
+            if name in mesh.axis_names and dim % mesh.shape[name] == 0:
+                return name
+            return None
+
+        spec = P(axis(cfg.data_axis, q.shape[0]),
+                 axis(cfg.tensor_axis, q.shape[1]), None, None)
+        # check_vma off: the kernel's out_shapes carry no varying-axes
+        # annotation (same as parallel/ulysses.py)
+        return shard_map(
+            functools.partial(_attention, causal=True), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return attn
+
+
 def forward(params, tokens, cfg: TransformerConfig, mesh=None):
-    """tokens [B, T] int32 -> logits [B, T, vocab]."""
+    """tokens [B, T] int32 -> logits [B, T, vocab]. Under a mesh, pass
+    it: attention then runs per shard (``_sharded_attention``), or as
+    ring attention with ``cfg.use_ring_attention``."""
     import jax
     import jax.numpy as jnp
 
@@ -144,6 +175,8 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None):
         from ..parallel.ring_attention import make_ring_attention
 
         attn_fn = make_ring_attention(mesh, seq_axis=cfg.seq_axis, causal=True)
+    elif mesh is not None:
+        attn_fn = _sharded_attention(mesh, cfg)
     else:
         attn_fn = functools.partial(_attention, causal=True)
 
@@ -169,7 +202,8 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None):
 
 def loss_fn(cfg: TransformerConfig, mesh=None):
     """Next-token cross-entropy loss closure for parallel.make_train_step.
-    batch = dict(tokens=[B,T] int32)."""
+    batch = dict(tokens=[B,T] int32). ``mesh``: the mesh the step runs
+    on, if any (see ``forward``)."""
     import jax
     import jax.numpy as jnp
 

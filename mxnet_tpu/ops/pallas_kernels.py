@@ -15,34 +15,59 @@ patterns XLA does not schedule optimally by itself:
   SoftmaxOutput's forward on large vocabularies.
 
 Enable/disable with MXNET_PALLAS=1/0; by default kernels are active only
-when ``jax.default_backend() == 'tpu'``. Off-TPU (tests) the kernels run
-in Pallas interpret mode so CPU CI exercises the same code path.
-Shapes that violate a kernel's constraints silently fall back to the plain
-jnp implementation — same contract as the reference falling back to the
-non-cuDNN path.
+when the default device is a TPU. Off-TPU (tests) the kernels run in
+Pallas interpret mode so CPU CI exercises the same code path; on a TPU
+``interpret`` is never set.
+A shape a kernel cannot take is routed to the plain XLA implementation
+by an explicit rule, and every such routing is counted in ``FALLBACKS``
+(mxtel ``pallas.fallback_total.<kernel>.<reason>``) and logged once per
+distinct shape — same contract as the reference falling back to the
+non-cuDNN path, minus the silence.
 """
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
+from .. import telemetry as _tel
+
 __all__ = ["enabled", "flash_attention", "flash_kernel_usable",
-           "fused_softmax"]
+           "fused_softmax", "FALLBACKS"]
+
+log = logging.getLogger("mxnet_tpu.pallas")
+
+#: Mosaic's default scoped-VMEM limit on v5e ("limit 16.00M" in the
+#: compiler's RESOURCE_EXHAUSTED message). Every operand block of a
+#: pallas_call is double-buffered against it by the pipeline.
+_VMEM_LIMIT = 16 * 1024 * 1024
+
+#: (kernel, reason) -> number of call sites routed to XLA instead of the
+#: kernel. Decisions are made while tracing, so this counts traces, not
+#: executions. Plain ints so a run without telemetry can assert on them
+#: (chip_smoke.py does).
+FALLBACKS = {}
+_logged = set()
+
+
+def _fallback(kernel, reason, shape):
+    """Count one routing of ``kernel`` to its XLA implementation."""
+    FALLBACKS[(kernel, reason)] = FALLBACKS.get((kernel, reason), 0) + 1
+    if _tel.ENABLED:
+        _tel.counter("pallas.fallback_total.%s.%s" % (kernel, reason)).inc()
+    if (kernel, reason, shape) not in _logged:
+        _logged.add((kernel, reason, shape))
+        # with the kernels off (the CPU default) every call lands here
+        level = logging.DEBUG if reason == "disabled" else logging.WARNING
+        log.log(level, "%s%s -> XLA (%s)", kernel, shape, reason)
 
 
 def _on_tpu():
     """True when computation actually lands on TPU: honours the pinned
-    default device (tests pin CPU while the TPU plugin is still loaded,
-    so ``jax.default_backend()`` alone is the wrong signal)."""
-    import jax
+    default device (``jax.default_backend()`` alone is the wrong signal)."""
+    from ..context import default_jax_device
 
-    try:
-        dev = jax.config.jax_default_device
-        if dev is not None:
-            return dev.platform == "tpu"
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax init failure
-        return False
+    return default_jax_device().platform == "tpu"
 
 
 def enabled():
@@ -61,7 +86,7 @@ def _interpret():
 
 def _env_int(name, default):
     """Int env knob; malformed/empty values fall back to the default
-    (the kernels' silent-fallback contract must survive a bad export)."""
+    (a bad export of a probe knob must not take the kernels down)."""
     try:
         v = os.environ.get(name, "")
         return int(v) if v.strip() else default
@@ -271,6 +296,7 @@ def _flash_attention_pallas(q, k, v, causal, scale, block_q, block_k):
             pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j)),
         ),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, tq, v.shape[-1]), lse  # lse: (b*h, 8, tq)
 
@@ -315,6 +341,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse3, dcap)
 
     dk, dv = pl.pallas_call(
@@ -338,6 +365,7 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
             pl.BlockSpec((1, block_k, dv_dim), lambda i, j: (i, j, 0)),
         ),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse3, dcap)
 
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
@@ -413,29 +441,50 @@ def _select_blocks(tq, tk, block_q=None, block_k=None):
     return block_q, block_k, ok
 
 
-def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None):
-    """True iff ``flash_attention`` will take the PALLAS KERNEL path for
-    ``[.., tq, d] x [.., tk, d] -> [.., tk, dv]`` operands: every gate
+def _flash_refusal(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
+    """Why ``flash_attention`` would NOT take the Pallas kernel for these
+    operands (a ``FALLBACKS`` reason), or None when it will: every gate
     the kernel applies — enablement, block-tiling legality, the
-    ``MXNET_FLASH_MIN_T`` crossover, and the per-cell VMEM residency of
-    the full K/V (and Q/dO in the backward). Public so composers
-    (e.g. the Ulysses sequence-parallel local attention) can choose
-    between the kernel and their OWN memory-bounded fallback instead of
-    ever hitting flash_attention's dense O(T^2) fallback."""
-    _, _, tiles = _select_blocks(tq, tk, block_q, block_k)
-    min_t = _env_int("MXNET_FLASH_MIN_T", 0)
-    budget = 8 * 1024 * 1024
-    return (
-        enabled()
-        and tiles
-        # the crossover is a hardware-perf decision; interpret mode
-        # (CPU tests) always takes the kernel path for coverage
-        and (tk >= min_t or _interpret())
-        # full K AND V per head are resident in VMEM per grid cell
-        # (same budget for Q+dO in the dkv backward kernel)
-        and tk * (d + dv) * 4 <= budget
-        and tq * (d + dv) * 4 <= budget
-    )
+    ``MXNET_FLASH_MIN_T`` crossover, and the scoped-VMEM footprint."""
+    if not enabled():
+        return "disabled"
+    block_q, block_k, tiles = _select_blocks(tq, tk, block_q, block_k)
+    if not tiles:
+        return "untileable"
+    # the crossover is a hardware-perf decision; interpret mode
+    # (CPU tests) always takes the kernel path for coverage
+    if tk < _env_int("MXNET_FLASH_MIN_T", 0) and not _interpret():
+        return "below_min_t"
+    # Scoped VMEM of the hungriest of the three kernels. Every operand
+    # block is double-buffered by the pipeline: the full-length operands
+    # (K and V in fwd/dq, Q and dO in dkv), the streamed in/out blocks,
+    # and the lse/dcap stats rows (full length in dkv); on top sit the
+    # body's f32 temporaries, two [block_q, block_k] score tiles and the
+    # accumulators. Fitted to what the chip's compiler reports and
+    # checked against it at T=128..32k, d=64..256, bf16 and f32
+    # (tests/unittest/test_chip_compile.py holds the bench shapes).
+    io = d + dv
+    scores = 2 * block_q * block_k * 4
+    fwd = (2 * tk * io + 2 * block_q * io) * itemsize \
+        + 2 * 8 * block_q * 4 + scores + block_q * dv * 4
+    dq = (2 * tk * io + 2 * block_q * (io + d)) * itemsize \
+        + 2 * 2 * 8 * block_q * 4 + scores + block_q * d * 4
+    dkv = (2 * tq * io + 2 * block_k * 2 * io) * itemsize \
+        + 2 * 2 * 8 * tq * 4 + scores + block_k * io * 4
+    if max(fwd, dq, dkv) > _VMEM_LIMIT - 512 * 1024:
+        return "vmem"
+    return None
+
+
+def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
+                        itemsize=4):
+    """True iff ``flash_attention`` will take the PALLAS KERNEL path for
+    ``[.., tq, d] x [.., tk, d] -> [.., tk, dv]`` operands of
+    ``itemsize`` bytes per element. Public so composers (e.g. the
+    Ulysses sequence-parallel local attention) can choose between the
+    kernel and their OWN memory-bounded fallback instead of ever
+    hitting flash_attention's dense O(T^2) fallback."""
+    return _flash_refusal(tq, tk, d, dv, block_q, block_k, itemsize) is None
 
 
 def flash_attention(q, k, v, causal=True, scale=None,
@@ -454,8 +503,9 @@ def flash_attention(q, k, v, causal=True, scale=None,
     crossover; MXNET_FLASH_DENSE_BWD=1 forces the dense recompute
     backward for A/B probes.
 
-    Falls back to plain XLA when shapes don't tile (time not divisible
-    by block, or kernels disabled).
+    Routed to plain XLA, and counted in ``FALLBACKS``, when the kernels
+    are disabled, the lengths do not tile, or the operands overflow the
+    scoped VMEM (``_flash_refusal`` names which).
 
     Block sizing (measured, docs/perf_analysis.md rounds 4-5): every
     q-block grid cell DMAs the FULL K/V into VMEM, so K/V HBM traffic
@@ -477,9 +527,11 @@ def flash_attention(q, k, v, causal=True, scale=None,
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     tq, tk = q.shape[2], k.shape[2]
     block_q, block_k, _tiles = _select_blocks(tq, tk, block_q, block_k)
-    usable = q.ndim == 4 and flash_kernel_usable(
-        tq, tk, q.shape[-1], v.shape[-1], block_q, block_k)
-    if not usable:
+    refusal = "ndim" if q.ndim != 4 else _flash_refusal(
+        tq, tk, q.shape[-1], v.shape[-1], block_q, block_k,
+        q.dtype.itemsize)
+    if refusal is not None:
+        _fallback("flash_attention", refusal, tuple(q.shape))
         return _attention_reference(q, k, v, causal, scale)
 
     dense_bwd = os.environ.get("MXNET_FLASH_DENSE_BWD", "") == "1"
@@ -528,28 +580,47 @@ def fused_softmax(x):
 
     Pallas analog of the reference's cuDNN softmax fast path
     (ref: src/operator/cudnn_softmax_activation-inl.h). Rows are tiled
-    across the grid; each row block is reduced entirely in VMEM. Falls back
-    to jax.nn.softmax when disabled or when a row would overflow VMEM.
+    across the grid in blocks of a multiple of 8 (the sublane tile; the
+    last block may be ragged — rows are independent, so what the padding
+    holds never reaches a stored row) or in one full-height block; each
+    block is reduced entirely in VMEM. Routed to jax.nn.softmax, and
+    counted in ``FALLBACKS``, when disabled or when 8 rows overflow VMEM.
     """
     import jax
-    import jax.numpy as jnp
 
-    if not (enabled() and x.ndim == 2):
+    if not enabled():
+        _fallback("fused_softmax", "disabled", tuple(x.shape))
+        return jax.nn.softmax(x, axis=-1)
+    if x.ndim != 2:
+        _fallback("fused_softmax", "ndim", tuple(x.shape))
         return jax.nn.softmax(x, axis=-1)
     n, c = x.shape
-    if c * 4 > 4 * 1024 * 1024:  # one f32 row block must fit VMEM
+    # per element of a block: input and output double-buffered by the
+    # pipeline, plus the kernel's f32 working copies (x, exp)
+    per_row = c * (4 * x.dtype.itemsize + 8)
+    block_rows = min(256, (3 * _VMEM_LIMIT // 4) // per_row // 8 * 8)
+    if block_rows == 0:
+        _fallback("fused_softmax", "vmem", tuple(x.shape))
         return jax.nn.softmax(x, axis=-1)
-    block_rows = 256
-    while block_rows > 1 and (n % block_rows != 0 or block_rows * c * 4 > 8 * 1024 * 1024):
-        block_rows //= 2
+    if block_rows >= n:
+        block_rows = n  # one block of the array's full height
 
     from jax.experimental import pallas as pl
 
-    return pl.pallas_call(
+    interpret = _interpret()
+    kernel = pl.pallas_call(
         _softmax_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        grid=(n // block_rows,),
+        grid=(pl.cdiv(n, block_rows),),
         in_specs=[pl.BlockSpec((block_rows, c), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
-        interpret=_interpret(),
-    )(x)
+        interpret=interpret,
+        name="fused_softmax",
+    )
+    if interpret:
+        return kernel(x)
+    # chosen where the program is lowered: on a TPU machine an executor
+    # bound to the host (ctx=mx.cpu(0)) shares this trace, and Mosaic
+    # kernels lower for the TPU only
+    return jax.lax.platform_dependent(
+        x, tpu=kernel, default=lambda x: jax.nn.softmax(x, axis=-1))

@@ -27,29 +27,19 @@ def set_default_devices(devices):
 def mark_varying(x, axis_name):
     """Mark a pytree of arrays device-varying along ``axis_name`` inside a
     shard_map body (loop-carry typing discipline for ppermute/all_to_all
-    results). Prefers ``lax.pcast(..., to='varying')``; falls back to the
-    deprecated ``lax.pvary`` on older jax; no-op when neither exists."""
+    results)."""
     from jax import lax
 
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    return lax.pcast(x, axes, to="varying")
 
 
 def axis_size(axis_name):
-    """Static size of a mapped mesh axis inside a shard_map/pmap body.
-    ``lax.axis_size`` only exists on newer jax; on older releases
-    ``lax.psum(1, axis)`` of a literal constant-folds to the same
-    concrete int (the pre-axis_size idiom), so loop bounds built from it
-    stay static."""
+    """Static size of a mapped mesh axis inside a shard_map/pmap body
+    (a concrete int, so loop bounds built from it stay static)."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def local_devices(platform=None):

@@ -76,11 +76,7 @@ def make_pipeline(mesh, stage_fn, pipe_axis="pipe", n_microbatches=4):
     pipe axis; input [n_microbatches, mb, ...] replicated; output taken
     from the last stage (psum-masked so every host sees it)."""
     import jax
-
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.7 layout
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     stages = mesh.shape[pipe_axis]

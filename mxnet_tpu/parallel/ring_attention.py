@@ -117,13 +117,8 @@ def make_ring_attention(mesh, seq_axis="seq", causal=True, q_offset=0):
     Takes/returns global arrays [B, H, T, D] with T sharded on seq_axis.
     Q and K/V lengths may differ; ``q_offset`` is the queries' absolute
     start position in the key sequence (chunked-prefill reuse)."""
-    import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     spec = P(None, None, seq_axis, None)
 

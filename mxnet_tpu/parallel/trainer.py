@@ -90,8 +90,24 @@ def make_train_step(loss_fn, optimizer=None, mesh=None, param_spec=None,
     def step_fn(params, opt_state, batch, rng):
         return jitted(params, opt_state, _put_batch(batch, batch_spec), rng)
 
+    # the jitted program itself, for callers that lower it ahead of time
+    # to read its HLO or its memory analysis (chip_smoke.py)
+    step_fn.jitted = jitted
+
     def init_state(params):
-        return optimizer.init(params)
+        state = optimizer.init(params)
+        if mesh is None:
+            return state
+        # optax makes its step counters on the default device,
+        # uncommitted, while the step returns them replicated over the
+        # mesh; jit keys its cache on input shardings, so without this
+        # the second call would compile the whole step again
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        replicated = NamedSharding(mesh, P())
+        return jax.tree.map(
+            lambda x: x if x.committed else jax.device_put(x, replicated),
+            state)
 
     return step_fn, init_state
 

@@ -80,7 +80,8 @@ def ulysses_attention(q, k, v, axis_name, causal=True, scale=None,
     tk_global = kl.shape[2]
     if (q_offset == 0 and t_global == tk_global
             and pk.flash_kernel_usable(t_global, tk_global, d,
-                                       vl.shape[-1])):
+                                       vl.shape[-1],
+                                       itemsize=ql.dtype.itemsize)):
         out = pk.flash_attention(ql, kl, vl, causal=causal, scale=scale)
         return heads_to_seq(out.astype(q.dtype))
     # fallback: blockwise over key chunks with the shared flash-style
@@ -122,11 +123,7 @@ def make_ulysses_attention(mesh, seq_axis="seq", causal=True, q_offset=0):
     arrays [batch, heads, seq, d] sharded on the sequence axis, with
     ``q_offset`` placing the query block inside the key sequence."""
     import jax
-
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.7 layout
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     spec = P(None, None, seq_axis, None)
@@ -135,17 +132,10 @@ def make_ulysses_attention(mesh, seq_axis="seq", causal=True, q_offset=0):
         q_offset=q_offset)
     # replication checking off: the Pallas flash kernel's out_shapes
     # carry no varying-axes annotation, which the checker rejects inside
-    # shard_map (jax >= 0.7 spells the knob check_vma, 0.4.x spells it
-    # check_rep and has no pallas replication rule at all); correctness
-    # is pinned by the dense parity + ring cross-check tests instead
-    kw = dict(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    try:
-        mapped = shard_map(fn, check_vma=False, **kw)
-    except TypeError:
-        try:
-            mapped = shard_map(fn, check_rep=False, **kw)
-        except TypeError:  # neither knob: checker not present
-            mapped = shard_map(fn, **kw)
+    # shard_map; correctness is pinned by the dense parity + ring
+    # cross-check tests instead
+    mapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
 
     def apply(q, k, v):
         shard = NamedSharding(mesh, spec)

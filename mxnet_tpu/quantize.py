@@ -341,12 +341,8 @@ def make_quantized_allreduce(mesh, axis, nper, block=None, stochastic=False):
     (the XLA int8 leg) and available to multi-process dist stores."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:  # jax 0.4.x spelling
-        from jax.experimental.shard_map import shard_map
 
     blk = block_size() if block is None else int(block)
     n = mesh.shape[axis]

@@ -31,8 +31,9 @@ Example::
 The body executes with ``pl``(jax.experimental.pallas), ``pltpu``, ``jnp``,
 ``lax``, and ``jax`` in scope. A Python callable ``kernel(in_refs...,
 out_refs...)`` is also accepted in place of source. Off-TPU the kernel runs
-in Pallas interpret mode so the same user code is testable on CPU — same
-contract as the rest of mxnet_tpu's Pallas fast paths.
+in Pallas interpret mode so the same user code is testable on CPU; with a
+TPU as the default device it is compiled by Mosaic, never interpreted —
+same contract as the rest of mxnet_tpu's Pallas fast paths.
 """
 from __future__ import annotations
 
@@ -57,14 +58,9 @@ def _decorate(name, in_names, out_names, body):
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    scope = {"jax": jax, "jnp": jnp, "lax": lax, "pl": pl}
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        scope["pltpu"] = pltpu
-    except ImportError:  # pragma: no cover - pallas tpu always present
-        pass
+    scope = {"jax": jax, "jnp": jnp, "lax": lax, "pl": pl, "pltpu": pltpu}
     ns = {}
     exec(compile(src, "<mxrtc:%s>" % name, "exec"), scope, ns)
     return ns[name]

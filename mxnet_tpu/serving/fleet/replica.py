@@ -254,10 +254,13 @@ def main(argv=None):
 
     _tel.server.mark_ready(False, "starting")
     host, _, port = args.bind.rpartition(":")
+    from ...compile import jit_cache
+
+    jit_cache.enable()
     eng = _build_demo_engine(args.seed)
-    # warm the jit programs BEFORE advertising ready: with a shared
-    # MXNET_COMPILE_CACHE_DIR a respawned replica comes back warm, the
-    # property the scale-up chaos leg measures
+    # warm the jit programs BEFORE advertising ready: the fleet shares
+    # one persistent jit cache, so a respawned replica comes back warm,
+    # the property the scale-up chaos leg measures
     eng.generate([np.arange(5, dtype=np.int32),
                   np.arange(23, dtype=np.int32)], max_new_tokens=3)
     eng.start()

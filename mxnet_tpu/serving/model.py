@@ -31,7 +31,7 @@ bucket, chunk lengths pad to the next chunk bucket, and the block-table
 width is a compile-time constant — so the number of distinct XLA
 programs is bounded by ``len(kinds) x len(batch_buckets) x
 len(chunk_buckets)`` and warm across processes via the PR 6 persistent
-jit cache (``MXNET_COMPILE_CACHE_DIR``); the jit/prof cache keys fold
+jit cache (``JAX_COMPILATION_CACHE_DIR``); the jit/prof cache keys fold
 the program KIND alongside the bucket, so a verify program can never
 alias a plain step at the same shapes. Padded lanes redirect their K/V
 writes to the pool's scratch block 0 and are masked out of attention
@@ -378,17 +378,11 @@ class ServingModel:
         if fn is None:
             import jax
 
-            from ..compile import jit_cache
-
             impl = getattr(self, self._KIND_IMPLS[key[0]])
             if key[0] == "draft_turn":
                 impl = functools.partial(impl, K=key[3])
-            # pools are donated on TPU; jaxlib 0.4.3x CPU executables
-            # deserialized from the persistent cache corrupt the heap
-            # under donation (jit_cache.donation_unsafe, PR 6) — keep
-            # the buffers there
-            donate = () if jit_cache.donation_unsafe() else (1, 2)
-            fn = jax.jit(impl, donate_argnums=donate)
+            # the K/V pools are donated: the step updates them in place
+            fn = jax.jit(impl, donate_argnums=(1, 2))
             # one compile per memo entry — the key IS the bucket; a
             # second compile behind the same key is a broken contract
             # the verifier names by arg-diff (MXNET_JIT_VERIFY)

@@ -18,9 +18,8 @@ Three views, all keyed consistently:
    bytes — the program's static HBM footprint), and returns the
    compiled callable so the attribution compile IS the program's one
    compile (no double build). Records are keyed
-   ``<compile.config_key()>|<site signature>`` — the same configuration
-   key the PR 6 persistent jit cache dirs hash, so a program's cost
-   record and its cache entry describe the same executable.
+   ``<compile.config_key()>|<site signature>``, so records taken under
+   different rewrite-pass configurations never alias.
 
 2. **Analytic graph cost.** :func:`graph_cost` walks a Symbol DAG with
    the jax-free IR utilities (``compile/ir.py``: shape/dtype sweeps)
@@ -44,7 +43,9 @@ Derived headline metrics — MFU against the chip's bf16 peak and
 roofline% against the HBM-bandwidth bound, the derivations bench.py and
 bench_lm.py previously hard-coded — live here (:func:`peak_flops`,
 :func:`hbm_gbps`, :func:`derived`) so `/profilez`, the bench legs and
-``tools/perf_gate.py`` all share one definition.
+``tools/perf_gate.py`` all share one definition. The peaks come from
+one table keyed by ``device_kind`` (:data:`PEAKS`); a device that is not
+in it (the CPU included) has no peak, and no MFU/roofline is derived.
 
 Enablement::
 
@@ -68,18 +69,20 @@ __all__ = [
     "graph_cost", "attribute_jit", "program_records",
     "note_step", "step_summary",
     "peak_flops", "hbm_gbps", "hbm_stats", "derived", "snapshot",
-    "DEFAULT_PEAK_BF16", "DEFAULT_HBM_GBPS", "ROOFLINE_IMG_S",
+    "PEAKS", "ROOFLINE_IMG_S",
     "PHASES",
 ]
 
 log = logging.getLogger("mxnet_tpu.prof")
 
-#: v5e chip bf16 peak (docs/perf_analysis.md) — the MFU denominator
-#: bench_lm.py has always used, promoted here so every consumer shares
-#: one number.
-DEFAULT_PEAK_BF16 = 197e12
-#: v5e HBM bandwidth (GB/s) — the roofline denominator.
-DEFAULT_HBM_GBPS = 819.0
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``:
+#: ``(bf16 FLOP/s, HBM GB/s)`` — the MFU and roofline denominators every
+#: consumer shares. Source: Google Cloud documentation, "TPU v5e"
+#: (197 TFLOP/s bf16, 819 GB/s HBM); a v5e chip reports itself as
+#: "TPU v5 lite". An unlisted kind has no peak (see peak_flops).
+PEAKS = {
+    "TPU v5 lite": (197e12, 819.0),
+}
 #: ResNet-50 bs=128 bf16 HBM roofline on one v5e chip: ~190 MB of
 #: activation traffic per image at 819 GB/s ≈ 3,400 img/s at perfect
 #: overlap (docs/perf_analysis.md "Roofline") — bench.py's derivation.
@@ -154,30 +157,33 @@ def reset():
 
 
 # -- derived-metric constants -------------------------------------------------
-def peak_flops():
-    """The chip's peak FLOP/s for MFU derivation:
-    ``MXNET_PROF_PEAK_FLOPS`` override, else the v5e bf16 peak. On a
-    CPU container the default is aspirational — the derived MFU is then
-    a consistency signal (did it regress), not an absolute one."""
-    raw = os.environ.get("MXNET_PROF_PEAK_FLOPS", "").strip()
+def _peak(index, env):
+    """One column of PEAKS for the default device, unless ``env``
+    overrides it; None when the device's kind is not listed."""
+    raw = os.environ.get(env, "").strip()
     if raw:
         try:
             return float(raw)
         except ValueError:
             pass
-    return DEFAULT_PEAK_BF16
+    from ..context import default_jax_device
+
+    row = PEAKS.get(default_jax_device().device_kind)
+    return None if row is None else row[index]
+
+
+def peak_flops():
+    """The default device's peak bf16 FLOP/s for MFU derivation:
+    ``MXNET_PROF_PEAK_FLOPS`` override, else its PEAKS row, else None —
+    an unlisted device (a CPU, a chip nobody entered) gets no MFU
+    rather than one measured against another chip's peak."""
+    return _peak(0, "MXNET_PROF_PEAK_FLOPS")
 
 
 def hbm_gbps():
     """HBM bandwidth (GB/s) for roofline%: ``MXNET_PROF_HBM_GBPS``
-    override, else the v5e figure."""
-    raw = os.environ.get("MXNET_PROF_HBM_GBPS", "").strip()
-    if raw:
-        try:
-            return float(raw)
-        except ValueError:
-            pass
-    return DEFAULT_HBM_GBPS
+    override, else the device's PEAKS row, else None."""
+    return _peak(1, "MXNET_PROF_HBM_GBPS")
 
 
 # -- analytic graph cost ------------------------------------------------------
@@ -656,20 +662,21 @@ def derived():
         if r.get("bytes_accessed"):
             bytes_done += r["bytes_accessed"] * calls
         dev_secs += ds
+    peak, hbm = peak_flops(), hbm_gbps()
     out = {
-        "peak_flops": peak_flops(),
-        "hbm_gbps": hbm_gbps(),
+        "peak_flops": peak,
+        "hbm_gbps": hbm,
         "roofline_img_s": ROOFLINE_IMG_S,
         "device_secs": dev_secs,
         "mfu": None,
         "roofline_pct": None,
     }
     if dev_secs > 0 and flops_done > 0:
-        out["mfu"] = flops_done / dev_secs / peak_flops()
         out["tflops"] = flops_done / dev_secs / 1e12
-    if dev_secs > 0 and bytes_done > 0:
-        out["roofline_pct"] = (100.0 * bytes_done / dev_secs
-                               / (hbm_gbps() * 1e9))
+        if peak:
+            out["mfu"] = flops_done / dev_secs / peak
+    if dev_secs > 0 and bytes_done > 0 and hbm:
+        out["roofline_pct"] = 100.0 * bytes_done / dev_secs / (hbm * 1e9)
     return out
 
 

@@ -283,7 +283,7 @@ def _statusz(params):
         "server_uptime_s": (time.time() - _started_t
                             if _started_t is not None else None),
         "journal": _journal_path(),
-        "jit_cache_dir": os.environ.get("MXNET_COMPILE_CACHE_DIR") or None,
+        "jit_cache_dir": jc.cache_dir() if jc is not None else None,
         "compile": compile_counters,
         "jit_verify": jit_verify,
         "env": {k: v for k, v in sorted(os.environ.items())

@@ -1,0 +1,145 @@
+"""The main path's Pallas kernels must compile for the chip, not only run
+under the interpreter: each is AOT-compiled here, with no chip attached,
+by the TPU compiler for a described ``v5e:2x2`` topology. Interpret mode
+accepted a block of 4 rows and a 31 MiB VMEM footprint; Mosaic refuses
+both (PR 22).
+
+One file, on purpose: only one process at a time may hold the TPU
+library, so the topology is described inside a module-scoped fixture (a
+worker that is not handed this file never loads it) and every compile
+runs in the test's own process.
+"""
+import functools
+
+import pytest
+
+#: [batch, heads, T, head_dim] of the LM bench at T=1024 and T=8192
+FLASH_SHAPES = [(16, 8, 1024, 128), (2, 8, 8192, 128)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_chip(monkeypatch):
+    """Kernels on, interpret mode off (the code under test asks the
+    default device, which is the CPU here), and the persistent cache off
+    around the compiles: an entry written for a described chip cannot be
+    read back without one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _kernels(fn, *shapes):
+    """Compile ``fn`` for the described chip; the kernel-carrying lines
+    of its HLO."""
+    import jax
+
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["T1024", "T8192"])
+def test_flash_forward_compiles(one_chip, shape):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    routed = dict(pk.FALLBACKS)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    calls = _kernels(functools.partial(pk.flash_attention, causal=True),
+                     q, q, q)
+    assert len(calls) == 1 and "flash_fwd" in calls[0]
+    assert pk.FALLBACKS == routed  # nothing was routed to XLA
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=["T1024", "T8192"])
+def test_flash_backward_compiles(one_chip, shape):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert len(calls) == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(name + ")" in ln for ln in calls) == 1, name
+
+
+def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
+    """f32 operands at T=8192, d=128 passed the old dtype-blind 8 MB rule
+    and then failed in Mosaic ("Scoped allocation ... 18.06M and limit
+    16.00M"); the rule now routes them to XLA, and counts it."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    assert pk.flash_kernel_usable(8192, 8192, 128, 128, itemsize=2)
+    assert not pk.flash_kernel_usable(8192, 8192, 128, 128, itemsize=4)
+    before = pk.FALLBACKS.get(("flash_attention", "vmem"), 0)
+    q = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.float32,
+                             sharding=one_chip)
+    calls = _kernels(functools.partial(pk.flash_attention, causal=True),
+                     q, q, q)
+    assert calls == []
+    assert pk.FALLBACKS[("flash_attention", "vmem")] == before + 1
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((128, 1000), "float32"),      # ResNet-50 head, rows a multiple of 8
+    ((100, 1000), "float32"),      # the reference examples' batch of 100
+    ((100, 1000), "bfloat16"),
+    ((16384, 32000), "float32"),   # the LM's [B*T, vocab]
+])
+def test_fused_softmax_compiles(one_chip, shape, dtype):
+    import jax
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    calls = _kernels(pk.fused_softmax, x)
+    assert len(calls) == 1 and "fused_softmax" in calls[0]
+
+
+def test_rtc_kernel_compiles(one_chip):
+    """A user kernel through mx.rtc.Rtc lowers without interpret mode."""
+    import jax
+
+    import mxnet_tpu as mx
+
+    x = mx.nd.zeros((256, 512))
+    k = mx.rtc.Rtc("axpy", [("x", x)], [("y", x)],
+                   "y[...] = x[...] * 2.0 + 1.0")
+    spec = ((128, 512), lambda i: (i, 0))
+    prog = k._compile((2,), ((spec,), (spec,)))
+    shape = jax.ShapeDtypeStruct((256, 512), "float32", sharding=one_chip)
+    text = prog.lower(shape).compile().as_text()
+    assert "tpu_custom_call" in text

@@ -42,16 +42,21 @@ def compile_on(monkeypatch):
 
 
 @pytest.fixture()
-def jit_cache_isolated():
-    """Undo the process-global jax cache-dir config a test installs."""
+def jit_cache_isolated(monkeypatch):
+    """The suite runs with the persistent cache off (conftest.py); let a
+    test place one, and undo the process-global jax config after it.
+    jax decides at a process's first compile whether it caches, so the
+    decision is reset on both sides of the test."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cc.reset_cache()
     yield
     import jax
 
     jax.config.update("jax_compilation_cache_dir", None)
-    jit_cache._configured_dir = None
-    from jax._src import compilation_cache as _cc
-
-    _cc.reset_cache()
+    jit_cache._ensured_dir = None
+    cc.reset_cache()
 
 
 def _chain_sym():
@@ -469,9 +474,9 @@ def test_conv_layout_tuning_on_device(tmp_path):
 
 def test_jit_cache_populates_and_bitflip_falls_back(
         tmp_path, monkeypatch, jit_cache_isolated):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
-    target = mxc.ensure_jit_cache()
-    assert target is not None and os.path.isdir(target)
+    assert mxc.ensure_jit_cache() is None  # nothing placed: cache off
+    target = jit_cache.enable(str(tmp_path))
+    assert target == str(tmp_path) == mxc.ensure_jit_cache()
     import jax
     import jax.numpy as jnp
 
@@ -479,10 +484,13 @@ def test_jit_cache_populates_and_bitflip_falls_back(
     r0 = np.asarray(jax.jit(lambda v: jnp.sin(v) @ v.T)(x))
     entries = [f for f in os.listdir(target) if f.endswith("-cache")]
     assert entries, "no cache entries written"
-    # flip one byte in the middle of an entry
+    # flip one byte of an entry's frame header: jax writes zstd frames
+    # without a content checksum, so only damage to the frame's own
+    # structure is certain to stop it decoding (a flip inside a literal
+    # run decodes, and is left to jax's deserialization fallback)
     victim = os.path.join(target, entries[0])
     raw = bytearray(open(victim, "rb").read())
-    raw[len(raw) // 2] ^= 0xFF
+    raw[0] ^= 0xFF
     open(victim, "wb").write(bytes(raw))
     before = jit_cache.CORRUPT
     checked, removed = jit_cache.verify_cache_dir(target)
@@ -495,14 +503,23 @@ def test_jit_cache_populates_and_bitflip_falls_back(
     assert np.array_equal(r0, r1)
 
 
-def test_jit_cache_keyed_by_pass_config(tmp_path, monkeypatch,
-                                        jit_cache_isolated):
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path))
-    d0 = mxc.ensure_jit_cache()
+def test_jit_cache_placed_by_jax_env_var_stays_there(
+        tmp_path, monkeypatch, jit_cache_isolated):
+    """JAX_COMPILATION_CACHE_DIR wins: where jax's own wiring placed the
+    cache, enable() moves nothing and no sub-directory is keyed under
+    it (the path is part of the cache key)."""
+    import jax
+
+    placed, other = tmp_path / "placed", tmp_path / "other"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    # what jax's flag wiring did when it was imported with the variable
+    jax.config.update("jax_compilation_cache_dir", str(placed))
+    assert jit_cache.enable(str(other)) == str(placed)
+    assert jit_cache.cache_dir() == str(placed)
     monkeypatch.setenv("MXNET_COMPILE_OPT", "1")
     mxc.reload()
-    d1 = mxc.ensure_jit_cache()
-    assert d0 != d1  # executables never shared across configurations
+    assert mxc.ensure_jit_cache() == str(placed)
+    assert os.listdir(str(placed)) == [] and not other.exists()
 
 
 _CHILD = r"""
@@ -524,7 +541,7 @@ def test_cold_start_cache_hits_across_processes(tmp_path):
     """The acceptance probe: a second process binding the same model
     with the same cache dir must HIT (compile.cache_hits_total > 0) —
     cold-start jit builds survive process restarts."""
-    env = dict(os.environ, MXNET_COMPILE_CACHE_DIR=str(tmp_path),
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
                MXNET_COMPILE_OPT="1",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
     env.pop("MXNET_ENGINE_VERIFY", None)

@@ -86,6 +86,31 @@ def test_optimizer_on_kvstore():
     check_diff_to_scalar(out, 1.0)
 
 
+@pytest.mark.parametrize("kv_type", ["local", "device"])
+def test_update_on_kvstore_with_no_context_on_the_host_device(kv_type):
+    """Data parallelism over accelerator contexts: the store is
+    initialised from host-resident parameters while every gradient lives
+    on a device, so none of the pushing contexts is the store's own.
+    The stored weight must follow the merged gradient (ref
+    kvstore_local.h Push) — with cpu(0) among the contexts, as in every
+    other test here, the mismatch never shows; on four real chips it
+    stopped Module.fit at the first update (PR 22)."""
+    kv = mx.kvstore.create(kv_type)
+    kv.init(3, mx.nd.ones(SHAPE))  # on cpu(0), where arg_params live
+    kv.set_optimizer(mx.optimizer.SGD(learning_rate=0.1, momentum=0.9))
+    devs = [mx.cpu(1), mx.cpu(2)]
+    grads = [mx.nd.ones(SHAPE, d) for d in devs]
+    outs = [mx.nd.zeros(SHAPE, d) for d in devs]
+    kv.push(3, grads)
+    kv.pull(3, out=outs)
+    for o in outs:
+        check_diff_to_scalar(o, 1 - 0.1 * 2)
+    kv.push(3, grads)  # momentum state was made beside the moved weight
+    kv.pull(3, out=outs)
+    for o in outs:
+        np.testing.assert_allclose(o.asnumpy(), 0.8 - 0.38, rtol=1e-6)
+
+
 def test_dist_sync_arithmetic_single_process():
     """The dist_sync acceptance arithmetic (ref:
     tests/nightly/dist_sync_kvstore.py:30-40) degenerated to 1 worker:

@@ -120,6 +120,27 @@ class TestOffByDefault:
         assert prof.step_summary() == {}
 
 
+def test_unlisted_device_kind_has_no_peak(monkeypatch):
+    """Peaks come from prof.PEAKS keyed by device_kind: the CPU is not
+    in it, so no MFU/roofline is derived against another chip's peak;
+    the env overrides still name one."""
+    monkeypatch.delenv("MXNET_PROF_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("MXNET_PROF_HBM_GBPS", raising=False)
+    assert prof.PEAKS["TPU v5 lite"] == (197e12, 819.0)
+    assert prof.peak_flops() is None and prof.hbm_gbps() is None
+    monkeypatch.setitem(prof._programs, "k", {
+        "calls": 2, "device_secs": 1.0, "flops": 1e9,
+        "bytes_accessed": 1e6})
+    d = prof.derived()
+    assert d["tflops"] == pytest.approx(2e-3)
+    assert d["mfu"] is None and d["roofline_pct"] is None
+    monkeypatch.setenv("MXNET_PROF_PEAK_FLOPS", "2e10")
+    monkeypatch.setenv("MXNET_PROF_HBM_GBPS", "1")
+    d = prof.derived()
+    assert d["mfu"] == pytest.approx(0.1)
+    assert d["roofline_pct"] == pytest.approx(0.2)
+
+
 # -- analytic cost model -------------------------------------------------------
 class TestGraphCost:
     def test_mlp_flops_exact(self):
@@ -216,6 +237,8 @@ class TestGraphCost:
 class TestStepBreakdown:
     def test_scanned_fit_records(self, monkeypatch, tmp_path):
         journal = tmp_path / "run.jsonl"
+        # the CPU has no row in prof.PEAKS: name a peak, or no MFU gauge
+        monkeypatch.setenv("MXNET_PROF_PEAK_FLOPS", "1.97e14")
         _enable(monkeypatch, journal=journal)
         model, train = _fit()
         model.fit(X=train, kvstore=None)
@@ -309,6 +332,7 @@ class TestProfilez:
         """The acceptance scrape: during a FeedForward.fit, /profilez
         serves per-program cost/memory attribution and the derived
         MFU/roofline fields."""
+        monkeypatch.setenv("MXNET_PROF_PEAK_FLOPS", "1.97e14")
         _enable(monkeypatch, http="0")
         seen = {}
 
@@ -409,6 +433,7 @@ class TestPerfGate:
         """End to end on a REAL fit journal: derive → write baseline →
         gate the same journal → pass (the clean-run acceptance leg)."""
         journal = tmp_path / "run.jsonl"
+        monkeypatch.setenv("MXNET_PROF_PEAK_FLOPS", "1.97e14")
         _enable(monkeypatch, journal=journal)
         model, train = _fit()
         model.fit(X=train, kvstore=None)

@@ -160,6 +160,18 @@ def test_cross_context_op_faults():
         _ = a + b
 
 
+@pytest.mark.parametrize("make", [mx.tpu, mx.gpu], ids=["tpu", "gpu"])
+def test_accelerator_context_without_accelerator_raises(make):
+    """An accelerator context never quietly means the CPU: with no chip
+    attached (this suite) resolving or using one raises, naming what jax
+    found."""
+    assert mx.num_devices("tpu") == 0
+    with pytest.raises(mx.MXNetError, match="no accelerator attached"):
+        make(0).jax_device
+    with pytest.raises(mx.MXNetError, match="no accelerator attached"):
+        mx.nd.zeros((2,), ctx=make(0))
+
+
 def test_reshape_broadcast():
     a = mx.nd.arange(0, 12).reshape((3, 4))
     assert a.shape == (3, 4)

@@ -185,17 +185,48 @@ def test_flash_attention_fallback_odd_shapes():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_fused_softmax_matches_jax():
+@pytest.mark.parametrize("shape", [
+    (64, 1000),    # one full-height block
+    (100, 1000),   # rows not a multiple of 8 (the examples' batch of 100)
+    (300, 129),    # several row blocks, the last one ragged
+    (7, 10),
+])
+def test_fused_softmax_matches_jax(shape):
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
     rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(64, 1000) * 3, jnp.float32)
+    x = jnp.asarray(rng.randn(*shape) * 3, jnp.float32)
+    routed = dict(pk.FALLBACKS)
     out = pk.fused_softmax(x)
+    assert pk.FALLBACKS == routed  # the kernel took it
     ref = jax.nn.softmax(x, axis=-1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_kernel_fallbacks_are_counted(monkeypatch):
+    """A shape a kernel cannot take goes to XLA by rule, and is counted
+    by (kernel, reason) — never in silence."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def routed(fn, *args):
+        before = dict(pk.FALLBACKS)
+        fn(*args)
+        return {k: v - before.get(k, 0) for k, v in pk.FALLBACKS.items()
+                if v != before.get(k, 0)}
+
+    q = jnp.zeros((1, 1, 37, 16), jnp.float32)  # 37 does not tile
+    assert routed(pk.flash_attention, q, q, q) == {
+        ("flash_attention", "untileable"): 1}
+    wide = jnp.zeros((8, 200192), jnp.float32)  # 8 rows overflow VMEM
+    assert routed(pk.fused_softmax, wide) == {("fused_softmax", "vmem"): 1}
+    monkeypatch.setenv("MXNET_PALLAS", "0")
+    assert routed(pk.fused_softmax, jnp.zeros((8, 16))) == {
+        ("fused_softmax", "disabled"): 1}
 
 
 def test_softmax_output_op_under_pallas():

@@ -234,7 +234,7 @@ def test_transformer_train_step_dp_tp():
         is_leaf=lambda x: hasattr(x, "shape"),
     )
     step, init = make_train_step(
-        tfm.loss_fn(cfg), optax.adam(1e-3), mesh=mesh,
+        tfm.loss_fn(cfg, mesh=mesh), optax.adam(1e-3), mesh=mesh,
         batch_spec={"tokens": NamedSharding(mesh, P("data", None))},
         donate=False,
     )

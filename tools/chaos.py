@@ -1618,7 +1618,7 @@ def _controller_env(scratch, tag, extra):
         "MXCTL_REPLICA_LOG": os.path.join(scratch, tag + "-{name}.log"),
         # respawns must come back warm: a shared persistent jit cache
         # is what makes restart-recovery fast enough to matter
-        "MXNET_COMPILE_CACHE_DIR": os.path.join(scratch, "jit-cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(scratch, "jit-cache"),
     })
     for k in list(env):
         if k.startswith("MXCTL_") and k not in ("MXCTL_STATE",):
@@ -2428,7 +2428,7 @@ def run_fleet(args):
         "MXNET_TELEMETRY": "1",
         "MXNET_TELEMETRY_JOURNAL": journal,
         "PYTHONPATH": REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        "MXNET_COMPILE_CACHE_DIR": os.path.join(scratch, "jit-cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(scratch, "jit-cache"),
     })
     if REPO not in sys.path:
         sys.path.insert(0, REPO)

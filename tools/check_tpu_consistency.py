@@ -8,7 +8,9 @@ TPU-vs-CPU parity). Binds the same symbols under cpu(0) and tpu(0) and
 asserts outputs and gradients agree within per-dtype tolerance.
 
 Run on a machine with a TPU attached:  python tools/check_tpu_consistency.py
-Exits nonzero on any mismatch; prints one line per case.
+Exits nonzero on any mismatch, and with no TPU attached (``mx.tpu(0)``
+raises); prints one line per case. ``chip_smoke.py`` runs the same case
+list as its first phase.
 """
 from __future__ import annotations
 
@@ -42,23 +44,32 @@ def cases():
         data=data, kernel=(4, 4), stride=(2, 2), pad=(1, 1), num_filter=4,
         name="op"), {"data": (2, 3, 8, 8)})
     yield ("act-chain", sym.Activation(sym.exp(data * 0.1), act_type="tanh"), {"data": (8, 8)})
+    # every Symbol model's head: rows a multiple of 8, and the reference
+    # examples' batch of 100, which is not (the fused_softmax kernel)
+    for batch in (128, 100):
+        yield ("SoftmaxOutput-%dx1000" % batch,
+               sym.SoftmaxOutput(data=data, name="op"),
+               {"data": (batch, 1000), "op_label": (batch,)})
 
 
-def main():
-    if mx.num_devices("tpu") == 0:
-        print("no TPU visible; nothing to check")
-        return 0
-    ctx_list = [{"ctx": mx.cpu(0)}, {"ctx": mx.tpu(0)}]
-    failures = 0
+def run_cases(ctx_list):
+    """check_consistency over every case; returns the failures as
+    ``[(name, exception), ...]`` and prints one line per case."""
+    failures = []
     for name, s, shapes in cases():
         try:
             check_consistency(
                 s, [dict(c, **shapes) for c in ctx_list], grad_req="write")
-            print("%-20s OK" % name)
-        except Exception as e:  # report all, fail at end
-            failures += 1
-            print("%-20s FAIL: %s" % (name, e))
-    return 1 if failures else 0
+            print("%-22s OK" % name)
+        except Exception as e:  # report every case, fail at the end
+            failures.append((name, e))
+            print("%-22s FAIL: %s" % (name, e))
+    return failures
+
+
+def main():
+    mx.tpu(0).jax_device  # raises at once with no chip: nothing to compare
+    return 1 if run_cases([{"ctx": mx.cpu(0)}, {"ctx": mx.tpu(0)}]) else 0
 
 
 if __name__ == "__main__":

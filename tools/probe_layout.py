@@ -167,6 +167,7 @@ def bench_symbol_variant(name, compile_on, batch=128, steps=10, warmup=2,
 
     import optax
 
+    import mxnet_tpu as mx
     import mxnet_tpu.compile as mxc
     from mxnet_tpu.models import get_resnet
     from mxnet_tpu.parallel.symbol_trainer import make_symbol_train_step
@@ -187,7 +188,7 @@ def bench_symbol_variant(name, compile_on, batch=128, steps=10, warmup=2,
             input_shapes={"data": (batch, 3, image, image),
                           "softmax_label": (batch,)},
             optimizer=optax.sgd(0.05, momentum=0.9),
-            compute_dtype="bfloat16",
+            compute_dtype="bfloat16", ctx=mx.tpu(0),
         )
         rng = np.random.RandomState(0)
         batch_vals = {
