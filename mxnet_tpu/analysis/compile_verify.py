@@ -122,12 +122,17 @@ def reset():
         _DIVERT = None
 
 
-def _counter(name):
+def _count(name, n=1):
+    """Bump a telemetry counter, under telemetry's own switch: with
+    ``telemetry.ENABLED`` false the verifier registers and bumps nothing
+    (its own ledgers, ``unexpected()`` / ``d2h_violations()``, do not
+    depend on it). ``n=0`` registers the counter at nought."""
     # mxtel-metrics: compile.recompiles_total jit.verify_compiles_total
     # mxtel-metrics: jit.verify_recompiles_total jit.verify_d2h_bytes_total
     # mxtel-metrics: jit.verify_d2h_violations_total
     from .. import telemetry as _tel
-    return _tel.counter(name)
+    if _tel.ENABLED:
+        _tel.counter(name).inc(n)
 
 
 def _journal(record):
@@ -270,15 +275,15 @@ class Boundary:
 
     def _on_compile(self, sig):
         self.compiles += 1
-        _counter("jit.verify_compiles_total").inc()
+        _count("jit.verify_compiles_total")
         if self.group is not None:
             with _lock:
                 _GROUP_COMPILES[self.group] = \
                     _GROUP_COMPILES.get(self.group, 0) + 1
         if self.compiles <= self.budget:
             return
-        _counter("compile.recompiles_total").inc()
-        _counter("jit.verify_recompiles_total").inc()
+        _count("compile.recompiles_total")
+        _count("jit.verify_recompiles_total")
         ref = _closest(self.sigs[:-1] if self.sigs
                        and self.sigs[-1] == sig else self.sigs, sig)
         diff = _sig_diff(ref, sig) if ref is not None else \
@@ -308,7 +313,7 @@ def wrap(name, fn, budget=1, group=None):
     # register the headline counter up front: a clean verified run then
     # journals an explicit compile.recompiles_total=0 snapshot, which is
     # what tools/baselines/jit_compile.json holds the line against
-    _counter("compile.recompiles_total")
+    _count("compile.recompiles_total", 0)
     b = Boundary(name, fn, budget, group)
     with _lock:
         _BOUNDARIES.append(b)
@@ -376,7 +381,7 @@ def d2h_region(name, budget_bytes=None):
     finally:
         stack.pop()
         if budget_bytes is not None and rec["bytes"] > budget_bytes:
-            _counter("jit.verify_d2h_violations_total").inc()
+            _count("jit.verify_d2h_violations_total")
             v = {"region": name, "bytes": rec["bytes"],
                  "budget_bytes": budget_bytes,
                  "sites": dict(rec["sites"])}
@@ -396,7 +401,7 @@ def note_d2h(nbytes, site):
     if not ENABLED:
         return
     nbytes = int(nbytes)
-    _counter("jit.verify_d2h_bytes_total").inc(nbytes)
+    _count("jit.verify_d2h_bytes_total", nbytes)
     stack = getattr(_tls, "stack", None)
     if stack:
         rec = stack[-1]

@@ -116,7 +116,19 @@ class TraceOp:
 class EngineTrace:
     """Append-only record of pushes / deletes / waits, with one shared
     monotonic seq so the three streams interleave deterministically.
-    Thread-safe: the engine records from pushing threads and workers."""
+    Thread-safe: the engine records from pushing threads and workers.
+
+    Safe to enter from a finalizer. A ``__del__`` runs on whatever
+    thread allocates next (the cyclic collector), so also on the thread
+    that is inside this trace's critical section, holding ``_lock``. No
+    builder blocks there: entered again on that thread it queues its
+    record, and the outer call stamps and appends the queue after its
+    own record, before it lets go — each record whole, with a seq of
+    its own, in order (``_record``; an op pushed that way reads seq 0
+    until then). What this cannot cover is a finalizer that WAITS for
+    another thread which records (that thread queues behind ``_lock``
+    like any other): the per-test time limit in tests/conftest.py
+    bounds that one."""
 
     def __init__(self):
         self.events = []    # [TraceOp]
@@ -127,7 +139,9 @@ class EngineTrace:
         self.lock_events = []   # [(seq, thread_id, name, 'acquire'|'release')]
         self.lock_edges = {}
         self._held = {}         # thread_id -> [lock name] stack
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
+        self._busy = False      # a thread is committing in _record()
+        self._nested = []       # records queued by finalizers run there
         self._seq = 0
         self._tls = threading.local()
         # live-verify progress, owned by the engine that records into
@@ -140,12 +154,40 @@ class EngineTrace:
         self._seq += 1
         return self._seq
 
+    def _record(self, commit):
+        """Run ``commit(seq)`` under ``_lock`` with the next seq.
+        ``_lock`` is re-entrant, so a finalizer run on the thread that
+        is in here gets in, finds the trace ``_busy`` and queues its
+        record; the outer call commits the queue after its own record,
+        on its way out. Nothing blocks, and no record lands in the
+        middle of another."""
+        with self._lock:
+            if self._busy:
+                self._nested.append(commit)
+                return
+            self._busy = True
+            try:
+                commit(self._next_seq())
+                while self._nested:
+                    self._nested.pop(0)(self._next_seq())
+            finally:
+                self._busy = False
+
+    def last_seq(self):
+        """The newest seq whose record is in the trace (read under the
+        lock: a seq is never visible before its record)."""
+        with self._lock:
+            return self._seq
+
     # -- builders (engine hooks AND synthetic-trace construction) -------------
     def push(self, name, const=(), mutable=(), reads_data=(), writes_data=()):
-        with self._lock:
-            ev = TraceOp(self._next_seq(), name, const, mutable,
-                         reads_data, writes_data)
+        ev = TraceOp(0, name, const, mutable, reads_data, writes_data)
+
+        def commit(seq):
+            ev.seq = seq
             self.events.append(ev)
+
+        self._record(commit)
         return ev
 
     def discard(self, ev):
@@ -159,8 +201,7 @@ class EngineTrace:
                 pass
 
     def delete_var(self, var):
-        with self._lock:
-            self.deletes.append((self._next_seq(), var))
+        self._record(lambda seq: self.deletes.append((seq, var)))
 
     def wait(self, var=None, inside=None):
         """Record wait_for_var (or wait_for_all when var is None).
@@ -169,8 +210,7 @@ class EngineTrace:
         if inside is None:
             inside = self.current_op()
         ctx = inside.seq if isinstance(inside, TraceOp) else inside
-        with self._lock:
-            self.waits.append((self._next_seq(), var, ctx))
+        self._record(lambda seq: self.waits.append((seq, var, ctx)))
 
     # -- runtime lock events (TracedLock wrappers) -----------------------------
     def lock_acquire(self, name, thread=None):
@@ -185,13 +225,15 @@ class EngineTrace:
 
     def _lock_event(self, name, kind, thread=None):
         tid = threading.get_ident() if thread is None else thread
-        with self._lock:
-            seq = self._next_seq()
+
+        def commit(seq):
             _fold_lock_event(self._held, self.lock_edges,
                              seq, tid, name, kind)
             self.lock_events.append((seq, tid, name, kind))
             if len(self.lock_events) > _LOCK_EVENT_TAIL:
                 del self.lock_events[:_LOCK_EVENT_TAIL // 2]
+
+        self._record(commit)
 
     # -- executing-op context (set by the engine around fn execution) ----------
     @contextmanager
@@ -486,6 +528,12 @@ class TracedLock:
     @property
     def name(self):
         return self._name
+
+    @property
+    def inner(self):
+        """The wrapped primitive: what a finalizer takes, so that it
+        records nothing (engine.Engine.__del__)."""
+        return self._inner
 
     def acquire(self, *args, **kwargs):
         got = self._inner.acquire(*args, **kwargs)

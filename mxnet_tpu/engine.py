@@ -146,12 +146,20 @@ class Engine:
         # cannot name them — its entry is popped at dispatch)
         self._inflight = {}
         lib = self._lib
+        # The trampoline reaches the engine's state, never the engine:
+        # no reference cycle, so an engine dies where its last reference
+        # goes and not at some later allocation on some other thread
+        # (close() says why that matters). Not through a weak reference:
+        # that is dead by the time __del__ runs, and close() drains the
+        # pending ops through this very callback.
+        live, inflight, errors = self._live, self._inflight, self._errors
+        live_lock = self._live_lock
 
         def _trampoline(argp, token):
             key = argp  # void* cast back to the int key
-            with self._live_lock:
-                fn, is_async, ev, ev_trace = self._live.pop(key)
-                self._inflight[key] = getattr(fn, "__name__", None) or "fn"
+            with live_lock:
+                fn, is_async, ev, ev_trace = live.pop(key)
+                inflight[key] = getattr(fn, "__name__", None) or "fn"
             # pair ev with the trace it was recorded into at push time:
             # if a recording() block ended while this op was in flight,
             # the now-attached trace must not adopt a foreign seq as its
@@ -165,8 +173,8 @@ class Engine:
                 def on_complete(_tok=token, _key=key):
                     if not called[0]:
                         called[0] = True
-                        with self._live_lock:
-                            self._inflight.pop(_key, None)
+                        with live_lock:
+                            inflight.pop(_key, None)
                         lib.EngineOprComplete(_tok)
 
                 try:
@@ -174,8 +182,8 @@ class Engine:
                         _faults.point("engine.task")
                         fn(on_complete)
                 except BaseException as e:  # surface on next wait()
-                    with self._live_lock:
-                        self._errors.append(e)
+                    with live_lock:
+                        errors.append(e)
                     on_complete()
             else:
                 try:
@@ -183,11 +191,11 @@ class Engine:
                         _faults.point("engine.task")
                         fn()
                 except BaseException as e:
-                    with self._live_lock:
-                        self._errors.append(e)
+                    with live_lock:
+                        errors.append(e)
                 finally:
-                    with self._live_lock:
-                        self._inflight.pop(key, None)
+                    with live_lock:
+                        inflight.pop(key, None)
             if _tel.ENABLED:
                 # async latency covers fn's dispatch body (durability is
                 # on_complete's clock, which may outlive this frame)
@@ -208,7 +216,18 @@ class Engine:
         shutdown (threaded_engine destructor joins its workers without
         fencing producers). Holding _live_lock across EngineDestroy is
         not an option: the worker-thread trampoline takes _live_lock, so
-        destroy's drain would deadlock."""
+        destroy's drain would deadlock.
+
+        As a finalizer it relies on two things. __del__ takes the plain
+        lock under the TracedLock, so it records nothing: a finalizer is
+        no program action (lock_lint exempts __del__ too), and it runs
+        on whatever thread drops or collects the engine, which may be
+        inside the recorder. And the engine is in no reference cycle of
+        its own making (see _trampoline), so it dies where its last
+        reference goes: for the caller that kept the contract above,
+        after its last wait, never on one of its own workers. The
+        recorder is safe against finalizers that do record
+        (EngineTrace._record); the engine does not lean on that."""
         with self._live_lock:
             h, self._handle = self._handle, None
         if h is not None and self._lib is not None:
@@ -216,6 +235,8 @@ class Engine:
 
     def __del__(self):
         try:
+            self._live_lock = getattr(self._live_lock, "inner",
+                                      self._live_lock)
             self.close()
         except Exception:
             pass
@@ -283,8 +304,7 @@ class Engine:
         # the trace lock — an unlocked read could observe a seq whose
         # event is not yet appended, and that event would then be
         # skipped by every later incremental verify.
-        with trace._lock:
-            snap = trace._seq
+        snap = trace.last_seq()
         findings = verify(trace, since_seq=trace.verify_seq)
         trace.verify_seq = snap + 1
         new = [f for f in findings if f.key() not in trace.verify_reported]

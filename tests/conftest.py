@@ -19,11 +19,16 @@ os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 # Run the whole suite under the engine hazard verifier (mxlint's engine
 # pass): every push's read/write var sets are recorded and statically
-# checked on each wait — use-after-free and wait-cycle deadlocks in any
-# test's engine usage fail that test instead of hanging CI. The full
-# trace is kept in memory and re-checked per wait: fine at test scale
-# (measured no-op on this suite), a debug mode, not a production one —
-# see docs/how_to/static_analysis.md.
+# checked on each wait, so a use-after-free or a wait-cycle in any
+# test's engine usage fails that test at the wait, before it blocks.
+# The verifier finds the hazards it can see in the trace; it bounds no
+# hang: _time_limit below does, with one limit for every test. What the
+# recorder keeps is that it is safe to enter from a finalizer (which
+# runs on whatever thread allocates next): a record made on the thread
+# that is already inside the trace's critical section is queued, never
+# waited for (EngineTrace._record). The full trace is kept in memory
+# and re-checked per wait: a debug mode, not a production one — see
+# docs/how_to/static_analysis.md.
 #
 # The same switch also arms the mxrace runtime lock recorder: the
 # serving engine, elastic coordinator, dependency engine and async
@@ -58,7 +63,43 @@ from mxnet_tpu.parallel import mesh as _mesh  # noqa: E402
 
 _mesh.set_default_devices(jax.devices("cpu"))
 
+import signal  # noqa: E402
+
 import pytest  # noqa: E402
+
+# Seconds one test may take, set-up and teardown included. The slowest
+# test of the suite takes 76 s and the slowest file 192 s (ISSUE 27's
+# probe), so 300 only ever cuts a hang.
+TEST_TIME_LIMIT = 300
+
+
+@pytest.fixture(autouse=True)
+def _time_limit():
+    """A hang costs one failed test, not the run: SIGALRM on the main
+    thread (where pytest and every xdist worker run tests) raises into
+    the test after TEST_TIME_LIMIT, and again every tenth of it, since a
+    raise that lands inside a finalizer or an ``except BaseException``
+    is swallowed there; for that case the handler also leaves a mark,
+    and the teardown fails the test by it. Lock, queue and socket waits
+    are interruptible; a wait inside native code is not, and there
+    ``faulthandler_timeout`` (pyproject.toml) at least prints where."""
+    fired = []
+
+    def on_alarm(signum, frame):
+        fired.append(signum)
+        pytest.fail("test passed its time limit of %d s" % TEST_TIME_LIMIT)
+
+    prev = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT,
+                     TEST_TIME_LIMIT / 10)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, prev)
+    if fired:
+        pytest.fail("test passed its time limit of %d s (the alarm fired "
+                    "%d time(s))" % (TEST_TIME_LIMIT, len(fired)))
 
 
 @pytest.fixture(autouse=True)

@@ -127,6 +127,30 @@ def test_delete_variable_deferred():
     assert out == [1]
 
 
+def test_dropped_engine_dies_without_the_collector():
+    """An engine is in no reference cycle of its own: it is closed where
+    its last reference goes, not at some later allocation on whatever
+    thread the cyclic collector then runs on (Engine.close)."""
+    import gc
+    import weakref
+
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        e = make_engine("ThreadedEngine")
+        v = e.new_variable()
+        out = []
+        e.push(lambda: out.append(1), mutable_vars=[v])
+        e.wait_for_all()
+        gone = weakref.ref(e)
+        del e, v
+        assert gone() is None
+        assert out == [1]
+    finally:
+        if was_enabled:
+            gc.enable()
+
+
 def _run_workload(e, n_vars, ops):
     """Run a random read/write workload; each op writes
     vals[w] = sum(vals[r] for r in reads) + op_index."""

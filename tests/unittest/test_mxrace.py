@@ -254,6 +254,79 @@ def test_traced_lock_records_into_ambient_trace():
         engine_verify.set_ambient_trace(prev)
 
 
+class _LocksWhenFinalized:
+    """Garbage whose finalizer takes a lock, as Engine.__del__ does."""
+
+    def __init__(self, lock):
+        self.lock = lock
+
+    def __del__(self):
+        with self.lock:
+            pass
+
+
+@pytest.mark.parametrize("garbage", ["engine", "traced_lock"])
+def test_finalizer_inside_the_recorder_does_not_deadlock(garbage,
+                                                         monkeypatch):
+    """A finalizer runs on whatever thread allocates next, so also on
+    the thread that is inside the recorder's critical section. Neither
+    an Engine that dies there nor a finalizer that takes a TracedLock
+    there may block on the trace's own lock, and no record may share or
+    overtake a seq."""
+    import gc
+    import threading
+    import weakref
+
+    monkeypatch.setenv("MXNET_ENGINE_VERIFY", "1")
+    trace = engine_verify.EngineTrace()
+    next_seq, collected = trace._next_seq, []
+
+    def next_seq_after_a_collection():
+        if not collected:   # once, and inside the critical section
+            collected.append(gc.collect())
+        return next_seq()
+
+    trace._next_seq = next_seq_after_a_collection
+    prev = engine_verify.set_ambient_trace(trace)
+    was_enabled = gc.isenabled()
+    gc.disable()    # the garbage must last until the collection above
+    try:
+        if garbage == "engine":
+            from mxnet_tpu.engine import Engine
+
+            junk = Engine()
+            assert isinstance(junk._live_lock, engine_verify.TracedLock)
+        else:
+            junk = _LocksWhenFinalized(
+                engine_verify.TracedLock(threading.Lock(), "finalizer"))
+        junk.cycle = junk   # only the cyclic collector can free it
+        gone = weakref.ref(junk)
+        del junk
+        t = threading.Thread(target=trace.lock_acquire, args=("outer",),
+                             daemon=True)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive(), \
+            "the recorder waits for a lock its own thread holds"
+    finally:
+        if was_enabled:
+            gc.enable()
+        engine_verify.set_ambient_trace(prev)
+    assert gone() is None   # finalized, and inside the critical section
+    mine = [e for e in trace.lock_events
+            if e[2] in ("outer", "finalizer", "engine.Engine._live_lock")]
+    seqs = [e[0] for e in trace.lock_events]
+    assert seqs == sorted(set(seqs))
+    # the engine's finalizer is no program action and records nothing;
+    # a finalizer that does record lands whole, after the record it
+    # interrupted
+    assert [e[2:] for e in mine] == {
+        "engine": [("outer", "acquire")],
+        "traced_lock": [("outer", "acquire"), ("finalizer", "acquire"),
+                        ("finalizer", "release")],
+    }[garbage]
+
+
 def test_maybe_trace_lock_env_gating(monkeypatch):
     import threading
 
