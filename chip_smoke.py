@@ -337,6 +337,7 @@ def phase_c(jax, size, seed, on_chip):
 
     cfg = lm_config(size)
     routed = dict(pk.FALLBACKS)
+    took = dict(pk.FLASH_CALLS)
     params = init_params(cfg, jax.random.PRNGKey(seed))
     step, init_state = make_train_step(loss_fn(cfg), optax.adam(1e-4))
     opt_state = init_state(params)
@@ -359,6 +360,19 @@ def phase_c(jax, size, seed, on_chip):
     check(losses[-1] < losses[0], "loss did not fall: %s", losses)
     check(pk.FALLBACKS == routed,
           "attention was routed off the kernel: %s", pk.FALLBACKS)
+    # what the step's kernels are: bf16 into the MXU (the model's dtype),
+    # the mask on fewer tiles than are visited, one call site a layer
+    took = {key: n - took.get(key, 0) for key, n in pk.FLASH_CALLS.items()
+            if n != took.get(key, 0)}
+    say("  flash kernels taken (kernel, operands, tiles visited/masked/"
+        "square): %s", took)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        sites = {key: n for key, n in took.items() if key[0] == name}
+        check(sum(sites.values()) == cfg.num_layers
+              and all(operand == str(np.dtype(cfg.dtype))
+                      and (masked < visited < square or square == 1)
+                      for _, operand, (visited, masked, square) in sites),
+              "%s: not the kernels this model should hold: %s", name, took)
     if on_chip:
         # the same program again, ahead of time (jax hands back the
         # executable it already built): its text says which kernels the
@@ -366,7 +380,7 @@ def phase_c(jax, size, seed, on_chip):
         text = cv.unwrap(step.jitted).lower(
             params, opt_state, batch, key).compile().as_text()
         calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-        found = {name: sum(name + ")" in ln for ln in calls)
+        found = {name: sum("/%s/" % name in ln for ln in calls)
                  for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
         say("  tpu_custom_call in the compiled step: %d %s", len(calls),
             found)

@@ -115,295 +115,388 @@ def _attention_reference(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
 
 
+#: (kernel, operand type of its products, (tiles visited, tiles the mask
+#: is applied on, tiles of the square) per head, in tiles of step x step)
+#: -> number of call sites that took the kernel. Filled while tracing,
+#: like ``FALLBACKS``: it says which mechanism a compiled step holds
+#: (chip_smoke.py asserts on it), not how often it ran.
+FLASH_CALLS = {}
+
+
+def _operand_dtype(dtype):
+    """What the kernels' products are fed: bfloat16 inputs go to the MXU
+    as they are stored, anything else as float32. Accumulation and the
+    softmax's statistics are float32 either way."""
+    import jax.numpy as jnp
+
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
+
+
+def _fold_scale(dtype, scale):
+    """Whether ``scale`` is multiplied into the q (or k) block before the
+    product, instead of into every score after it: in float32 always, in
+    bfloat16 only when that is exact (a power of two; 0.125 at d=64)."""
+    import math
+
+    import jax.numpy as jnp
+
+    return (_operand_dtype(dtype) == jnp.float32
+            or math.frexp(scale)[0] == 0.5)
+
+
+def _tile_counts(t_own, t_other, step, causal):
+    """(visited, masked, square) tiles of one head, in tiles of ``step``
+    x ``step``: what a kernel's loops below cover of the score square. A
+    causal kernel visits the tiles on and under the diagonal and applies
+    the mask on the diagonal's own tiles only."""
+    n_own, n_other = t_own // step, t_other // step
+    if not causal:
+        return n_own * n_other, 0, n_own * n_other
+    return n_own * (n_own + 1) // 2, n_own, n_own * n_other
+
+
+def _took_kernel(kernel, dtype, tiles):
+    """Count one call site that took ``kernel``."""
+    import jax.numpy as jnp
+
+    operand = jnp.dtype(_operand_dtype(dtype)).name
+    key = (kernel, operand, tiles)
+    FLASH_CALLS[key] = FLASH_CALLS.get(key, 0) + 1
+    if _tel.ENABLED:
+        _tel.counter("pallas.kernel_total.%s.%s" % (kernel, operand)).inc()
+
+
+# The three kernels build their score tiles transposed, st[k, q]: k
+# positions down the sublanes, q positions along the lanes. What is kept
+# per q position (running max and sum, lse, dcap) is then a lane-dense
+# row that broadcasts down the sublanes as it is stored, and the
+# accumulators are [d, positions] with every lane in use. And it decides
+# which products fill the MXU: contraction over d (q.k^T, dO.v^T) uses
+# d of its 128 rows whatever the layout, but a product whose OUTPUT is d
+# wide (p.v, ds.k, p^T.dO, ds^T.q) streams d rows through full 128 x 128
+# tiles of p or ds when written [d, n] = x^T[d, m] . p[m, n], where
+# [n, d] = p[n, m] . x[m, d] would fill d of 128 columns.
+_NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
+_TN = (((0,), (0,)), ((), ()))  # [m, d] x [m, n] -> [d, n]
+_TT = (((0,), (1,)), ((), ()))  # [m, d] x [n, m] -> [d, n]
+
+
+def _dot(a, b, dims):
+    """A product on the MXU: operands as they come, float32 out."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _under_diagonal(square):
+    """``square`` [n, n] of transposed scores whose first row and first
+    column are the same position: keep k <= q."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    k = lax.broadcasted_iota(jnp.int32, square.shape, 0)
+    q = lax.broadcasted_iota(jnp.int32, square.shape, 1)
+    return jnp.where(k <= q, square, -1e30)
+
+
+def _put_lanes(old, new, lo, hi):
+    """``old`` with its lanes ``[lo, hi)`` replaced by ``new``."""
+    import jax.numpy as jnp
+
+    parts = [old[:, :lo], new, old[:, hi:]]
+    parts = [x for x in parts if x.shape[1]]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                      block_q, block_k, n_k):
+                      block, step, n_steps):
+    """One block of ``block`` q positions against k/v, ``step`` positions
+    at a time. Causal: the steps wholly before the block in a loop, then
+    the block's own ``block // step`` steps unrolled, each against the q
+    lanes from its diagonal on (lanes before it see nothing of it) and
+    masked on its diagonal tile alone."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # [block_q, d]
-    bq, d = q.shape
+    op = _operand_dtype(q_ref.dtype)
+    fold = _fold_scale(q_ref.dtype, scale)
+    row0 = pl.program_id(1) * block
+    q = q_ref[0].astype(op)  # [block, d]
+    if fold:
+        q = q * scale
 
-    def body(i, carry):
-        acc, l, m = carry
-        kblk = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if causal:
-            qpos = iq * block_q + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            kpos = i * block_k + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        pv = lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_new = acc * alpha[:, None] + pv
-        return acc_new, l_new, m_new
+    def tile(k0, carry, lo=0, diagonal=False):
+        # the running max and sum ride 8 sublanes deep, [8, block] with
+        # every row the same: lanes of a one-row value cannot be sliced
+        acc, l, m = carry  # [dv, block], [8, block], [8, block]
+        kblk = k_ref[0, pl.ds(k0, step), :].astype(op)
+        vblk = v_ref[0, pl.ds(k0, step), :].astype(op)
+        st = _dot(kblk, q[lo:], _NT)
+        if not fold:
+            st = st * scale
+        if diagonal:
+            st = _put_lanes(st, _under_diagonal(st[:, :step]), 0, step)
+        m_old, l_old = m[:, lo:], l[:, lo:]
+        m_new = jnp.maximum(m_old, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new[0:1])
+        alpha = jnp.exp(m_old - m_new)
+        l_new = l_old * alpha + jnp.sum(pt, axis=0, keepdims=True)
+        pv = _dot(vblk, pt.astype(op), _TN)
+        acc_new = acc[:, lo:] * alpha[0:1] + pv
+        return (_put_lanes(acc, acc_new, lo, block),
+                _put_lanes(l, l_new, lo, block),
+                _put_lanes(m, m_new, lo, block))
 
-    acc0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    m0 = jnp.full((bq,), -1e30, jnp.float32)
+    carry = (jnp.zeros((v_ref.shape[-1], block), jnp.float32),
+             jnp.zeros((8, block), jnp.float32),
+             jnp.full((8, block), -1e30, jnp.float32))
+    carry = lax.fori_loop(0, row0 // step if causal else n_steps,
+                          lambda i, c: tile(i * step, c), carry)
     if causal:
-        # only k blocks whose start can be <= the last q position of this block
-        upper = lax.div((iq + 1) * block_q - 1, block_k) + 1
-        upper = jnp.minimum(upper, n_k)
-    else:
-        upper = n_k
-    acc, l, m = lax.fori_loop(0, upper, body, (acc0, l0, m0))
+        for lo in range(0, block, step):
+            carry = tile(row0 + lo, carry, lo, diagonal=True)
+    acc, l, m = carry
     l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / l[0:1]).T.astype(o_ref.dtype)
     # log-sum-exp per row: the backward reconstructs p = exp(s - lse).
-    # Stored 8-row broadcast: Mosaic requires the last-two block dims be
-    # (8k, 128k) or full, so a (1, block_q) row block would not lower —
-    # stats ride as (bh, 8, tq) with every sublane row identical.
-    lse_ref[0] = jnp.broadcast_to((m + jnp.log(l))[None, :], (8, bq))
+    # Stored 8 rows deep like the running statistics: Mosaic requires the
+    # last-two block dims be (8k, 128k) or full, so a (1, block) row
+    # block would not lower.
+    lse_ref[0] = m + jnp.log(l)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                         dq_ref, *, scale, causal, block_q, block_k, n_k):
-    """dQ for one q block: stream K/V blocks, rebuild p from the saved
-    lse, accumulate ds·K (flash-attention backward, q side)."""
+                         dq_ref, *, scale, causal, block, step, n_steps):
+    """dQ for one block of q positions: stream k/v as the forward does,
+    rebuild p from the saved lse, accumulate k^T.ds (flash-attention
+    backward, q side)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale   # [bq, d]
-    do = do_ref[0].astype(jnp.float32)         # [bq, dv]
-    lse = lse_ref[0, 0]                        # [bq] (8-row broadcast)
-    dcap = dcap_ref[0, 0]                      # [bq] = rowsum(dO * O)
-    bq = q.shape[0]
+    op = _operand_dtype(q_ref.dtype)
+    fold = _fold_scale(q_ref.dtype, scale)
+    row0 = pl.program_id(1) * block
+    q = q_ref[0].astype(op)      # [block, d]
+    if fold:
+        q = q * scale
+    do = do_ref[0].astype(op)    # [block, dv]
 
-    def body(i, acc):
-        kblk = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if causal:
-            qpos = iq * block_q + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            kpos = i * block_k + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, -1e30)
-        p = jnp.exp(s - lse[:, None])
-        dp = lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - dcap[:, None])
-        return acc + lax.dot_general(ds, kblk, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+    def tile(k0, acc, lo=0, diagonal=False):
+        kblk = k_ref[0, pl.ds(k0, step), :].astype(op)
+        vblk = v_ref[0, pl.ds(k0, step), :].astype(op)
+        st = _dot(kblk, q[lo:], _NT)
+        if not fold:
+            st = st * scale
+        if diagonal:
+            st = _put_lanes(st, _under_diagonal(st[:, :step]), 0, step)
+        # lse, and dcap = rowsum(dO * O): one of their 8 equal rows
+        pt = jnp.exp(st - lse_ref[0, 0:1, lo:])
+        dpt = _dot(vblk, do[lo:], _NT)
+        dst = (pt * (dpt - dcap_ref[0, 0:1, lo:])).astype(op)
+        new = acc[:, lo:] + _dot(kblk, dst, _TN)
+        return _put_lanes(acc, new, lo, block)
 
+    acc = jnp.zeros((q.shape[1], block), jnp.float32)  # [d, block]
+    acc = lax.fori_loop(0, row0 // step if causal else n_steps,
+                        lambda i, c: tile(i * step, c), acc)
     if causal:
-        upper = jnp.minimum(lax.div((iq + 1) * block_q - 1, block_k) + 1, n_k)
-    else:
-        upper = n_k
-    acc0 = jnp.zeros(q.shape, jnp.float32)
-    acc = lax.fori_loop(0, upper, body, acc0)
-    dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
+        for lo in range(0, block, step):
+            acc = tile(row0 + lo, acc, lo, diagonal=True)
+    dq_ref[0] = (acc * scale).T.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                          dk_ref, dv_ref, *, scale, causal, block_q,
-                          block_k, n_q):
-    """dK/dV for one k block: stream Q/dO blocks, accumulate p^T·dO and
-    ds^T·q (flash-attention backward, k side)."""
+                          dk_ref, dv_ref, *, scale, causal, block, step,
+                          n_steps):
+    """dK/dV for one block of ``block`` k positions: stream q/dO ``step``
+    positions at a time, accumulate dO^T.p and q^T.ds (flash-attention
+    backward, k side). Causal: the block's own ``block // step`` steps
+    unrolled, each against the k rows up to its diagonal (rows after it
+    are seen by nothing of it) and masked on its diagonal tile alone,
+    then the steps wholly after the block in a loop."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    ik = pl.program_id(1)
-    kblk = k_ref[0].astype(jnp.float32)   # [bk, d]
-    vblk = v_ref[0].astype(jnp.float32)   # [bk, dv]
-    bk = kblk.shape[0]
+    op = _operand_dtype(q_ref.dtype)
+    fold = _fold_scale(q_ref.dtype, scale)
+    col0 = pl.program_id(1) * block
+    kblk = k_ref[0].astype(op)   # [block, d]
+    if fold:
+        kblk = kblk * scale
+    vblk = v_ref[0].astype(op)   # [block, dv]
 
-    def body(j, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(j * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.ds(j * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(j * block_q, block_q)]
-        dcap = dcap_ref[0, 0, pl.ds(j * block_q, block_q)]
-        s = lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if causal:
-            qpos = j * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            kpos = ik * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(kpos <= qpos, s, -1e30)
-        p = jnp.exp(s - lse[:, None])                       # [bq, bk]
-        dv_new = dv + lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - dcap[:, None])
-        dk_new = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+    def tile(q0, carry, hi=block, diagonal=False):
+        dk, dv = carry  # [d, block], [dv, block]
+        lanes = pl.ds(q0, step)
+        q = q_ref[0, lanes, :].astype(op)
+        do = do_ref[0, lanes, :].astype(op)
+        lse = lse_ref[0, 0:1, lanes]     # [1, step]
+        dcap = dcap_ref[0, 0:1, lanes]
+        st = _dot(kblk[:hi], q, _NT)
+        if not fold:
+            st = st * scale
+        if diagonal:
+            below = _under_diagonal(st[hi - step:])
+            st = (jnp.concatenate([st[:hi - step], below], axis=0)
+                  if hi > step else below)
+        pt = jnp.exp(st - lse)
+        dv_new = dv[:, :hi] + _dot(do, pt.astype(op), _TT)
+        dpt = _dot(vblk[:hi], do, _NT)
+        dst = (pt * (dpt - dcap)).astype(op)
+        dk_new = dk[:, :hi] + _dot(q, dst, _TT)
+        return _put_lanes(dk, dk_new, 0, hi), _put_lanes(dv, dv_new, 0, hi)
 
+    carry = (jnp.zeros(kblk.shape[::-1], jnp.float32),
+             jnp.zeros(vblk.shape[::-1], jnp.float32))
     if causal:
-        # q blocks at or after this k block's first position
-        lower = lax.div(ik * block_k, block_q)
-    else:
-        lower = 0
-    dk0 = jnp.zeros(kblk.shape, jnp.float32)
-    dv0 = jnp.zeros(vblk.shape, jnp.float32)
-    dk, dv = lax.fori_loop(lower, n_q, body, (dk0, dv0))
-    # q was pre-scaled, so ds^T·q already carries one factor of scale;
-    # dk = scale * ds^T·q_unscaled == ds^T·(q*scale)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        for hi in range(step, block + step, step):
+            carry = tile(col0 + hi - step, carry, hi, diagonal=True)
+    carry = lax.fori_loop((col0 + block) // step if causal else 0, n_steps,
+                          lambda j, c: tile(j * step, c), carry)
+    dk, dv = carry
+    # dk = scale * ds^T.q whether the scale went into k or into the scores
+    dk_ref[0] = (dk * scale).T.astype(dk_ref.dtype)
+    dv_ref[0] = dv.T.astype(dv_ref.dtype)
 
 
-def _flash_attention_pallas(q, k, v, causal, scale, block_q, block_k):
-    """Forward kernel; returns (o, lse) with lse saved for the backward."""
+@functools.lru_cache(maxsize=None)
+def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
+                interpret):
+    """One of the three kernels at one setting, as a jitted pallas_call.
+    Cached, so that a model's layers share it: its body is then traced
+    and lowered once a program and not once a call site (24 layers are
+    72 sites; a warm gpt2-medium set-up spent 1.2 s more on them than
+    the parent's before this; my chip runs, PR 28)."""
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    def whole(rows, width):
+        return pl.BlockSpec((1, rows, width), lambda i, j: (i, 0, 0))
+
+    def blocked(width):
+        return pl.BlockSpec((1, block, width), lambda i, j: (i, j, 0))
+
+    stats = pl.BlockSpec((1, 8, block), lambda i, j: (i, 0, j))
+    own, other = (tk, tq) if name == "flash_bwd_dkv" else (tq, tk)
+    body, in_specs, out_specs, out_shape = {
+        "flash_fwd": (
+            _flash_fwd_kernel,
+            [blocked(d), whole(tk, d), whole(tk, dv)],
+            (blocked(dv), stats),
+            (jax.ShapeDtypeStruct((bh, tq, dv), dtype),
+             jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32))),
+        "flash_bwd_dq": (
+            _flash_bwd_dq_kernel,
+            [blocked(d), whole(tk, d), whole(tk, dv), blocked(dv), stats,
+             stats],
+            blocked(d),
+            jax.ShapeDtypeStruct((bh, tq, d), dtype)),
+        "flash_bwd_dkv": (
+            _flash_bwd_dkv_kernel,
+            [whole(tq, d), blocked(d), blocked(dv), whole(tq, dv),
+             whole(8, tq), whole(8, tq)],
+            (blocked(d), blocked(dv)),
+            (jax.ShapeDtypeStruct((bh, tk, d), dtype),
+             jax.ShapeDtypeStruct((bh, tk, dv), dtype))),
+    }[name]
+    return jax.jit(pl.pallas_call(
+        functools.partial(body, scale=scale, causal=causal, block=block,
+                          step=step, n_steps=other // step),
+        out_shape=out_shape, grid=(bh, own // block), in_specs=in_specs,
+        out_specs=out_specs, interpret=interpret, name=name))
+
+
+def _flash_attention_pallas(q, k, v, causal, scale, block, step):
+    """Forward kernel; returns (o, lse) with lse saved for the backward."""
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[-1]
     bh = b * h
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, v.shape[-1])
-    n_q = tq // block_q
-    n_k = tk // block_k
-
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, n_k=n_k,
-    )
-    import jax.numpy as jnp
-
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, tq, v.shape[-1]), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32),
-        ),
-        grid=(bh, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, v3.shape[-1]), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, v3.shape[-1]), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j)),
-        ),
-        interpret=_interpret(),
-        name="flash_fwd",
-    )(q3, k3, v3)
-    return out.reshape(b, h, tq, v.shape[-1]), lse  # lse: (b*h, 8, tq)
+    _took_kernel("flash_fwd", q.dtype, _tile_counts(tq, tk, step, causal))
+    out, lse = _flash_call("flash_fwd", q.dtype.name, bh, tq, tk, d, dv,
+                           causal, scale, block, step, _interpret())(
+        q.reshape(bh, tq, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv))
+    return out.reshape(b, h, tq, dv), lse  # lse: (b*h, 8, tq)
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                block_q, block_k):
+                                block, step):
     """Blockwise backward: neither pass materialises the [T, T] score
     matrix in HBM — the cliff the dense-vjp fallback hits at long T."""
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
-    dv_dim = v.shape[-1]
+    tk, dv = k.shape[2], v.shape[-1]
     bh = b * h
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, dv_dim)
-    do3 = g.reshape(bh, tq, dv_dim)
-    lse3 = lse  # (bh, 8, tq), 8-row broadcast (see _flash_fwd_kernel)
-    # D_i = rowsum(dO * O): one fused elementwise+reduce pass in XLA,
-    # broadcast to the same 8-row stats layout
-    dcap = jnp.broadcast_to(
-        jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1).reshape(bh, 1, tq), (bh, 8, tq))
-    n_q = tq // block_q
-    n_k = tk // block_k
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        grid=(bh, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, dv_dim), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, dv_dim), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(q3, k3, v3, do3, lse3, dcap)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, dv_dim), v.dtype),
-        ),
-        grid=(bh, n_k),
-        in_specs=[
-            pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dv_dim), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tq, dv_dim), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 8, tq), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 8, tq), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dv_dim), lambda i, j: (i, j, 0)),
-        ),
-        interpret=_interpret(),
-        name="flash_bwd_dkv",
-    )(q3, k3, v3, do3, lse3, dcap)
-
+    operands = (q.reshape(bh, tq, d), k.reshape(bh, tk, d),
+                v.reshape(bh, tk, dv), g.reshape(bh, tq, dv), lse,
+                # D_i = rowsum(dO * O): one fused elementwise+reduce pass
+                # in XLA, broadcast to lse's 8-row stats layout
+                jnp.broadcast_to(
+                    jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                            axis=-1).reshape(bh, 1, tq), (bh, 8, tq)))
+    setting = (q.dtype.name, bh, tq, tk, d, dv, causal, scale)
+    _took_kernel("flash_bwd_dq", q.dtype, _tile_counts(tq, tk, step, causal))
+    dq = _flash_call("flash_bwd_dq", *setting, block, step,
+                     _interpret())(*operands)
+    # the k side owns blocks of k and steps through q: the same two
+    # sizes, fitted to the other length where the two differ
+    block, step, _ = _select_blocks(tk, tq, block, step)
+    _took_kernel("flash_bwd_dkv", q.dtype, _tile_counts(tk, tq, step, causal))
+    dk, dv_ = _flash_call("flash_bwd_dkv", *setting, block, step,
+                          _interpret())(*operands)
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, dv_dim))
+            dv_.reshape(b, h, tk, dv))
 
 
 def _select_blocks(tq, tk, block_q=None, block_k=None):
     """Resolve flash block sizes for a (tq, tk) problem.
 
     Returns ``(block_q, block_k, ok)``; ``ok=False`` means no legal tiling
-    exists and the caller must use the dense path.
+    exists and the caller must use the dense path. A program of the
+    forward and dq kernels owns ``block_q`` q positions and steps through
+    k/v ``block_k`` positions at a time; the dkv kernel is their mirror
+    image with the same two numbers (it owns ``block_q`` k positions and
+    steps through q), so it asks with the lengths swapped.
 
-    - ``block_q=None`` picks the shape-keyed default: 1024 for T>=8192,
-      512 below (measured in docs/perf_analysis.md — K/V HBM traffic per
-      q row scales with 1/block_q, so long context wants larger q blocks;
-      1024 buys ~+5 MFU points at T=8192 with no effect at 1k-4k).
-    - ``block_k=None`` defaults to 512 (capped there): wider K tiles
-      halve/quarter the inner-loop iterations and widen the MXU dots —
-      128 -> 512 measured +19% tokens/s at T=1024 and +54% at T=8192
-      (docs/perf_analysis.md r5). 1024 FAILS to compile (VMEM), so the
-      cap is hard and env probes clamp to it.
+    - Defaults 1024 and 512 (``_flash_plan`` halves the first while the
+      operands overflow VMEM). Device time of the three kernels, forward
+      and ``jax.grad``, causal, bf16, on one v5e (tools/flash_probe.py; my
+      chip runs, PR 28), ms a call at [8,16,1024,64] / [1,16,8192,64]:
+      1024x512 1.13 / 6.46, 512x512 1.40 / 7.19, 1024x256 1.32 / 8.44,
+      512x256 1.83 / 10.7, 256x256 2.62 / 15.5 (2048x512: Mosaic refuses,
+      VMEM). The q positions ride the lanes of the score tile and the
+      four MXUs split a product by its 128-lane column tiles, so narrow
+      blocks starve them; a program costs several hundred cycles besides.
+      Earlier rounds' claims for the float32 body on another chip
+      (docs/perf_analysis.md r4/r5: "block_k 128 -> 512 +19% tokens/s at
+      T=1024, +54% at T=8192; block_q 1024 +5 MFU points at T=8192") were
+      not re-measured and do not describe this body.
+    - ``block_k`` is capped at 512: a [1024, 1024] float32 score tile is
+      4 MiB, and the kernels hold two or three.
+    - ``block_k`` divides ``block_q``: a causal kernel unrolls its own
+      block's ``block_q // block_k`` diagonal steps.
     - Env knobs MXNET_FLASH_BLOCK_Q/K override for A/B probes; malformed
       values fall back silently.
     - Blocks shrink to a divisor of T so lengths tileable at a smaller
       block stay on the kernel.
     - Mosaic legality (enforced uniformly so CPU interpret mode takes the
-      same path a TPU compile would): block_q rides the lane (last)
-      dimension of the (1, 8, block_q) lse/dcap stats blocks AND the
-      backward kernels' ``pl.ds(j * block_q, block_q)`` lane slices,
-      whose start index is a dynamic loop variable — Mosaic must prove
-      it a multiple of 128, which only holds when block_q itself is.
+      same path a TPU compile would): both blocks ride the lane (last)
+      dimension of score tiles, of the (1, 8, block) lse/dcap stats blocks
+      and of the dkv kernel's ``pl.ds(j * block_k, block_k)`` lane slices,
+      whose start index is a dynamic loop variable — Mosaic must prove it
+      a multiple of 128, which only holds when the block itself is.
       Probed on chip (r5): even a FULL-dim off-128 block fails with
       "cannot statically prove that index in dimension 2 is a multiple
       of 128", so the rule is strict 128-multiples for both blocks and
       off-128 lengths (including any T < 128) take the dense path.
     """
     if block_q is None:
-        block_q = 1024 if tq >= 8192 else 512
+        block_q = 1024
     if block_k is None:
         block_k = 512
     block_q = _env_int("MXNET_FLASH_BLOCK_Q", block_q)
@@ -431,49 +524,65 @@ def _select_blocks(tq, tk, block_q=None, block_k=None):
             if tq % (m * 128) == 0:
                 block_q = m * 128
                 break
-    if tk % block_k or block_k % 128:
+    if tk % block_k or block_k % 128 or block_q % block_k:
         for m in range(block_k // 128, 0, -1):
-            if tk % (m * 128) == 0:
+            if tk % (m * 128) == 0 and block_q % (m * 128) == 0:
                 block_k = m * 128
                 break
     aligned = block_q % 128 == 0 and block_k % 128 == 0
-    ok = aligned and tq % block_q == 0 and tk % block_k == 0
+    ok = (aligned and tq % block_q == 0 and tk % block_k == 0
+          and block_q % block_k == 0)
     return block_q, block_k, ok
 
 
-def _flash_refusal(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
-    """Why ``flash_attention`` would NOT take the Pallas kernel for these
-    operands (a ``FALLBACKS`` reason), or None when it will: every gate
-    the kernel applies — enablement, block-tiling legality, the
-    ``MXNET_FLASH_MIN_T`` crossover, and the scoped-VMEM footprint."""
-    if not enabled():
-        return "disabled"
+def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize):
+    """Bytes of scoped VMEM the hungriest of the three kernels asks for.
+    Every operand block is double-buffered by the pipeline, each at least
+    128 lanes wide in VMEM whatever d is: the full-length operands (K and
+    V in fwd/dq, Q and dO in dkv), the streamed in/out blocks, and the
+    lse/dcap stats rows (full length in dkv). On top sit the body's
+    float32 [block_k, block_q] score tiles (what the compiler keeps of
+    them at once: 2 in fwd, 2.75 in dq, 1.5 in dkv) and the [d, block_q]
+    accumulators. Fitted to the sizes the chip's compiler reports when it
+    refuses, at T=1k..32k, d=64..256, bf16 and f32, blocks 256..1024: it
+    accepts nothing of those that Mosaic refuses, and refuses one shape
+    that Mosaic takes (tests/unittest/test_chip_compile.py holds the
+    bench shapes)."""
+    io = (max(d, 128) + max(dv, 128)) * itemsize
+    tile = block_k * block_q * 4
+    fwd = 2 * (tk + block_q) * io + 2 * 8 * block_q * 4 \
+        + 2 * tile + 4 * dv * block_q
+    dq = 2 * (tk + block_q) * io + 2 * block_q * max(d, 128) * itemsize \
+        + 2 * 2 * 8 * block_q * 4 + 2.75 * tile + 4 * d * block_q
+    dkv = 2 * (tq + 2 * block_q) * io + 2 * 2 * 8 * tq * 4 \
+        + 1.5 * tile + 4 * (d + dv) * block_q
+    return max(fwd, dq, dkv)
+
+
+def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
+    """``(block_q, block_k, refusal)``: the blocks ``flash_attention``
+    runs these operands at, and why it would NOT take the Pallas kernels
+    (a ``FALLBACKS`` reason) or None when it will: every gate the kernels
+    apply — enablement, block-tiling legality, the ``MXNET_FLASH_MIN_T``
+    crossover, and the scoped-VMEM footprint. A ``block_q`` the caller
+    did not name is halved while the footprint overflows, so that long
+    or wide operands stay on the kernels at a smaller block."""
+    named = block_q is not None
     block_q, block_k, tiles = _select_blocks(tq, tk, block_q, block_k)
+    if not enabled():
+        return block_q, block_k, "disabled"
     if not tiles:
-        return "untileable"
+        return block_q, block_k, "untileable"
     # the crossover is a hardware-perf decision; interpret mode
     # (CPU tests) always takes the kernel path for coverage
     if tk < _env_int("MXNET_FLASH_MIN_T", 0) and not _interpret():
-        return "below_min_t"
-    # Scoped VMEM of the hungriest of the three kernels. Every operand
-    # block is double-buffered by the pipeline: the full-length operands
-    # (K and V in fwd/dq, Q and dO in dkv), the streamed in/out blocks,
-    # and the lse/dcap stats rows (full length in dkv); on top sit the
-    # body's f32 temporaries, two [block_q, block_k] score tiles and the
-    # accumulators. Fitted to what the chip's compiler reports and
-    # checked against it at T=128..32k, d=64..256, bf16 and f32
-    # (tests/unittest/test_chip_compile.py holds the bench shapes).
-    io = d + dv
-    scores = 2 * block_q * block_k * 4
-    fwd = (2 * tk * io + 2 * block_q * io) * itemsize \
-        + 2 * 8 * block_q * 4 + scores + block_q * dv * 4
-    dq = (2 * tk * io + 2 * block_q * (io + d)) * itemsize \
-        + 2 * 2 * 8 * block_q * 4 + scores + block_q * d * 4
-    dkv = (2 * tq * io + 2 * block_k * 2 * io) * itemsize \
-        + 2 * 2 * 8 * tq * 4 + scores + block_k * io * 4
-    if max(fwd, dq, dkv) > _VMEM_LIMIT - 512 * 1024:
-        return "vmem"
-    return None
+        return block_q, block_k, "below_min_t"
+    while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize) > _VMEM_LIMIT:
+        smaller = _select_blocks(tq, tk, block_q // 2, block_k)
+        if named or not smaller[2] or smaller[0] >= block_q:
+            return block_q, block_k, "vmem"
+        block_q, block_k, _ = smaller
+    return block_q, block_k, None
 
 
 def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
@@ -484,7 +593,7 @@ def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
     Ulysses sequence-parallel local attention) can choose between the
     kernel and their OWN memory-bounded fallback instead of ever
     hitting flash_attention's dense O(T^2) fallback."""
-    return _flash_refusal(tq, tk, d, dv, block_q, block_k, itemsize) is None
+    return _flash_plan(tq, tk, d, dv, block_q, block_k, itemsize)[2] is None
 
 
 def flash_attention(q, k, v, causal=True, scale=None,
@@ -494,42 +603,54 @@ def flash_attention(q, k, v, causal=True, scale=None,
     Forward AND backward run as Pallas kernels: the forward saves the
     per-row log-sum-exp, and the backward reconstructs attention weights
     blockwise from it (standard flash-attention backward), so the [T, T]
-    score matrix never exists in HBM in either direction. Measured on
-    the real chip (docs/perf_analysis.md, round 4): with the kernel
-    backward, flash beats the dense XLA path at EVERY training length —
-    1.06x tokens/s at T=1024 rising to 19x at T=8192, where dense
-    spills to 2% MFU and flash holds 39% — so the kernel is the default
-    whenever shapes tile. MXNET_FLASH_MIN_T (default 0) can re-impose a
-    crossover; MXNET_FLASH_DENSE_BWD=1 forces the dense recompute
-    backward for A/B probes.
+    score matrix never exists in HBM in either direction. The kernel is
+    the default whenever shapes tile (an earlier round's claim, on
+    another chip and an older body, docs/perf_analysis.md r4: the kernel
+    backward beats the dense XLA path at every training length, 1.06x
+    tokens/s at T=1024 rising to 19x at T=8192; not re-measured here).
+    MXNET_FLASH_MIN_T (default 0) can re-impose a crossover;
+    MXNET_FLASH_DENSE_BWD=1 forces the dense recompute backward for A/B
+    probes.
+
+    The products take their operands in the inputs' type: bfloat16 q, k,
+    v (and the p and ds tiles rebuilt from them) go to the MXU as
+    bfloat16, float32 inputs as float32; either way every product
+    accumulates in float32 and the running max and sum, exp, lse and
+    dcap are float32. The scale goes into the q (or k) block where that
+    is exact (float32, or a power of two as 0.125 at d=64), otherwise
+    into the scores. On one v5e the three kernels at the gpt2-medium
+    step's shape, [8, 16, 1024, 64] bfloat16 causal, take 1.13 ms of
+    device time a layer where the float32-operand body of PR 27 took
+    1.87 (tools/flash_probe.py; my chip runs, PR 28); what the change
+    was made of is in ``_NT``'s comment and PERF.md section 6.
 
     Routed to plain XLA, and counted in ``FALLBACKS``, when the kernels
-    are disabled, the lengths do not tile, or the operands overflow the
-    scoped VMEM (``_flash_refusal`` names which).
+    are disabled, the lengths do not tile, the operands overflow the
+    scoped VMEM even at the smallest block (``_flash_plan`` names
+    which), or causal attention is asked over tq != tk. Every call site
+    that takes the kernels is counted in ``FLASH_CALLS``.
 
-    Block sizing (measured, docs/perf_analysis.md rounds 4-5): every
-    q-block grid cell DMAs the FULL K/V into VMEM, so K/V HBM traffic
-    scales with tq/block_q — block_q 128 -> 512 took T=8192 training
-    from 41% to 59% MFU and T=1024 from 55% to 61% (r4 figures, under
-    the OLD 18Td accounting — r5 switched the bench to the standard
-    12Td convention, so don't compare them to current MFU numbers;
-    tokens/s comparisons are convention-free); 512 -> 1024 buys a
-    further ~12% tokens/s at T=8192. block_k widens the inner-loop MXU
-    dots and cuts loop iterations: 128 -> 512 measured +19% tokens/s at
-    T=1024 and +54% at T=8192 (1024 fails to compile — VMEM — so 512
-    is a hard cap). Defaults are therefore shape-keyed in
-    ``_select_blocks`` (block_q: 1024 for T>=8192, 512 below, clamped
-    to tq; block_k: 512); MXNET_FLASH_BLOCK_Q/K override for probes.
+    Block sizing: ``_select_blocks`` (1024 q positions a program, k/v
+    512 at a time, with this PR's readings); MXNET_FLASH_BLOCK_Q/K
+    override for probes.
     """
     import jax
+    import jax.numpy as jnp
 
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5
+    # one type for all three, so that every product has two operands of it
+    dtype = jnp.result_type(q, k, v)
+    q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
     tq, tk = q.shape[2], k.shape[2]
-    block_q, block_k, _tiles = _select_blocks(tq, tk, block_q, block_k)
-    refusal = "ndim" if q.ndim != 4 else _flash_refusal(
+    block_q, block_k, refusal = _flash_plan(
         tq, tk, q.shape[-1], v.shape[-1], block_q, block_k,
         q.dtype.itemsize)
+    if q.ndim != 4:
+        refusal = "ndim"
+    elif causal and tq != tk and refusal is None:
+        # the causal kernels unroll each block's own diagonal steps
+        refusal = "causal_rectangle"
     if refusal is not None:
         _fallback("flash_attention", refusal, tuple(q.shape))
         return _attention_reference(q, k, v, causal, scale)

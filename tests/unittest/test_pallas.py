@@ -136,24 +136,30 @@ def test_flash_attention_block_divisor_shrink(monkeypatch):
 
 
 def test_flash_block_selection_rules():
-    """Block selection must only emit hardware-legal tilings: block_q
-    rides the lane dim of the stats blocks, so it must be a multiple of
-    128 or the full q length (advisor r4); the default is shape-keyed
-    (1024 at T>=8192)."""
+    """Block selection must only emit hardware-legal tilings: both blocks
+    ride the lane dim of score tiles and stats blocks, so each must be a
+    multiple of 128 (advisor r4), and block_k must divide block_q (a
+    causal kernel unrolls its own block's block_q // block_k diagonal
+    steps); the defaults are 1024 and 512 at every length (PR 28)."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
     assert pk._select_blocks(8192, 8192) == (1024, 512, True)
     assert pk._select_blocks(16384, 16384) == (1024, 512, True)
-    assert pk._select_blocks(4096, 4096) == (512, 512, True)
-    # block_k is hard-capped at 512 (1024 fails to compile on chip)
+    assert pk._select_blocks(4096, 4096) == (1024, 512, True)
+    assert pk._select_blocks(1024, 1024) == (1024, 512, True)
+    assert pk._select_blocks(512, 512) == (512, 512, True)
+    # block_k is hard-capped at 512 (a 1024 x 1024 float32 score tile is
+    # 4 MiB and the kernels hold two or three)
     assert pk._select_blocks(8192, 8192, block_k=1024) == (1024, 512, True)
     # divisor shrink keeps tileable lengths on the kernel, scanning all
-    # 128-multiples (8320 = 128*65 tiles at 640, not a power-of-two)
-    assert pk._select_blocks(640, 640) == (128, 128, True)
-    assert pk._select_blocks(1280, 1280) == (256, 256, True)
+    # 128-multiples (8320 = 128*65 tiles at 640, not a power-of-two),
+    # and block_k shrinks on to a divisor of block_q
+    assert pk._select_blocks(640, 640) == (640, 128, True)
+    assert pk._select_blocks(1280, 1280) == (640, 128, True)
+    assert pk._select_blocks(1536, 1536) == (768, 384, True)
     assert pk._select_blocks(8320, 8320) == (640, 128, True)
     # a sub-128 request rounds up to a legal block instead of going dense
-    assert pk._select_blocks(8192, 8192, block_q=64) == (128, 512, True)
+    assert pk._select_blocks(8192, 8192, block_q=64) == (128, 128, True)
     # off-128 lengths have NO legal tiling — probed on real Mosaic (r5):
     # even a full-dim off-128 block fails, because the backward kernels'
     # dynamic lane slices need a provable 128-multiple start index. Such
@@ -165,12 +171,214 @@ def test_flash_block_selection_rules():
         assert not ok, (tq, tk)
     # an explicit sub-128 block_q is rounded up to the legal 128 tiling
     # rather than lowered as-is or dropped to dense
-    assert pk._select_blocks(256, 256, block_q=64) == (128, 256, True)
+    assert pk._select_blocks(256, 256, block_q=64) == (128, 128, True)
     # a non-128-multiple request re-scans for a legal divisor instead of
     # going dense (192 @ 4992 -> 128, 320 @ 1280 -> 256); the k side
-    # scans the same way (4992 = 13*384)
-    assert pk._select_blocks(4992, 4992, block_q=192) == (128, 384, True)
+    # scans the same way, among the divisors of block_q
+    assert pk._select_blocks(4992, 4992, block_q=192) == (128, 128, True)
     assert pk._select_blocks(1280, 1280, block_q=320) == (256, 256, True)
+    assert pk._select_blocks(512, 512, block_q=256, block_k=512) == (
+        256, 256, True)
+    # the resolution is a fixed point: the dkv kernel asks again with the
+    # blocks the forward was given
+    for t in (640, 1024, 1536, 8320):
+        bq, bk, _ = pk._select_blocks(t, t)
+        assert pk._select_blocks(t, t, bq, bk) == (bq, bk, True)
+
+
+def test_flash_plan_halves_the_default_block(monkeypatch):
+    """A block_q the caller did not name is halved while the operands
+    overflow the scoped VMEM; a named one is refused; operands that fit
+    at no block go to XLA."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2) == (
+        1024, 512, None)
+    assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4) == (
+        512, 512, None)
+    assert pk._flash_plan(4096, 4096, 128, 128, block_q=1024,
+                          itemsize=4)[2] == "vmem"
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=4)[2] == "vmem"
+    monkeypatch.setenv("MXNET_FLASH_BLOCK_Q", "1024")  # a probe is a name
+    assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4)[2] == "vmem"
+
+
+#: (T, block_q, block_k): one tile; several tiles with block_q != block_k;
+#: a square the diagonal crosses on some visited tiles and not on others
+FLASH_TILINGS = {"one_tile": (128, 128, 128),
+                 "wide_k": (256, 128, 256),
+                 "tall_q": (512, 256, 128),
+                 "diagonal": (512, 128, 128)}
+#: tolerance of (the forward, the gradients): float32 absolute, as the
+#: float32 tests above; bfloat16 as a share of the reference's largest
+#: value (its outputs and its p / ds operands keep 8 bits of mantissa)
+FLASH_TOLERANCE = {"float32": (2e-5, 3e-4), "bfloat16": (1.5e-2, 1.5e-2)}
+
+
+@pytest.mark.parametrize("tiling", sorted(FLASH_TILINGS))
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_by_type_and_tiling(dtype, causal, tiling):
+    """Forward and jax.grad against the dense reference computed in
+    float32 from the same (rounded) inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    t, bq, bk = FLASH_TILINGS[tiling]
+    rng = np.random.RandomState(11)
+    d = 64
+    q, k, v, g = (jnp.asarray(rng.randn(1, 2, t, d), dtype)
+                  for _ in range(4))
+
+    def fast(q, k, v):
+        return pk.flash_attention(q, k, v, causal=causal, block_q=bq,
+                                  block_k=bk)
+
+    def ref(q, k, v):
+        return pk._attention_reference(q, k, v, causal, d ** -0.5)
+
+    routed = dict(pk.FALLBACKS)
+    out, pull = jax.vjp(fast, q, k, v)
+    got = (out,) + pull(g)
+    assert pk.FALLBACKS == routed  # the kernels took it
+    assert all(a.dtype == q.dtype for a in got)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want, pull = jax.vjp(ref, *f32)
+    want = (want,) + pull(g.astype(jnp.float32))
+    for i, (a, b) in enumerate(zip(got, want)):
+        tol = FLASH_TOLERANCE[dtype][i > 0]
+        if dtype == "bfloat16":
+            tol *= float(np.abs(b).max())
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol, rtol=0)
+
+
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, loops, calls and kernels included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's own
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_products_take_the_inputs_type(dtype):
+    """bfloat16 q, k, v: every product of the three kernels is fed
+    bfloat16 and accumulates in float32; float32 inputs keep float32
+    products. Read from the kernels' jaxprs: 2 + 3 + 4 products, each
+    once in the loop's tile body and once in the diagonal step's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    q = jnp.zeros((1, 1, 256, 64), dtype)
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, block_q=128,
+                                  block_k=128).astype(jnp.float32).sum()
+
+    outer = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    kernels = {
+        eqn.params["name"]: [e for e in _eqns(eqn.params["jaxpr"])
+                             if e.primitive.name == "dot_general"]
+        for eqn in _eqns(outer.jaxpr) if eqn.primitive.name == "pallas_call"}
+    assert {name: len(dots) for name, dots in kernels.items()} == {
+        "flash_fwd": 4, "flash_bwd_dq": 6, "flash_bwd_dkv": 8}
+    for name, dots in kernels.items():
+        for eqn in dots:
+            operands = {str(var.aval.dtype) for var in eqn.invars}
+            assert operands == {dtype}, (name, eqn)
+            assert eqn.params["preferred_element_type"] == jnp.float32, (
+                name, eqn)
+
+
+def test_flash_calls_are_counted():
+    """Every call site that takes the kernels is counted with the operand
+    type of its products and the tiles it visits / masks / of the square
+    (a tile wholly under the diagonal takes the body without a mask)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def took(shape, dtype, **kw):
+        before = dict(pk.FLASH_CALLS)
+        x = jax.ShapeDtypeStruct(shape, dtype)
+        jax.eval_shape(jax.grad(
+            lambda q, k, v: pk.flash_attention(q, k, v, **kw).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+        return {key: n - before.get(key, 0)
+                for key, n in pk.FLASH_CALLS.items()
+                if n != before.get(key, 0)}
+
+    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    # the benchmark cell's attention: as it runs (1024 x 512), at 256 x 256
+    assert took((8, 16, 1024, 64), jnp.bfloat16, causal=True) == {
+        (name, "bfloat16", (3, 2, 4)): 1 for name in kernels}
+    assert took((8, 16, 1024, 64), jnp.bfloat16, causal=True, block_q=256,
+                block_k=256) == {
+        (name, "bfloat16", (10, 4, 16)): 1 for name in kernels}
+    assert took((1, 1, 512, 64), jnp.float32, causal=True, block_q=256,
+                block_k=128) == {
+        (name, "float32", (10, 4, 16)): 1 for name in kernels}
+    assert took((1, 1, 512, 64), jnp.float32, causal=False, block_q=256,
+                block_k=128) == {
+        (name, "float32", (16, 0, 16)): 1 for name in kernels}
+
+
+@pytest.mark.parametrize("t,step", [(1024, 512), (1024, 256), (1152, 128)])
+def test_flash_tile_counts_match_the_mask(t, step):
+    """What is counted is the causal square itself: the step x step tiles
+    with a visible element are visited, those that also hold a hidden
+    one (the diagonal's) are masked."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    seen = (np.arange(t)[None, :] <= np.arange(t)[:, None]).reshape(
+        t // step, step, t // step, step).transpose(0, 2, 1, 3)
+    visited = seen.any(axis=(2, 3))
+    masked = visited & ~seen.all(axis=(2, 3))
+    assert pk._tile_counts(t, t, step, True) == (
+        visited.sum(), masked.sum(), visited.size)
+
+
+def test_flash_causal_over_unequal_lengths_goes_to_xla():
+    """The causal kernels unroll each block's own diagonal steps, which
+    exist only where tq == tk: a causal rectangle is routed, and counted."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(12)
+    q = jnp.asarray(rng.randn(1, 1, 128, 32), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 1, 256, 32), jnp.float32)
+    before = pk.FALLBACKS.get(("flash_attention", "causal_rectangle"), 0)
+    out = pk.flash_attention(q, k, k, causal=True)
+    assert pk.FALLBACKS[("flash_attention", "causal_rectangle")] == before + 1
+    ref = pk._attention_reference(q, k, k, True, 32 ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    # without the mask the rectangle stays on the kernels, forward and
+    # backward (the dkv kernel fits the two blocks to the other length)
+    import jax
+
+    routed = dict(pk.FALLBACKS)
+    out, pull = jax.vjp(
+        lambda q, k, v: pk.flash_attention(q, k, v, causal=False), q, k, k)
+    assert pk.FALLBACKS == routed
+    ref, pull_ref = jax.vjp(
+        lambda q, k, v: pk._attention_reference(q, k, v, False, 32 ** -0.5),
+        q, k, k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for a, b in zip(pull(out), pull_ref(out)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 
 
 def test_flash_attention_fallback_odd_shapes():
