@@ -216,18 +216,30 @@ def kda_layer(x, p, cfg: HybridConfig):
     H, D = cfg.kda_heads, cfg.kda_head_dim
     mm = functools.partial(_mm, dtype=cfg.dtype)
 
-    def heads(w, taps):
-        return jax.nn.silu(_short_conv(mm(x, w), taps)).reshape(B, T, H, D)
+    def heads(w, taps):  # [B, T, H * D], a head's channels side by side
+        return jax.nn.silu(_short_conv(mm(x, w), taps))
+
+    # which head a channel belongs to, [H * D, H]: a head's sum of squares
+    # and its way back to the channels are float32 products with it, so
+    # that q and k stay laid out as the projections left them (a sum over
+    # the last axis of [B, T, H, D] makes XLA re-tile the tensor twice)
+    member = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
+        jnp.float32)
+    hi = lax.Precision.HIGHEST
 
     def unit(t):
-        return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+        scale = lax.rsqrt(jnp.dot(t * t, member, precision=hi) + 1e-6)
+        return t * jnp.dot(scale, member.T, precision=hi)
+
+    def split(t):
+        return t.reshape(B, T, H, D)
 
     with jax.named_scope("kda"):
-        q = unit(heads(p["wq"], p["conv_q"])) * D ** -0.5
-        k = unit(heads(p["wk"], p["conv_k"]))
-        v = heads(p["wv"], p["conv_v"])
-        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
-            mm(mm(x, p["f_a"]), p["f_b"]) + p["dt_bias"]).reshape(B, T, H, D)
+        q = split(unit(heads(p["wq"], p["conv_q"])) * D ** -0.5)
+        k = split(unit(heads(p["wk"], p["conv_k"])))
+        v = split(heads(p["wv"], p["conv_v"]))
+        g = split(jnp.repeat(-jnp.exp(p["A_log"]), D) * jax.nn.softplus(
+            mm(mm(x, p["f_a"]), p["f_b"]) + p["dt_bias"]))
         beta = jax.nn.sigmoid(mm(x, p["wb"]))
         o = kda_attention(q, k, v, g, beta, dtype=cfg.dtype)
         gate = jax.nn.sigmoid(mm(mm(x, p["g_a"]), p["g_b"]))

@@ -187,8 +187,9 @@ def test_latent_attention_shape_compiles(one_chip):
 
 
 def test_kda_kernels_compile(one_chip):
-    """The gated delta rule's pass of the state across chunks, forward and
-    backward, at the hybrid LM's head (128 wide) and operand type."""
+    """The gated delta rule's two stages, forward and backward, at the
+    benchmark cell's shapes (B=1, T=8192, 32 heads of 128) and operand
+    type: one kernel each, counted, and nothing routed to XLA."""
     import jax
     import jax.numpy as jnp
 
@@ -199,12 +200,13 @@ def test_kda_kernels_compile(one_chip):
         return kda.kda_attention(q, k, v, g, beta, dtype=jnp.bfloat16).sum()
 
     routed, took = dict(pk.FALLBACKS), dict(kda.KDA_CALLS)
-    x = jax.ShapeDtypeStruct((1, 2048, 4, 128), jnp.float32,
+    x = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32,
                              sharding=one_chip)
-    b = jax.ShapeDtypeStruct((1, 2048, 4), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32, sharding=one_chip)
     calls = _kernels(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x, x, b)
-    assert sum("kda_state_fwd" in ln for ln in calls) == 1
-    assert sum("kda_state_bwd" in ln for ln in calls) == 1
+    for kernel in ("kda_chunk_fwd", "kda_chunk_bwd", "kda_state_fwd",
+                   "kda_state_bwd"):
+        assert sum(kernel in ln for ln in calls) == 1, kernel
+        assert kda.KDA_CALLS[(kernel, "bfloat16")] == took.get(
+            (kernel, "bfloat16"), 0) + 1, kernel
     assert pk.FALLBACKS == routed
-    assert kda.KDA_CALLS[("kda_state_bwd", "bfloat16")] == took.get(
-        ("kda_state_bwd", "bfloat16"), 0) + 1

@@ -3,10 +3,12 @@ plain reference (``benchmark/references/kimi-linear-48b-a3b.py``: float32
 ``jax.numpy``, the token-by-token recurrence, masked softmax, a loop over
 experts), at small sizes on the CPU with seeded weights:
 
-* KDA's chunked path (plain XLA, and the ``kda_state_fwd`` /
-  ``kda_state_bwd`` kernels in interpret mode) against the recurrence,
-  values and all five gradients, over several chunks with gates near both
-  ends of their range;
+* KDA's chunked path (plain XLA, and both stages as kernels in interpret
+  mode: ``kda_chunk_fwd`` / ``kda_chunk_bwd``, ``kda_state_fwd`` /
+  ``kda_state_bwd``) against the recurrence, values and all five
+  gradients, over several chunks with gates near both ends of their range;
+  the in-chunk kernels against the XLA stage they replace, output by
+  output and gradient by gradient;
 * latent attention against the reference's;
 * the share-aware expert layer against the reference's loop, in a typical
   batch, in one where every token names the same held expert (more than the
@@ -132,15 +134,16 @@ def test_kda_chunked_matches_the_recurrence(ref, path, monkeypatch):
     got_grads = jax.grad(loss(kda.kda_attention), argnums=range(5))(*args)
     for got_g, want_g in zip(got_grads, want_grads):
         close(got_g, want_g, 2e-5)
-    if path == "kernels":  # both kernels were taken, nothing was routed
-        assert kda.KDA_CALLS.get(("kda_state_fwd", "float32"), 0) > took.get(
-            ("kda_state_fwd", "float32"), 0)
-        assert kda.KDA_CALLS.get(("kda_state_bwd", "float32"), 0) > took.get(
-            ("kda_state_bwd", "float32"), 0)
+    if path == "kernels":  # both stages' kernels were taken, nothing routed
+        for kernel in ("kda_chunk_fwd", "kda_chunk_bwd", "kda_state_fwd",
+                       "kda_state_bwd"):
+            assert kda.KDA_CALLS.get((kernel, "float32"), 0) > took.get(
+                (kernel, "float32"), 0), kernel
         assert pk.FALLBACKS == routed
-    else:  # counted as routed to XLA
-        assert pk.FALLBACKS[("kda", "disabled")] > routed.get(
-            ("kda", "disabled"), 0)
+    else:  # both stages counted as routed to XLA
+        for stage in ("kda", "kda_chunk"):
+            assert pk.FALLBACKS[(stage, "disabled")] > routed.get(
+                (stage, "disabled"), 0)
 
 
 def test_kda_narrow_head_is_routed_to_xla_and_counted(ref, monkeypatch):
@@ -150,11 +153,16 @@ def test_kda_narrow_head_is_routed_to_xla_and_counted(ref, monkeypatch):
 
     monkeypatch.setenv("MXNET_PALLAS", "1")
     args, _ = _kda_inputs(T=128, D=16)
-    before = pk.FALLBACKS.get(("kda", "untileable"), 0)
+    before = dict(pk.FALLBACKS)
     close(kda.kda_attention(*args), ref.delta_rule(*args), 1e-5)
-    assert pk.FALLBACKS[("kda", "untileable")] == before + 1
+    for stage in ("kda", "kda_chunk"):
+        assert pk.FALLBACKS[(stage, "untileable")] == before.get(
+            (stage, "untileable"), 0) + 1
     with pytest.raises(ValueError):
         kda.kda_attention(*(a[:, :100] for a in args))
+    # a 256-wide head overflows the in-chunk backward's VMEM on the chip
+    assert kda._plan(1, 2048, 4, 256, 256) == (0, "vmem")
+    assert kda._plan(1, 8192, 32, 128, 128) == (8, None)
 
 
 def test_kda_bfloat16_operands_stay_close(ref):
@@ -167,6 +175,101 @@ def test_kda_bfloat16_operands_stay_close(ref):
     args, _ = _kda_inputs()
     close(kda.kda_attention(*args, dtype=jnp.bfloat16),
           ref.delta_rule(*args), 3e-2)
+
+
+PREPARED = ["w", "u0", "qg", "kd", "aqk", "decay"]
+INPUTS = ["q", "k", "v", "g", "beta"]
+
+
+@pytest.fixture(scope="module")
+def chunk_stages():
+    """``kind -> (outputs, gradients)`` of the in-chunk stage as the
+    kernels (interpret mode) and as the XLA stage they replace, under the
+    same dense cotangents: 16 chunks of a 128-wide head, two grid steps a
+    head. Computed once a kind; each output and gradient is a case."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import kda
+
+    cache = {}
+
+    def stages(kind):
+        if kind in cache:
+            return cache[kind]
+        dtype = jnp.dtype("bfloat16" if kind == "bfloat16" else "float32")
+        args, _ = _kda_inputs(T=1024)
+        if kind == "hard":  # every third position: e^G underflows in a chunk
+            third = jnp.arange(1024)[None, :, None, None] % 3 == 0
+            args = args[:3] + (jnp.where(third, -20.0, args[3]),) + args[4:]
+
+        def xla(*a):
+            return kda._prepare(*(x.transpose(0, 2, 1, 3) for x in a[:4]),
+                                a[4].transpose(0, 2, 1), dtype)
+
+        def kernels(*a):
+            return kda.chunk_stage(*a, dtype=dtype)
+
+        with pytest.MonkeyPatch.context() as mp, \
+                jax.default_matmul_precision("highest"):
+            mp.setenv("MXNET_PALLAS", "1")
+            took = dict(kda.KDA_CALLS)
+            want, pull_want = jax.vjp(xla, *args)
+            got, pull_got = jax.vjp(kernels, *args)
+            keys = jax.random.split(jax.random.PRNGKey(11), len(want))
+            cts = tuple(jax.random.normal(key, o.shape, jnp.float32).astype(
+                o.dtype) for key, o in zip(keys, want))
+            grads = pull_got(cts), pull_want(cts)
+        for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
+            assert kda.KDA_CALLS[(kernel, dtype.name)] == took.get(
+                (kernel, dtype.name), 0) + 1
+        cache[kind] = (got, want), grads
+        return cache[kind]
+
+    return stages
+
+
+#: float32 operands: rounding alone; bfloat16: the band of
+#: ``test_kda_bfloat16_operands_stay_close``; hard gates: a cumulative gate
+#: of -500 is known to 6e-5 in float32, and so is any factor e^(G - G')
+BANDS = {"float32": 1e-5, "bfloat16": 3e-2, "hard": 1e-4}
+
+
+@pytest.mark.parametrize("kind", list(BANDS))
+@pytest.mark.parametrize("name", PREPARED)
+def test_kda_chunk_kernel_outputs_match_xla(chunk_stages, kind, name):
+    (got, want), _ = chunk_stages(kind)
+    at = PREPARED.index(name)
+    assert got[at].shape == want[at].shape
+    assert got[at].dtype == want[at].dtype
+    assert bool(np.all(np.isfinite(np.asarray(got[at], np.float32))))
+    close(got[at], want[at], BANDS[kind])
+
+
+@pytest.mark.parametrize("kind", list(BANDS))
+@pytest.mark.parametrize("name", INPUTS)
+def test_kda_chunk_kernel_gradients_match_xla(chunk_stages, kind, name):
+    _, (got, want) = chunk_stages(kind)
+    at = INPUTS.index(name)
+    assert got[at].shape == want[at].shape
+    assert bool(np.all(np.isfinite(np.asarray(got[at]))))
+    close(got[at], want[at], BANDS[kind])
+
+
+def test_kda_hard_gates_stay_finite_and_match_the_recurrence(ref, monkeypatch):
+    """Gates of -20 a position and harder: e^G underflows within a chunk,
+    and no factor above 1 may be formed on the way. Both stages as kernels
+    give the recurrence's values, finite."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import kda
+
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    args, _ = _kda_inputs()
+    args = args[:3] + (jnp.minimum(args[3] * 4.0, -20.0),) + args[4:]
+    got = kda.kda_attention(*args)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    close(got, ref.delta_rule(*args), 1e-5)
 
 
 # -- MLA, MoE ----------------------------------------------------------------------
