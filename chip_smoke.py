@@ -8,7 +8,7 @@ a seed, and checks by the repo's own means that what comes out is right:
   a. op parity chip vs host — the cases of tools/check_tpu_consistency.py
   b. the public trainer     — mx.FeedForward.fit, ResNet-50 s2d, bs 128,
                               bf16, the scanned K-step path
-  c. the LM trainer         — models/transformer.py at the bench_lm width
+  c. the LM trainer         — models/transformer.py, 1024 wide, 12 layers,
                               through parallel.make_train_step, with the
                               Pallas flash kernels in the compiled step
   d. serving                — serving.Engine over the same width: submit/
@@ -84,7 +84,7 @@ TINY = {
 NEAR_TIE = 0.0625
 
 #: SGD rate of the ResNet-50 phases (momentum 0.9). From a Xavier start
-#: bench_fit.py's 0.05 spikes the loss for a dozen steps before it falls,
+#: 0.05 spikes the loss for a dozen steps before it falls,
 #: and 0.01 still wanders in float32; at this rate the rehearsals fall
 #: step after step over several seeds, which is what a smoke can check.
 STEADY_LR = 0.002
@@ -166,7 +166,7 @@ def phase_a(mx, host_ctx, chip_ctx):
 
 # -- b. the public trainer -----------------------------------------------------
 def learnable_pool(mx, batch, image, ctx, seed, pool=4, classes=10):
-    """A fixed pool of device-resident batches (as bench_fit.py serves),
+    """A fixed pool of device-resident batches,
     in which the label can be read off the image: each of ``classes``
     labels has its own pattern under the noise. A trainer that works
     drives the loss on it down within a few dozen steps."""
@@ -230,7 +230,7 @@ def phase_b(mx, size, ctx, seed):
 
     image, batch, K = size["image"], size["batch"], size["scan_k"]
     steps = K * size["chunks"]
-    # steps per dispatch of the scanned fit path (bench_fit.py's setting)
+    # steps per dispatch of the scanned fit path
     os.environ["MXNET_TRAIN_SCAN_K"] = str(K)
     mx.random.seed(seed)  # the initializer draws from these
     np.random.seed(seed)
@@ -447,8 +447,8 @@ def phase_d(jax, lm_size, size, seed):
 
     cfg = lm_config(lm_size)
     params = init_params(cfg, jax.random.PRNGKey(seed))
-    # the layer-truncated draft bench_serve.make_draft builds: the
-    # target's first layers under its own embeddings and final norm
+    # a layer-truncated draft: the target's first layers under its own
+    # embeddings and final norm
     n_draft = size["draft_layers"]
     draft_params = {"embed": params["embed"],
                     "pos_embed": params["pos_embed"],

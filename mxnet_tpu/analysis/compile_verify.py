@@ -311,8 +311,7 @@ def wrap(name, fn, budget=1, group=None):
     if isinstance(fn, Boundary):
         return fn
     # register the headline counter up front: a clean verified run then
-    # journals an explicit compile.recompiles_total=0 snapshot, which is
-    # what tools/baselines/jit_compile.json holds the line against
+    # journals an explicit compile.recompiles_total=0 snapshot
     _count("compile.recompiles_total", 0)
     b = Boundary(name, fn, budget, group)
     with _lock:

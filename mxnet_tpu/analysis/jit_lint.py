@@ -84,7 +84,6 @@ DEFAULT_TARGETS = (
     os.path.join("serving", "engine.py"),
     os.path.join("serving", "scheduler.py"),
     os.path.join("parallel", "fit_trainer.py"),
-    os.path.join("parallel", "symbol_trainer.py"),
     os.path.join("parallel", "trainer.py"),
     os.path.join("telemetry", "prof.py"),
     "compile",

@@ -837,8 +837,7 @@ def _stub_serving_engine():
     eng = Engine({"embed": np.zeros((64, 8), np.float32)}, mcfg, scfg)
 
     def stub_step(params, k, v, tokens, start, chunk_len, tables, active,
-                  min_batch_bucket=None, temperature=None, top_k=None,
-                  top_p=None, seed=None):
+                  temperature=None, top_k=None, top_p=None, seed=None):
         t = np.asarray(tokens)
         nxt = ((t[:, -1] + np.asarray(start) + 1) % 61 + 1).astype(np.int32)
         return nxt, k, v

@@ -5,8 +5,8 @@ gap (ROADMAP "Compilation layer"; ground: PAPERS.md TVM):
 
 1. **Graph-rewrite passes** over the Symbol graph before executor
    lowering — constant folding (fold.py), NCHW→NHWC layout selection
-   with transpose hoisting (layout.py, the production promotion of
-   tools/probe_layout.py), elementwise-chain fusion (fuse.py) and the
+   with transpose hoisting (layout.py), elementwise-chain fusion
+   (fuse.py) and the
    tuned matmul-accumulation flag (precision.py). Each pass is a
    separate module sharing the ir.py walk utilities with
    ``analysis/graph_lint.py``, individually disableable, and checked

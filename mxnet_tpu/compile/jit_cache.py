@@ -13,7 +13,7 @@ wiring is then the only thing that places it — nothing here overrides
 the directory or keys a sub-directory under it (the path is part of the
 cache key, so a directory that moves never hits). With the variable
 unset the library runs without a cache; the repo's entry points
-(``chip_smoke.py``, ``bench*.py``, ``serving.fleet.replica``) call
+(``chip_smoke.py``, ``benchmark/run.py``, ``serving.fleet.replica``) call
 :func:`enable`, which then places it at one fixed path inside the
 checkout (:data:`REPO_CACHE_DIR`). The tuning database
 (``compile/autotune.py``) lives beside the entries.
@@ -46,7 +46,7 @@ Hit/miss accounting rides jax's monitoring events
 (``/jax/compilation_cache/cache_hits`` / ``cache_misses``) into both
 mxtel counters (``compile.cache_hits_total`` / ``misses_total``) and
 module-level plain ints readable without telemetry (chip_smoke.py and
-bench.py's cold-start leg report them from a bare process).
+the benchmark's ``compile_misses`` read them from a bare process).
 """
 from __future__ import annotations
 

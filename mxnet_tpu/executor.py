@@ -731,9 +731,9 @@ class Executor:
     # -- helpers ---------------------------------------------------------------
     def _release_device_arrays(self):
         """Free this executor's device arg/grad/aux arrays while keeping
-        the traced program (`_run`) usable as a pure function. Trainers
-        that only borrow `_run` (fit_trainer, symbol_trainer) call this
-        so the bound method doesn't pin a second parameter set in HBM.
+        the traced program (`_run`) usable as a pure function. A trainer
+        that only borrows `_run` (fit_trainer) calls this so the bound
+        method doesn't pin a second parameter set in HBM.
         The executor is unusable for forward/backward afterwards."""
         self.arg_arrays = self.grad_arrays = self.aux_arrays = None
         self._outputs_nd = None
@@ -868,8 +868,7 @@ class Executor:
             # Only worth it when EVERY head is a loss op: with any
             # non-loss head, backward() REQUIRES out_grads and re-runs
             # the vjp with real cotangents, so a fused pass here would
-            # compute a full backward only to discard it (same predicate
-            # as parallel/symbol_trainer.py).
+            # compute a full backward only to discard it.
             self._outputs_shape_probe()
             hg = [g for g in self._default_head_grads() if g is not None]
             if _prof.ENABLED:

@@ -921,9 +921,8 @@ class FeedForward(BASE_ESTIMATOR):
         self._pred_exec = None
         self.begin_epoch = begin_epoch
         # TPU extension: mixed-precision training through the scanned fit
-        # path (f32 master weights, `compute_dtype` activations/matmuls;
-        # same scheme as parallel/symbol_trainer.py). None = f32, or set
-        # MXNET_COMPUTE_DTYPE=bfloat16 process-wide.
+        # path (f32 master weights, `compute_dtype` activations/matmuls).
+        # None = f32, or set MXNET_COMPUTE_DTYPE=bfloat16 process-wide.
         import os
 
         self.compute_dtype = (

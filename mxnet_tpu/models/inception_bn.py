@@ -6,7 +6,7 @@ Two variants, matching the reference's symbol files:
   BASELINE.md row 1: 842→2943 img/s on 1→4 GTX 980);
 - ``get_inception_bn`` — the full 224x224 model behind the headline
   ImageNet epoch times (ref: symbol_inception-bn.py; BASELINE.md:
-  2,495 s/epoch at bs=512 on 4x Titan X, the bench.py baseline), and
+  2,495 s/epoch at bs=512 on 4x Titan X), and
   with ``num_classes=21841`` the full-ImageNet-21k config
   (symbol_inception-bn-full.py, imagenet_full.md).
 Ioffe & Szegedy 2015 (arXiv:1502.03167)."""

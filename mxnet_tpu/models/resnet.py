@@ -1,4 +1,5 @@
-"""ResNet (v1) — baseline config 2, the bench.py flagship
+"""ResNet (v1) — baseline config 2, the benchmark's Symbol-path
+configuration ``resnet50``
 (ref: example/image-classification/symbol_resnet.py; arch per He et al.).
 Built bf16-friendly: BN statistics in f32; conv accumulation follows the
 backend default (f32 on TPU MXU).

@@ -93,30 +93,9 @@ def param_partition_specs(cfg: TransformerConfig):
     }
 
 
-def _ablate(which):
-    """Measurement knob: MXNET_LM_ABLATE is a comma set naming model
-    pieces to stub out for time-attribution probes on the real chip
-    ("ln" = layer norms become scale+bias only, "ce" = the loss head
-    skips log-softmax). Default off; numbers in docs/perf_analysis.md.
-    Same pattern as MXNET_BN_AUTODIFF / MXNET_BN_STATS_SAMPLE."""
-    import os
-
-    raw = os.environ.get("MXNET_LM_ABLATE", "")
-    names = {t.strip() for t in raw.split(",") if t.strip()}
-    unknown = names - {"ln", "ce"}
-    if unknown:
-        # a silently ignored typo would corrupt a recorded perf table
-        raise ValueError("MXNET_LM_ABLATE: unknown piece(s) %s "
-                         "(valid: ln, ce)" % sorted(unknown))
-    return which in names
-
-
 def _layer_norm(x, p, eps=1e-5):
     import jax.numpy as jnp
 
-    if _ablate("ln"):  # stats passes removed; affine kept
-        return (x.astype(jnp.float32) * p["scale"]
-                + p["bias"]).astype(x.dtype)
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
@@ -212,10 +191,6 @@ def loss_fn(cfg: TransformerConfig, mesh=None):
         tokens = batch["tokens"]
         logits = forward(params, tokens[:, :-1], cfg, mesh=mesh)
         targets = tokens[:, 1:]
-        if _ablate("ce"):  # keep the logits matmul, skip the softmax-CE
-            return -jnp.mean(jnp.take_along_axis(
-                logits.astype(jnp.float32), targets[..., None],
-                axis=-1)[..., 0])
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return jnp.mean(nll)

@@ -18,7 +18,6 @@ non-TPU backends low-precision conv accumulation is backend-default.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -417,7 +416,7 @@ register(
 
 
 # -- BatchNorm (ref: src/operator/batch_norm-inl.h:314) ------------------------
-def _bn_norm_fwd_impl(x, gamma, beta, eps, axes, bshape, sample=1):
+def _bn_norm_fwd_impl(x, gamma, beta, eps, axes, bshape):
     # E[x^2]-E[x]^2 instead of jnp.var's E[(x-E[x])^2]: the two-pass
     # form must finish the mean reduction before it can START the
     # variance pass (two full HBM reads of the activation, serialized);
@@ -426,17 +425,8 @@ def _bn_norm_fwd_impl(x, gamma, beta, eps, axes, bshape, sample=1):
     # cuDNN BN fast path makes the same trade). Clamp: cancellation
     # can produce a small negative where true var ~ 0.
     x32 = x.astype(jnp.float32)
-    # sample>1: statistics from a CONTIGUOUS batch prefix of N/sample
-    # rows (ghost-BN style estimator over N/sample images x all spatial
-    # positions; batches are shuffled so a prefix is an unbiased sample)
-    # — cuts the stats pass's HBM read by the same factor. Contiguity
-    # matters: a strided x[::k] slice measured 897 img/s vs the 2,630
-    # baseline on chip (XLA materializes the gather); the prefix slice
-    # is a view-shaped read that fuses. Opt-in via
-    # MXNET_BN_STATS_SAMPLE; default exact (reference semantics).
-    xs = x32[:max(1, x32.shape[0] // sample)] if sample > 1 else x32
-    mean = jnp.mean(xs, axis=axes)
-    sqmean = jnp.mean(jnp.square(xs), axis=axes)
+    mean = jnp.mean(x32, axis=axes)
+    sqmean = jnp.mean(jnp.square(x32), axis=axes)
     var = jnp.maximum(sqmean - jnp.square(mean), 0.0)
     # multiply by rsqrt (not divide by sqrt): XLA:TPU keeps the division
     # out of the fused elementwise loop this way
@@ -512,23 +502,9 @@ def _bn_fwd(params, inputs, aux, is_train, rng):
     axes = (0,) + tuple(range(2, data.ndim))
     bshape = (1, -1) + (1,) * (data.ndim - 2)
     if is_train and not params["use_global_stats"]:
-        try:
-            sample = max(1, int(os.environ.get("MXNET_BN_STATS_SAMPLE", "1")))
-        except ValueError:
-            sample = 1
-        if sample > 1 or os.environ.get("MXNET_BN_AUTODIFF", "") == "1":
-            # autodiff path: the r4 backward (A/B probe — measured within
-            # ~0.6% of the custom vjp, docs/perf_analysis.md r5) and the
-            # only path where subsampled statistics differentiate exactly
-            # (the stats gradient flows to sampled rows only; the custom
-            # bwd formula assumes full-batch stats)
-            out, mean, var, _ = _bn_norm_fwd_impl(
-                data, gamma.astype(jnp.float32), beta.astype(jnp.float32),
-                eps, axes, bshape, sample=sample)
-        else:
-            out, mean, var = _bn_train_norm(
-                data, gamma.astype(jnp.float32), beta.astype(jnp.float32),
-                eps, axes, bshape)
+        out, mean, var = _bn_train_norm(
+            data, gamma.astype(jnp.float32), beta.astype(jnp.float32),
+            eps, axes, bshape)
         new_mm = moving_mean * momentum + jax.lax.stop_gradient(mean) * (1 - momentum)
         new_mv = moving_var * momentum + jax.lax.stop_gradient(var) * (1 - momentum)
         return [out], [new_mm, new_mv]

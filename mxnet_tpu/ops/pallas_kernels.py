@@ -563,20 +563,16 @@ def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
     """``(block_q, block_k, refusal)``: the blocks ``flash_attention``
     runs these operands at, and why it would NOT take the Pallas kernels
     (a ``FALLBACKS`` reason) or None when it will: every gate the kernels
-    apply — enablement, block-tiling legality, the ``MXNET_FLASH_MIN_T``
-    crossover, and the scoped-VMEM footprint. A ``block_q`` the caller
-    did not name is halved while the footprint overflows, so that long
-    or wide operands stay on the kernels at a smaller block."""
+    apply — enablement, block-tiling legality and the scoped-VMEM
+    footprint. A ``block_q`` the caller did not name is halved while the
+    footprint overflows, so that long or wide operands stay on the
+    kernels at a smaller block."""
     named = block_q is not None
     block_q, block_k, tiles = _select_blocks(tq, tk, block_q, block_k)
     if not enabled():
         return block_q, block_k, "disabled"
     if not tiles:
         return block_q, block_k, "untileable"
-    # the crossover is a hardware-perf decision; interpret mode
-    # (CPU tests) always takes the kernel path for coverage
-    if tk < _env_int("MXNET_FLASH_MIN_T", 0) and not _interpret():
-        return block_q, block_k, "below_min_t"
     while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize) > _VMEM_LIMIT:
         smaller = _select_blocks(tq, tk, block_q // 2, block_k)
         if named or not smaller[2] or smaller[0] >= block_q:
@@ -603,14 +599,8 @@ def flash_attention(q, k, v, causal=True, scale=None,
     Forward AND backward run as Pallas kernels: the forward saves the
     per-row log-sum-exp, and the backward reconstructs attention weights
     blockwise from it (standard flash-attention backward), so the [T, T]
-    score matrix never exists in HBM in either direction. The kernel is
-    the default whenever shapes tile (an earlier round's claim, on
-    another chip and an older body, docs/perf_analysis.md r4: the kernel
-    backward beats the dense XLA path at every training length, 1.06x
-    tokens/s at T=1024 rising to 19x at T=8192; not re-measured here).
-    MXNET_FLASH_MIN_T (default 0) can re-impose a crossover;
-    MXNET_FLASH_DENSE_BWD=1 forces the dense recompute backward for A/B
-    probes.
+    score matrix never exists in HBM in either direction. The kernels
+    run whenever the shapes tile.
 
     The products take their operands in the inputs' type: bfloat16 q, k,
     v (and the p and ds tiles rebuilt from them) go to the MXU as
@@ -655,8 +645,6 @@ def flash_attention(q, k, v, causal=True, scale=None,
         _fallback("flash_attention", refusal, tuple(q.shape))
         return _attention_reference(q, k, v, causal, scale)
 
-    dense_bwd = os.environ.get("MXNET_FLASH_DENSE_BWD", "") == "1"
-
     @jax.custom_vjp
     def attn(q, k, v):
         o, _ = _flash_attention_pallas(q, k, v, causal, scale,
@@ -670,11 +658,6 @@ def flash_attention(q, k, v, causal=True, scale=None,
 
     def bwd(res, g):
         q, k, v, o, lse = res
-        if dense_bwd:  # A/B probe path: recompute attention densely
-            _, pullback = jax.vjp(
-                lambda q, k, v: _attention_reference(q, k, v, causal, scale),
-                q, k, v)
-            return pullback(g)
         return _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal,
                                            scale, block_q, block_k)
 

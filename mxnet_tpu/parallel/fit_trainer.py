@@ -5,8 +5,8 @@ python/mxnet/model.py:117 _train_multi_device) — its engine pipelines the
 per-batch pushes so the Python loop never blocks. Here every jitted
 dispatch pays a host-side cost, and a loop that fences every batch
 (metric updates do) pays it in full, so a per-batch loop is
-structurally slower than the compiled trainer bench.py measures.
-This module closes that gap for the public API:
+structurally slower than one compiled train step a dispatch
+(``parallel/trainer.py``). This module closes that gap for the public API:
 K training steps run as ONE dispatched ``lax.scan`` program — forward,
 backward, and the REAL ``mxnet_tpu.optimizer.Optimizer.update`` traced
 into the program — so ``FeedForward.fit``/``Module.fit`` get the same
@@ -263,7 +263,7 @@ class FitTrainer:
                 ]
                 outs, new_aux = self._run(vals, aux, rng, is_train=True)
                 # inexact heads only get cotangents; aux is state, not a
-                # differentiable output (see symbol_trainer.step_impl)
+                # differentiable output
                 flt = [o for o in outs
                        if jnp.issubdtype(o.dtype, jnp.inexact)]
                 return flt, (outs, new_aux)

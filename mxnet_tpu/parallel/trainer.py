@@ -4,8 +4,8 @@ Where the reference's hot loop is Engine pushes of per-op kernels plus
 KVStore reduce (SURVEY §3.1), the TPU-native hot loop is ONE jit-compiled
 program per step: forward + backward + optimizer update, with buffer
 donation for in-place weight updates and shardings that put gradients on
-ICI all-reduces. This is what bench.py measures and what the Module/KVStore
-facade ultimately delegates to on a mesh.
+ICI all-reduces. Both LM cells of the benchmark run it, and the
+Module/KVStore facade ultimately delegates to it on a mesh.
 
 Sharding model: params/opt_state are committed to the mesh with
 jax.device_put before training (ShardedTrainer does this); jit then infers

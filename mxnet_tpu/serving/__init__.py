@@ -17,17 +17,15 @@ single-request fixed-shape ``Predictor``:
   logits never leave the device;
 - :mod:`.scheduler` — continuous batching: admit/evict per decode step
   against a token budget (speculative slots cost their whole verify
-  chunk), prefill/decode split, recompute-style preemption (plus the
-  static-batching baseline policy for A/B);
+  chunk), prefill/decode split, recompute-style preemption;
 - :mod:`.engine` — the request front-end: ``Engine.submit(prompt) ->
   stream of tokens``, a synchronous ``generate`` batch API,
   cancellation, max-queue-depth admission control, draft-model
   speculative decoding (``MXNET_SERVE_SPEC``, off by default), and the
   ``serving.*`` mxtel catalog.
 
-Bench: ``bench_serve.py`` (Poisson open-loop load, static vs continuous
-tokens/s + p99 TTFT; ``--spec`` for the speculative leg). Guide:
-docs/how_to/serving.md.
+On the chip the engine runs in ``chip_smoke.py``; the benchmark has no
+serving cell yet (PERF.md section 7). Guide: docs/how_to/serving.md.
 """
 from __future__ import annotations
 

@@ -6,8 +6,8 @@ params, a paged KV pool sized by :class:`ServingConfig`, a
 (:class:`~.model.ServingModel`). Each ``step()`` runs at most one
 decode batch and one prefill batch (scheduler.py module docstring);
 ``start()`` drives steps from a background thread so ``submit`` is a
-non-blocking producer API, while tests and the bench drive ``step()``
-directly for determinism.
+non-blocking producer API, while tests drive ``step()`` directly for
+determinism.
 
 Admission control: ``submit`` raises :class:`QueueFullError` past
 ``max_queue_depth`` (counted as a rejection — the caller sheds load),
@@ -33,8 +33,7 @@ and ``serving.spec_turns`` / ``serving.spec_tokens_drafted`` /
 window — docs/how_to/weight_sync.md), ``serving.token_latency_s``
 (gap between consecutive tokens of one request) and
 ``serving.spec_accepted_tokens``. Mirrored as plain numbers in
-``Engine.stats()`` so telemetry-off processes (bench subprocesses)
-still get the record.
+``Engine.stats()`` so telemetry-off processes still get the record.
 
 Live weight sync (ISSUE 17, ``MXNET_WSYNC``): ``install_weights``
 swaps a staged, gated param set (target + draft + host unembed)
@@ -107,7 +106,6 @@ class ServingConfig:
     prefill_chunk: int = None
     token_budget: int = None
     max_queue_depth: int = None
-    policy: str = "continuous"
     eos_id: int = None
     max_seq_tokens: int = None   # per-request cap; default model max_seq_len
     # speculative decoding (off by default — with spec False the engine
@@ -225,7 +223,7 @@ class Engine:
     Parameters
     ----------
     params : pytree
-        ``models/transformer.py`` params (what bench_lm.py trains).
+        ``models/transformer.py`` params (``init_params``' pytree).
     model_cfg : TransformerConfig
     cfg : ServingConfig, optional
     draft_params, draft_cfg : pytree / TransformerConfig, optional
@@ -286,13 +284,6 @@ class Engine:
                 raise MXNetError(
                     "ServingConfig.spec requires draft_params + "
                     "draft_cfg (the draft transformer)")
-            if self.cfg.policy == "static":
-                # the static policy is the fixed-shape A/B baseline;
-                # spec turns dispatch at ragged buckets and would
-                # silently break its methodology — reject the combo
-                raise MXNetError(
-                    "speculative decoding requires policy="
-                    "'continuous' (static is the fixed-shape baseline)")
             if self.cfg.spec_k < 1:
                 raise MXNetError("spec_k must be >= 1, got %d"
                                  % self.cfg.spec_k)
@@ -315,7 +306,7 @@ class Engine:
         self.sched = Scheduler(
             self.pool, max_batch=self.cfg.max_batch,
             prefill_chunk=self.cfg.prefill_chunk,
-            token_budget=self.cfg.token_budget, policy=self.cfg.policy,
+            token_budget=self.cfg.token_budget,
             max_active=self.cfg.max_active, draft_pool=self.draft_pool,
             spec_k=spec_k, events_max=self.cfg.events_max)
         # under MXNET_ENGINE_VERIFY=1 the locks are TracedLock-wrapped:
@@ -954,22 +945,16 @@ class Engine:
         start = np.asarray(
             [len(r.prompt) + len(r.generated) - 1 for r in reqs], np.int32)
         temp, tk, tp, sd = self._samp_arrays(reqs)
-        # static policy = fixed-shape serving: decode dispatches at the
-        # full batch width even as the batch drains (dead slots are
-        # padded lanes), faithfully paying what static batching pays on
-        # accelerators where a decode step costs the same at any live
-        # count; continuous dispatches at the ragged bucket
-        min_b = self.cfg.max_batch if self.cfg.policy == "static" else None
         # token-vector-only contract: the step's one D2H is the sampled
         # token vector at bucket width (4 bytes/lane) — the ledger
         # fails the turn if anything more (e.g. logits) crosses
-        Bv = bucket_for(max(B, min_b or 1), self.model.batch_buckets)
+        Bv = bucket_for(B, self.model.batch_buckets)
         with _tel.span("serve.decode"), \
                 _cv.d2h_region("serve.decode_step", budget_bytes=4 * Bv):
             nxt, kp, vp = self.model.step(
                 self.params, self.pool.k, self.pool.v, tokens, start,
                 np.ones((B,), np.int32), self._tables(reqs),
-                np.ones((B,), bool), min_batch_bucket=min_b,
+                np.ones((B,), bool),
                 temperature=temp, top_k=tk, top_p=tp, seed=sd)
         now = time.monotonic()
         with self._lock:
@@ -1269,8 +1254,8 @@ class Engine:
                 _tel.histogram("serving.ttft_s").observe(now - req.submit_t)
             if now <= self._sync_mark_until:
                 # TTFT landed inside a sync window: the degradation
-                # signal tools/perf_gate.py gates (ttft_sync_p99_s must
-                # stay within tolerance of the no-sync baseline)
+                # signal ``tools/chaos.py --wsync`` holds (ttft_sync_p99_s
+                # must stay within tolerance of the no-sync baseline)
                 self._sync_ttfts.append(now - req.submit_t)
                 if _tel.ENABLED:
                     _tel.histogram("serving.ttft_sync_s").observe(
@@ -1382,14 +1367,14 @@ class Engine:
 
     # -- reporting -----------------------------------------------------------
     def latency_samples(self):
-        """Copies of the raw TTFT / per-token latency sample lists (the
-        bench slices per-window percentiles out of a reused engine)."""
+        """Copies of the raw TTFT / per-token latency sample lists (a
+        caller slices per-window percentiles out of a reused engine)."""
         with self._lock:
             return list(self._ttfts), list(self._token_lats)
 
     def stats(self):
         """Plain-number mirror of the serving metrics (works with
-        telemetry off — the bench subprocess contract)."""
+        telemetry off)."""
         def pct(xs, q):
             if not xs:
                 return None
@@ -1454,7 +1439,6 @@ class Engine:
                               if req.submit_t is not None else None),
                 })
             out = {
-                "policy": self.cfg.policy,
                 "draining": self._draining,
                 "drained": self._drained,
                 "spec": {

@@ -87,7 +87,7 @@ class ReplicaServer:
     bind : (host, port) or None
         RPC endpoint (port 0 ephemeral). ``None`` builds a socketless
         replica whose ``_dispatch`` the router drives in-process (the
-        bench/mxrace shape — no sockets, same code path).
+        mxrace shape — no sockets, same code path).
     """
 
     def __init__(self, engine, name="replica0", bind=("127.0.0.1", 0)):
@@ -215,7 +215,7 @@ class ReplicaServer:
 
 # -- the supervised-process entry point --------------------------------------
 def _build_demo_engine(seed):
-    """A small, deterministic engine for the chaos/bench fleet: every
+    """A small, deterministic engine for the chaos fleet: every
     replica seeded identically serves byte-identical streams, which is
     what makes redelivery provable end to end."""
     import jax

@@ -72,7 +72,7 @@ class FleetClient:
     """One handle on a fleet peer (replica or router). Stateless
     between calls; transport errors retry under the kv.coord policy
     (``MXNET_KV_RETRIES``). ``direct=`` wires the client straight to an
-    in-process peer's ``_dispatch`` — the bench/mxrace shape: no
+    in-process peer's ``_dispatch`` — the mxrace shape: no
     sockets, same protocol dicts, same status handling."""
 
     def __init__(self, addr=None, direct=None, timeout=30.0):
@@ -259,7 +259,7 @@ class Router:
     bind : (host, port) or None
         Registration RPC endpoint (``fleet_register``/``fleet_leave``;
         port 0 ephemeral). ``None`` builds a socketless router for
-        tests/bench that register replicas in-process.
+        tests that register replicas in-process.
     inflight_cap : int, optional
         Per-replica concurrent placements (``MXNET_FLEET_INFLIGHT``,
         default 8).
@@ -362,7 +362,7 @@ class Router:
     def register(self, name, addr=None, client=None):
         """Add (or revive) a replica. Called by the ``fleet_register``
         arm when a replica finishes warmup (the /readyz-gated
-        registration), and directly by tests/bench with ``client=``."""
+        registration), and directly by tests with ``client=``."""
         if client is None:
             if addr is None:
                 raise MXNetError("register needs addr or client")

@@ -78,7 +78,7 @@ class ServingModel:
     Parameters
     ----------
     cfg : TransformerConfig
-        Model geometry (the same config object bench_lm.py trains).
+        Model geometry (the config object ``models/transformer.py`` trains).
     block_size : int
         Paged-pool tokens per block.
     max_blocks_per_req : int
@@ -436,8 +436,8 @@ class ServingModel:
 
     # -- host-facing API -----------------------------------------------------
     def step(self, params, kpool, vpool, tokens, start, chunk_len,
-             block_tables, active, min_batch_bucket=None, temperature=None,
-             top_k=None, top_p=None, seed=None):
+             block_tables, active, temperature=None, top_k=None,
+             top_p=None, seed=None):
         """Run one bucketed step over host-side (numpy) batch inputs.
 
         Inputs are RAGGED: ``tokens`` is [B, C_real<=bucket] already
@@ -445,20 +445,13 @@ class ServingModel:
         the batch and chunk dims to their buckets and slices the result
         back down. Sampling params default to greedy (temperature 0).
 
-        ``min_batch_bucket`` forces at least that batch bucket — the
-        static-batching baseline dispatches decode at the FIXED batch
-        shape even when slots have drained (dead slots are padded
-        lanes), which is what "static" means on hardware where a decode
-        step costs the same at any live count.
-
         Returns (next_token [B_real] int32 numpy, kpool, vpool) — the
         token vector is the ONLY device->host transfer; logits stay on
         device (the fused-sampler contract, asserted via the mxprof
         ``d2h_bytes`` channel).
         """
         B_real, C_real = tokens.shape
-        B = bucket_for(max(B_real, min_batch_bucket or 1),
-                       self.batch_buckets)
+        B = bucket_for(B_real, self.batch_buckets)
         C = 1 if C_real == 1 else bucket_for(C_real, self.chunk_buckets)
 
         def padb(a, fill=0):

@@ -15,11 +15,7 @@ the now-standard continuous batching shape):
   crosses a block boundary and the pool can't hand out one more block,
   the youngest running request is preempted — its blocks are freed and
   it re-queues at the front with its already-streamed tokens folded
-  into a recompute context (so nothing the client saw is lost);
-- the **static** policy is the A/B baseline (bench_serve.py): admission
-  only happens when the active set is fully drained, i.e. classic
-  static batching — every batch runs to the completion of its slowest
-  member while newly arrived requests queue.
+  into a recompute context (so nothing the client saw is lost).
 
 All decisions are deterministic functions of (arrival order, config,
 pool state): the ``events`` log of two runs over the same trace is
@@ -144,20 +140,16 @@ class Scheduler:
     token_budget : int
         Per-step cap on total tokens entering the model: the decode
         batch (1/request) plus prefill chunks must fit under it.
-    policy : "continuous" | "static"
     """
 
     def __init__(self, pool, max_batch=8, prefill_chunk=128,
-                 token_budget=None, policy="continuous", max_active=None,
-                 draft_pool=None, spec_k=0, events_max=None):
-        if policy not in ("continuous", "static"):
-            raise ValueError("unknown policy %r" % (policy,))
+                 token_budget=None, max_active=None, draft_pool=None,
+                 spec_k=0, events_max=None):
         self.pool = pool
         self.max_batch = int(max_batch)
         self.prefill_chunk = int(prefill_chunk)
         self.token_budget = int(token_budget if token_budget is not None
                                 else self.max_batch + self.prefill_chunk)
-        self.policy = policy
         # speculative decoding: the draft model's paged pool (same
         # block geometry, kv_cache.PagedKVPool.mirror) whose per-request
         # tables stay in LOCKSTEP with the target tables — every alloc/
@@ -169,13 +161,9 @@ class Scheduler:
         self.spec_k = int(spec_k)
         # admission depth: more requests than one decode batch may be
         # active so freshly-prefilled requests backfill drained decode
-        # slots immediately (decode occupancy is the throughput lever);
-        # static keeps depth == batch (one batch at a time, by design)
-        if policy == "static":
-            self.max_active = self.max_batch
-        else:
-            self.max_active = int(max_active if max_active is not None
-                                  else 2 * self.max_batch)
+        # slots immediately (decode occupancy is the throughput lever)
+        self.max_active = int(max_active if max_active is not None
+                              else 2 * self.max_batch)
         self.queue = collections.deque()
         self.active = []          # admission-ordered PREFILL/DECODE reqs
         # deterministic audit log, BOUNDED: long-lived serving processes
@@ -269,10 +257,6 @@ class Scheduler:
 
     def _admit_one(self, req):
         need = blocks_for_tokens(req.ctx_len, self.pool.block_size)
-        if self.policy == "static":
-            # static batches are sized once: reserve the whole worst
-            # case so the batch can always run to completion
-            need = blocks_for_tokens(req.total_len(), self.pool.block_size)
         if not self._alloc_pair(req, need):
             return False
         req.state = PREFILL
@@ -283,8 +267,6 @@ class Scheduler:
         return True
 
     def _admit(self):
-        if self.policy == "static" and self.active:
-            return  # classic static batching: drain before refill
         while self.queue and len(self.active) < self.max_active:
             if not self._admit_one(self.queue[0]):
                 break  # OOM backpressure: wait for frees
@@ -337,11 +319,7 @@ class Scheduler:
         """Roll both block tables back after a speculative turn: free
         blocks past the next write position — the block-granular form
         of "roll back to the first rejection" (rejected draft
-        positions' KV is dead weight; the masks already exclude it).
-        Static policy reserved the worst case at admission and keeps
-        it."""
-        if self.policy == "static":
-            return
+        positions' KV is dead weight; the masks already exclude it)."""
         pos = len(req.prompt) + len(req.generated) - 1
         keep = pos // self.pool.block_size + 1
         if keep < len(req.blocks):

@@ -40,10 +40,9 @@ Three views, all keyed consistently:
    ``tools/trace_merge.py`` merges (``prof_rows``).
 
 Derived headline metrics — MFU against the chip's bf16 peak and
-roofline% against the HBM-bandwidth bound, the derivations bench.py and
-bench_lm.py previously hard-coded — live here (:func:`peak_flops`,
-:func:`hbm_gbps`, :func:`derived`) so `/profilez`, the bench legs and
-``tools/perf_gate.py`` all share one definition. The peaks come from
+roofline% against the HBM-bandwidth bound — live here
+(:func:`peak_flops`, :func:`hbm_gbps`, :func:`derived`) so `/profilez`
+and ``tools/telemetry_report.py`` share one definition. The peaks come from
 one table keyed by ``device_kind`` (:data:`PEAKS`); a device that is not
 in it (the CPU included) has no peak, and no MFU/roofline is derived.
 
@@ -85,7 +84,7 @@ PEAKS = {
 }
 #: ResNet-50 bs=128 bf16 HBM roofline on one v5e chip: ~190 MB of
 #: activation traffic per image at 819 GB/s ≈ 3,400 img/s at perfect
-#: overlap (docs/perf_analysis.md "Roofline") — bench.py's derivation.
+#: overlap (docs/perf_analysis.md "Roofline").
 ROOFLINE_IMG_S = 3400.0
 
 #: the fenced sub-phases a step decomposes into (note_step keys)
@@ -320,7 +319,7 @@ def graph_cost(symbol, input_shapes, input_types=None):
         "nodes": out,
         "flops": int(total_flops),
         # fwd+bwd ≈ 3x fwd for matmul-dominated graphs (the standard
-        # training-FLOPs convention bench_lm.py also counts by)
+        # training-FLOPs convention)
         "flops_train": int(3 * total_flops),
         "bytes": int(total_bytes),
         "params_bytes": int(params_bytes),
@@ -642,10 +641,9 @@ def derived():
     """Headline derivations over the attributed programs:
 
     - ``mfu``: executed FLOPs / device seconds / chip peak, over every
-      program with measured device time (the bench_lm derivation,
-      continuous);
-    - ``roofline_pct``: achieved bytes/s as % of HBM bandwidth — the
-      bench.py ResNet roofline generalized to whatever ran;
+      program with measured device time;
+    - ``roofline_pct``: achieved bytes/s as % of HBM bandwidth over
+      whatever ran;
     - per-program ``mfu`` on the top entry.
     """
     with _lock:

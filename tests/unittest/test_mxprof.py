@@ -14,17 +14,11 @@ The load-bearing acceptance properties:
 - **`/profilez` round-trip**: scraped MID-``FeedForward.fit`` the
   endpoint serves per-program cost/memory attribution and derived
   MFU/roofline fields;
-- **perf gate**: ``tools/perf_gate.py`` exits 0 on a clean run's
-  journal, nonzero on a seeded regression, and 2 with no baseline
-  overlap;
 - satellites: real Prometheus histogram families on ``/metrics``,
   ``tracez:<span>:p99`` metrics for mxctl rules (colon-safe rule
   parsing), merged per-rank prof rows, report-tool profiling section.
 """
 import json
-import os
-import subprocess
-import sys
 import urllib.request
 
 import numpy as np
@@ -33,13 +27,6 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.telemetry import prof
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if os.path.join(ROOT, "tools") not in sys.path:
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-
-import perf_gate  # noqa: E402
 
 
 def _enable(monkeypatch, journal=None, http=None, prof_on=True):
@@ -370,87 +357,6 @@ class TestProfilez:
                 "http://127.0.0.1:%d/profilez" % port, timeout=10) as r:
             p = json.loads(r.read().decode())
         assert p["enabled"] is False and p["programs"] == []
-
-
-# -- perf gate ----------------------------------------------------------------
-class TestPerfGate:
-    def _journal(self, path, step_p50, samples, mfu, hbm):
-        perf_gate._fake_journal(str(path), step_p50=step_p50,
-                                samples=samples, mfu=mfu, hbm=hbm)
-
-    def test_pass_and_write_baseline(self, tmp_path, capsys):
-        j = tmp_path / "good.jsonl"
-        base = tmp_path / "base.json"
-        self._journal(j, 0.02, 5000.0, 0.68, 1e9)
-        assert perf_gate.run_gate([str(j)], None, 0.1,
-                                  write_baseline=str(base)) == 0
-        assert perf_gate.run_gate([str(j)], str(base), 0.1) == 0
-        doc = json.loads(base.read_text())
-        assert doc["metrics"]["mfu"] == 0.68
-
-    def test_seeded_regression_exits_nonzero(self, tmp_path):
-        good = tmp_path / "good.jsonl"
-        bad = tmp_path / "bad.jsonl"
-        base = tmp_path / "base.json"
-        self._journal(good, 0.02, 5000.0, 0.68, 1e9)
-        self._journal(bad, 0.03, 3900.0, 0.50, 1.6e9)
-        perf_gate.run_gate([str(good)], None, 0.1,
-                           write_baseline=str(base))
-        assert perf_gate.run_gate([str(bad)], str(base), 0.1) == 1
-        # within-band noise passes; an improvement is not a regression
-        ok = tmp_path / "ok.jsonl"
-        self._journal(ok, 0.021, 5200.0, 0.70, 0.9e9)
-        assert perf_gate.run_gate([str(ok)], str(base), 0.1) == 0
-
-    def test_missing_baseline_is_loud(self, tmp_path):
-        j = tmp_path / "good.jsonl"
-        self._journal(j, 0.02, 5000.0, 0.68, 1e9)
-        assert perf_gate.run_gate([str(j)], str(tmp_path / "nope.json"),
-                                  0.1) == 2
-        empty = tmp_path / "other.json"
-        empty.write_text('{"metrics": {"unrelated": 1.0}}')
-        assert perf_gate.run_gate([str(j)], str(empty), 0.1) == 2
-        # and an empty journal has nothing to gate
-        nothing = tmp_path / "empty.jsonl"
-        nothing.write_text("")
-        assert perf_gate.run_gate([str(nothing)], str(empty), 0.1) == 2
-
-    def test_bench_record_as_baseline(self, tmp_path):
-        j = tmp_path / "good.jsonl"
-        self._journal(j, 0.02, 5000.0, 0.68, 1e9)
-        bench = tmp_path / "BENCH_rX.json"
-        bench.write_text(json.dumps({
-            "n": 5, "cmd": "bench", "rc": 0, "tail": "",
-            "parsed": {"metric": "transformer_lm_train_throughput",
-                       "value": 106882.1, "mfu": 0.68}}))
-        assert perf_gate.run_gate([str(j)], str(bench), 0.1) == 0
-        bench.write_text(json.dumps({
-            "parsed": {"metric": "transformer_lm_train_throughput",
-                       "mfu": 0.90}}))
-        assert perf_gate.run_gate([str(j)], str(bench), 0.1) == 1
-
-    def test_real_journal_gate(self, monkeypatch, tmp_path):
-        """End to end on a REAL fit journal: derive → write baseline →
-        gate the same journal → pass (the clean-run acceptance leg)."""
-        journal = tmp_path / "run.jsonl"
-        monkeypatch.setenv("MXNET_PROF_PEAK_FLOPS", "1.97e14")
-        _enable(monkeypatch, journal=journal)
-        model, train = _fit()
-        model.fit(X=train, kvstore=None)
-        telemetry.flush(mark="exit")
-        base = tmp_path / "base.json"
-        assert perf_gate.run_gate([str(journal)], None, 0.1,
-                                  write_baseline=str(base)) == 0
-        assert perf_gate.run_gate([str(journal)], str(base), 0.1) == 0
-        doc = json.loads(base.read_text())
-        assert "mfu" in doc["metrics"]  # the prof channel made it
-
-    def test_cli_selftest(self):
-        out = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "tools", "perf_gate.py"),
-             "--selftest"], capture_output=True, text=True, timeout=60)
-        assert out.returncode == 0, out.stdout + out.stderr
-        assert "-> OK" in out.stdout
 
 
 # -- satellites ---------------------------------------------------------------

@@ -182,68 +182,43 @@ def test_batchnorm_train_stats():
     assert np.allclose(mm, 0.1 * batch_mean, rtol=1e-3)
 
 
-def test_batchnorm_stats_subsample(monkeypatch):
-    """MXNET_BN_STATS_SAMPLE=k normalizes with statistics from the
-    first N/k batch rows (ghost-BN estimator over a contiguous prefix —
-    strided sampling measured 3x slower on chip, docs/perf_analysis.md
-    r5); default stays exact. Gradients still agree with finite
-    differences of the sampled objective."""
-    x = np.random.rand(8, 3, 4, 4).astype("f") * 5
-    s = sym.BatchNorm(sym.Variable("data"), fix_gamma=False, name="bn")
+@pytest.mark.parametrize("layout", ["nchw", "nhwc"])
+def test_batchnorm_custom_vjp_matches_autodiff(layout):
+    """``_bn_train_norm``'s hand-written backward (the one path training
+    takes, at the channel axis of both layouts: ``ops/nn.py: _bn_fwd`` and
+    ``compile/layout.py: _bn_nhwc_fwd``) against ``jax.grad`` through the
+    same forward, ``_bn_norm_fwd_impl``: dx, dgamma, dbeta, with
+    cotangents on the statistics too."""
+    import jax
+    import jax.numpy as jnp
 
-    def run():
-        args = {"data": mx.nd.array(x),
-                "bn_gamma": mx.nd.ones((3,)),
-                "bn_beta": mx.nd.zeros((3,))}
-        aux = {"bn_moving_mean": mx.nd.zeros((3,)),
-               "bn_moving_var": mx.nd.ones((3,))}
-        exe = s.bind(mx.cpu(), args, aux_states=aux, grad_req="null")
-        return exe.forward(is_train=True)[0].asnumpy()
+    from mxnet_tpu.ops.nn import _bn_norm_fwd_impl, _bn_train_norm
 
-    monkeypatch.setenv("MXNET_BN_STATS_SAMPLE", "2")
-    out = run()
-    mean = x[:4].mean((0, 2, 3))
-    var = x[:4].var((0, 2, 3))
-    expect = (x - mean[None, :, None, None]) / np.sqrt(
-        var[None, :, None, None] + 1e-3)
-    assert np.allclose(out, expect, atol=1e-4)
-    # gradient of the SAMPLED objective agrees with finite differences
-    # (the sampled path must route through autodiff — the custom vjp
-    # formula assumes full-batch statistics)
-    def loss_and_grad(xv):
-        args = {"data": mx.nd.array(xv),
-                "bn_gamma": mx.nd.ones((3,)),
-                "bn_beta": mx.nd.zeros((3,))}
-        grads = {"data": mx.nd.zeros(xv.shape)}
-        aux = {"bn_moving_mean": mx.nd.zeros((3,)),
-               "bn_moving_var": mx.nd.ones((3,))}
-        exe = s.bind(mx.cpu(), args, args_grad=grads, aux_states=aux,
-                     grad_req={"data": "write"})
-        out = exe.forward(is_train=True)[0]
-        w = np.cos(np.arange(out.size)).reshape(out.shape).astype("f")
-        exe.backward([mx.nd.array(w)])
-        return float((out.asnumpy() * w).sum()), \
-            exe.grad_dict["data"].asnumpy().copy()
+    rng = np.random.RandomState(0)
+    if layout == "nchw":
+        shape, axes, bshape = (6, 3, 4, 5), (0, 2, 3), (1, -1, 1, 1)
+    else:
+        shape, axes, bshape = (6, 4, 5, 3), (0, 1, 2), (1, 1, 1, -1)
+    x = jnp.asarray(rng.randn(*shape).astype("f") * 2.0 + 0.5)
+    gamma = jnp.asarray(rng.rand(3).astype("f") + 0.5)
+    beta = jnp.asarray(rng.randn(3).astype("f"))
+    wy = jnp.asarray(rng.randn(*shape).astype("f"))
+    wm, wv = (jnp.asarray(rng.randn(3).astype("f")) for _ in range(2))
 
-    _, g = loss_and_grad(x)
-    eps = 1e-2
-    rng = np.random.RandomState(3)
-    for _ in range(4):
-        i = tuple(rng.randint(0, d) for d in x.shape)
-        xp = x.copy(); xp[i] += eps
-        xm = x.copy(); xm[i] -= eps
-        lp, _ = loss_and_grad(xp)
-        lm, _ = loss_and_grad(xm)
-        assert np.allclose(g[i], (lp - lm) / (2 * eps), atol=2e-2), \
-            (g[i], (lp - lm) / (2 * eps))
+    def objective(norm):
+        def f(x, gamma, beta):
+            y, mean, var = norm(x, gamma, beta, 1e-3, axes, bshape)[:3]
+            return jnp.sum(y * wy) + jnp.sum(mean * wm) + jnp.sum(var * wv)
+        return f
 
-    monkeypatch.delenv("MXNET_BN_STATS_SAMPLE")
-    out = run()
-    mean = x.mean((0, 2, 3))
-    var = x.var((0, 2, 3))
-    expect = (x - mean[None, :, None, None]) / np.sqrt(
-        var[None, :, None, None] + 1e-3)
-    assert np.allclose(out, expect, atol=1e-4)
+    got = jax.grad(objective(_bn_train_norm), argnums=(0, 1, 2))(
+        x, gamma, beta)
+    want = jax.grad(objective(_bn_norm_fwd_impl), argnums=(0, 1, 2))(
+        x, gamma, beta)
+    for name, g, w in zip(("dx", "dgamma", "dbeta"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 def test_softmax_output_grad():

@@ -88,31 +88,6 @@ def test_flash_attention_bwd_kernel_parity_multiblock():
                                        atol=3e-4)
 
 
-def test_flash_attention_dense_bwd_probe_path(monkeypatch):
-    """MXNET_FLASH_DENSE_BWD=1 keeps the dense-recompute backward for
-    A/B probes; it must agree with the kernel backward."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.RandomState(6)
-    b, h, t, d = 1, 2, 128, 32
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-
-    def loss(q, k, v):
-        return pk.flash_attention(q, k, v, causal=True, block_q=128,
-                                  block_k=128).sum()
-
-    g_kernel = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("MXNET_FLASH_DENSE_BWD", "1")
-    g_dense = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(g_kernel, g_dense):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
-
-
 def test_flash_attention_block_divisor_shrink(monkeypatch):
     """T divisible by 128 but not by the 512 default must stay on the
     kernel (block shrinks to a divisor) and malformed env knobs fall
@@ -129,7 +104,6 @@ def test_flash_attention_block_divisor_shrink(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
     for bad in ("", "0", "notanint"):
         monkeypatch.setenv("MXNET_FLASH_BLOCK_Q", bad)
-        monkeypatch.setenv("MXNET_FLASH_MIN_T", bad)
         out = pk.flash_attention(q, q, q, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5)

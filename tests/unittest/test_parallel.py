@@ -429,42 +429,3 @@ def test_pipeline_stage_count_mismatch_rejected():
     ws = {"w": np.zeros((4, 4, 4), "f")}
     with pytest.raises(ValueError, match="stage"):
         pipe(ws, np.zeros((2, 2, 4), "f"))
-
-
-def test_symbol_train_loop_matches_sequential_steps():
-    """step.loop (K steps per dispatch via lax.scan) must produce the
-    same params as K sequential step() calls on the same batches."""
-    import jax
-    import optax
-    from mxnet_tpu.models import get_mlp
-    from mxnet_tpu.parallel.symbol_trainer import make_symbol_train_step
-
-    K, bs = 3, 8
-    sym = get_mlp()
-    shapes = {"data": (bs, 32), "softmax_label": (bs,)}
-    rng = np.random.RandomState(0)
-    batches = {
-        "data": rng.rand(K, bs, 32).astype("f"),
-        "softmax_label": rng.randint(0, 10, (K, bs)).astype("f"),
-    }
-
-    def build():
-        return make_symbol_train_step(
-            sym, input_shapes=shapes, optimizer=optax.sgd(0.1), seed=7,
-            donate=False)
-
-    step, state_a = build()
-    key = jax.random.PRNGKey(5)
-    subkeys = jax.random.split(key, K)
-    for i in range(K):
-        state_a, _ = step(
-            state_a, {k: v[i] for k, v in batches.items()}, subkeys[i])
-
-    step2, state_b = build()
-    state_b, last = step2.loop(state_b, batches, key)
-
-    for name in state_a["params"]:
-        np.testing.assert_allclose(
-            np.asarray(state_a["params"][name]),
-            np.asarray(state_b["params"][name]),
-            rtol=2e-5, atol=2e-6, err_msg=name)

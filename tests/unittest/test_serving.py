@@ -119,7 +119,7 @@ class TestScheduler:
         rng = np.random.RandomState(seed)
         pool = PagedKVPool(1, 1, 4, num_blocks=9, block_size=4)
         sched = Scheduler(pool, max_batch=3, prefill_chunk=8,
-                          policy="continuous", max_active=4)
+                          max_active=4)
         arrivals = [
             Request(rng.randint(0, 9, (int(rng.randint(3, 12)),)),
                     max_new_tokens=int(rng.randint(2, 10)))
@@ -187,24 +187,33 @@ class TestScheduler:
         assert sched.queue[0] is young  # front of queue, not back
         assert ("evict", young.rid) in sched.events
 
-    def test_static_policy_drains_before_refill(self):
+    def test_admission_backfills_while_a_batch_lives(self):
         pool = PagedKVPool(1, 1, 4, num_blocks=33, block_size=4)
-        sched = Scheduler(pool, max_batch=2, prefill_chunk=8,
-                          policy="static")
+        sched = Scheduler(pool, max_batch=2, prefill_chunk=8)
+        assert sched.max_active == 4  # twice the decode batch
         reqs = [Request(np.zeros(3, np.int32), max_new_tokens=3)
-                for _ in range(4)]
-        for r in reqs:
+                for _ in range(5)]
+        for r in reqs[:2]:
             sched.submit(r)
+        plan = sched.plan()
+        assert [r.rid for r in sched.active] == [r.rid for r in reqs[:2]]
+        for req, _, clen in plan.prefill:
+            sched.note_prefilled(req, clen)
+            req.generated.append(0)
+        # the first two decode; three more arrive and nothing has drained
+        for r in reqs[2:]:
+            sched.submit(r)
+        plan = sched.plan()
+        assert [r.rid for r in plan.decode] == [r.rid for r in reqs[:2]]
+        # admitted past the decode batch, up to max_active and no further
+        assert [r.rid for r in sched.active] == [r.rid for r in reqs[:4]]
+        assert [r.rid for r, _, _ in plan.prefill] == [reqs[2].rid,
+                                                      reqs[3].rid]
+        assert list(sched.queue) == [reqs[4]]
+        # one completion frees one slot, and the queue's head takes it
+        sched.finish(reqs[0])
         sched.plan()
-        first_two = {r.rid for r in sched.active}
-        assert first_two == {reqs[0].rid, reqs[1].rid}
-        # nothing new admitted while the batch lives
-        sched.plan()
-        assert {r.rid for r in sched.active} == first_two
-        for r in list(sched.active):
-            sched.finish(r)
-        sched.plan()
-        assert {r.rid for r in sched.active} == {reqs[2].rid, reqs[3].rid}
+        assert [r.rid for r in sched.active] == [r.rid for r in reqs[1:]]
 
 
 # -- ragged-vs-padded decode numerics ----------------------------------------

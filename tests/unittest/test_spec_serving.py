@@ -362,18 +362,6 @@ class TestSpecOffByDefault:
             Engine(params, cfg, ServingConfig(block_size=8, num_blocks=33),
                    draft_params=dparams, draft_cfg=dcfg)
 
-    def test_spec_with_static_policy_rejected(self, model, draft):
-        """Static is the fixed-shape A/B baseline; speculation would
-        silently dispatch it at ragged buckets — the combo is refused
-        at construction."""
-        cfg, params, _ = model
-        dparams, dcfg = draft
-        with pytest.raises(MXNetError):
-            Engine(params, cfg,
-                   ServingConfig(block_size=8, num_blocks=33,
-                                 policy="static", spec=True, spec_k=2),
-                   draft_params=dparams, draft_cfg=dcfg)
-
     def test_no_spec_metrics_registered(self, model, monkeypatch):
         monkeypatch.setenv("MXNET_TELEMETRY", "1")
         tel.reset()

@@ -31,8 +31,9 @@ leg (MXNET_KV_QUANTIZE, docs/how_to/low_precision_comms.md):
   is recorded in every JSON record (``link_mbps``) so no number is
   comparable to a differently-paced one.
 
-Every leg emits one bench.py-schema JSON line (median-of-``--repeats``
-windows, min/median/max/spread, logical vs wire bytes per round).
+Every leg emits one JSON line (``metric``, ``value`` = the median of
+``--repeats`` windows, ``unit``, min/median/max/spread, logical vs wire
+bytes per round).
 
 Smoke runs on CPU::
 
@@ -60,8 +61,8 @@ _BASELINE_GBS = {2: 11.10, 8: 4.41}
 
 
 def _emit(metric, unit, rates, extra=None, baseline=None):
-    """bench.py's record schema: median headline + spread over the
-    repeated steady-state windows."""
+    """One record: median headline + spread over the repeated
+    steady-state windows."""
     med = statistics.median(rates)
     rec = {
         "metric": metric,

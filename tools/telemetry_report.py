@@ -126,8 +126,8 @@ def comm_compression(records):
 
 def serving_timeline(records):
     """[(t, serving.tokens_per_s)] across metric snapshots — the served
-    throughput over the run (bench_serve.py journals; spots admission
-    stalls, eviction storms, drain phases)."""
+    throughput over the run (spots admission stalls, eviction storms,
+    drain phases)."""
     out = []
     for r in metrics_records(records):
         v = r.get("gauges", {}).get("serving.tokens_per_s")
@@ -392,7 +392,7 @@ def wsync_section(records):
     s = hists.get("serving.ttft_sync_s")
     if s:
         lines.append("  TTFT inside sync windows: count %d p50 %.6g "
-                     "p99 %.6g max %.6g (perf_gate ttft_sync_p99_s)"
+                     "p99 %.6g max %.6g (ttft_sync_p99_s)"
                      % (s.get("count", 0), s.get("p50") or 0,
                         s.get("p99") or 0, s.get("max") or 0))
     return lines
