@@ -296,23 +296,52 @@ def _mlp_half(x, norm, p, mlp, cfg):
     return x + y.reshape(B, T, d), counts
 
 
+#: what ``forward`` keeps of a half's forward pass for its backward pass, by
+#: the names ``ops/remat.py`` lists; everything else in a half is rebuilt
+KEPT = ("flash", "kda_chunk", "moe_sort", "moe_hidden")
+
+
 def forward(params, tokens, cfg: HybridConfig):
     """tokens [B, T] int32 -> (logits [B, T, vocab] float32, routing
-    counts [moe layers, held experts] int32)."""
+    counts [moe layers, held experts] int32).
+
+    Each half of a block (attention, MLP) is checkpointed: the backward
+    pass gets the half's input and rebuilds the rest, one half at a time,
+    EXCEPT what ``KEPT`` names, which costs more to rebuild than to hold (a
+    kernel's forward would run a second time only to give its residuals
+    back). Kept per token of B x T, with the kernels on: an MLA layer's
+    flash output and log-sum-exp, ``H (2 dv + 32)`` bytes (9 KB at 32 heads
+    of 128); a KDA layer's in-chunk results, ``H (8 D + 6 C + 4 D / C)``
+    bytes at chunks of ``C = 64`` (45 KB at 32 heads of 128); a sorted
+    expert layer's bucket rows and its gate and up products, ``4 + 8
+    moe_d_ff`` bytes a row (two rows a token at 8 of 256 experts held: 16
+    KB). The benchmark's five layers at B x T = 8192 keep 256 KB a token,
+    2.10 GB (telemetry: ``remat.saved_bytes.<name>``); q, k, v, the gates,
+    every projection, the state's pass and the experts' down product are
+    rebuilt from the half's input as before."""
     import jax
     import jax.numpy as jnp
 
+    from .. import telemetry as _tel
+    from ..ops import remat
+
+    policy = jax.checkpoint_policies.save_only_these_names(*KEPT)
+    offered = dict(remat.OFFERED)
     x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     counts = []
     for lp, kind, mlp in zip(params["layers"], cfg.attention, cfg.mlp):
-        # each half of a block is recomputed by itself in the backward
-        # pass, so that only one half's activations are live at a time
-        x = jax.checkpoint(functools.partial(
-            _attention_half, kind=kind, cfg=cfg))(x, lp["norm1"], lp["attn"])
-        x, n = jax.checkpoint(functools.partial(
-            _mlp_half, mlp=mlp, cfg=cfg))(x, lp["norm2"], lp["mlp"])
+        x = jax.checkpoint(
+            functools.partial(_attention_half, kind=kind, cfg=cfg),
+            policy=policy)(x, lp["norm1"], lp["attn"])
+        x, n = jax.checkpoint(
+            functools.partial(_mlp_half, mlp=mlp, cfg=cfg),
+            policy=policy)(x, lp["norm2"], lp["mlp"])
         if n is not None:
             counts.append(n)
+    if _tel.ENABLED:
+        for name in KEPT:
+            _tel.gauge("remat.saved_bytes.%s" % name).set(
+                remat.OFFERED.get(name, 0) - offered.get(name, 0))
     x = _rms_norm(x, params["norm_f"], cfg.rms_eps)
     lo, hi = cfg.experts_held
     counts = jnp.stack(counts) if counts else jnp.zeros((0, hi - lo),

@@ -598,7 +598,7 @@ def _chunk_stage_pallas(per_step, dtype):
     arguments time-leading as :func:`chunk_stage` takes them."""
     import jax
     import jax.numpy as jnp
-
+    from . import remat
     f32 = jnp.float32
 
     def call(name, q, k, v, g, beta, *cotangents):
@@ -616,14 +616,14 @@ def _chunk_stage_pallas(per_step, dtype):
 
     def fwd(*args):
         *prepared, inverse = call("kda_chunk_fwd", *args)
+        *prepared, inverse = remat.offer("kda_chunk", *prepared, inverse)
         return tuple(prepared), (args, inverse)
 
     def bwd(res, cotangents):
         args, inverse = res
-        B, T, H, D = args[0].shape
         *wide, dbeta = call("kda_chunk_bwd", *args, inverse, *cotangents)
-        grads = [dx.reshape(B, T, H, D) for dx in wide] + [
-            dbeta.reshape(B, H, T).transpose(0, 2, 1)]
+        grads = [dx.reshape(args[0].shape) for dx in wide] + [
+            dbeta.reshape(*dbeta.shape[:2], -1).transpose(0, 2, 1)]
         return tuple(dx.astype(x.dtype) for dx, x in zip(grads, args))
 
     run.defvjp(fwd, bwd)

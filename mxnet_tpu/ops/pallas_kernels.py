@@ -626,9 +626,9 @@ def flash_attention(q, k, v, causal=True, scale=None,
     """
     import jax
     import jax.numpy as jnp
+    from . import remat
 
-    if scale is None:
-        scale = 1.0 / float(q.shape[-1]) ** 0.5
+    scale = 1.0 / float(q.shape[-1]) ** 0.5 if scale is None else scale
     # one type for all three, so that every product has two operands of it
     dtype = jnp.result_type(q, k, v)
     q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
@@ -654,11 +654,11 @@ def flash_attention(q, k, v, causal=True, scale=None,
     def fwd(q, k, v):
         o, lse = _flash_attention_pallas(q, k, v, causal, scale,
                                          block_q, block_k)
+        o, lse = remat.offer("flash", o, lse)
         return o, (q, k, v, o, lse)
 
     def bwd(res, g):
-        q, k, v, o, lse = res
-        return _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal,
+        return _flash_attention_bwd_pallas(*res, g, causal,
                                            scale, block_q, block_k)
 
     attn.defvjp(fwd, bwd)

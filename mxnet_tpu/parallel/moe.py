@@ -249,6 +249,8 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     import jax.numpy as jnp
     from jax import lax
 
+    from ..ops import remat
+
     N, d = x.shape
     lo, hi = held
     n = hi - lo
@@ -266,7 +268,10 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     rows = share_bucket_rows(N, num_experts, held, top_k)
 
     def sorted_rows():
-        sel = jnp.argsort(key, stable=True)[:rows]
+        # which assignments fill the bucket (a sort of N * top_k keys), and
+        # below the gate and up products over it: offered to a caller that
+        # checkpoints by name, to keep instead of rebuilding (ops/remat.py)
+        sel, = remat.offer("moe_sort", jnp.argsort(key, stable=True)[:rows])
         tok = sel // top_k
         valid = jnp.arange(rows) < landed
         weight = jnp.where(valid, w.reshape(-1)[sel], 0.0)
@@ -283,8 +288,9 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
                                   preferred_element_type=f32)
 
         xs = jnp.where(valid[:, None], x[tok], 0.0)
-        hidden = (jax.nn.silu(grouped(xs, experts["w_gate"]))
-                  * grouped(xs, experts["w_up"]))
+        gate, up = remat.offer("moe_hidden", grouped(xs, experts["w_gate"]),
+                               grouped(xs, experts["w_up"]))
+        hidden = jax.nn.silu(gate) * up
         out = grouped(hidden, experts["w_down"]) * weight[:, None]
         return jnp.zeros((N, d), f32).at[tok].add(out)
 

@@ -17,7 +17,10 @@ experts), at small sizes on the CPU with seeded weights:
 * the share test: the parts that all the shares give, the shared expert
   counted once, add up to the uncut layer;
 * the whole model's loss and every leaf's gradient, and a few steps through
-  ``parallel.make_train_step`` + ``optax.adam``.
+  ``parallel.make_train_step`` + ``optax.adam``;
+* what ``forward`` keeps by name for the backward pass (``KEPT``): each
+  kernel's forward once a layer in the gradient's jaxpr, the same loss and
+  gradients as with nothing kept, and the bytes the gauges report.
 """
 import importlib.util
 import os
@@ -507,3 +510,183 @@ def test_trains_through_make_train_step(ref):
     assert counts.shape == (cfg.moe_layers, 4)
     ratio = hybrid_lm.record_routing(np.asarray(counts)[None], 128, cfg)
     assert ratio >= 1.0
+
+
+# -- what the forward keeps for the backward pass ----------------------------------
+
+
+def kernel_config(**over):
+    """The smallest model whose shapes the kernels admit (KDA heads 128
+    wide, T = 256 = one grid step of four chunks, flash blocks of 256) and
+    whose expert layers sort under ``lax.cond``: 4 of 64 experts held, so
+    the bucket (512 rows) is smaller than what could land (1,024)."""
+    from mxnet_tpu.models import hybrid_lm
+
+    kw = dict(vocab_size=256, d_model=64, attention=("kda", "kda", "mla"),
+              mlp=("dense", "moe", "moe"), kda_heads=2, kda_head_dim=128,
+              num_heads=2, kv_lora_rank=32, qk_nope_dim=64, qk_rope_dim=64,
+              v_head_dim=128, d_ff=128, moe_d_ff=32, num_experts=64,
+              experts_per_token=4, experts_held=(0, 4), dtype="bfloat16")
+    kw.update(over)
+    return hybrid_lm.HybridConfig(**kw)
+
+
+def _call_sites(jaxpr, found):
+    """``found[name] += 1`` for every Pallas kernel and every sort in
+    ``jaxpr`` and the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + 1
+        elif eqn.primitive.name in ("sort", "ragged_dot_general"):
+            name = eqn.primitive.name
+            found[name] = found.get(name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _call_sites(sub, found)
+    return found
+
+
+@pytest.fixture(scope="module")
+def kept_traces():
+    """The gradient of ``loss_fn`` traced abstractly (nothing runs) at
+    :func:`kernel_config`, with ``forward``'s policy and with nothing kept:
+    ``(call sites, call sites with nothing kept, saved residuals' text,
+    bytes the gauges read)``."""
+    import io
+    from contextlib import redirect_stdout
+
+    import jax
+    import jax.ad_checkpoint
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import hybrid_lm
+
+    cfg = kernel_config()
+    params = jax.eval_shape(lambda key: hybrid_lm.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 257), jnp.int32)}
+
+    def loss(params, batch):
+        return hybrid_lm.loss_fn(cfg)(params, batch, None)[0]
+
+    def sites():
+        return _call_sites(jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr,
+                           {})
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("MXNET_PALLAS", "1")
+        mp.setenv("MXNET_TELEMETRY", "1")
+        telemetry.reset()
+        telemetry.reload()
+        kept = sites()
+        gauges = {name: telemetry.snapshot()["gauges"].get(
+            "remat.saved_bytes.%s" % name) for name in hybrid_lm.KEPT}
+        text = io.StringIO()
+        with redirect_stdout(text):
+            jax.ad_checkpoint.print_saved_residuals(loss, params, batch)
+        mp.setattr(hybrid_lm, "KEPT", ())
+        bare = sites()
+    telemetry.reload()
+    return cfg, kept, bare, text.getvalue(), gauges
+
+
+#: kernel (or operation) -> (the name its results are kept under, which
+#: layers of :func:`kernel_config` hold it, its call sites a layer in the
+#: gradient with the policy and with nothing kept, how the kept value reads
+#: among the saved residuals: what the sorted path keeps leaves its
+#: ``lax.cond`` as that operation's own results); of an expert layer's
+#: twelve grouped products the gate's and the up's forward are kept
+KEPT_FORWARDS = {
+    "flash_fwd": ("flash", "mla", 1, 2, "named 'flash'"),
+    "kda_chunk_fwd": ("kda_chunk", "kda", 1, 2, "named 'kda_chunk'"),
+    "sort": ("moe_sort", "moe", 1, 2, "i32[512] output of cond"),
+    "ragged_dot_general": ("moe_hidden", "moe", 10, 12,
+                           "f32[512,32] output of cond")}
+
+
+@pytest.mark.parametrize("kernel", list(KEPT_FORWARDS))
+def test_a_kept_forward_runs_once_a_layer(kept_traces, kernel):
+    """A bare ``jax.checkpoint`` runs a kernel's forward again in the
+    backward pass to get its residuals back; kept by name, it runs once."""
+    from mxnet_tpu.models import hybrid_lm
+
+    cfg, kept, bare, residuals, _ = kept_traces
+    name, kind, once, twice, saved_as = KEPT_FORWARDS[kernel]
+    layers = sum(k == kind for k in cfg.attention + cfg.mlp)
+    assert layers and name in hybrid_lm.KEPT
+    assert kept[kernel] == once * layers and bare[kernel] == twice * layers
+    assert saved_as in residuals
+    # what is not named is rebuilt as before: the state's pass runs twice
+    assert kept["kda_state_fwd"] == bare["kda_state_fwd"] == 4
+    assert all(kept[k] == bare[k] for k in bare if k.endswith(
+        ("_bwd", "_dq", "_dkv")))
+
+
+@pytest.mark.parametrize(
+    "name", ["flash", "kda_chunk", "moe_sort", "moe_hidden"])
+def test_saved_bytes_gauges_read_what_the_layers_keep(kept_traces, name):
+    """``remat.saved_bytes.<name>`` against the bytes reckoned from the
+    shapes: a later change that keeps more is seen here."""
+    from mxnet_tpu.models import hybrid_lm
+    from mxnet_tpu.ops.kda import CHUNK
+    from mxnet_tpu.parallel import moe
+
+    cfg, _, _, _, gauges = kept_traces
+    B, T = 1, 256
+    H, D = cfg.kda_heads, cfg.kda_head_dim
+    rows = moe.share_bucket_rows(B * T, cfg.num_experts, cfg.experts_held,
+                                 cfg.experts_per_token)
+    want = {
+        # o bfloat16 and the log-sum-exp, float32 on eight sublanes
+        "flash": B * cfg.num_heads * T * (2 * cfg.v_head_dim + 8 * 4),
+        # w, u0, qg, kd [T, D] and aqk [T, C] bfloat16; the inverse [T, C]
+        # and a chunk's decay [T / C, D] float32
+        "kda_chunk": 2 * B * H * (T * (4 * D * 2 + CHUNK * 2 + CHUNK * 4)
+                                  + T // CHUNK * D * 4),
+        # two expert layers: a bucket row's assignment, int32, and its
+        # gate and up products, float32
+        "moe_sort": 2 * 4 * rows,
+        "moe_hidden": 2 * rows * 2 * cfg.moe_d_ff * 4,
+    }
+    assert set(want) == set(hybrid_lm.KEPT)
+    assert gauges[name] == want[name]
+
+
+def test_keeping_residuals_changes_no_value(monkeypatch):
+    """The loss and every gradient leaf with ``forward``'s policy equal
+    those with nothing kept (each half rebuilt whole, as before PR 33): the
+    kernels in interpret mode at the smallest shapes they admit, the expert
+    layers on their sorted path under ``lax.cond``."""
+    import jax
+
+    from mxnet_tpu.models import hybrid_lm
+    from mxnet_tpu.ops import kda
+
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    cfg = kernel_config(attention=("kda", "mla"), mlp=("moe", "moe"))
+    params = hybrid_lm.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (1, 257), 0, cfg.vocab_size)}
+
+    def run():
+        took = dict(kda.KDA_CALLS)
+        out = jax.jit(jax.value_and_grad(
+            hybrid_lm.loss_fn(cfg), has_aux=True))(params, batch, None)
+        return out, kda.KDA_CALLS[("kda_chunk_fwd", "bfloat16")] - took.get(
+            ("kda_chunk_fwd", "bfloat16"), 0)
+
+    ((got, counts), got_grad), kept_sites = run()
+    monkeypatch.setattr(hybrid_lm, "KEPT", ())
+    ((want, _), want_grad), bare_sites = run()
+    # the kernels ran, and the sorted path (a bucket of 512 rows) took both
+    # layers' assignments
+    assert kept_sites == bare_sites == 2
+    assert 0 < int(counts.sum(axis=-1).max()) <= 512
+    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
+    for got_leaf, want_leaf in zip(jax.tree.leaves(got_grad),
+                                   jax.tree.leaves(want_grad)):
+        close(got_leaf, want_leaf, 1e-6)
