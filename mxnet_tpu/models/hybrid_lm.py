@@ -4,18 +4,28 @@
 told, layer by layer, which attention and which MLP a block has:
 
 * ``attention[i]`` is ``"kda"`` (gated delta-rule linear attention with a
-  per-channel decay, ``ops/kda.py``) or ``"mla"`` (latent attention: keys
+  per-channel decay, ``ops/kda.py``), ``"mla"`` (latent attention: keys
   and values rebuilt from a shared low-rank latent, a key part shared by
-  the heads, softmax through ``ops.pallas_kernels.flash_attention``);
+  the heads, softmax through ``ops.pallas_kernels.flash_attention``),
+  ``"swa"`` or ``"full"`` (grouped-query softmax attention under rotary
+  positions: ``num_heads`` query heads read ``num_kv_heads`` key/value
+  heads of ``head_dim``; ``"swa"`` sees its last ``window`` keys and turns
+  by the plain rotation, ``"full"`` sees every earlier key and turns by
+  YaRN's; both through the same kernels);
 * ``mlp[i]`` is ``"dense"`` (a gated SiLU MLP) or ``"moe"`` (one chip's
   share of a sparse expert layer, ``parallel.moe.moe_share_ffn``: the
-  router scores all ``num_experts``, this chip computes the experts
-  ``experts_held = (lo, hi)`` and the shared expert, nothing is dropped).
+  router (``router``: ``"sigmoid"`` with a score-correction bias, or
+  ``"softmax"``) scores all ``num_experts``, this chip computes the experts
+  ``experts_held = (lo, hi)`` and the shared expert if there is one,
+  nothing is dropped).
 
-Pre-norm residual blocks with RMS norm, NO position encoding anywhere (the
-linear-attention layers carry position in their decay), an untied head.
-Kimi-Linear-48B-A3B is the published member (arXiv:2510.26692):
-``docs/how_to/hybrid_lm.md`` has the config keys.
+Pre-norm residual blocks with RMS norm and an untied head. Position: the
+``"kda"`` and ``"mla"`` layers have no encoding (the linear-attention
+layers carry position in their decay, NoPE latent attention beside them);
+the ``"swa"`` and ``"full"`` layers rotate q and k (:func:`rope_inv_freq`).
+Published members: Kimi-Linear-48B-A3B (arXiv:2510.26692; kda, mla,
+sigmoid router, a shared expert) and Mellum2-12B-A2.5B (swa, full, softmax
+router, no shared expert); ``docs/how_to/hybrid_lm.md`` has the config keys.
 
 Precision: parameters are float32 masters; every matrix product takes its
 operands in ``cfg.dtype`` (bfloat16) and accumulates in float32; the
@@ -51,6 +61,17 @@ class HybridConfig:
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
+    # swa / full: ``num_heads`` query heads over ``num_kv_heads`` (0: as
+    # many) key/value heads of ``head_dim``; the rotations' parameters
+    num_kv_heads: int = 0
+    head_dim: int = 64
+    window: int = 1024
+    rope_theta: float = 10000.0
+    yarn_factor: float = 1.0
+    yarn_original_length: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     # mlps
     d_ff: int = 2048
     moe_d_ff: int = 256
@@ -60,6 +81,7 @@ class HybridConfig:
     num_shared_experts: int = 1
     route_scale: float = 1.0
     renormalize: bool = True
+    router: str = "sigmoid"  # or "softmax": parallel.moe.route_top_k
     dtype: str = "bfloat16"  # operands of the matrix products
     expert_axis: str = "expert"
     tensor_axis: str = "model"
@@ -68,10 +90,15 @@ class HybridConfig:
         if len(self.attention) != len(self.mlp):
             raise ValueError("attention and mlp name %d and %d layers"
                              % (len(self.attention), len(self.mlp)))
-        bad = (set(self.attention) - {"kda", "mla"}) | (
+        bad = (set(self.attention) - {"kda", "mla", "swa", "full"}) | (
             set(self.mlp) - {"dense", "moe"})
         if bad:
             raise ValueError("unknown layer kinds %s" % sorted(bad))
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError("unknown router %r" % (self.router,))
+        if self.num_heads % self.kv_heads or self.head_dim % 2:
+            raise ValueError("%d query heads over %d key/value heads of %d"
+                             % (self.num_heads, self.kv_heads, self.head_dim))
         lo, hi = self.experts_held
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError("experts_held %r of %d experts"
@@ -80,6 +107,10 @@ class HybridConfig:
     @property
     def num_layers(self):
         return len(self.attention)
+
+    @property
+    def kv_heads(self):
+        return self.num_kv_heads or self.num_heads
 
     @property
     def moe_layers(self):
@@ -136,19 +167,25 @@ def init_params(cfg: HybridConfig, key):
             "wo": dense((H * cfg.v_head_dim, d)),
         }
 
+    def gqa():
+        q, kv = cfg.num_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        return {"wq": dense((d, q)), "wk": dense((d, kv)),
+                "wv": dense((d, kv)), "wo": dense((q, d))}
+
     def mlp():
         return {"w_gate": dense((d, cfg.d_ff)), "w_up": dense((d, cfg.d_ff)),
                 "w_down": dense((cfg.d_ff, d))}
 
+    attn = {"kda": kda, "mla": mla, "swa": gqa, "full": gqa}
     layers = []
     for kind, m in zip(cfg.attention, cfg.mlp):
         layers.append({
             "norm1": jnp.ones((d,), jnp.float32),
-            "attn": kda() if kind == "kda" else mla(),
+            "attn": attn[kind](),
             "norm2": jnp.ones((d,), jnp.float32),
             "mlp": mlp() if m == "dense" else moe.init_share_params(
                 next(keys), cfg.num_experts, cfg.experts_held, d,
-                cfg.moe_d_ff, cfg.num_shared_experts)})
+                cfg.moe_d_ff, cfg.num_shared_experts, score=cfg.router)})
     return {"embed": dense((cfg.vocab_size, d), 0.02), "layers": layers,
             "norm_f": jnp.ones((d,), jnp.float32),
             "lm_head": dense((d, cfg.vocab_size))}
@@ -169,10 +206,12 @@ def param_partition_specs(cfg: HybridConfig):
            "dt_bias": P(t), "wb": col, "g_a": rep, "g_b": col,
            "o_norm": rep, "wo": row}
     mla = {"wq": col, "wkva": rep, "kv_norm": rep, "wkvb": col, "wo": row}
+    gqa = {"wq": col, "wk": col, "wv": col, "wo": row}
+    attn = {"kda": kda, "mla": mla, "swa": gqa, "full": gqa}
     dense = {"w_gate": col, "w_up": col, "w_down": row}
     experts = moe.share_partition_specs(bool(cfg.num_shared_experts),
-                                        cfg.expert_axis)
-    layers = [{"norm1": rep, "attn": dict(kda if kind == "kda" else mla),
+                                        cfg.expert_axis, cfg.router)
+    layers = [{"norm1": rep, "attn": dict(attn[kind]),
                "norm2": rep, "mlp": dict(dense if m == "dense" else experts)}
               for kind, m in zip(cfg.attention, cfg.mlp)]
     return {"embed": row, "layers": layers, "norm_f": rep, "lm_head": col}
@@ -278,8 +317,90 @@ def mla_layer(x, p, cfg: HybridConfig):
         return mm(o.transpose(0, 2, 1, 3).reshape(B, T, H * dv), p["wo"])
 
 
+def rope_inv_freq(cfg: HybridConfig, kind):
+    """``(inv_freq [head_dim / 2] float32, factor)`` of a layer kind's
+    rotation: channel ``m`` and ``m + head_dim / 2`` turn by ``pos *
+    inv_freq[m]``, and cos and sin are multiplied by ``factor``.
+
+    ``"swa"``: the plain rotation, ``theta ** (-2 m / head_dim)``, factor 1.
+    ``"full"``: YaRN (arXiv:2309.00071) as the published configurations'
+    library computes it, static at every length: channels that turn more
+    than ``beta_fast`` times over the original length keep their frequency,
+    those that turn less than ``beta_slow`` times have it divided by
+    ``yarn_factor``, a linear ramp between the two; both cos and sin carry
+    ``yarn_attention_factor`` (so the scores carry its square)."""
+    import math
+
+    import numpy as np
+
+    half = cfg.head_dim // 2
+    plain = cfg.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+    if kind == "swa" or cfg.yarn_factor == 1.0:
+        return plain.astype(np.float32), 1.0
+
+    def turns_at(turns):  # the channel that turns ``turns`` times
+        return cfg.head_dim * math.log(cfg.yarn_original_length / (
+            turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(turns_at(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(turns_at(cfg.yarn_beta_slow)), cfg.head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = plain * (1.0 - ramp) + plain / cfg.yarn_factor * ramp
+    return inv_freq.astype(np.float32), float(cfg.yarn_attention_factor)
+
+
+def _rotate(x, cos, sin):
+    """x [B, T, H, D] float32 turned by cos, sin [T, D] (the two halves of
+    D side by side): ``x cos + [-x2, x1] sin``."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos[:, None, :] + turned * sin[:, None, :]
+
+
+def gqa_layer(x, p, cfg: HybridConfig, kind):
+    """x [B, T, d] (normed, float32) -> grouped-query softmax attention
+    under rotary positions: ``kind`` ``"swa"`` (the last ``cfg.window``
+    keys, the plain rotation) or ``"full"`` (every earlier key, YaRN). q
+    and k are projected with ``cfg.dtype`` operands into float32, rotated
+    in float32 and cast once for the kernels; ``num_heads / kv_heads``
+    query heads read one key/value head, unrepeated."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.pallas_kernels import flash_attention
+
+    B, T, _ = x.shape
+    H, G, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    dtype = jnp.dtype(cfg.dtype)
+    mm = functools.partial(_mm, dtype=dtype)
+    with jax.named_scope("attn." + kind):
+        q = mm(x, p["wq"]).reshape(B, T, H, D)
+        k = mm(x, p["wk"]).reshape(B, T, G, D)
+        v = mm(x, p["wv"]).reshape(B, T, G, D)
+        with jax.named_scope("rope"):
+            inv_freq, factor = rope_inv_freq(cfg, kind)
+            angle = jnp.arange(T, dtype=jnp.float32)[:, None] * jnp.asarray(
+                inv_freq)
+            angle = jnp.concatenate([angle, angle], axis=-1)
+            cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+            q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+
+        def heads(t):
+            return t.astype(dtype).transpose(0, 2, 1, 3)
+
+        o = flash_attention(heads(q), heads(k), heads(v), causal=True,
+                            scale=D ** -0.5,
+                            window=cfg.window if kind == "swa" else None)
+        return mm(o.transpose(0, 2, 1, 3).reshape(B, T, H * D), p["wo"])
+
+
 def _attention_half(x, norm, p, kind, cfg):
     h = _rms_norm(x, norm, cfg.rms_eps)
+    if kind in ("swa", "full"):
+        return x + gqa_layer(h, p, cfg, kind)
     return x + (kda_layer if kind == "kda" else mla_layer)(h, p, cfg)
 
 
@@ -292,7 +413,7 @@ def _mlp_half(x, norm, p, mlp, cfg):
     B, T, d = h.shape
     y, counts = moe.moe_share_ffn(
         p, h.reshape(B * T, d), cfg.experts_per_token, cfg.experts_held,
-        cfg.route_scale, cfg.renormalize, cfg.dtype)
+        cfg.route_scale, cfg.renormalize, cfg.dtype, cfg.router)
     return x + y.reshape(B, T, d), counts
 
 
@@ -318,7 +439,16 @@ def forward(params, tokens, cfg: HybridConfig):
     KB). The benchmark's five layers at B x T = 8192 keep 256 KB a token,
     2.10 GB (telemetry: ``remat.saved_bytes.<name>``); q, k, v, the gates,
     every projection, the state's pass and the experts' down product are
-    rebuilt from the half's input as before."""
+    rebuilt from the half's input as before.
+
+    A swa or full layer keeps ``H (2 D + 32)`` bytes a token like MLA's.
+    ``moe_hidden`` goes by the bucket's rows, not by what lands: at 16 of 64
+    experts held the bucket is 65,536 rows for 8,192 tokens, everything
+    that could land, and four layers' gate and up products are 1.88 GB of
+    mostly empty rows where Kimi-Linear's four are 0.54 GB. It is kept all
+    the same: the Mellum2 step then holds 5.23 GB while it runs (3.70 GB
+    with the two products rebuilt) beside 7.14 GB of state, inside the chip
+    (AOT, PR 34)."""
     import jax
     import jax.numpy as jnp
 

@@ -99,16 +99,24 @@ def _env_int(name, default):
 # ---------------------------------------------------------------------------
 
 
-def _attention_reference(q, k, v, causal, scale):
-    """Plain XLA attention, also the backward path for the Pallas forward."""
+def _attention_reference(q, k, v, causal, scale, window=None):
+    """Plain XLA attention, also the backward path for the Pallas forward.
+    Fewer k/v heads than q heads are repeated by group; ``window``: a
+    causal query sees its last ``window`` keys, itself included."""
     import jax.numpy as jnp
 
+    group = q.shape[1] // k.shape[1] if q.ndim == 4 else 1
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         tq, tk = scores.shape[-2], scores.shape[-1]
         iq = jnp.arange(tq)[:, None]
         ik = jnp.arange(tk)[None, :]
-        scores = jnp.where(ik <= iq, scores, -1e30)
+        seen = ik <= iq
+        if window is not None:
+            seen = seen & (ik > iq - window)
+        scores = jnp.where(seen, scores, -1e30)
     import jax
 
     p = jax.nn.softmax(scores, axis=-1)
@@ -144,15 +152,75 @@ def _fold_scale(dtype, scale):
             or math.frexp(scale)[0] == 0.5)
 
 
-def _tile_counts(t_own, t_other, step, causal):
+def _least(a, b):
+    import jax.numpy as jnp
+
+    both = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    import jax.numpy as jnp
+
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
+
+
+def _q_side_steps(row0, block, step, window):
+    """``(start, edge_end, end)``: the k/v steps wholly before the block of
+    q positions ``[row0, row0 + block)`` that a windowed forward or dq
+    kernel visits. ``[start, edge_end)`` hold a key that some query of the
+    block no longer sees (``k <= q - window``: masked), ``[edge_end, end)``
+    are seen whole. Steps before ``start`` hold no visible key. Python ints
+    (``_tile_counts``) or traced scalars (the kernels) alike."""
+    end = row0 // step
+    start = _most(row0 - window + 1, 0) // step
+    edge_end = _most(row0 + block + step - 1 - window, 0) // step
+    return start, _least(end, _most(start, edge_end)), end
+
+
+def _k_side_steps(col0, block, step, window, n_steps):
+    """``(first, clean_end, end)``: the q steps wholly after the block of k
+    positions ``[col0, col0 + block)`` that a windowed dkv kernel visits:
+    ``[first, clean_end)`` see the whole block, ``[clean_end, end)`` hold a
+    query that no longer sees its first keys (masked). Steps from ``end``
+    on see none of it."""
+    first = (col0 + block) // step
+    end = _least(n_steps, (col0 + block + window - 2) // step + 1)
+    return first, _least(end, _most(first, (col0 + window) // step)), end
+
+
+def _tile_counts(t_own, t_other, step, causal, window=None, block=None,
+                 k_side=False):
     """(visited, masked, square) tiles of one head, in tiles of ``step``
     x ``step``: what a kernel's loops below cover of the score square. A
     causal kernel visits the tiles on and under the diagonal and applies
-    the mask on the diagonal's own tiles only."""
+    the mask on the diagonal's own tiles only. With a ``window`` the count
+    follows the windowed kernels' loops, a block of ``block`` positions at
+    a time (``k_side``: the dkv kernel's, which owns k positions): the
+    steps wholly outside the window are not visited, and the mask is also
+    applied on the steps its edge crosses, over the block's whole width."""
     n_own, n_other = t_own // step, t_other // step
     if not causal:
         return n_own * n_other, 0, n_own * n_other
-    return n_own * (n_own + 1) // 2, n_own, n_own * n_other
+    if window is None:
+        return n_own * (n_own + 1) // 2, n_own, n_own * n_other
+    wide = block // step
+    visited = masked = 0
+    for at in range(0, t_own, block):
+        if k_side:
+            first, clean_end, end = _k_side_steps(at, block, step, window,
+                                                  n_other)
+            edge = end - clean_end
+        else:
+            first, edge_end, end = _q_side_steps(at, block, step, window)
+            edge = edge_end - first
+        visited += (end - first) * wide
+        masked += edge * wide
+        for i in range(1, wide + 1):  # the block's own steps, i tiles each
+            visited += i
+            masked += i if window <= i * step - 1 else 1
+    return visited, masked, n_own * n_other
 
 
 def _took_kernel(kernel, dtype, tiles):
@@ -200,6 +268,34 @@ def _under_diagonal(square):
     return jnp.where(k <= q, square, -1e30)
 
 
+def _in_window(st, off, window, diagonal=False):
+    """``st`` [k, q] of transposed scores whose first row's position is
+    ``off`` (a scalar, traced or not) past its first column's: keep
+    ``k > q - window``, and ``k <= q`` too where the tile holds the
+    diagonal."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    ahead = (lax.broadcasted_iota(jnp.int32, st.shape, 0)
+             - lax.broadcasted_iota(jnp.int32, st.shape, 1) + off)  # k - q
+    keep = ahead > -window
+    if diagonal:
+        keep = keep & (ahead <= 0)
+    return jnp.where(keep, st, -1e30)
+
+
+def _edge_loops(tile, carry, step, lo, mid, hi, edge_first):
+    """``tile`` over the steps ``[lo, mid)`` and then ``[mid, hi)`` of a
+    windowed kernel; the part the window's edge crosses (``edge=True``:
+    masked) comes first on the q side and last on the k side."""
+    from jax import lax
+
+    carry = lax.fori_loop(
+        lo, mid, lambda i, c: tile(i * step, c, edge=edge_first), carry)
+    return lax.fori_loop(
+        mid, hi, lambda i, c: tile(i * step, c, edge=not edge_first), carry)
+
+
 def _put_lanes(old, new, lo, hi):
     """``old`` with its lanes ``[lo, hi)`` replaced by ``new``."""
     import jax.numpy as jnp
@@ -210,12 +306,16 @@ def _put_lanes(old, new, lo, hi):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                      block, step, n_steps):
+                      block, step, n_steps, window=None):
     """One block of ``block`` q positions against k/v, ``step`` positions
     at a time. Causal: the steps wholly before the block in a loop, then
     the block's own ``block // step`` steps unrolled, each against the q
     lanes from its diagonal on (lanes before it see nothing of it) and
-    masked on its diagonal tile alone."""
+    masked on its diagonal tile alone. With a ``window`` the loop starts at
+    the first step that holds a visible key and masks the steps the
+    window's edge crosses (``_q_side_steps``); a row that sees nothing of
+    such a step gathers ones under the starting maximum, which the first
+    key it does see (it always sees itself) scales to nothing."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -227,7 +327,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     if fold:
         q = q * scale
 
-    def tile(k0, carry, lo=0, diagonal=False):
+    def tile(k0, carry, lo=0, diagonal=False, edge=False):
         # the running max and sum ride 8 sublanes deep, [8, block] with
         # every row the same: lanes of a one-row value cannot be sliced
         acc, l, m = carry  # [dv, block], [8, block], [8, block]
@@ -236,7 +336,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         st = _dot(kblk, q[lo:], _NT)
         if not fold:
             st = st * scale
-        if diagonal:
+        if edge:
+            st = _in_window(st, 0 if diagonal else k0 - row0, window,
+                            diagonal)
+        elif diagonal:
             st = _put_lanes(st, _under_diagonal(st[:, :step]), 0, step)
         m_old, l_old = m[:, lo:], l[:, lo:]
         m_new = jnp.maximum(m_old, jnp.max(st, axis=0, keepdims=True))
@@ -252,11 +355,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     carry = (jnp.zeros((v_ref.shape[-1], block), jnp.float32),
              jnp.zeros((8, block), jnp.float32),
              jnp.full((8, block), -1e30, jnp.float32))
-    carry = lax.fori_loop(0, row0 // step if causal else n_steps,
-                          lambda i, c: tile(i * step, c), carry)
+    if window is None:
+        carry = lax.fori_loop(0, row0 // step if causal else n_steps,
+                              lambda i, c: tile(i * step, c), carry)
+    else:
+        carry = _edge_loops(tile, carry, step, *_q_side_steps(
+            row0, block, step, window), edge_first=True)
     if causal:
         for lo in range(0, block, step):
-            carry = tile(row0 + lo, carry, lo, diagonal=True)
+            carry = tile(row0 + lo, carry, lo, diagonal=True,
+                         edge=window is not None and window < block - lo)
     acc, l, m = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l[0:1]).T.astype(o_ref.dtype)
@@ -268,10 +376,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                         dq_ref, *, scale, causal, block, step, n_steps):
-    """dQ for one block of q positions: stream k/v as the forward does,
-    rebuild p from the saved lse, accumulate k^T.ds (flash-attention
-    backward, q side)."""
+                         dq_ref, *, scale, causal, block, step, n_steps,
+                         window=None):
+    """dQ for one block of q positions: stream k/v as the forward does
+    (under a ``window`` too), rebuild p from the saved lse, accumulate
+    k^T.ds (flash-attention backward, q side)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -284,13 +393,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         q = q * scale
     do = do_ref[0].astype(op)    # [block, dv]
 
-    def tile(k0, acc, lo=0, diagonal=False):
+    def tile(k0, acc, lo=0, diagonal=False, edge=False):
         kblk = k_ref[0, pl.ds(k0, step), :].astype(op)
         vblk = v_ref[0, pl.ds(k0, step), :].astype(op)
         st = _dot(kblk, q[lo:], _NT)
         if not fold:
             st = st * scale
-        if diagonal:
+        if edge:
+            st = _in_window(st, 0 if diagonal else k0 - row0, window,
+                            diagonal)
+        elif diagonal:
             st = _put_lanes(st, _under_diagonal(st[:, :step]), 0, step)
         # lse, and dcap = rowsum(dO * O): one of their 8 equal rows
         pt = jnp.exp(st - lse_ref[0, 0:1, lo:])
@@ -300,23 +412,32 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         return _put_lanes(acc, new, lo, block)
 
     acc = jnp.zeros((q.shape[1], block), jnp.float32)  # [d, block]
-    acc = lax.fori_loop(0, row0 // step if causal else n_steps,
-                        lambda i, c: tile(i * step, c), acc)
+    if window is None:
+        acc = lax.fori_loop(0, row0 // step if causal else n_steps,
+                            lambda i, c: tile(i * step, c), acc)
+    else:
+        acc = _edge_loops(tile, acc, step, *_q_side_steps(
+            row0, block, step, window), edge_first=True)
     if causal:
         for lo in range(0, block, step):
-            acc = tile(row0 + lo, acc, lo, diagonal=True)
+            acc = tile(row0 + lo, acc, lo, diagonal=True,
+                       edge=window is not None and window < block - lo)
     dq_ref[0] = (acc * scale).T.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
                           dk_ref, dv_ref, *, scale, causal, block, step,
-                          n_steps):
+                          n_steps, window=None):
     """dK/dV for one block of ``block`` k positions: stream q/dO ``step``
     positions at a time, accumulate dO^T.p and q^T.ds (flash-attention
     backward, k side). Causal: the block's own ``block // step`` steps
     unrolled, each against the k rows up to its diagonal (rows after it
     are seen by nothing of it) and masked on its diagonal tile alone,
-    then the steps wholly after the block in a loop."""
+    then the steps wholly after the block in a loop; with a ``window``
+    that loop ends at the last step that holds a query which sees the
+    block, and the steps the window's trailing edge crosses are masked
+    (``_k_side_steps``). One q head's part: under grouped queries the
+    results are float32 and the group is summed outside."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -329,7 +450,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         kblk = kblk * scale
     vblk = v_ref[0].astype(op)   # [block, dv]
 
-    def tile(q0, carry, hi=block, diagonal=False):
+    def tile(q0, carry, hi=block, diagonal=False, edge=False):
         dk, dv = carry  # [d, block], [dv, block]
         lanes = pl.ds(q0, step)
         q = q_ref[0, lanes, :].astype(op)
@@ -339,7 +460,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         st = _dot(kblk[:hi], q, _NT)
         if not fold:
             st = st * scale
-        if diagonal:
+        if edge:
+            st = _in_window(st, step - hi if diagonal else col0 - q0,
+                            window, diagonal)
+        elif diagonal:
             below = _under_diagonal(st[hi - step:])
             st = (jnp.concatenate([st[:hi - step], below], axis=0)
                   if hi > step else below)
@@ -354,9 +478,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
              jnp.zeros(vblk.shape[::-1], jnp.float32))
     if causal:
         for hi in range(step, block + step, step):
-            carry = tile(col0 + hi - step, carry, hi, diagonal=True)
-    carry = lax.fori_loop((col0 + block) // step if causal else 0, n_steps,
-                          lambda j, c: tile(j * step, c), carry)
+            carry = tile(col0 + hi - step, carry, hi, diagonal=True,
+                         edge=window is not None and window < hi)
+    if window is None:
+        carry = lax.fori_loop((col0 + block) // step if causal else 0,
+                              n_steps, lambda j, c: tile(j * step, c), carry)
+    else:
+        carry = _edge_loops(tile, carry, step, *_k_side_steps(
+            col0, block, step, window, n_steps), edge_first=False)
     dk, dv = carry
     # dk = scale * ds^T.q whether the scale went into k or into the scores
     dk_ref[0] = (dk * scale).T.astype(dk_ref.dtype)
@@ -365,92 +494,128 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
 
 @functools.lru_cache(maxsize=None)
 def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
-                interpret):
+                interpret, group=1, window=None):
     """One of the three kernels at one setting, as a jitted pallas_call.
     Cached, so that a model's layers share it: its body is then traced
     and lowered once a program and not once a call site (24 layers are
     72 sites; a warm gpt2-medium set-up spent 1.2 s more on them than
-    the parent's before this; my chip runs, PR 28)."""
+    the parent's before this; my chip runs, PR 28).
+
+    ``group`` q heads read one k/v head (``bh`` counts q heads; k and v
+    come with ``bh // group``): the index maps send program ``i`` to k/v
+    head ``i // group``, nothing is copied. The dkv kernel then gives one
+    q head's part, in float32, for the caller to sum over the group. With
+    a ``window`` the call is named ``flash_win_*``, so that a trace tells
+    the windowed layers' device time from the others'."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    # a group of one maps as before it could be grouped: no operation
+    kv_head = (lambda i: i) if group == 1 else (lambda i: i // group)
+
     def whole(rows, width):
         return pl.BlockSpec((1, rows, width), lambda i, j: (i, 0, 0))
+
+    def whole_kv(rows, width):
+        return pl.BlockSpec((1, rows, width),
+                            lambda i, j: (kv_head(i), 0, 0))
 
     def blocked(width):
         return pl.BlockSpec((1, block, width), lambda i, j: (i, j, 0))
 
+    def blocked_kv(width):
+        return pl.BlockSpec((1, block, width),
+                            lambda i, j: (kv_head(i), j, 0))
+
     stats = pl.BlockSpec((1, 8, block), lambda i, j: (i, 0, j))
     own, other = (tk, tq) if name == "flash_bwd_dkv" else (tq, tk)
+    part = dtype if group == 1 else jnp.float32  # one q head's dk and dv
     body, in_specs, out_specs, out_shape = {
         "flash_fwd": (
             _flash_fwd_kernel,
-            [blocked(d), whole(tk, d), whole(tk, dv)],
+            [blocked(d), whole_kv(tk, d), whole_kv(tk, dv)],
             (blocked(dv), stats),
             (jax.ShapeDtypeStruct((bh, tq, dv), dtype),
              jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32))),
         "flash_bwd_dq": (
             _flash_bwd_dq_kernel,
-            [blocked(d), whole(tk, d), whole(tk, dv), blocked(dv), stats,
-             stats],
+            [blocked(d), whole_kv(tk, d), whole_kv(tk, dv), blocked(dv),
+             stats, stats],
             blocked(d),
             jax.ShapeDtypeStruct((bh, tq, d), dtype)),
         "flash_bwd_dkv": (
             _flash_bwd_dkv_kernel,
-            [whole(tq, d), blocked(d), blocked(dv), whole(tq, dv),
+            [whole(tq, d), blocked_kv(d), blocked_kv(dv), whole(tq, dv),
              whole(8, tq), whole(8, tq)],
             (blocked(d), blocked(dv)),
-            (jax.ShapeDtypeStruct((bh, tk, d), dtype),
-             jax.ShapeDtypeStruct((bh, tk, dv), dtype))),
+            (jax.ShapeDtypeStruct((bh, tk, d), part),
+             jax.ShapeDtypeStruct((bh, tk, dv), part))),
     }[name]
     return jax.jit(pl.pallas_call(
         functools.partial(body, scale=scale, causal=causal, block=block,
-                          step=step, n_steps=other // step),
+                          step=step, n_steps=other // step, window=window),
         out_shape=out_shape, grid=(bh, own // block), in_specs=in_specs,
-        out_specs=out_specs, interpret=interpret, name=name))
+        out_specs=out_specs, interpret=interpret,
+        name=_kernel_name(name, window)))
 
 
-def _flash_attention_pallas(q, k, v, causal, scale, block, step):
+def _kernel_name(name, window):
+    return name if window is None else name.replace("flash_", "flash_win_")
+
+
+def _flash_attention_pallas(q, k, v, causal, scale, block, step, window=None):
     """Forward kernel; returns (o, lse) with lse saved for the backward."""
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[-1]
+    hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
     bh = b * h
-    _took_kernel("flash_fwd", q.dtype, _tile_counts(tq, tk, step, causal))
+    _took_kernel(_kernel_name("flash_fwd", window), q.dtype,
+                 _tile_counts(tq, tk, step, causal, window, block))
     out, lse = _flash_call("flash_fwd", q.dtype.name, bh, tq, tk, d, dv,
-                           causal, scale, block, step, _interpret())(
-        q.reshape(bh, tq, d), k.reshape(bh, tk, d), v.reshape(bh, tk, dv))
+                           causal, scale, block, step, _interpret(),
+                           h // hkv, window)(
+        q.reshape(bh, tq, d), k.reshape(b * hkv, tk, d),
+        v.reshape(b * hkv, tk, dv))
     return out.reshape(b, h, tq, dv), lse  # lse: (b*h, 8, tq)
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                block, step):
+                                block, step, window=None):
     """Blockwise backward: neither pass materialises the [T, T] score
-    matrix in HBM — the cliff the dense-vjp fallback hits at long T."""
+    matrix in HBM — the cliff the dense-vjp fallback hits at long T.
+    Under grouped queries dk and dv come from the kernel a q head at a
+    time in float32 and are summed over each group here, before their one
+    rounding to the operands' type."""
     import jax.numpy as jnp
 
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[-1]
-    bh = b * h
-    operands = (q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-                v.reshape(bh, tk, dv), g.reshape(bh, tq, dv), lse,
+    hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
+    bh, group = b * h, h // hkv
+    operands = (q.reshape(bh, tq, d), k.reshape(b * hkv, tk, d),
+                v.reshape(b * hkv, tk, dv), g.reshape(bh, tq, dv), lse,
                 # D_i = rowsum(dO * O): one fused elementwise+reduce pass
                 # in XLA, broadcast to lse's 8-row stats layout
                 jnp.broadcast_to(
                     jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                             axis=-1).reshape(bh, 1, tq), (bh, 8, tq)))
     setting = (q.dtype.name, bh, tq, tk, d, dv, causal, scale)
-    _took_kernel("flash_bwd_dq", q.dtype, _tile_counts(tq, tk, step, causal))
+    _took_kernel(_kernel_name("flash_bwd_dq", window), q.dtype,
+                 _tile_counts(tq, tk, step, causal, window, block))
     dq = _flash_call("flash_bwd_dq", *setting, block, step,
-                     _interpret())(*operands)
+                     _interpret(), group, window)(*operands)
     # the k side owns blocks of k and steps through q: the same two
     # sizes, fitted to the other length where the two differ
     block, step, _ = _select_blocks(tk, tq, block, step)
-    _took_kernel("flash_bwd_dkv", q.dtype, _tile_counts(tk, tq, step, causal))
+    _took_kernel(_kernel_name("flash_bwd_dkv", window), q.dtype,
+                 _tile_counts(tk, tq, step, causal, window, block,
+                              k_side=True))
     dk, dv_ = _flash_call("flash_bwd_dkv", *setting, block, step,
-                          _interpret())(*operands)
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv_.reshape(b, h, tk, dv))
+                          _interpret(), group, window)(*operands)
+    if group > 1:
+        dk, dv_ = (x.reshape(b, hkv, group, tk, -1).sum(axis=2).astype(
+            q.dtype) for x in (dk, dv_))
+    return (dq.reshape(b, h, tq, d), dk.reshape(b, hkv, tk, d),
+            dv_.reshape(b, hkv, tk, dv))
 
 
 def _select_blocks(tq, tk, block_q=None, block_k=None):
@@ -535,7 +700,7 @@ def _select_blocks(tq, tk, block_q=None, block_k=None):
     return block_q, block_k, ok
 
 
-def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize):
+def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize, group=1):
     """Bytes of scoped VMEM the hungriest of the three kernels asks for.
     Every operand block is double-buffered by the pipeline, each a whole
     number of 128-lane tiles wide in VMEM whatever d is (192 takes 256):
@@ -546,7 +711,10 @@ def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize):
     dkv the block's own k and v. Fitted to the sizes the chip's compiler
     reports when it refuses, at T=1k..32k, d=64..256, bf16 and f32, blocks
     256..1024 (and d=192, dv=128 at T=8192; AOT, PR 30): it accepts nothing
-    of those that Mosaic refuses (test_chip_compile.py holds the shapes)."""
+    of those that Mosaic refuses (test_chip_compile.py holds the shapes).
+    Under grouped queries (``group`` > 1) dkv's two result blocks are
+    float32. A window changes nothing: the kernels still hold the whole
+    of the other side's operands."""
     wide, wide_v = (-(-n // 128) * 128 for n in (d, dv))  # whole lane tiles
     io = (wide + wide_v) * itemsize
     tile = block_k * block_q * 4
@@ -556,10 +724,13 @@ def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize):
         + 2 * 2 * 8 * block_q * 4 + 2.75 * tile + 4 * d * block_q
     dkv = 2 * (tq + 2 * block_q) * io + 2 * 2 * 8 * tq * 4 \
         + 1.5 * tile + 4 * (d + dv) * block_q + block_q * io
+    if group > 1:
+        dkv += 2 * block_q * (wide + wide_v) * (4 - itemsize)
     return max(fwd, dq, dkv)
 
 
-def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
+def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4,
+                group=1):
     """``(block_q, block_k, refusal)``: the blocks ``flash_attention``
     runs these operands at, and why it would NOT take the Pallas kernels
     (a ``FALLBACKS`` reason) or None when it will: every gate the kernels
@@ -573,7 +744,8 @@ def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
         return block_q, block_k, "disabled"
     if not tiles:
         return block_q, block_k, "untileable"
-    while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize) > _VMEM_LIMIT:
+    while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize,
+                      group) > _VMEM_LIMIT:
         smaller = _select_blocks(tq, tk, block_q // 2, block_k)
         if named or not smaller[2] or smaller[0] >= block_q:
             return block_q, block_k, "vmem"
@@ -582,19 +754,27 @@ def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4):
 
 
 def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
-                        itemsize=4):
+                        itemsize=4, group=1):
     """True iff ``flash_attention`` will take the PALLAS KERNEL path for
     ``[.., tq, d] x [.., tk, d] -> [.., tk, dv]`` operands of
     ``itemsize`` bytes per element. Public so composers (e.g. the
     Ulysses sequence-parallel local attention) can choose between the
     kernel and their OWN memory-bounded fallback instead of ever
     hitting flash_attention's dense O(T^2) fallback."""
-    return _flash_plan(tq, tk, d, dv, block_q, block_k, itemsize)[2] is None
+    return _flash_plan(tq, tk, d, dv, block_q, block_k, itemsize,
+                       group)[2] is None
 
 
-def flash_attention(q, k, v, causal=True, scale=None,
+def flash_attention(q, k, v, causal=True, scale=None, window=None,
                     block_q=None, block_k=None):
-    """Blockwise-softmax attention. q,k,v: [batch, heads, time, d_head].
+    """Blockwise-softmax attention. q: [batch, heads, time, d_head]; k, v:
+    the same, or with fewer heads that divide q's (grouped queries: q
+    head ``h`` reads k/v head ``h // group``, through the kernels' index
+    maps, nothing is repeated in memory). ``window``: a causal query sees
+    its last ``window`` keys, itself included (``q - window < k <= q``);
+    the kernels then visit only the tiles that hold a visible key and are
+    named ``flash_win_fwd`` / ``flash_win_bwd_dq`` / ``flash_win_bwd_dkv``.
+    A window that reaches every key is no window.
 
     Forward AND backward run as Pallas kernels: the forward saves the
     per-row log-sum-exp, and the backward reconstructs attention weights
@@ -633,9 +813,19 @@ def flash_attention(q, k, v, causal=True, scale=None,
     dtype = jnp.result_type(q, k, v)
     q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
     tq, tk = q.shape[2], k.shape[2]
+    group = 1
+    if q.ndim == 4:
+        if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+            raise ValueError("%d q heads over %d k and %d v heads"
+                             % (q.shape[1], k.shape[1], v.shape[1]))
+        group = q.shape[1] // k.shape[1]
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window of %r, causal %r" % (window, causal))
+        window = None if window >= tk else int(window)
     block_q, block_k, refusal = _flash_plan(
         tq, tk, q.shape[-1], v.shape[-1], block_q, block_k,
-        q.dtype.itemsize)
+        q.dtype.itemsize, group)
     if q.ndim != 4:
         refusal = "ndim"
     elif causal and tq != tk and refusal is None:
@@ -643,23 +833,23 @@ def flash_attention(q, k, v, causal=True, scale=None,
         refusal = "causal_rectangle"
     if refusal is not None:
         _fallback("flash_attention", refusal, tuple(q.shape))
-        return _attention_reference(q, k, v, causal, scale)
+        return _attention_reference(q, k, v, causal, scale, window)
 
     @jax.custom_vjp
     def attn(q, k, v):
         o, _ = _flash_attention_pallas(q, k, v, causal, scale,
-                                       block_q, block_k)
+                                       block_q, block_k, window)
         return o
 
     def fwd(q, k, v):
         o, lse = _flash_attention_pallas(q, k, v, causal, scale,
-                                         block_q, block_k)
+                                         block_q, block_k, window)
         o, lse = remat.offer("flash", o, lse)
         return o, (q, k, v, o, lse)
 
     def bwd(res, g):
         return _flash_attention_bwd_pallas(*res, g, causal,
-                                           scale, block_q, block_k)
+                                           scale, block_q, block_k, window)
 
     attn.defvjp(fwd, bwd)
     return attn(q, k, v)

@@ -106,9 +106,10 @@ def shard_moe_params(params, mesh):
 
 
 def init_share_params(rng, num_experts, held, d_model, d_ff, shared=1,
-                      dtype="float32"):
+                      dtype="float32", score="sigmoid"):
     """Params of :func:`moe_share_ffn`: the router over all ``num_experts``
-    with its score-correction bias, the gated MLPs of the experts
+    with its score-correction bias (a ``"sigmoid"`` router's; a
+    ``"softmax"`` router has none), the gated MLPs of the experts
     ``held = (lo, hi)`` stacked on a leading axis, and one shared expert
     ``shared`` times as wide (``shared=0``: none)."""
     import jax
@@ -124,23 +125,26 @@ def init_share_params(rng, num_experts, held, d_model, d_ff, shared=1,
                 "w_up": dense(lead + (d_model, width)),
                 "w_down": dense(lead + (width, d_model))}
 
-    params = {"router": dense((d_model, num_experts)),
-              "router_bias": jax.random.normal(
-                  next(k), (num_experts,), dtype) * 0.02,
-              "experts": mlp(d_ff, (n,))}
+    params = {"router": dense((d_model, num_experts))}
+    if score == "sigmoid":
+        params["router_bias"] = jax.random.normal(
+            next(k), (num_experts,), dtype) * 0.02
+    params["experts"] = mlp(d_ff, (n,))
     if shared:
         params["shared"] = mlp(d_ff * shared)
     return params
 
 
-def share_partition_specs(shared=True, axis="expert"):
+def share_partition_specs(shared=True, axis="expert", score="sigmoid"):
     """PartitionSpecs of :func:`init_share_params`: the held experts on
     mesh axis ``axis``, the rest replicated."""
     from jax.sharding import PartitionSpec as P
 
     mlp = {"w_gate": P(), "w_up": P(), "w_down": P()}
-    specs = {"router": P(), "router_bias": P(),
-             "experts": {k: P(axis, None, None) for k in mlp}}
+    specs = {"router": P()}
+    if score == "sigmoid":
+        specs["router_bias"] = P()
+    specs["experts"] = {k: P(axis, None, None) for k in mlp}
     if shared:
         specs["shared"] = mlp
     return specs
@@ -162,20 +166,30 @@ def _top_k(scores, k):
     return jnp.stack(picked, axis=-1)
 
 
-def route_top_k(params, x, top_k, route_scale=1.0, renormalize=True):
-    """Sigmoid scores over ALL experts in float32, the ``top_k`` chosen by
-    score + bias (the bias is a buffer: no gradient reaches it), and their
-    weights ``s_i / sum_chosen(s) * route_scale``. x [N, d] ->
-    idx, w [N, top_k]."""
+def route_top_k(params, x, top_k, route_scale=1.0, renormalize=True,
+                score="sigmoid"):
+    """Scores over ALL experts in float32, the ``top_k`` chosen, and their
+    weights ``s_i / sum_chosen(s) * route_scale``. x [N, d] -> idx, w
+    [N, top_k]. ``score="sigmoid"``: sigmoid scores, chosen by score + bias
+    (the bias is a buffer: no gradient reaches it). ``score="softmax"``:
+    the softmax over all the experts, chosen by the probabilities
+    themselves; no bias is read."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x.astype(f32), params["router"].astype(f32),
-                               precision=lax.Precision.HIGHEST))
-    idx = _top_k(
-        s + lax.stop_gradient(params["router_bias"].astype(f32)), top_k)
+    logits = jnp.dot(x.astype(f32), params["router"].astype(f32),
+                     precision=lax.Precision.HIGHEST)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        idx = _top_k(s, top_k)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        idx = _top_k(
+            s + lax.stop_gradient(params["router_bias"].astype(f32)), top_k)
+    else:
+        raise ValueError("unknown router score %r" % (score,))
     w = jnp.take_along_axis(s, idx, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -229,7 +243,7 @@ def share_bucket_rows(tokens, num_experts, held, top_k):
 
 
 def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
-                  dtype=None):
+                  dtype=None, score="sigmoid"):
     """One chip's share of a sparse expert layer. x [N, d] ->
     (y [N, d] float32, assignments per held expert [hi - lo] int32).
 
@@ -244,7 +258,13 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     here than the bucket holds the held experts run over every token under
     a mask instead. The shared expert, if any, is plain matmuls over every
     token. ``dtype``: the operand type of the products (default x's);
-    accumulation is float32."""
+    accumulation is float32. ``score``: the router's score function
+    (:func:`route_top_k`).
+
+    Where the bucket is as large as everything that could land here (a
+    share of a quarter under top 8: ``share_bucket_rows`` returns its
+    ``worst``), there is no overflow to catch: the program holds the
+    sorted path alone, no ``lax.cond`` and no dense path."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -258,7 +278,8 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     f32 = jnp.float32
     num_experts = params["router"].shape[-1]
     with jax.named_scope("moe.route"):
-        idx, w = route_top_k(params, x, top_k, route_scale, renormalize)
+        idx, w = route_top_k(params, x, top_k, route_scale, renormalize,
+                             score)
         local = idx - lo
         here = (local >= 0) & (local < n)
         key = jnp.where(here, local, n).reshape(-1)  # held first, by expert

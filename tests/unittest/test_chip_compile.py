@@ -186,6 +186,42 @@ def test_latent_attention_shape_compiles(one_chip):
     assert len(calls) == 3 and pk.FALLBACKS == routed
 
 
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_grouped_query_shapes_compile(one_chip, window):
+    """The Mellum2 cell's attention calls: 32 query heads of 128 over 4
+    key/value heads at T = 8192, with the 1,024 window and without: the
+    forward and the three kernels of the gradient, under the window
+    kernels' own names, at the plan the footprint rule picks for a group
+    (512 x 512: at 1024 x 512 dkv's float32 per-head results took 16.69M
+    of 16.00M inside the whole step; AOT, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def attend(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, window=window)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8) == (
+        512, 512, None)
+    routed = dict(pk.FALLBACKS)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    prefix = "flash_" if window is None else "flash_win_"
+    calls = _kernels(attend, q, k, k)
+    assert len(calls) == 1 and "/%sfwd/" % prefix in calls[0]
+    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert len(calls) == 3
+    for name in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert sum("/%s%s/" % (prefix, name) in ln for ln in calls) == 1, name
+    assert pk.FALLBACKS == routed
+
+
 def test_kda_kernels_compile(one_chip):
     """The gated delta rule's two stages, forward and backward, at the
     benchmark cell's shapes (B=1, T=8192, 32 heads of 128) and operand

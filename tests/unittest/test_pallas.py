@@ -324,6 +324,164 @@ def test_flash_tile_counts_match_the_mask(t, step):
         visited.sum(), masked.sum(), visited.size)
 
 
+#: window (None: none) -> what it is at T = 512 under blocks of 256 and
+#: steps of 128: a multiple of the step, NOT a multiple of the step (and
+#: narrower than a step), wider than a block, wider than T (no window)
+FLASH_WINDOWS = {"none": None, "step_multiple": 256, "ragged": 100,
+                 "wide": 300, "over_T": 1000}
+
+
+@pytest.mark.parametrize("window", sorted(FLASH_WINDOWS))
+@pytest.mark.parametrize("group", [1, 8])
+def test_flash_grouped_queries_and_window(group, window):
+    """Forward and all three gradients against the dense reference, several
+    blocks long: ``group`` query heads read one key/value head (dk and dv
+    summed over the group), a causal query sees its last ``window`` keys.
+    The window kernels are the ones taken, under their own names."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    w = FLASH_WINDOWS[window]
+    t, d, hkv = 512, 64, 1 if group == 8 else 2
+    rng = np.random.RandomState(5)
+    q, g = (jnp.asarray(rng.randn(1, hkv * group, t, d), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, hkv, t, d), jnp.float32)
+            for _ in range(2))
+
+    def fast(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, window=w,
+                                  block_q=256, block_k=128)
+
+    def ref(q, k, v):
+        return pk._attention_reference(q, k, v, True, d ** -0.5, w)
+
+    routed, took = dict(pk.FALLBACKS), dict(pk.FLASH_CALLS)
+    with jax.default_matmul_precision("highest"):
+        out, pull = jax.vjp(fast, q, k, v)
+        got = (out,) + pull(g)
+        want, pull = jax.vjp(ref, q, k, v)
+        want = (want,) + pull(g)
+    assert pk.FALLBACKS == routed
+    names = {key[0] for key, n in pk.FLASH_CALLS.items()
+             if n != took.get(key, 0)}
+    windowed = w is not None and w < t
+    assert names == {("flash_win_" if windowed else "flash_") + side
+                     for side in ("fwd", "bwd_dq", "bwd_dkv")}
+    assert [a.shape for a in got] == [q.shape, q.shape, k.shape, v.shape]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=0)
+    if windowed:  # the reference itself saw a window
+        full = pk._attention_reference(q, k, v, True, d ** -0.5)
+        assert float(jnp.max(jnp.abs(full - want[0]))) > 1e-2
+
+
+def test_flash_grouped_queries_bfloat16_sum_in_float32():
+    """bfloat16 operands under a group of 8: dk and dv are the float32 sum
+    of the eight heads' parts rounded once, so they come as near the
+    float32 reference as a single head's do."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(6)
+    q, g = (jnp.asarray(rng.randn(1, 8, 256, 64), jnp.bfloat16)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, 1, 256, 64), jnp.bfloat16)
+            for _ in range(2))
+    _, pull = jax.vjp(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, window=96, block_q=128, block_k=128), q, k, v)
+    got = pull(g)
+    assert [a.dtype for a in got] == [jnp.bfloat16] * 3
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    _, pull = jax.vjp(lambda q, k, v: pk._attention_reference(
+        q, k, v, True, 0.125, 96), *f32)
+    for a, b in zip(got, pull(g.astype(jnp.float32))):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=1.5e-2 * float(np.abs(b).max()),
+                                   rtol=0)
+    with pytest.raises(ValueError):
+        pk.flash_attention(q[:, :3], k.repeat(2, 1), v.repeat(2, 1))
+    with pytest.raises(ValueError):
+        pk.flash_attention(q, k, v, causal=False, window=96)
+
+
+@pytest.mark.parametrize("k_side", [False, True], ids=["q_side", "k_side"])
+@pytest.mark.parametrize("t,block,step,window", [
+    (1024, 256, 128, 256), (1024, 512, 256, 200), (1024, 256, 256, 700),
+    (512, 128, 128, 64), (2048, 512, 256, 1024), (8192, 512, 512, 1024)])
+def test_flash_tile_counts_with_a_window_match_the_mask(t, block, step,
+                                                        window, k_side):
+    """The windowed kernels' loops against a count by hand from the mask
+    itself: a block's steps outside its own ``block // step`` are visited
+    iff the step x block strip holds a visible element and masked iff it
+    also holds a hidden one; the block's own steps are each one strip from
+    the diagonal on, masked over the diagonal tile alone unless the
+    window's edge crosses the strip. No strip wholly outside the window is
+    visited."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    own, other = np.arange(t)[:, None], np.arange(t)[None, :]
+    if k_side:  # rows: the block's k positions; columns: q positions
+        seen = (own <= other) & (own > other - window)
+    else:
+        seen = (other <= own) & (other > own - window)
+    wide = block // step
+    visited = masked = 0
+    for at in range(0, t, block):
+        rows = seen[at:at + block]
+        for s in range(t // step):
+            strip = rows[:, s * step:(s + 1) * step]
+            if at <= s * step < at + block:  # one of the block's own steps
+                # the strip from the diagonal on: the rows (or columns)
+                # that can see the step at all
+                lo = s * step - at
+                part = strip[:lo + step] if k_side else strip[lo:]
+                tiles = part.shape[0] // step
+                visited += tiles
+                hidden_past_diagonal = not (
+                    part[:-step] if k_side else part[step:]).all()
+                masked += tiles if hidden_past_diagonal else 1
+            elif strip.any():
+                visited += wide
+                masked += 0 if strip.all() else wide
+    assert pk._tile_counts(t, t, step, True, window, block, k_side) == (
+        visited, masked, (t // step) ** 2)
+    # never more than the causal kernels, which visit all under the
+    # diagonal, and fewer once the window is shorter than T less a block
+    causal = pk._tile_counts(t, t, step, True)[0]
+    assert visited <= causal and (visited < causal or window > t - 2 * block)
+
+
+def test_flash_window_calls_are_counted_with_what_they_visit():
+    """A traced call under a window is counted under the window kernels'
+    names with the tiles their loops visit: at the benchmark cell's shapes
+    45 of the 136 tiles under the diagonal."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    before = dict(pk.FLASH_CALLS)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: pk.flash_attention(
+            q, k, v, causal=True, window=1024).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, k, k)
+    new = {key: n - before.get(key, 0) for key, n in pk.FLASH_CALLS.items()
+           if n != before.get(key, 0)}
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8)[:2] == (
+        512, 512)
+    assert new == {(name, "bfloat16", (45, 30, 256)): 1 for name in (
+        "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv")}
+    assert pk._tile_counts(8192, 8192, 512, True)[0] == 136
+
+
 def test_flash_causal_over_unequal_lengths_goes_to_xla():
     """The causal kernels unroll each block's own diagonal steps, which
     exist only where tq == tk: a causal rectangle is routed, and counted."""
