@@ -33,7 +33,12 @@ Two expert layers live here, and they are not interchangeable:
   dense way over the held experts (every assignment still counted), never
   cut. On one chip it runs without an exchange; what the absent experts
   would add is left out, which is the partial result a deployment's combine
-  would sum.
+  would sum. The grouped products are ``ops/grouped_matmul.py``'s Pallas
+  kernels (``moe_gmm`` forward and for the input's cotangent, ``moe_tgmm``
+  for the weights' gradient), tiles sized to the shape; kernels off (the
+  CPU default), a width that is no multiple of 128 or tiles that do not
+  fit VMEM, and they are ``lax.ragged_dot``, counted in
+  ``pallas_kernels.FALLBACKS`` under ``moe_gmm``.
 """
 from __future__ import annotations
 
@@ -251,8 +256,8 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     ``params["experts"]`` stacks. Every token is scored against all the
     experts and its ``top_k`` chosen over all; the assignments that name a
     held expert are gathered, sorted by expert and pushed through grouped
-    products (``lax.ragged_dot``) for gate, up and down, then scattered
-    back under their weights. The products run over the whole bucket
+    products (``ops.grouped_matmul.grouped_matmul``) for gate, up and down,
+    then scattered back under their weights. The products run over the whole bucket
     (:func:`share_bucket_rows`) at every step: the same work whatever the
     routing. No capacity, no dropped token: when more assignments land
     here than the bucket holds the held experts run over every token under
@@ -270,6 +275,7 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     from jax import lax
 
     from ..ops import remat
+    from ..ops.grouped_matmul import grouped_matmul
 
     N, d = x.shape
     lo, hi = held
@@ -305,8 +311,7 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
         sizes = counts.at[n - 1].add(rows - landed)
 
         def grouped(a, b):
-            return lax.ragged_dot(a.astype(dtype), b.astype(dtype), sizes,
-                                  preferred_element_type=f32)
+            return grouped_matmul(a.astype(dtype), b.astype(dtype), sizes)
 
         xs = jnp.where(valid[:, None], x[tok], 0.0)
         gate, up = remat.offer("moe_hidden", grouped(xs, experts["w_gate"]),

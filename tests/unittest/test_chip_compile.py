@@ -246,3 +246,81 @@ def test_kda_kernels_compile(one_chip):
         assert kda.KDA_CALLS[(kernel, "bfloat16")] == took.get(
             (kernel, "bfloat16"), 0) + 1, kernel
     assert pk.FALLBACKS == routed
+
+
+#: rows, the model's width, an expert's width, experts held: the sorted
+#: bucket of the benchmark's two hybrid cells
+GROUPED_CELLS = {"mellum2": (65536, 2304, 896, 16),
+                 "kimi": (16384, 2304, 1024, 8)}
+
+
+def _grouped_operands(one_chip, m, k, n, g):
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
+def test_grouped_products_compile(one_chip, cell, product):
+    """The expert layer's grouped products at both cells' shapes, gate/up
+    ([rows, d] x [g, d, f]) and down ([rows, f] x [g, f, d]), each in its
+    three directions: the product, the input's cotangent with the right
+    operand read transposed, and the weights' gradient; at the tiles
+    ``_plan`` picks, counted, nothing routed to XLA."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    m, d, f, g = GROUPED_CELLS[cell]
+    k, n = (d, f) if product == "up" else (f, d)
+
+    def loss(a, b, sizes):
+        out = gm.grouped_matmul(a, b, sizes)
+        return jnp.sum(out), out
+
+    routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
+    calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True),
+                     *_grouped_operands(one_chip, m, k, n, g))
+    assert sum("moe_gmm" in ln for ln in calls) == 2
+    assert sum("moe_tgmm" in ln for ln in calls) == 1 and len(calls) == 3
+    new = {key: count - took.get(key, 0)
+           for key, count in gm.GMM_CALLS.items() if count != took.get(key)}
+    assert new == {
+        ("moe_gmm", "bfloat16", gm._plan(m, k, n, g, 2)[0]): 1,
+        ("moe_gmm", "bfloat16", gm._plan(m, n, k, g, 2)[0]): 1,
+        ("moe_tgmm", "bfloat16", gm._plan(m, k, n, g, 2, "moe_tgmm")[0]): 1}
+    assert pk.FALLBACKS == routed
+
+
+@pytest.mark.parametrize("kernel,plan,size", [
+    ("moe_gmm", (512, 2304, 896), "18.46M"),    # the right operand whole
+    ("moe_gmm", (1024, 768, 896), "16.12M"),
+    ("moe_tgmm", (256, 2304, 896), "18.88M"),
+    ("moe_tgmm", (1024, 1152, 896), "21.30M"),
+])
+def test_grouped_vmem_rule_refuses_what_the_compiler_refuses(
+        one_chip, kernel, plan, size):
+    """Tiles past the scoped VMEM at the Mellum2 cell's gate/up product:
+    ``_vmem`` counts them over the limit, so ``_plan`` never offers them,
+    and Mosaic refuses them when handed them all the same."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    m, k, n, g = 65536, 2304, 896, 16
+    assert gm._vmem(kernel, *plan, 2, k // plan[1]) > pk._VMEM_LIMIT
+    assert gm._plan(m, k, n, g, 2, kernel)[0] != plan
+    lhs, rhs, sizes = _grouped_operands(one_chip, m, k, n, g)
+    if kernel == "moe_tgmm":
+        rhs = jax.ShapeDtypeStruct((m, n), jnp.bfloat16, sharding=one_chip)
+    call = gm._call(kernel, "bfloat16", m, k, n, g, plan, False, False)
+    with pytest.raises(Exception, match="Scoped allocation with size " + size):
+        call.lower(sizes, lhs, rhs).compile()

@@ -421,12 +421,14 @@ def test_config_refuses_what_it_cannot_build():
 
 
 def _primitives(jaxpr, found):
-    """``found[name] += 1`` for every Pallas kernel (by its name) and every
-    other primitive in ``jaxpr`` and the jaxprs its equations hold."""
+    """``found[name] += 1`` for every Pallas kernel (by its name; what its
+    body holds, a ``pl.when``'s ``cond`` say, is not the program's) and
+    every other primitive in ``jaxpr`` and the jaxprs its equations hold."""
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "pallas_call":
-            name = eqn.params["name"]
+            found[eqn.params["name"]] = found.get(eqn.params["name"], 0) + 1
+            continue
         found[name] = found.get(name, 0) + 1
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
@@ -448,9 +450,9 @@ def gradient_sites():
     from mxnet_tpu.models import hybrid_lm
 
     cfg = hybrid_lm.HybridConfig(
-        vocab_size=256, d_model=64, attention=("swa", "swa", "swa", "full"),
+        vocab_size=256, d_model=128, attention=("swa", "swa", "swa", "full"),
         mlp=("moe",) * 4, num_heads=4, num_kv_heads=2, head_dim=64,
-        window=128, rope_theta=5e5, yarn_factor=16.0, moe_d_ff=32,
+        window=128, rope_theta=5e5, yarn_factor=16.0, moe_d_ff=128,
         num_experts=64, experts_per_token=8, experts_held=(0, 16),
         num_shared_experts=0, router="softmax", dtype="bfloat16")
     params = jax.eval_shape(lambda key: hybrid_lm.init_params(cfg, key),
@@ -485,11 +487,14 @@ def test_a_share_of_a_quarter_leaves_no_cond_in_the_step(gradient_sites):
     """The bucket is everything that could land (``share_bucket_rows``
     returns its worst case), so the program holds the sorted path alone:
     twelve grouped products a layer with nothing kept, ten with the gate's
-    and the up's forward kept, and no ``cond``."""
+    and the up's forward kept (seven ``moe_gmm``: three forward, the down
+    product rebuilt, three cotangents; three ``moe_tgmm``), none routed to
+    XLA, and no ``cond``."""
     kept, bare = gradient_sites
     assert "cond" not in kept and "cond" not in bare
-    assert kept["ragged_dot_general"] == 4 * 10
-    assert bare["ragged_dot_general"] == 4 * 12
+    assert "ragged_dot_general" not in kept and "ragged_dot_general" not in bare
+    assert (kept["moe_gmm"], kept["moe_tgmm"]) == (4 * 7, 4 * 3)
+    assert (bare["moe_gmm"], bare["moe_tgmm"]) == (4 * 9, 4 * 3)
     assert kept["sort"] == 4 and bare["sort"] == 8
 
 

@@ -359,22 +359,22 @@ def test_grouped_products_run_over_the_whole_bucket(ref, monkeypatch, case):
     bucket's rows: the rows a batch leaves empty ride in the last group, so
     a step's work does not follow the routing."""
     import jax
-    from jax import lax
 
+    from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.parallel import moe
 
     x, p, sz = _moe_case(ref, case)
     rows = moe.share_bucket_rows(x.shape[0], sz["E"], sz["held"], sz["top_k"])
     seen = []
-    grouped = lax.ragged_dot
+    grouped = gm.grouped_matmul
 
-    def watched(a, b, sizes, **kw):  # traced inside ``lax.cond``
+    def watched(a, b, sizes):  # traced inside ``lax.cond``
         jax.debug.callback(
             lambda sizes, n_rows=a.shape[0]: seen.append(
                 (n_rows, np.asarray(sizes))), sizes)
-        return grouped(a, b, sizes, **kw)
+        return grouped(a, b, sizes)
 
-    monkeypatch.setattr(lax, "ragged_dot", watched)
+    monkeypatch.setattr(gm, "grouped_matmul", watched)
     _, counts = moe.moe_share_ffn(p, x, sz["top_k"], sz["held"],
                                   sz["route_scale"])
     counts = np.asarray(jax.block_until_ready(counts))

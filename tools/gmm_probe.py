@@ -1,0 +1,165 @@
+#!/usr/bin/env python3
+"""Time of the grouped-product kernels alone, on the chip.
+
+    python tools/gmm_probe.py [--shapes 65536x2304x896x16,...]
+        [--plans default,512x1152x896,...] [--kinds gmm,gmm_t,tgmm]
+        [--yardsticks ragged_dot,megablox] [--dtype bfloat16] [--iters 20]
+        [--tag NAME] [--out chiprun_out/gmm_probe.jsonl]
+
+For every shape ``MxKxNxG`` (rows, the right operand's two widths, groups)
+it runs the three products a layer's gradient needs: ``gmm`` ([M, K] x
+[G, K, N]), ``gmm_t`` ([M, N] x [G, K, N]^T, the input's cotangent) and
+``tgmm`` ([M, K]^T x [M, N] per group, the weights' gradient), each at
+every ``tm x tk x tn`` of ``--plans`` (``default`` = what
+``grouped_matmul._plan`` picks), ``--iters`` times back to back between two
+fences on the host's clock (one kernel a call and nothing else on the
+device, so that is its device time to a few microseconds), and prints
+milliseconds a call and TFLOP/s over the 2 M K N products. Yardsticks at
+the same operands: ``lax.ragged_dot`` (XLA's own kernel) and
+``jax.experimental.pallas.ops.tpu.megablox`` at the plan's tiling. Rows
+are split unevenly over the groups, one group empty, two fifths of the
+rows in the last as ``moe_share_ffn`` sends its empty rows. No chip:
+exit 2, nothing printed.
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", default="65536x2304x896x16,"
+                    "65536x896x2304x16,16384x2304x1024x8,16384x1024x2304x8")
+    ap.add_argument("--plans", default="default")
+    ap.add_argument("--kinds", default="gmm,gmm_t,tgmm")
+    ap.add_argument("--yardsticks", default="")
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--tag", default="")
+    ap.add_argument("--out", default=os.path.join(
+        REPO, "chiprun_out", "gmm_probe.jsonl"))
+    args = ap.parse_args(argv)
+
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return 2
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    dtype = jnp.dtype(args.dtype)
+    rows = []
+
+    def timed(fn, *operands):
+        jax.block_until_ready(fn(*operands))
+        start = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*operands)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - start) / args.iters
+
+    def report(row, flop, fn=None, *operands):
+        """One line: the time of ``fn``, or the ``error`` the row came with."""
+        try:
+            if fn is not None:
+                row["ms"] = timed(fn, *operands)
+                row["tflops"] = flop / row["ms"] / 1e9
+        except Exception as e:  # a refused plan must not end the sweep
+            row["error"] = "%s: %s" % (type(e).__name__, str(e)[-300:])
+        rows.append(row)
+        with open(args.out, "a") as f:
+            f.write(json.dumps(row) + "\n")
+        print(json.dumps(row), flush=True)
+
+    for shape in args.shapes.split(","):
+        m, k, n, g = (int(x) for x in shape.split("x"))
+        rng = np.random.RandomState(0)
+        share = rng.dirichlet(np.ones(g) * 0.5)
+        share[g // 2] = 0.0
+        sizes = np.floor(share / share.sum() * 0.6 * m).astype(np.int32)
+        sizes[-1] += m - sizes.sum()
+        sizes = jnp.asarray(sizes)
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        lhs = jax.random.normal(keys[0], (m, k), jnp.float32).astype(dtype)
+        rhs = jax.random.normal(keys[1], (g, k, n), jnp.float32).astype(dtype)
+        out = jax.random.normal(keys[2], (m, n), jnp.float32).astype(dtype)
+        operands = {"gmm": (lhs, rhs), "gmm_t": (out, rhs),
+                    "tgmm": (lhs, out)}
+        for kind in args.kinds.split(","):
+            kernel = "moe_tgmm" if kind == "tgmm" else "moe_gmm"
+            wide = (m, n, k) if kind == "gmm_t" else (m, k, n)
+            for plan in args.plans.split(","):
+                if plan == "default":
+                    tiles, refusal = gm._plan(*wide, g, dtype.itemsize,
+                                              kernel)
+                else:
+                    tiles, refusal = tuple(
+                        int(x) for x in plan.split("x")), None
+                row = {"tag": args.tag, "shape": shape, "kind": kind,
+                       "plan": tiles, "dtype": dtype.name,
+                       "device_kind": dev.device_kind}
+                flop = 2.0 * m * k * n
+                if refusal is not None:
+                    report(dict(row, error=refusal), flop)
+                    continue
+                if any(w % t for w, t in zip(wide, tiles)):
+                    continue  # another kind's plan
+
+                def run(a, b, kind=kind, kernel=kernel, tiles=tiles):
+                    return gm._product(kernel, a, b, sizes, tiles,
+                                       transposed=kind == "gmm_t")
+
+                report(row, flop, jax.jit(run), *operands[kind])
+                for yard in filter(None, args.yardsticks.split(",")):
+                    report(dict(row, yardstick=yard), flop,
+                           jax.jit(_yardstick(yard, kind, tiles, sizes)),
+                           *operands[kind])
+    return 0 if all("error" not in row for row in rows) else 1
+
+
+def _yardstick(yard, kind, tiles, sizes):
+    """The same product by ``lax.ragged_dot`` (through its own gradient
+    rules) or by megablox at ``tiles``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    f32 = jnp.float32
+    if yard == "megablox":
+        import importlib
+
+        mb = importlib.import_module(  # the package's ``gmm`` is a function
+            "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+        if kind == "tgmm":
+            return lambda a, b: mb.tgmm(a.T, b, sizes, f32, tiles)
+        return lambda a, b: mb.gmm(a, b, sizes, f32, tiles,
+                                   transpose_rhs=kind == "gmm_t")
+
+    def product(a, b):
+        return lax.ragged_dot(a, b, sizes, preferred_element_type=f32)
+
+    if kind == "gmm":
+        return product
+    if kind == "gmm_t":  # (cotangent, rhs) -> the left operand's gradient
+        return lambda out, b: jax.vjp(
+            lambda a: product(a, b),
+            jnp.zeros((out.shape[0], b.shape[1]), b.dtype))[1](
+                out.astype(f32))[0]
+    return lambda a, out: jax.vjp(
+        lambda b: product(a, b), jnp.zeros(
+            (sizes.shape[0], a.shape[1], out.shape[1]), a.dtype))[1](
+                out.astype(f32))[0]
+
+
+if __name__ == "__main__":
+    sys.exit(main())
