@@ -41,6 +41,15 @@ log = logging.getLogger("mxnet_tpu.pallas")
 #: compiler's RESOURCE_EXHAUSTED message). Every operand block of a
 #: pallas_call is double-buffered against it by the pipeline.
 _VMEM_LIMIT = 16 * 1024 * 1024
+#: the most scoped VMEM a flash call may ask Mosaic for in place of that
+#: default (``vmem_limit_bytes``), when its operands fit at no block under
+#: it: half of the 128 MiB of VMEM a v5e TensorCore has (the chip's
+#: published figure; the other half stays with whatever XLA keeps there
+#: around the kernel). Whole-length operands past it go to XLA as before.
+_VMEM_CAP = 64 * 1024 * 1024
+#: what such a call asks for over ``_flash_vmem``'s footprint: the
+#: compiler's own scratch beside what the model counts
+_VMEM_MARGIN = 1.25
 
 #: (kernel, reason) -> number of call sites routed to XLA instead of the
 #: kernel. Decisions are made while tracing, so this counts traces, not
@@ -124,8 +133,9 @@ def _attention_reference(q, k, v, causal, scale, window=None):
 
 
 #: (kernel, operand type of its products, (tiles visited, tiles the mask
-#: is applied on, tiles of the square) per head, in tiles of step x step)
-#: -> number of call sites that took the kernel. Filled while tracing,
+#: is applied on, tiles of the square) per head, in tiles of step x step[,
+#: the scoped-VMEM limit the call names, where it names one]) -> number of
+#: call sites that took the kernel. Filled while tracing,
 #: like ``FALLBACKS``: it says which mechanism a compiled step holds
 #: (chip_smoke.py asserts on it), not how often it ran.
 FLASH_CALLS = {}
@@ -223,12 +233,15 @@ def _tile_counts(t_own, t_other, step, causal, window=None, block=None,
     return visited, masked, n_own * n_other
 
 
-def _took_kernel(kernel, dtype, tiles):
-    """Count one call site that took ``kernel``."""
+def _took_kernel(kernel, dtype, tiles, vmem_limit=None):
+    """Count one call site that took ``kernel``; a call that asks for its
+    own scoped-VMEM limit carries it as a fourth part of the key."""
     import jax.numpy as jnp
 
     operand = jnp.dtype(_operand_dtype(dtype)).name
     key = (kernel, operand, tiles)
+    if vmem_limit is not None:
+        key += (vmem_limit,)
     FLASH_CALLS[key] = FLASH_CALLS.get(key, 0) + 1
     if _tel.ENABLED:
         _tel.counter("pallas.kernel_total.%s.%s" % (kernel, operand)).inc()
@@ -494,7 +507,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
 
 @functools.lru_cache(maxsize=None)
 def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
-                interpret, group=1, window=None):
+                interpret, group=1, window=None, vmem_limit=None):
     """One of the three kernels at one setting, as a jitted pallas_call.
     Cached, so that a model's layers share it: its body is then traced
     and lowered once a program and not once a call site (24 layers are
@@ -506,10 +519,14 @@ def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
     head ``i // group``, nothing is copied. The dkv kernel then gives one
     q head's part, in float32, for the caller to sum over the group. With
     a ``window`` the call is named ``flash_win_*``, so that a trace tells
-    the windowed layers' device time from the others'."""
+    the windowed layers' device time from the others'. ``vmem_limit``
+    (``_flash_plan`` names it where no block fits under the default) is
+    handed to Mosaic as the call's scoped-VMEM limit; None passes no
+    compiler parameter at all."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     # a group of one maps as before it could be grouped: no operation
     kv_head = (lambda i: i) if group == 1 else (lambda i: i // group)
@@ -552,35 +569,39 @@ def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
             (jax.ShapeDtypeStruct((bh, tk, d), part),
              jax.ShapeDtypeStruct((bh, tk, dv), part))),
     }[name]
+    params = {} if vmem_limit is None or interpret else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)}
     return jax.jit(pl.pallas_call(
         functools.partial(body, scale=scale, causal=causal, block=block,
                           step=step, n_steps=other // step, window=window),
         out_shape=out_shape, grid=(bh, own // block), in_specs=in_specs,
         out_specs=out_specs, interpret=interpret,
-        name=_kernel_name(name, window)))
+        name=_kernel_name(name, window), **params))
 
 
 def _kernel_name(name, window):
     return name if window is None else name.replace("flash_", "flash_win_")
 
 
-def _flash_attention_pallas(q, k, v, causal, scale, block, step, window=None):
+def _flash_attention_pallas(q, k, v, causal, scale, block, step, window=None,
+                            vmem_limit=None):
     """Forward kernel; returns (o, lse) with lse saved for the backward."""
     b, h, tq, d = q.shape
     hkv, tk, dv = k.shape[1], k.shape[2], v.shape[-1]
     bh = b * h
     _took_kernel(_kernel_name("flash_fwd", window), q.dtype,
-                 _tile_counts(tq, tk, step, causal, window, block))
+                 _tile_counts(tq, tk, step, causal, window, block),
+                 vmem_limit)
     out, lse = _flash_call("flash_fwd", q.dtype.name, bh, tq, tk, d, dv,
                            causal, scale, block, step, _interpret(),
-                           h // hkv, window)(
+                           h // hkv, window, vmem_limit)(
         q.reshape(bh, tq, d), k.reshape(b * hkv, tk, d),
         v.reshape(b * hkv, tk, dv))
     return out.reshape(b, h, tq, dv), lse  # lse: (b*h, 8, tq)
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                block, step, window=None):
+                                block, step, window=None, vmem_limit=None):
     """Blockwise backward: neither pass materialises the [T, T] score
     matrix in HBM — the cliff the dense-vjp fallback hits at long T.
     Under grouped queries dk and dv come from the kernel a q head at a
@@ -600,17 +621,18 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
                             axis=-1).reshape(bh, 1, tq), (bh, 8, tq)))
     setting = (q.dtype.name, bh, tq, tk, d, dv, causal, scale)
     _took_kernel(_kernel_name("flash_bwd_dq", window), q.dtype,
-                 _tile_counts(tq, tk, step, causal, window, block))
+                 _tile_counts(tq, tk, step, causal, window, block),
+                 vmem_limit)
     dq = _flash_call("flash_bwd_dq", *setting, block, step,
-                     _interpret(), group, window)(*operands)
+                     _interpret(), group, window, vmem_limit)(*operands)
     # the k side owns blocks of k and steps through q: the same two
     # sizes, fitted to the other length where the two differ
     block, step, _ = _select_blocks(tk, tq, block, step)
     _took_kernel(_kernel_name("flash_bwd_dkv", window), q.dtype,
                  _tile_counts(tk, tq, step, causal, window, block,
-                              k_side=True))
+                              k_side=True), vmem_limit)
     dk, dv_ = _flash_call("flash_bwd_dkv", *setting, block, step,
-                          _interpret(), group, window)(*operands)
+                          _interpret(), group, window, vmem_limit)(*operands)
     if group > 1:
         dk, dv_ = (x.reshape(b, hkv, group, tk, -1).sum(axis=2).astype(
             q.dtype) for x in (dk, dv_))
@@ -637,6 +659,11 @@ def _select_blocks(tq, tk, block_q=None, block_k=None):
       VMEM). The q positions ride the lanes of the score tile and the
       four MXUs split a product by its 128-lane column tiles, so narrow
       blocks starve them; a program costs several hundred cycles besides.
+      At 256-wide keys AND values, [1,20,8192,256], where no block fits
+      the default scoped VMEM and ``_flash_plan`` names the calls' limit
+      instead (my chip runs, PR 36): 1024x512 19.37 (fwd 4.69, dq 6.27,
+      dkv 8.41), 512x512 20.07, 256x256 28.01: the defaults stand. At
+      [1,32,8192,192] x 192 the same way 1024x512 reads 27.67.
       Earlier rounds' claims for the float32 body on another chip
       (docs/perf_analysis.md r4/r5: "block_k 128 -> 512 +19% tokens/s at
       T=1024, +54% at T=8192; block_q 1024 +5 MFU points at T=8192") were
@@ -714,7 +741,13 @@ def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize, group=1):
     of those that Mosaic refuses (test_chip_compile.py holds the shapes).
     Under grouped queries (``group`` > 1) dkv's two result blocks are
     float32. A window changes nothing: the kernels still hold the whole
-    of the other side's operands."""
+    of the other side's operands. Past the default limit, where
+    ``_flash_plan`` names the calls' own, it is what they ask for: at
+    T=8192, d = dv = 256, bf16 it reads 27.0 / 22.0 / 19.1 / 18.0 MiB at
+    1024x512 / 512x512 / 256x256 / 128x128 where Mosaic reports 21.00 /
+    19.00 / 18.00 / 17.50M for dkv, the hungriest (f32 at d = dv = 128 the
+    same four; AOT, PR 36): 3-29 % over, so the limit named is never
+    under what the compiler takes."""
     wide, wide_v = (-(-n // 128) * 128 for n in (d, dv))  # whole lane tiles
     io = (wide + wide_v) * itemsize
     tile = block_k * block_q * 4
@@ -731,26 +764,42 @@ def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize, group=1):
 
 def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4,
                 group=1):
-    """``(block_q, block_k, refusal)``: the blocks ``flash_attention``
-    runs these operands at, and why it would NOT take the Pallas kernels
-    (a ``FALLBACKS`` reason) or None when it will: every gate the kernels
-    apply — enablement, block-tiling legality and the scoped-VMEM
-    footprint. A ``block_q`` the caller did not name is halved while the
-    footprint overflows, so that long or wide operands stay on the
-    kernels at a smaller block."""
+    """``(block_q, block_k, refusal, vmem_limit)``: the blocks
+    ``flash_attention`` runs these operands at, why it would NOT take the
+    Pallas kernels (a ``FALLBACKS`` reason) or None when it will, and the
+    scoped-VMEM limit the calls must ask Mosaic for, or None where its
+    default does: every gate the kernels apply — enablement, block-tiling
+    legality and the scoped-VMEM footprint. A ``block_q`` the caller did
+    not name is halved while the footprint overflows, so that long or wide
+    operands stay on the kernels at a smaller block.
+
+    Where the operands fit under the default limit at NO block (the
+    whole-length ones alone are past it: 256-wide keys and values at T =
+    8192 in bfloat16 are 16.8 MB double-buffered) a smaller block buys
+    nothing: the blocks stay as ``_select_blocks`` gives them and the
+    calls name their own limit, ``_flash_vmem``'s footprint with
+    ``_VMEM_MARGIN`` over it in whole MiB, refused (``"vmem"``) past
+    ``_VMEM_CAP``."""
     named = block_q is not None
     block_q, block_k, tiles = _select_blocks(tq, tk, block_q, block_k)
     if not enabled():
-        return block_q, block_k, "disabled"
+        return block_q, block_k, "disabled", None
     if not tiles:
-        return block_q, block_k, "untileable"
+        return block_q, block_k, "untileable", None
+    if _flash_vmem(tq, tk, d, dv, 128, 128, itemsize, group) > _VMEM_LIMIT:
+        mib = 1024 * 1024
+        limit = -int(-_VMEM_MARGIN * _flash_vmem(
+            tq, tk, d, dv, block_q, block_k, itemsize, group) // mib) * mib
+        if limit > _VMEM_CAP:
+            return block_q, block_k, "vmem", None
+        return block_q, block_k, None, limit
     while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize,
                       group) > _VMEM_LIMIT:
         smaller = _select_blocks(tq, tk, block_q // 2, block_k)
         if named or not smaller[2] or smaller[0] >= block_q:
-            return block_q, block_k, "vmem"
+            return block_q, block_k, "vmem", None
         block_q, block_k, _ = smaller
-    return block_q, block_k, None
+    return block_q, block_k, None, None
 
 
 def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
@@ -795,10 +844,13 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
     was made of is in ``_NT``'s comment and PERF.md section 6.
 
     Routed to plain XLA, and counted in ``FALLBACKS``, when the kernels
-    are disabled, the lengths do not tile, the operands overflow the
-    scoped VMEM even at the smallest block (``_flash_plan`` names
-    which), or causal attention is asked over tq != tk. Every call site
-    that takes the kernels is counted in ``FLASH_CALLS``.
+    are disabled, the lengths do not tile, the operands overflow
+    ``_VMEM_CAP`` of scoped VMEM (``_flash_plan`` names which), or causal
+    attention is asked over tq != tk. Operands that fit under Mosaic's
+    default limit at no block (256-wide keys and values at T=8192) run at
+    ``_select_blocks``' blocks under a limit the calls name themselves.
+    Every call site that takes the kernels is counted in ``FLASH_CALLS``,
+    with that limit in its key where one is set.
 
     Block sizing: ``_select_blocks`` (1024 q positions a program, k/v
     512 at a time, with this PR's readings); MXNET_FLASH_BLOCK_Q/K
@@ -823,7 +875,7 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
         if not causal or window < 1:
             raise ValueError("a window of %r, causal %r" % (window, causal))
         window = None if window >= tk else int(window)
-    block_q, block_k, refusal = _flash_plan(
+    block_q, block_k, refusal, vmem_limit = _flash_plan(
         tq, tk, q.shape[-1], v.shape[-1], block_q, block_k,
         q.dtype.itemsize, group)
     if q.ndim != 4:
@@ -838,18 +890,18 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
     @jax.custom_vjp
     def attn(q, k, v):
         o, _ = _flash_attention_pallas(q, k, v, causal, scale,
-                                       block_q, block_k, window)
+                                       block_q, block_k, window, vmem_limit)
         return o
 
     def fwd(q, k, v):
-        o, lse = _flash_attention_pallas(q, k, v, causal, scale,
-                                         block_q, block_k, window)
+        o, lse = _flash_attention_pallas(q, k, v, causal, scale, block_q,
+                                         block_k, window, vmem_limit)
         o, lse = remat.offer("flash", o, lse)
         return o, (q, k, v, o, lse)
 
     def bwd(res, g):
-        return _flash_attention_bwd_pallas(*res, g, causal,
-                                           scale, block_q, block_k, window)
+        return _flash_attention_bwd_pallas(*res, g, causal, scale, block_q,
+                                           block_k, window, vmem_limit)
 
     attn.defvjp(fwd, bwd)
     return attn(q, k, v)

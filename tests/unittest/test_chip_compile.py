@@ -98,9 +98,15 @@ def test_flash_backward_compiles(one_chip, shape):
 def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
     """f32 operands at T=8192, d=128 passed the old dtype-blind 8 MB rule
     and then failed in Mosaic ("Scoped allocation ... 18.06M and limit
-    16.00M"); the rule now routes them to XLA, and counts it. f32 at
-    T=4096 overflows at the default 1024-wide block only (Mosaic: 16.76M
-    in dq): the rule halves the block and the kernels compile."""
+    16.00M"); the rule routed them to XLA until PR 36 and now, since they
+    fit under the default limit at no block, names the limit their calls
+    ask for, under which all three kernels compile; past the cap (T=32768)
+    they still go to XLA, and that is counted. f32 at T=4096 overflows at
+    the default 1024-wide block only (Mosaic: 16.76M in dq): the rule
+    halves the block and the kernels compile, no limit named. 256-wide
+    keys and values in bfloat16 at T=8192: Mosaic refuses the smallest
+    block under its default (17.50M in dkv), which is why the rule names a
+    limit there and shrinks nothing."""
     import jax
     import jax.numpy as jnp
 
@@ -111,9 +117,14 @@ def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
             jnp.float32).sum()
 
     assert pk.flash_kernel_usable(8192, 8192, 128, 128, itemsize=2)
-    assert not pk.flash_kernel_usable(8192, 8192, 128, 128, itemsize=4)
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=4)[3] > pk._VMEM_LIMIT
     before = pk.FALLBACKS.get(("flash_attention", "vmem"), 0)
     q = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.float32,
+                             sharding=one_chip)
+    assert len(_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) == 3
+    assert pk.FALLBACKS.get(("flash_attention", "vmem"), 0) == before
+    assert not pk.flash_kernel_usable(32768, 32768, 128, 128, itemsize=4)
+    q = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.float32,
                              sharding=one_chip)
     calls = _kernels(functools.partial(pk.flash_attention, causal=True),
                      q, q, q)
@@ -121,11 +132,20 @@ def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
     assert pk.FALLBACKS[("flash_attention", "vmem")] == before + 1
 
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4) == (
-        512, 512, None)
+        512, 512, None, None)
     q = jax.ShapeDtypeStruct((4, 8, 4096, 128), jnp.float32,
                              sharding=one_chip)
     assert len(_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) == 3
     assert pk.FALLBACKS[("flash_attention", "vmem")] == before + 1
+
+    wide = jax.ShapeDtypeStruct((2, 8192, 256), jnp.bfloat16,
+                                sharding=one_chip)
+    stats = jax.ShapeDtypeStruct((2, 8, 8192), jnp.float32,
+                                 sharding=one_chip)
+    call = pk._flash_call("flash_bwd_dkv", "bfloat16", 2, 8192, 8192, 256,
+                          256, True, 0.0625, 128, 128, False)
+    with pytest.raises(Exception, match="Scoped allocation with size 17.50M"):
+        call.lower(wide, wide, wide, wide, stats, stats).compile()
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -160,12 +180,19 @@ def test_rtc_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_latent_attention_shape_compiles(one_chip):
-    """The hybrid LM's latent-attention call: 192-wide keys, 128-wide
-    values, T = 8192. A 192-wide block fills 256 lanes in VMEM: the
-    footprint rule knows, goes down to 256 x 256, and all three kernels
-    compile (at 512 x 512 Mosaic refused dq, 16.61M of 16.00M, and at
-    512 x 256 dkv inside a whole step, 16.12M; AOT, PR 30)."""
+@pytest.mark.parametrize("heads,d,dv", [(2, 192, 128), (20, 256, 256)],
+                         ids=["kimi-192-128", "glm-256-256"])
+def test_latent_attention_shape_compiles(one_chip, heads, d, dv):
+    """The hybrid LMs' latent-attention calls at T = 8192. Kimi-Linear's:
+    192-wide keys, 128-wide values. A 192-wide block fills 256 lanes in
+    VMEM: the footprint rule knows, goes down to 256 x 256, and all three
+    kernels compile (at 512 x 512 Mosaic refused dq, 16.61M of 16.00M, and
+    at 512 x 256 dkv inside a whole step, 16.12M; AOT, PR 30).
+    GLM-4.7-Flash's: 20 heads of 256-wide keys AND values, which fit under
+    the default limit at no block (``_flash_vmem`` reads 18.0 MB at 128 x
+    128, Mosaic 17.50M): the plan keeps ``_select_blocks``' blocks, names
+    the limit, and the three kernels compile under it, counted with the
+    limit in their key (AOT, PR 36)."""
     import jax
     import jax.numpy as jnp
 
@@ -175,15 +202,26 @@ def test_latent_attention_shape_compiles(one_chip):
         return pk.flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
-    assert pk._flash_plan(8192, 8192, 192, 128, itemsize=2) == (
-        256, 256, None)
-    routed = dict(pk.FALLBACKS)
-    q = jax.ShapeDtypeStruct((1, 2, 8192, 192), jnp.bfloat16,
+    plan = pk._flash_plan(8192, 8192, d, dv, itemsize=2)
+    if d == 192:
+        assert plan == (256, 256, None, None)
+    else:
+        assert plan[:3] == pk._select_blocks(8192, 8192)[:2] + (None,)
+        assert pk._VMEM_LIMIT < plan[3] <= pk._VMEM_CAP
+    routed, took = dict(pk.FALLBACKS), dict(pk.FLASH_CALLS)
+    q = jax.ShapeDtypeStruct((1, heads, 8192, d), jnp.bfloat16,
                              sharding=one_chip)
-    v = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16,
+    v = jax.ShapeDtypeStruct((1, heads, 8192, dv), jnp.bfloat16,
                              sharding=one_chip)
     calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
     assert len(calls) == 3 and pk.FALLBACKS == routed
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum("/%s/" % name in ln for ln in calls) == 1, name
+    new = [key for key, n in pk.FLASH_CALLS.items() if n != took.get(key, 0)]
+    assert sorted(key[0] for key in new) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert all(key[3:] == (() if plan[3] is None else (plan[3],))
+               for key in new)
 
 
 @pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
@@ -206,7 +244,7 @@ def test_grouped_query_shapes_compile(one_chip, window):
         return attend(q, k, v).astype(jnp.float32).sum()
 
     assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8) == (
-        512, 512, None)
+        512, 512, None, None)
     routed = dict(pk.FALLBACKS)
     q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)

@@ -162,20 +162,57 @@ def test_flash_block_selection_rules():
 
 def test_flash_plan_halves_the_default_block(monkeypatch):
     """A block_q the caller did not name is halved while the operands
-    overflow the scoped VMEM; a named one is refused; operands that fit
-    at no block go to XLA."""
+    overflow the scoped VMEM; a named one is refused while a smaller block
+    would fit; operands that fit at no block stay on the kernels under a
+    limit of their own (the next test), and go to XLA past the cap."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2) == (
-        1024, 512, None)
+        1024, 512, None, None)
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4) == (
-        512, 512, None)
+        512, 512, None, None)
     assert pk._flash_plan(4096, 4096, 128, 128, block_q=1024,
                           itemsize=4)[2] == "vmem"
-    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=4)[2] == "vmem"
+    assert pk._flash_plan(32768, 32768, 128, 128, itemsize=4)[2:] == (
+        "vmem", None)
     monkeypatch.setenv("MXNET_FLASH_BLOCK_Q", "1024")  # a probe is a name
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4)[2] == "vmem"
+
+
+def test_flash_plan_names_a_limit_where_no_block_fits(monkeypatch):
+    """256-wide keys AND values at T = 8192 (GLM-4.7-Flash's latent
+    attention): the whole-length operands alone are past Mosaic's default
+    16 MiB at every block, so the plan keeps ``_select_blocks``' blocks and
+    names the scoped VMEM the calls ask for: the footprint and a quarter,
+    in whole MiB, under the cap. Where a block fits under the default the
+    plan is what it was and names none: Kimi-Linear's 192/128 head, the
+    dense LM's and Mellum2's grouped heads."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    for block in (1024, 512, 256, 128):
+        assert pk._flash_vmem(8192, 8192, 256, 256, block, min(block, 512),
+                              2) > pk._VMEM_LIMIT
+    bq, bk, refusal, limit = pk._flash_plan(8192, 8192, 256, 256, itemsize=2)
+    assert (bq, bk, refusal) == pk._select_blocks(8192, 8192)[:2] + (None,)
+    need = pk._flash_vmem(8192, 8192, 256, 256, bq, bk, 2)
+    assert pk._VMEM_MARGIN * need <= limit < pk._VMEM_MARGIN * need + 2 ** 20
+    assert limit % 2 ** 20 == 0 and pk._VMEM_LIMIT < limit <= pk._VMEM_CAP
+    # a probe's named blocks are kept too: nothing smaller would fit
+    assert pk._flash_plan(8192, 8192, 256, 256, 512, 512, itemsize=2)[:3] == (
+        512, 512, None)
+    assert pk.flash_kernel_usable(8192, 8192, 256, 256, itemsize=2)
+    # float32 at T = 8192 and 128-wide heads went to XLA until PR 36
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=4)[2] is None
+    # as before: a block fits, no limit is named
+    assert pk._flash_plan(8192, 8192, 192, 128, itemsize=2) == (
+        256, 256, None, None)
+    assert pk._flash_plan(1024, 1024, 64, 64, itemsize=2) == (
+        1024, 512, None, None)
+    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8) == (
+        512, 512, None, None)
 
 
 #: (T, block_q, block_k): one tile; several tiles with block_q != block_k;
@@ -221,6 +258,43 @@ def test_flash_attention_by_type_and_tiling(dtype, causal, tiling):
     assert all(a.dtype == q.dtype for a in got)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     want, pull = jax.vjp(ref, *f32)
+    want = (want,) + pull(g.astype(jnp.float32))
+    for i, (a, b) in enumerate(zip(got, want)):
+        tol = FLASH_TOLERANCE[dtype][i > 0]
+        if dtype == "bfloat16":
+            tol *= float(np.abs(b).max())
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("tiling", ["tall_q", "diagonal"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_at_256_wide_keys_and_values(dtype, tiling):
+    """GLM-4.7-Flash's head: d = dv = 256 at a scale of 1 / 16 (a power of
+    two, folded into the block), causal, over several blocks: the forward
+    and the three gradients against the dense reference in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    t, bq, bk = FLASH_TILINGS[tiling]
+    rng = np.random.RandomState(13)
+    q, k, v, g = (jnp.asarray(rng.randn(1, 2, t, 256) * 0.5, dtype)
+                  for _ in range(4))
+    assert pk._fold_scale(q.dtype, 0.0625)
+
+    def fast(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, scale=0.0625,
+                                  block_q=bq, block_k=bk)
+
+    routed = dict(pk.FALLBACKS)
+    out, pull = jax.vjp(fast, q, k, v)
+    got = (out,) + pull(g)
+    assert pk.FALLBACKS == routed
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want, pull = jax.vjp(
+        lambda q, k, v: pk._attention_reference(q, k, v, True, 0.0625), *f32)
     want = (want,) + pull(g.astype(jnp.float32))
     for i, (a, b) in enumerate(zip(got, want)):
         tol = FLASH_TOLERANCE[dtype][i > 0]
