@@ -13,24 +13,39 @@ here, with tiles sized to the shape by :func:`_plan`:
 * ``moe_gmm``: ``[m, k] x [g, k, n] -> [m, n]``, and with the right
   operand read transposed ``[m, n] x [g, k, n]^T -> [m, k]`` (the input's
   cotangent: through the index map and the product's dimensions, the
-  weights are never transposed in memory);
+  weights are never transposed in memory); its store can multiply each
+  row by a float32 scale (the routing weight of the down product);
+* ``moe_gmm_pair``: the same body over two pairs of operands,
+  ``a1 . b1^T + a2 . b2^T`` summed in float32 and written once (the
+  input's cotangent through gate and up, whose weights are never
+  concatenated in memory);
 * ``moe_tgmm``: ``lhs^T [k, m] x [m, n]`` per group ``-> [g, k, n]`` (the
-  weights' gradient), accumulated in the result's own block in VMEM over
-  the row tiles of one group.
+  weights' gradient), accumulated in VMEM over the row tiles of one group:
+  in the result's own block where the result is float32, else in a
+  float32 scratch block that is rounded into it at the group's last visit.
 
-Both walk a list of VISITS, (row tile, group) pairs that come in by scalar
+All walk a list of VISITS, (row tile, group) pairs that come in by scalar
 prefetch beside the groups' offsets: a row tile that a group boundary cuts
 is visited once per group under a row mask, an empty group once with
 nothing in the mask (so ``moe_tgmm`` writes its block as zeros). The list
 has the static worst-case length, ``m // tm + g - 1``, the surplus visits
 masked whole: the same grid whatever the routing. Operands go to the MXU
-in the type they arrive in, accumulation and results are float32.
+in the type they arrive in and accumulation is float32. A result is
+written ONCE, in the type its consumer reads: the forward products
+float32; the hidden's cotangent and the weights' gradients in the
+operands' type (float32 accumulation, one rounding at the store: the bits
+``astype`` on a float32 result gives); the input's cotangent through gate
+and up in the type the input arrived in (float32 rows: the scatter-add
+back to the tokens reads float32), so that no XLA pass over ``[rows, d]``
+exists only to rescale, round, widen or add what a kernel has just
+written.
 
-:func:`grouped_matmul` is the entry point, a ``jax.custom_vjp`` whose
-residuals are its operands. Kernels off (the CPU default), a width that is
-no multiple of 128 or tiles that do not fit: ``lax.ragged_dot``, counted in
-``pallas_kernels.FALLBACKS`` under ``moe_gmm``. ``MXNET_PALLAS`` and
-interpret mode govern these kernels as they govern the others.
+:func:`grouped_matmul` and :func:`grouped_pair` are the entry points, each
+a ``jax.custom_vjp`` whose residuals are its operands. Kernels off (the CPU
+default), a width that is no multiple of 128 or tiles that do not fit:
+``lax.ragged_dot``, counted in ``pallas_kernels.FALLBACKS`` under
+``moe_gmm``. ``MXNET_PALLAS`` and interpret mode govern these kernels as
+they govern the others.
 """
 from __future__ import annotations
 
@@ -39,10 +54,11 @@ import functools
 from .. import telemetry as _tel
 from . import pallas_kernels as _pk
 
-__all__ = ["grouped_matmul", "GMM_CALLS"]
+__all__ = ["grouped_matmul", "grouped_pair", "GMM_CALLS"]
 
-#: (kernel, operand type, (tm, tk, tn)) -> number of call sites that took
-#: the kernel, filled while tracing like ``pallas_kernels.FLASH_CALLS``
+#: (kernel, operand type, result type, whether the store scales its rows,
+#: (tm, tk, tn)) -> number of call sites that took the kernel, filled while
+#: tracing like ``pallas_kernels.FLASH_CALLS``
 GMM_CALLS = {}
 
 _NN = (((1,), (0,)), ((), ()))  # [m, k] x [k, n] -> [m, n]
@@ -50,31 +66,59 @@ _NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k] -> [m, n]
 _TN = (((0,), (0,)), ((), ()))  # [m, k] x [m, n] -> [k, n]
 
 
-def _took_kernel(kernel, dtype, tiles):
+def _pairs(kernel):
+    """How many (lhs, rhs) pairs ``kernel`` multiplies into one result."""
+    return 2 if kernel == "moe_gmm_pair" else 1
+
+
+def _took_kernel(kernel, dtype, out_dtype, scaled, tiles):
     import jax.numpy as jnp
 
-    key = (kernel, jnp.dtype(dtype).name, tiles)
+    key = (kernel, jnp.dtype(dtype).name, jnp.dtype(out_dtype).name,
+           scaled, tiles)
     GMM_CALLS[key] = GMM_CALLS.get(key, 0) + 1
     if _tel.ENABLED:
-        _tel.counter("pallas.kernel_total.%s.%s" % key[:2]).inc()
+        _tel.counter("pallas.kernel_total.%s.%s.%s%s" % (
+            key[:3] + (".scaled" if scaled else "",))).inc()
 
 
-def _vmem(kernel, tm, tk, tn, itemsize, k_steps=1):
+def _vmem(kernel, tm, tk, tn, itemsize, k_steps=1, out_itemsize=4,
+          scaled=False):
     """Bytes of scoped VMEM a kernel asks for at tiles ``(tm, tk, tn)``:
     every operand and result block twice over for the pipeline, then the
-    body's own. ``moe_gmm``: where k is stepped through, the float32
-    accumulator and the product before it is added; at one step the
-    product goes to the result's block as it is made, half of it in
-    flight. ``moe_tgmm``: the left operand's tile transposed, and the
-    narrower operand's masked copy with its float32 form. Fitted to what
-    the chip's compiler accepts and refuses at both benchmark cells'
-    shapes, never under it (AOT and my chip runs, PR 35;
-    test_chip_compile.py holds the shapes)."""
+    body's own. ``moe_gmm`` (``moe_gmm_pair``: two of each operand block):
+    the left operand's tile once more, as the compiler lays it out for the
+    MXU; where k is stepped through, the float32 accumulator and the
+    product before it is added; where a pair is summed at one step, the
+    first float32 product; else the product goes to the result's block as
+    it is made, a few hundred bytes a row in flight (more where it is
+    rounded on the way); the rows' scale comes as a [1, tm] float32 block
+    and is turned into a column 128 rows at a time.
+    ``moe_tgmm``: the left operand's tile transposed, the narrower
+    operand's masked copy with its float32 form, and for a result narrower
+    than float32 the float32 scratch block it is rounded from (with the
+    narrower result's blocks, what the float32 result's two take). Fitted
+    to what the chip's compiler asks for at the three benchmark cells'
+    shapes, read stage by stage under a rising limit: 0 - 2 % over it at
+    sixty settings, never under (AOT, PR 37; test_chip_compile.py holds
+    the shapes)."""
     if kernel == "moe_tgmm":
-        return (2 * tm * (tk + tn) * itemsize + 2 * tk * tn * 4
-                + tm * tk * itemsize + tm * min(tk, tn) * (4 + itemsize))
-    blocks = 2 * (tm * tk + tk * tn) * itemsize + 2 * tm * tn * 4
-    return blocks + tm * tn * (8 if k_steps > 1 else 2)
+        scratch = tk * tn * 4 if out_itemsize < 4 else 0
+        return (2 * tm * (tk + tn) * itemsize + 2 * tk * tn * out_itemsize
+                + scratch + tm * tk * itemsize
+                + tm * min(tk, tn) * (4 + itemsize))
+    pairs = _pairs(kernel)
+    blocks = (2 * pairs * (tm * tk + tk * tn) * itemsize
+              + 2 * tm * tn * out_itemsize
+              + (tm * 576 + 128 * 1024 if scaled else 0))
+    own = tm * tk * itemsize + (256 * tm if pairs > 1 else 0)
+    if k_steps > 1:
+        own += 8 * tm * tn
+    elif pairs > 1:
+        own += 4 * tm * tn
+    else:
+        own += tm * (1408 if out_itemsize < 4 else 768)
+    return blocks + own
 
 
 def _divisors(width):
@@ -82,41 +126,62 @@ def _divisors(width):
     return [d for d in range(width, 0, -128) if width % d == 0]
 
 
-def _plan(m, k, n, g, itemsize, kernel="moe_gmm"):
+def _plan(m, k, n, g, itemsize, kernel="moe_gmm", out_itemsize=4,
+          scaled=False):
     """``((tm, tk, tn), refusal)``: the tiles a product of ``m`` rows,
     contraction ``k`` and result width ``n`` over ``g`` groups runs at, and
     why it would NOT take the kernel (a ``FALLBACKS`` reason) or None when
-    it will. The only place that knows shapes: of the tiles that fit
-    ``_VMEM_LIMIT``, those that move the fewest bytes between HBM and
-    VMEM, the larger tiles on a tie. A ``moe_gmm`` reads the left operand
-    once per column tile, and the right operand's [k, n] at every visit,
-    or once a group where ``tk`` is all of k (the block then stays while
-    the visits are one group's); a ``moe_tgmm`` reads each operand once
-    per tile of the other's width. Ms a call on one v5e at the Mellum2
+    it will. ``out_itemsize``: the item size of the result's type;
+    ``scaled``: the store multiplies by a row scale. The only place that
+    knows shapes: of the tiles that fit ``_VMEM_LIMIT``, those that move
+    the fewest bytes between HBM and VMEM, the larger tiles on a tie. A
+    ``moe_gmm`` reads the left operand once per column tile, and the right
+    operand's [k, n] at every visit, or once a group where ``tk`` is all
+    of k (the block then stays while the visits are one group's); a
+    ``moe_gmm_pair`` does so for both of its pairs (``k`` is ONE pair's
+    contraction); a ``moe_tgmm`` reads each operand once per tile of the
+    other's width. Ms a call on one v5e at the Mellum2
     cell's [65536, 2304] x [16, 2304, 896] in bfloat16 (tools/gmm_probe.py;
     my chip runs, PR 35; ``lax.ragged_dot`` 6.08, megablox 1.96 at its
     best tiling that fits): 256 x 2304 x 896 1.57 (the pick: 603 MB moved,
     172 TFLOP/s), 512 x 1152 x 896 2.04 (1,127 MB), 512 x 768 x 896 2.16,
     512 x 384 x 896 2.38, 256 x 1152 x 896 2.40, 256 x 2304 x 128 3.49;
     the weights' gradient 512 x 1152 x 896 1.79 (the pick), 256 x 1152 x
-    896 1.84, 512 x 768 x 896 1.85, 1024 x 768 x 896 1.91."""
+    896 1.84, 512 x 768 x 896 1.85, 1024 x 768 x 896 1.91. By what the
+    store does (``--results float32,bfloat16 --scale --pair``; my chip
+    runs, PR 37; float32 result / bfloat16 result at the pick): the
+    product 1.58 / 1.56; with the rows' scale in the store 1.61 / 1.59; the
+    right operand read transposed 1.61 (256 x 896 x 2304) / 1.61 (512 x 896
+    x 2304, which fits with the narrower result); the weights' gradient
+    1.79 / 1.74 (the scratch block costs nothing, the narrower write-back
+    saves); the pair [65536, 896] x [16, 2304, 896]^T twice -> [65536,
+    2304] at 256 x 896 x 1152 (two right-hand blocks of [2304, 896] do not
+    fit beside a row tile: the column tile is halved and both left operands
+    are read twice) 3.17 / 3.14, 171 TFLOP/s, where two single calls take
+    3.21 and leave an add. At the GLM cell's [32768, 2048] x [8, 2048, 1536]
+    (128-row tiles): 1.21 / 1.21, scaled 1.24 / 1.22, the weights' gradient
+    1.42 / 1.35, the pair 2.43 / 2.42 at 128 x 1536 x 1024; at the Kimi
+    cell's [16384, 2304] x [8, 2304, 1024]: 0.50 / 0.50, scaled 0.51 / 0.50,
+    the weights' gradient 0.60 / 0.56, the pair 0.98 / 0.97."""
     if not _pk.enabled():
         return None, "disabled"
     if m % 128 or k % 128 or n % 128:
         return None, "untileable"
+    pairs = _pairs(kernel)
     best = None
     for tm in (t for t in (512, 256, 128) if m % t == 0):
         visits = m // tm + g - 1
         for tn in _divisors(n):
             for tk in _divisors(k):
-                if _vmem(kernel, tm, tk, tn, itemsize,
-                         k // tk) > _pk._VMEM_LIMIT:
+                if _vmem(kernel, tm, tk, tn, itemsize, k // tk,
+                         out_itemsize, scaled) > _pk._VMEM_LIMIT:
                     continue
                 if kernel == "moe_tgmm":
                     moved = m * (k * (n // tn) + n * (k // tk))
                 else:
-                    moved = (m * k * (n // tn)
-                             + (g if tk == k else visits) * k * n)
+                    moved = pairs * (
+                        m * k * (n // tn)
+                        + (g if tk == k else visits) * k * n)
                 if best is None or moved < best[0]:
                     best = (moved, (tm, tk, tn))
     if best is None:
@@ -161,20 +226,47 @@ def _rows_of_visit(offsets, groups, tiles, real, v, shape, tm):
     return (row >= lo) & (row < hi)
 
 
-def _gmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
-                *acc_ref, tm, k_steps, dims):
-    """One visit's [tm, tn] block of ``moe_gmm``, ``tk`` of the contraction
-    a grid step; the rows of the visit's group are stored at the last."""
+def _column(row_ref):
+    """A ``[1, tm]`` float32 block as a ``[tm, 1]`` column, 128 rows at a
+    time: 128 lanes of the row spread down a square, masked to its
+    diagonal and summed along the lanes (one term a row that is not zero:
+    exact). The rows' scale comes in lane-dense so; as ``[m, 1]`` it would
+    lie a lane tile wide in HBM, 128 times its size."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    diagonal = (lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+                == lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(diagonal, row_ref[:, at:at + 128], 0.0), axis=1,
+                 keepdims=True) for at in range(0, row_ref.shape[1], 128)],
+        axis=0)
+
+
+def _gmm_kernel(offsets, groups, tiles, real, *refs, tm, k_steps, dims,
+                pairs, scaled):
+    """One visit's [tm, tn] block of ``moe_gmm`` (``pairs`` of operands:
+    their products summed), ``tk`` of the contraction a grid step; the rows
+    of the visit's group are stored at the last, under their scale where
+    there is one, in the result's type."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    operands, rest = refs[:2 * pairs], refs[2 * pairs:]
+    scale_ref = rest[0] if scaled else None
+    out_ref, *acc_ref = rest[scaled:]
     v, at = pl.program_id(1), pl.program_id(2)
-    part = _pk._dot(lhs_ref[...], rhs_ref[...], dims)
+    part = _pk._dot(operands[0][...], operands[1][...], dims)
+    for a_ref, b_ref in zip(operands[2::2], operands[3::2]):
+        part += _pk._dot(a_ref[...], b_ref[...], dims)
 
     def store(total):
+        if scaled:
+            total = total * _column(scale_ref)
         mine = _rows_of_visit(offsets, groups, tiles, real, v,
                               out_ref.shape, tm)
-        out_ref[...] = jnp.where(mine, total, out_ref[...])
+        out_ref[...] = jnp.where(mine, total.astype(out_ref.dtype),
+                                 out_ref[...])
 
     if k_steps == 1:
         store(part)
@@ -194,19 +286,23 @@ def _gmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
         store(acc[...])
 
 
-def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref, *,
-                 tm):
+def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
+                 *acc_ref, tm, visits):
     """One visit's part of a group's [tk, tn] block of ``moe_tgmm``: zeros
     at the group's first visit, then the product of the visit's rows, the
-    narrower operand masked to them."""
+    narrower operand masked to them. A float32 result is summed where it
+    lies; a narrower one in the float32 scratch block, rounded into the
+    result at the group's last visit (the surplus visits are the last
+    group's, so that is the grid's last step there)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     v = pl.program_id(2)
+    acc = acc_ref[0] if acc_ref else out_ref
 
     @pl.when((v == 0) | (groups[jnp.maximum(v - 1, 0)] != groups[v]))
     def _first():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        acc[...] = jnp.zeros_like(acc)
 
     a, b = lhs_ref[...], rhs_ref[...]
     if a.shape[1] <= b.shape[1]:
@@ -215,18 +311,26 @@ def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref, *,
     else:
         b = jnp.where(_rows_of_visit(offsets, groups, tiles, real, v,
                                      b.shape, tm), b, 0)
-    out_ref[...] += _pk._dot(a, b, _TN)
+    acc[...] += _pk._dot(a, b, _TN)
+    if acc_ref:
+        @pl.when((v == visits - 1)
+                 | (groups[jnp.minimum(v + 1, visits - 1)] != groups[v]))
+        def _last():
+            out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
-def _call(kernel, dtype, m, k, n, g, plan, transposed, interpret):
-    """One of the two kernels at one setting, jitted over (group sizes,
-    lhs, rhs) with its list of visits. Cached, so that a model's layers
+def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
+          interpret):
+    """One of the kernels at one setting, jitted over (group sizes,
+    operands...) with its list of visits. Cached, so that a model's layers
     share it (``pallas_kernels._flash_call``): the list's dozen small
     operations were a second of tracing a step with 40 call sites
     otherwise. ``m, k, n``: rows, contraction and result width of a
-    ``moe_gmm`` (``transposed``: the right operand is [g, n, k]); of a
-    ``moe_tgmm`` the rows and the widths of its two operands."""
+    ``moe_gmm`` (``transposed``: the right operand is [g, n, k];
+    ``moe_gmm_pair``: lhs, rhs, lhs, rhs, each pair contracting ``k``;
+    ``scaled``: a last operand [1, m] float32); of a ``moe_tgmm`` the rows
+    and the widths of its two operands. ``out_dtype``: the result's."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -234,20 +338,24 @@ def _call(kernel, dtype, m, k, n, g, plan, transposed, interpret):
 
     tm, tk, tn = plan
     visits = m // tm + g - 1
+    out_dtype = jnp.dtype(out_dtype)
     if kernel == "moe_tgmm":
-        body = functools.partial(_tgmm_kernel, tm=tm)
+        body = functools.partial(_tgmm_kernel, tm=tm, visits=visits)
         grid = (n // tn, k // tk, visits)
         in_specs = [
             pl.BlockSpec((tm, tk), lambda j, i, v, o, gr, t, r: (t[v], i)),
             pl.BlockSpec((tm, tn), lambda j, i, v, o, gr, t, r: (t[v], j))]
         out_specs = pl.BlockSpec(
             (None, tk, tn), lambda j, i, v, o, gr, t, r: (gr[v], i, j))
-        out_shape = jax.ShapeDtypeStruct((g, k, n), jnp.float32)
-        scratch = []
+        out_shape = jax.ShapeDtypeStruct((g, k, n), out_dtype)
+        scratch = [pltpu.VMEM((tk, tn), jnp.float32)] * (
+            out_dtype.itemsize < 4)
     else:
         k_steps = k // tk
-        body = functools.partial(_gmm_kernel, tm=tm, k_steps=k_steps,
-                                 dims=_NT if transposed else _NN)
+        pairs = _pairs(kernel)
+        body = functools.partial(
+            _gmm_kernel, tm=tm, k_steps=k_steps, pairs=pairs, scaled=scaled,
+            dims=_NT if transposed else _NN)
         grid = (n // tn, visits, k_steps)
         if transposed:
             rhs = pl.BlockSpec(
@@ -257,10 +365,13 @@ def _call(kernel, dtype, m, k, n, g, plan, transposed, interpret):
                 (None, tk, tn), lambda j, v, i, o, gr, t, r: (gr[v], i, j))
         in_specs = [
             pl.BlockSpec((tm, tk), lambda j, v, i, o, gr, t, r: (t[v], i)),
-            rhs]
+            rhs] * pairs
+        if scaled:
+            in_specs.append(pl.BlockSpec(
+                (1, tm), lambda j, v, i, o, gr, t, r: (0, t[v])))
         out_specs = pl.BlockSpec(
             (tm, tn), lambda j, v, i, o, gr, t, r: (t[v], j))
-        out_shape = jax.ShapeDtypeStruct((m, n), jnp.float32)
+        out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
         scratch = [pltpu.VMEM((tm, tn), jnp.float32)] * (k_steps > 1)
     params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"))}
@@ -270,40 +381,62 @@ def _call(kernel, dtype, m, k, n, g, plan, transposed, interpret):
             num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
         interpret=interpret, name=kernel, **params)
-    return jax.jit(lambda group_sizes, lhs, rhs: call(
-        *_visits(group_sizes, m, tm), lhs, rhs))
+    return jax.jit(lambda group_sizes, *operands: call(
+        *_visits(group_sizes, m, tm), *operands))
 
 
-def _product(kernel, lhs, rhs, group_sizes, plan, transposed=False):
-    """``kernel`` over ``lhs`` and ``rhs`` at ``plan``, counted."""
+def _product(kernel, operands, group_sizes, plan, out_dtype,
+             transposed=False, scale=None):
+    """``kernel`` over ``operands`` (lhs, rhs; a pair: lhs, rhs, lhs, rhs)
+    at ``plan``, its result in ``out_dtype``, its rows times ``scale`` [m]
+    where there is one; counted."""
+    import jax.numpy as jnp
+
+    lhs, rhs = operands[:2]
     m, k = lhs.shape
     g = group_sizes.shape[0]
     if kernel == "moe_tgmm":
         n = rhs.shape[1]
     else:
         n = rhs.shape[1] if transposed else rhs.shape[2]
-    _took_kernel(kernel, lhs.dtype, plan)
-    return _call(kernel, lhs.dtype.name, m, k, n, g, plan, transposed,
-                 _pk._interpret())(group_sizes, lhs, rhs)
+    scaled = scale is not None
+    if scaled:
+        operands += (scale.astype(jnp.float32)[None, :],)
+    out_dtype = jnp.dtype(out_dtype)
+    _took_kernel(kernel, lhs.dtype, out_dtype, scaled, plan)
+    return _call(kernel, lhs.dtype.name, out_dtype.name, m, k, n, g, plan,
+                 transposed, scaled, _pk._interpret())(group_sizes, *operands)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def _weights_gradient(lhs, g_out, group_sizes, plan):
+    """``lhs^T . g_out`` per group, in the operands' type."""
+    return _product("moe_tgmm", (lhs, g_out), group_sizes, plan, lhs.dtype)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, row_scale=None):
     """``lhs [m, k]`` times ``rhs [g, k, n]`` by groups of rows ->
     ``[m, n]`` float32: the first ``group_sizes[0]`` rows meet ``rhs[0]``,
     the next ``group_sizes[1]`` rows ``rhs[1]``, and so on.
     ``sum(group_sizes) == m`` is the caller's to keep (``moe_share_ffn``
     does: its empty rows ride in the last group); rows past the last group
     are never written. The operands go to the MXU in their common type,
-    accumulation is float32.
+    accumulation is float32. ``row_scale [m]`` (float32): each row of the
+    float32 total is multiplied by its scale before it is written, the
+    bits of ``grouped_matmul(lhs, rhs, group_sizes) * row_scale[:, None]``
+    without that pass over ``[m, n]``.
 
-    The product and both gradients run as the ``moe_gmm`` / ``moe_tgmm``
+    The product and its gradients run as the ``moe_gmm`` / ``moe_tgmm``
     kernels (:func:`_plan` sizes their tiles from the shapes), under one
     ``jax.custom_vjp`` that keeps its operands and nothing the kernels
-    make. Routed to ``lax.ragged_dot``, and counted in
-    ``pallas_kernels.FALLBACKS`` under ``moe_gmm``, when the kernels are
+    make: the cotangents of ``lhs`` and ``rhs`` are written in the
+    operands' type by the kernels (``g * row_scale`` rounded to it first,
+    where there is a scale); the scale's is ``sum_c g[r, c] * (lhs .
+    rhs)[r, c]`` over the unscaled product, rebuilt by the forward kernel.
+    Routed to ``lax.ragged_dot`` (the scale a plain multiply), and counted
+    in ``pallas_kernels.FALLBACKS`` under ``moe_gmm``, when the kernels are
     disabled, a dimension is no multiple of 128, or no tiles fit the
     scoped VMEM. Every call site that takes a kernel is counted in
-    ``GMM_CALLS`` with its tiles."""
+    ``GMM_CALLS`` with its types and tiles."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -312,30 +445,91 @@ def grouped_matmul(lhs, rhs, group_sizes):
     lhs, rhs = lhs.astype(dtype), rhs.astype(dtype)
     (m, k), (g, _, n) = lhs.shape, rhs.shape
     size = dtype.itemsize
-    plans = (_plan(m, k, n, g, size), _plan(m, n, k, g, size),
-             _plan(m, k, n, g, size, "moe_tgmm"))
+    plans = (_plan(m, k, n, g, size, scaled=row_scale is not None),
+             _plan(m, n, k, g, size, out_itemsize=size),
+             _plan(m, k, n, g, size, "moe_tgmm", out_itemsize=size))
     refusal = next((why for _, why in plans if why is not None), None)
     if refusal is not None:
         _pk._fallback("moe_gmm", refusal, (m, k, n, g))
-        return lax.ragged_dot(lhs, rhs, group_sizes,
-                              preferred_element_type=jnp.float32)
+        out = lax.ragged_dot(lhs, rhs, group_sizes,
+                             preferred_element_type=jnp.float32)
+        return out if row_scale is None else out * row_scale[:, None]
     forward, to_lhs, to_rhs = (plan for plan, _ in plans)
 
-    @jax.custom_vjp
-    def product(lhs, rhs, group_sizes):
-        return _product("moe_gmm", lhs, rhs, group_sizes, forward)
+    def run(lhs, rhs, group_sizes, row_scale):
+        return _product("moe_gmm", (lhs, rhs), group_sizes, forward,
+                        jnp.float32, scale=row_scale)
 
-    def fwd(lhs, rhs, group_sizes):
-        return (_product("moe_gmm", lhs, rhs, group_sizes, forward),
-                (lhs, rhs, group_sizes))
+    def fwd(*operands):
+        return run(*operands), operands
 
     def bwd(kept, g_out):
-        lhs, rhs, group_sizes = kept
+        lhs, rhs, group_sizes, row_scale = kept
+        g_scale = None
+        if row_scale is not None:
+            unscaled = run(lhs, rhs, group_sizes, None)
+            g_scale = jnp.sum(g_out * unscaled, axis=1).astype(
+                row_scale.dtype)
+            g_out = g_out * row_scale[:, None]
         g_out = g_out.astype(dtype)
-        g_lhs = _product("moe_gmm", g_out, rhs, group_sizes, to_lhs,
+        g_lhs = _product("moe_gmm", (g_out, rhs), group_sizes, to_lhs, dtype,
                          transposed=True)
-        g_rhs = _product("moe_tgmm", lhs, g_out, group_sizes, to_rhs)
-        return g_lhs.astype(dtype), g_rhs.astype(dtype), None
+        return (g_lhs, _weights_gradient(lhs, g_out, group_sizes, to_rhs),
+                None, g_scale)
 
+    product = jax.custom_vjp(run)
     product.defvjp(fwd, bwd)
-    return product(lhs, rhs, group_sizes)
+    return product(lhs, rhs, group_sizes, row_scale)
+
+
+def grouped_pair(lhs, rhs_a, rhs_b, group_sizes):
+    """``(grouped_matmul(lhs, rhs_a), grouped_matmul(lhs, rhs_b))``, both
+    float32, under ONE ``jax.custom_vjp``: the two forward products as they
+    are, and ``lhs``'s cotangent ``g_a . rhs_a^T + g_b . rhs_b^T`` as one
+    ``moe_gmm_pair`` call that sums both products in float32 and writes
+    the sum once, where two calls write two ``[m, k]`` results that are
+    each rounded and then added. The operands' type is the weights';
+    ``lhs`` may arrive wider (the gathered rows of a float32 activation):
+    it is rounded to the weights' type here, kept so, and its cotangent
+    is written in the type it ARRIVED in, which is the type the cotangent's
+    consumer reads (the scatter-add back to the tokens takes float32: a
+    bfloat16 result would be one more pass that widens it). Where the
+    pair's blocks fit no plan (or the kernels take nothing), two
+    :func:`grouped_matmul` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype, arrived = jnp.result_type(rhs_a, rhs_b), lhs.dtype
+    rhs_a, rhs_b = rhs_a.astype(dtype), rhs_b.astype(dtype)
+    (m, k), (g, _, n) = lhs.shape, rhs_a.shape
+    size = dtype.itemsize
+    plans = (_plan(m, k, n, g, size),
+             _plan(m, n, k, g, size, "moe_gmm_pair",
+                   out_itemsize=arrived.itemsize),
+             _plan(m, k, n, g, size, "moe_tgmm", out_itemsize=size))
+    if any(why for _, why in plans):
+        lhs = lhs.astype(dtype)
+        return (grouped_matmul(lhs, rhs_a, group_sizes),
+                grouped_matmul(lhs, rhs_b, group_sizes))
+    forward, to_lhs, to_rhs = (plan for plan, _ in plans)
+
+    def run(lhs, rhs_a, rhs_b, group_sizes):
+        return tuple(_product("moe_gmm", (lhs.astype(dtype), rhs),
+                              group_sizes, forward, jnp.float32)
+                     for rhs in (rhs_a, rhs_b))
+
+    def fwd(lhs, *rest):
+        lhs = lhs.astype(dtype)
+        return run(lhs, *rest), (lhs,) + rest
+
+    def bwd(kept, g_out):
+        lhs, rhs_a, rhs_b, group_sizes = kept
+        g_a, g_b = (g.astype(dtype) for g in g_out)
+        g_lhs = _product("moe_gmm_pair", (g_a, rhs_a, g_b, rhs_b),
+                         group_sizes, to_lhs, arrived, transposed=True)
+        return (g_lhs, _weights_gradient(lhs, g_a, group_sizes, to_rhs),
+                _weights_gradient(lhs, g_b, group_sizes, to_rhs), None)
+
+    pair = jax.custom_vjp(run)
+    pair.defvjp(fwd, bwd)
+    return pair(lhs, rhs_a, rhs_b, group_sizes)

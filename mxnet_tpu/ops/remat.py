@@ -17,7 +17,9 @@ rule: o and the row log-sum-exp), ``kda_chunk`` (``kda``'s in-chunk
 forward rule: the kernel's seven results), and from
 ``parallel.moe.moe_share_ffn``'s sorted path ``moe_sort`` (which
 assignments fill the bucket, the argsort's result) and ``moe_hidden`` (the
-gate and up products over the bucket, float32).
+gate and up products over the bucket, float32; the rows a batch left empty
+hold the finite products of the tokens that ride there under a zero
+weight, not zeros: nothing downstream of the down product reads them).
 
 A float that is kept AND used further on in the forward pass gets a
 ``reduce_precision`` from ``jax.checkpoint`` (against XLA carrying a

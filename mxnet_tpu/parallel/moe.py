@@ -28,17 +28,23 @@ Two expert layers live here, and they are not interchangeable:
   rows are a fixed bucket of eight times the expected load (a factor fitted
   to the benchmark's one cell: :func:`share_bucket_rows`), and the grouped
   products run over ALL of it at every step, the rows a batch leaves empty
-  as zero rows of the last group: a step takes the same time whatever the
-  routing. A batch that sends more than the bucket holds is computed the
+  in the last group under a zero weight: a step takes the same time
+  whatever the routing. A batch that sends more than the bucket holds is
+  computed the
   dense way over the held experts (every assignment still counted), never
   cut. On one chip it runs without an exchange; what the absent experts
   would add is left out, which is the partial result a deployment's combine
   would sum. The grouped products are ``ops/grouped_matmul.py``'s Pallas
-  kernels (``moe_gmm`` forward and for the input's cotangent, ``moe_tgmm``
-  for the weights' gradient), tiles sized to the shape; kernels off (the
-  CPU default), a width that is no multiple of 128 or tiles that do not
-  fit VMEM, and they are ``lax.ragged_dot``, counted in
-  ``pallas_kernels.FALLBACKS`` under ``moe_gmm``.
+  kernels (``moe_gmm`` forward and for the hidden's cotangent,
+  ``moe_gmm_pair`` for the input's through gate and up, ``moe_tgmm`` for
+  the weights' gradient), tiles sized to the shape, each result written
+  once in the form its consumer reads: the routing weight is multiplied in
+  by the down product's store, the hidden's cotangent and the weights'
+  gradients leave the kernels in the operands' type, the input's cotangent
+  in float32 for the scatter-add. Kernels off (the CPU default), a width
+  that is no multiple of 128 or tiles that do not fit VMEM, and they are
+  ``lax.ragged_dot``, counted in ``pallas_kernels.FALLBACKS`` under
+  ``moe_gmm``.
 """
 from __future__ import annotations
 
@@ -256,10 +262,16 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     ``params["experts"]`` stacks. Every token is scored against all the
     experts and its ``top_k`` chosen over all; the assignments that name a
     held expert are gathered, sorted by expert and pushed through grouped
-    products (``ops.grouped_matmul.grouped_matmul``) for gate, up and down,
-    then scattered back under their weights. The products run over the whole bucket
-    (:func:`share_bucket_rows`) at every step: the same work whatever the
-    routing. No capacity, no dropped token: when more assignments land
+    products (``ops.grouped_matmul``: ``grouped_pair`` for gate and up,
+    ``grouped_matmul`` for down, whose store multiplies each row by its
+    routing weight), then scattered back. The products run over the whole
+    bucket (:func:`share_bucket_rows`) at every step: the same work
+    whatever the routing. The rows a batch leaves empty are NOT masked:
+    they carry the tokens of assignments held elsewhere (the sort's tail)
+    under a weight of exactly zero, so they add nothing to the result and,
+    from the down product's store back, nothing to any gradient; what a
+    caller keeps under ``moe_hidden`` holds finite values, not zeros, in
+    those rows. No capacity, no dropped token: when more assignments land
     here than the bucket holds the held experts run over every token under
     a mask instead. The shared expert, if any, is plain matmuls over every
     token. ``dtype``: the operand type of the products (default x's);
@@ -275,7 +287,7 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
     from jax import lax
 
     from ..ops import remat
-    from ..ops.grouped_matmul import grouped_matmul
+    from ..ops.grouped_matmul import grouped_matmul, grouped_pair
 
     N, d = x.shape
     lo, hi = held
@@ -303,21 +315,22 @@ def moe_share_ffn(params, x, top_k, held, route_scale=1.0, renormalize=True,
         valid = jnp.arange(rows) < landed
         weight = jnp.where(valid, w.reshape(-1)[sel], 0.0)
         # the rows the batch left empty ride in the last held expert's
-        # group, as zero rows under a zero weight: the grouped products
-        # then run over the whole bucket at every step, whatever the
-        # routing (a product skips the tiles past its last group, so the
-        # step's time would follow the load, and on the chip it leaves
+        # group under a ZERO WEIGHT and no mask: they carry the tokens of
+        # the argsort's tail (assignments held elsewhere: finite values),
+        # the down product's store multiplies them by 0, and from there
+        # back every cotangent they would add is exactly zero. The grouped
+        # products then run over the whole bucket at every step, whatever
+        # the routing (a product skips the tiles past its last group, so
+        # the step's time would follow the load, and on the chip it leaves
         # those rows uninitialised, forward and backward)
         sizes = counts.at[n - 1].add(rows - landed)
-
-        def grouped(a, b):
-            return grouped_matmul(a.astype(dtype), b.astype(dtype), sizes)
-
-        xs = jnp.where(valid[:, None], x[tok], 0.0)
-        gate, up = remat.offer("moe_hidden", grouped(xs, experts["w_gate"]),
-                               grouped(xs, experts["w_up"]))
+        gate, up = remat.offer("moe_hidden", *grouped_pair(
+            x[tok], experts["w_gate"].astype(dtype),
+            experts["w_up"].astype(dtype), sizes))
         hidden = jax.nn.silu(gate) * up
-        out = grouped(hidden, experts["w_down"]) * weight[:, None]
+        out = grouped_matmul(hidden.astype(dtype),
+                             experts["w_down"].astype(dtype), sizes,
+                             row_scale=weight)
         return jnp.zeros((N, d), f32).at[tok].add(out)
 
     def every_token():

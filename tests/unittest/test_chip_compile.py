@@ -287,9 +287,10 @@ def test_kda_kernels_compile(one_chip):
 
 
 #: rows, the model's width, an expert's width, experts held: the sorted
-#: bucket of the benchmark's two hybrid cells
+#: bucket of the benchmark's three hybrid cells
 GROUPED_CELLS = {"mellum2": (65536, 2304, 896, 16),
-                 "kimi": (16384, 2304, 1024, 8)}
+                 "kimi": (16384, 2304, 1024, 8),
+                 "glm": (32768, 2048, 1536, 8)}
 
 
 def _grouped_operands(one_chip, m, k, n, g):
@@ -301,14 +302,19 @@ def _grouped_operands(one_chip, m, k, n, g):
             jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip))
 
 
-@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("product", ["up", "down", "pair"])
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
 def test_grouped_products_compile(one_chip, cell, product):
-    """The expert layer's grouped products at both cells' shapes, gate/up
-    ([rows, d] x [g, d, f]) and down ([rows, f] x [g, f, d]), each in its
-    three directions: the product, the input's cotangent with the right
-    operand read transposed, and the weights' gradient; at the tiles
-    ``_plan`` picks, counted, nothing routed to XLA."""
+    """The expert layer's grouped products at the three cells' shapes, each
+    in all its directions at the tiles ``_plan`` picks, counted, nothing
+    routed to XLA. ``up``: gate or up alone ([rows, d] x [g, d, f]);
+    ``down`` ([rows, f] x [g, f, d]) with the routing weight in its store,
+    and rebuilt without it for the weight's cotangent; ``pair``: gate and
+    up under one rule over float32 rows, as ``moe_share_ffn`` hands them
+    over, the input's cotangent as ONE float32 ``moe_gmm_pair``. Forward
+    results float32; a bfloat16 input's cotangent (the right operand read
+    transposed) and the weights' gradient in bfloat16, rounded in the
+    kernels' stores."""
     import jax
     import jax.numpy as jnp
 
@@ -316,37 +322,70 @@ def test_grouped_products_compile(one_chip, cell, product):
     from mxnet_tpu.ops import pallas_kernels as pk
 
     m, d, f, g = GROUPED_CELLS[cell]
-    k, n = (d, f) if product == "up" else (f, d)
+    k, n = (f, d) if product == "down" else (d, f)
+    lhs, rhs, sizes = _grouped_operands(one_chip, m, k, n, g)
+    scale = jax.ShapeDtypeStruct((m,), jnp.float32, sharding=one_chip)
+    if product == "pair":
+        lhs = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
 
-    def loss(a, b, sizes):
-        out = gm.grouped_matmul(a, b, sizes)
+    def loss(a, b, scale, sizes):
+        if product == "pair":
+            # two right operands that XLA cannot fold into one
+            gate, up = gm.grouped_pair(a, b, jnp.flip(b, 0), sizes)
+            out = gate + 2 * up  # and two cotangents
+        else:
+            out = gm.grouped_matmul(
+                a, b, sizes, row_scale=scale if product == "down" else None)
         return jnp.sum(out), out
 
     routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
-    calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True),
-                     *_grouped_operands(one_chip, m, k, n, g))
-    assert sum("moe_gmm" in ln for ln in calls) == 2
-    assert sum("moe_tgmm" in ln for ln in calls) == 1 and len(calls) == 3
+    calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                        has_aux=True), lhs, rhs, scale, sizes)
     new = {key: count - took.get(key, 0)
            for key, count in gm.GMM_CALLS.items() if count != took.get(key)}
-    assert new == {
-        ("moe_gmm", "bfloat16", gm._plan(m, k, n, g, 2)[0]): 1,
-        ("moe_gmm", "bfloat16", gm._plan(m, n, k, g, 2)[0]): 1,
-        ("moe_tgmm", "bfloat16", gm._plan(m, k, n, g, 2, "moe_tgmm")[0]): 1}
+    bf, f32 = "bfloat16", "float32"
+    forward = gm._plan(m, k, n, g, 2, scaled=product == "down")[0]
+    to_rhs = gm._plan(m, k, n, g, 2, "moe_tgmm", out_itemsize=2)[0]
+    if product == "pair":
+        want = {("moe_gmm", bf, f32, False, forward): 2,
+                ("moe_gmm_pair", bf, f32, False,
+                 gm._plan(m, n, k, g, 2, "moe_gmm_pair")[0]): 1,
+                ("moe_tgmm", bf, bf, False, to_rhs): 2}
+    else:
+        want = {("moe_gmm", bf, f32, product == "down", forward): 1,
+                ("moe_gmm", bf, bf, False,
+                 gm._plan(m, n, k, g, 2, out_itemsize=2)[0]): 1,
+                ("moe_tgmm", bf, bf, False, to_rhs): 1}
+        if product == "down":
+            want[("moe_gmm", bf, f32, False, forward)] = 1
+    assert new == want
+    named = {kernel: sum(kernel in ln for ln in calls)
+             for kernel in ("moe_gmm", "moe_gmm_pair", "moe_tgmm")}
+    named["moe_gmm"] -= named["moe_gmm_pair"]  # a pair's line names both
+    assert named == {kernel: sum(count for key, count in want.items()
+                                 if key[0] == kernel) for kernel in named}
+    assert len(calls) == sum(want.values())
     assert pk.FALLBACKS == routed
 
 
-@pytest.mark.parametrize("kernel,plan,size", [
-    ("moe_gmm", (512, 2304, 896), "18.46M"),    # the right operand whole
-    ("moe_gmm", (1024, 768, 896), "16.12M"),
-    ("moe_tgmm", (256, 2304, 896), "18.88M"),
-    ("moe_tgmm", (1024, 1152, 896), "21.30M"),
+@pytest.mark.parametrize("kernel,plan,result,size", [
+    ("moe_gmm", (512, 2304, 896), "float32", "18.46M"),  # the operand whole
+    ("moe_gmm", (1024, 768, 896), "float32", "16.12M"),
+    ("moe_gmm", (512, 2304, 896), "bfloat16", "16.96M"),
+    ("moe_tgmm", (256, 2304, 896), "float32", "18.88M"),
+    ("moe_tgmm", (1024, 1152, 896), "float32", "21.30M"),
+    ("moe_tgmm", (256, 2304, 896), "bfloat16", "18.88M"),
+    # the input's cotangent through gate and up, [rows, f] x [g, d, f]^T
+    ("moe_gmm_pair", (512, 896, 1152), "float32", "19.00M"),
+    ("moe_gmm_pair", (512, 896, 1152), "bfloat16", "16.75M"),
+    ("moe_gmm_pair", (128, 896, 2304), "bfloat16", "17.75M"),
 ])
 def test_grouped_vmem_rule_refuses_what_the_compiler_refuses(
-        one_chip, kernel, plan, size):
-    """Tiles past the scoped VMEM at the Mellum2 cell's gate/up product:
-    ``_vmem`` counts them over the limit, so ``_plan`` never offers them,
-    and Mosaic refuses them when handed them all the same."""
+        one_chip, kernel, plan, result, size):
+    """Tiles past the scoped VMEM at the Mellum2 cell's gate/up product and
+    its paired cotangent: ``_vmem`` counts them over the limit, so
+    ``_plan`` never offers them, and Mosaic refuses them when handed them
+    all the same."""
     import jax
     import jax.numpy as jnp
 
@@ -354,11 +393,21 @@ def test_grouped_vmem_rule_refuses_what_the_compiler_refuses(
     from mxnet_tpu.ops import pallas_kernels as pk
 
     m, k, n, g = 65536, 2304, 896, 16
-    assert gm._vmem(kernel, *plan, 2, k // plan[1]) > pk._VMEM_LIMIT
-    assert gm._plan(m, k, n, g, 2, kernel)[0] != plan
+    pair = kernel == "moe_gmm_pair"
+    if pair:
+        k, n = n, k
+    out = jnp.dtype(result).itemsize
+    assert gm._vmem(kernel, *plan, 2, k // plan[1], out) > pk._VMEM_LIMIT
+    assert gm._plan(m, k, n, g, 2, kernel, out_itemsize=out)[0] != plan
     lhs, rhs, sizes = _grouped_operands(one_chip, m, k, n, g)
+    operands = (lhs, rhs)
     if kernel == "moe_tgmm":
-        rhs = jax.ShapeDtypeStruct((m, n), jnp.bfloat16, sharding=one_chip)
-    call = gm._call(kernel, "bfloat16", m, k, n, g, plan, False, False)
+        operands = (lhs, jax.ShapeDtypeStruct((m, n), jnp.bfloat16,
+                                              sharding=one_chip))
+    elif pair:
+        operands = 2 * (lhs, jax.ShapeDtypeStruct(
+            (g, n, k), jnp.bfloat16, sharding=one_chip))
+    call = gm._call(kernel, "bfloat16", result, m, k, n, g, plan, pair,
+                    False, False)
     with pytest.raises(Exception, match="Scoped allocation with size " + size):
-        call.lower(sizes, lhs, rhs).compile()
+        call.lower(sizes, *operands).compile()

@@ -1,6 +1,8 @@
 """``ops/grouped_matmul.py``: the ``moe_gmm`` / ``moe_tgmm`` kernels in
-interpret mode against ``lax.ragged_dot``, values and both gradients; the
-visit list they walk; the plan and what it routes to XLA."""
+interpret mode against ``lax.ragged_dot``, values and both gradients; a
+result in the operands' type, the rows' scale in the store and the paired
+cotangent against the float32 results they replace; the visit list they
+walk; the plan and what it routes to XLA."""
 import numpy as np
 import pytest
 
@@ -182,7 +184,19 @@ def test_what_the_kernels_cannot_take_goes_to_xla_and_is_counted(
     assert gm.GMM_CALLS == took
 
 
-def test_gmm_calls_records_the_plan_of_each_direction():
+def _new_calls(took):
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    return {key: n - took.get(key, 0) for key, n in gm.GMM_CALLS.items()
+            if n != took.get(key, 0)}
+
+
+@pytest.mark.parametrize("entry", ["plain", "scaled", "pair"])
+def test_gmm_calls_records_plan_types_and_scale_of_each_direction(entry):
+    """The forward products write float32, every cotangent the operands'
+    type; only the down product's forward store scales (its rebuilt,
+    unscaled twin feeds the scale's cotangent); the pair's input cotangent
+    is ONE call over two right-hand blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -190,15 +204,271 @@ def test_gmm_calls_records_the_plan_of_each_direction():
 
     lhs, rhs, weight = _operands("bfloat16")
     sizes = jnp.asarray(LAYOUTS["inside_tiles"], jnp.int32)
+    scale = jnp.linspace(0.0, 1.0, M)
+    bf, f32 = "bfloat16", "float32"
     took = dict(gm.GMM_CALLS)
-    jax.make_jaxpr(jax.grad(lambda a, b: jnp.sum(
-        gm.grouped_matmul(a, b, sizes) * weight), argnums=(0, 1)))(lhs, rhs)
-    new = {key: n - took.get(key, 0) for key, n in gm.GMM_CALLS.items()
-           if n != took.get(key, 0)}
-    assert new == {
-        ("moe_gmm", "bfloat16", (128, K, N)): 1,   # forward
-        ("moe_gmm", "bfloat16", (128, N, K)): 1,   # the input's cotangent
-        ("moe_tgmm", "bfloat16", (128, K, N)): 1}  # the weights' gradient
+    if entry == "pair":
+        jax.make_jaxpr(jax.grad(lambda a, b: sum(
+            jnp.sum(out * weight) for out in gm.grouped_pair(a, b, b, sizes)),
+            argnums=(0, 1)))(lhs, rhs)
+        want = {("moe_gmm", bf, f32, False, (128, K, N)): 2,
+                ("moe_gmm_pair", bf, bf, False, (128, N, K)): 1,
+                ("moe_tgmm", bf, bf, False, (128, K, N)): 2}
+    else:
+        scaled = entry == "scaled"
+        jax.make_jaxpr(jax.grad(lambda a, b, s: jnp.sum(gm.grouped_matmul(
+            a, b, sizes, row_scale=s if scaled else None) * weight),
+            argnums=(0, 1, 2)))(lhs, rhs, scale)
+        want = {("moe_gmm", bf, f32, scaled, (128, K, N)): 1,   # forward
+                ("moe_gmm", bf, bf, False, (128, N, K)): 1,     # d lhs
+                ("moe_tgmm", bf, bf, False, (128, K, N)): 1}    # d rhs
+        if scaled:  # the unscaled product, rebuilt for the scale's cotangent
+            want[("moe_gmm", bf, f32, False, (128, K, N))] = 1
+    assert _new_calls(took) == want
+
+
+#: tokens, width, an expert's width, experts, held, top k, router, shared
+#: experts: the expert layer of the benchmark's three hybrid cells
+CELLS = {"mellum2": (8192, 2304, 896, 64, (0, 16), 8, "softmax", 0),
+         "glm": (8192, 2048, 1536, 64, (0, 8), 4, "sigmoid", 1),
+         "kimi": (8192, 2304, 1024, 256, (0, 8), 8, "sigmoid", 1)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
+    """``moe_share_ffn``'s gradient traced (nothing runs) at a cell's
+    shapes. Float32: the three forward products (the down product's with
+    its scale), the unscaled down product rebuilt for the routing weight's
+    cotangent, and the input's cotangent through gate and up, ONE pair
+    whose consumer, the scatter-add back to the float32 tokens, reads
+    float32. In the operands' type: the hidden's cotangent (the SiLU's
+    gradient rounds it) and every weights' gradient. No ``moe_gmm`` result
+    on a cotangent path is float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.parallel import moe
+
+    tokens, d, ff, experts, held, top_k, score, shared = CELLS[cell]
+    params = jax.eval_shape(
+        lambda key: moe.init_share_params(key, experts, held, d, ff, shared,
+                                          score=score), jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.float32)
+    took, routed = dict(gm.GMM_CALLS), dict(pk.FALLBACKS)
+    jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(moe.moe_share_ffn(
+        p, x, top_k, held, dtype="bfloat16", score=score)[0]),
+        argnums=(0, 1)))(params, x)
+    m = moe.share_bucket_rows(tokens, experts, held, top_k)
+    g = held[1] - held[0]
+    bf, f32 = "bfloat16", "float32"
+
+    def tiles(k, n, kernel="moe_gmm", **how):
+        return gm._plan(m, k, n, g, 2, kernel, **how)[0]
+
+    down = tiles(ff, d, scaled=True)
+    new = _new_calls(took)
+    # counted per trace: twice where the sorted path is a ``lax.cond``'s
+    # branch (the Kimi cell's bucket is smaller than all that could land)
+    traces = new[("moe_gmm", bf, f32, True, down)]
+    assert traces == (2 if cell == "kimi" else 1)
+    assert {key: n / traces for key, n in new.items()} == {
+        ("moe_gmm", bf, f32, False, tiles(d, ff)): 2,       # gate, up
+        ("moe_gmm", bf, f32, True, down): 1,                # down
+        ("moe_gmm", bf, f32, False, down): 1,               # down, rebuilt
+        ("moe_gmm", bf, bf, False, tiles(d, ff, out_itemsize=2)): 1,
+        ("moe_gmm_pair", bf, f32, False, tiles(ff, d, "moe_gmm_pair")): 1,
+        ("moe_tgmm", bf, bf, False,
+         tiles(d, ff, "moe_tgmm", out_itemsize=2)): 2,
+        ("moe_tgmm", bf, bf, False,
+         tiles(ff, d, "moe_tgmm", out_itemsize=2)): 1}
+    assert pk.FALLBACKS == routed
+
+
+#: a plan of one step over the contraction and one that steps through both
+#: widths (an accumulator in ``moe_gmm``, tiles of both widths in ``moe_tgmm``)
+PLANS = {"one_step": None, "stepped": (128, 128, 128)}
+
+
+def _direction(kind, lhs, rhs, g_out):
+    """(kernel, operands, transposed, the plan's (m, k, n)) of one of the
+    three products a layer's gradient needs."""
+    if kind == "gmm":
+        return "moe_gmm", (lhs, rhs), False, (M, K, rhs.shape[2])
+    if kind == "gmm_t":
+        return "moe_gmm", (g_out, rhs), True, (M, rhs.shape[2], K)
+    return "moe_tgmm", (lhs, g_out), False, (M, K, rhs.shape[2])
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+@pytest.mark.parametrize("kind", ["gmm", "gmm_t", "tgmm"])
+@pytest.mark.parametrize("layout", ["tail_in_last", "empty_middle",
+                                    "inside_tiles"])
+def test_a_bfloat16_result_is_the_float32_result_rounded(layout, kind, plan):
+    """Float32 accumulation and ONE rounding at the store: to the last bit
+    what ``astype`` makes of the float32 result, in every group and in an
+    empty group's zeros."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    lhs, rhs, g_out = _operands("bfloat16", n=256)
+    g_out = g_out.astype(jnp.bfloat16)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    kernel, operands, transposed, (m, k, n) = _direction(kind, lhs, rhs, g_out)
+    tiles = PLANS[plan] or gm._plan(m, k, n, 4, 2, kernel, out_itemsize=2)[0]
+    assert (k // tiles[1] > 1) == (plan == "stepped")
+    wide, narrow = (gm._product(kernel, operands, sizes, tiles, dtype,
+                                transposed=transposed)
+                    for dtype in (jnp.float32, jnp.bfloat16))
+    assert wide.dtype == jnp.float32 and narrow.dtype == jnp.bfloat16
+    assert np.array_equal(np.asarray(wide.astype(jnp.bfloat16), np.float32),
+                          np.asarray(narrow, np.float32))
+    assert np.asarray(narrow, np.float32).any()
+
+
+def _scaled_ragged_dot(a, b, sizes, scale):
+    return _ragged_dot(a, b, sizes) * scale[:, None]
+
+
+@pytest.mark.parametrize("dtype,rel", [("float32", 1e-5), ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("layout", ["tail_in_last", "empty_middle",
+                                    "inside_tiles"])
+def test_the_rows_scale_in_the_store(layout, dtype, rel):
+    """``row_scale``: the bits of the unscaled product times the scale (the
+    same float32 multiply, made before the one write), zero rows under a
+    zero scale, and the gradients of all three operands as
+    ``lax.ragged_dot``'s under the plain multiply."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    lhs, rhs, weight = _operands(dtype)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    scale = jax.random.uniform(jax.random.PRNGKey(5), (M,), jnp.float32)
+    scale = jnp.where(jnp.arange(M) < M - 100, scale, 0.0)  # an empty tail
+
+    def value_and_grads(product):
+        def loss(a, b, s):
+            out = product(a, b, sizes, s)
+            return jnp.sum(out * weight), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                             has_aux=True)(lhs, rhs, scale)
+        return [np.asarray(x, np.float64) for x in (out,) + grads]
+
+    got = value_and_grads(lambda a, b, sizes, s: gm.grouped_matmul(
+        a, b, sizes, row_scale=s))
+    plain = np.asarray(gm.grouped_matmul(lhs, rhs, sizes)
+                       * scale[:, None], np.float64)
+    assert np.array_equal(got[0], plain) and not got[0][M - 100:].any()
+    want = value_and_grads(_scaled_ragged_dot)
+    _close(got[0], want[0], 1e-5)
+    for a, b in zip(got[1:], want[1:]):
+        assert a.shape == b.shape
+        _close(a, b, rel)
+    # under a zero scale a row asks nothing of the left operand
+    assert not got[1][M - 100:].any()
+
+
+@pytest.mark.parametrize("arrived", ["bfloat16", "float32"])
+@pytest.mark.parametrize("plan", sorted(PLANS))
+@pytest.mark.parametrize("layout", ["tail_in_last", "empty_middle",
+                                    "inside_tiles"])
+def test_the_pairs_input_cotangent_is_the_float32_sum_written_once(
+        layout, plan, arrived, monkeypatch):
+    """``grouped_pair``: both values as two ``grouped_matmul`` calls give
+    them; both weights' gradients as the two calls'; the input's cotangent
+    the float32 sum of the two products in the type the input ARRIVED in:
+    for float32 rows that sum itself, for bfloat16 rows the sum rounded
+    once (to the last bit where the contraction is one step: the sum is
+    then the same float32 addition), nearer the float32 sum than two
+    roundings and an add are."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    if PLANS[plan]:
+        monkeypatch.setattr(gm, "_plan",
+                            lambda *a, **kw: (PLANS[plan], None))
+    bf = jnp.bfloat16
+    lhs, rhs_a, weight_a = _operands("bfloat16", n=256)
+    lhs = lhs.astype(arrived)  # values bfloat16 holds, so nothing is lost
+    rhs_b = jnp.flip(rhs_a, axis=0) * 0.5
+    weight_b = jnp.flip(weight_a, axis=1)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+
+    def loss(products):
+        def of(a, b, c):
+            gate, up = products(a, b, c)
+            return jnp.sum(gate * weight_a) + jnp.sum(up * weight_b), (
+                gate, up)
+        return of
+
+    took = dict(gm.GMM_CALLS)
+    (_, got), got_grads = jax.value_and_grad(
+        loss(lambda a, b, c: gm.grouped_pair(a, b, c, sizes)),
+        argnums=(0, 1, 2), has_aux=True)(lhs, rhs_a, rhs_b)
+    assert {key[2]: n for key, n in _new_calls(took).items()
+            if key[0] == "moe_gmm_pair"} == {arrived: 1}
+    (_, want), want_grads = jax.value_and_grad(
+        loss(lambda a, b, c: (gm.grouped_matmul(a.astype(bf), b, sizes),
+                              gm.grouped_matmul(a.astype(bf), c, sizes))),
+        argnums=(0, 1, 2), has_aux=True)(lhs, rhs_a, rhs_b)
+    for a, b in zip(got + got_grads[1:], want + want_grads[1:]):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32))
+    tiles = gm._plan(M, 256, K, 4, 2, out_itemsize=2)[0]
+    halves = [gm._product("moe_gmm", (g.astype(bf), rhs), sizes, tiles,
+                          jnp.float32, transposed=True)
+              for g, rhs in ((weight_a, rhs_a), (weight_b, rhs_b))]
+    exact = np.asarray((halves[0] + halves[1]).astype(arrived), np.float32)
+    d_lhs = np.asarray(got_grads[0], np.float32)
+    assert got_grads[0].dtype == jnp.dtype(arrived)
+    if plan == "one_step":
+        assert np.array_equal(d_lhs, exact)
+    _close(d_lhs, exact, 1e-2 if arrived == "bfloat16" else 1e-5)
+    wide = np.asarray(halves[0] + halves[1], np.float64)
+    twice = np.asarray(want_grads[0], np.float64)
+    assert np.abs(d_lhs - wide).sum() <= np.abs(twice - wide).sum()
+
+
+@pytest.mark.parametrize("switch,reason", [("0", "disabled"),
+                                           ("1", "untileable")])
+def test_scale_and_pair_off_the_kernels_are_ragged_dots(switch, reason,
+                                                        monkeypatch):
+    """The fallback keeps today's arithmetic: the scale a plain multiply,
+    the pair two products, each counted in ``FALLBACKS``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("MXNET_PALLAS", switch)
+    n = N if reason == "disabled" else 96
+    lhs, rhs, weight = _operands("float32", n=n)
+    sizes = jnp.asarray(LAYOUTS["tail_in_last"], jnp.int32)
+    scale = jnp.linspace(0.0, 2.0, M)
+    before = pk.FALLBACKS.get(("moe_gmm", reason), 0)
+    took = dict(gm.GMM_CALLS)
+
+    def grads(product):
+        return jax.grad(lambda a, b, s: jnp.sum(product(a, b, s) * weight),
+                        argnums=(0, 1, 2))(lhs, rhs, scale)
+
+    got = grads(lambda a, b, s: gm.grouped_matmul(a, b, sizes, row_scale=s))
+    want = grads(lambda a, b, s: _scaled_ragged_dot(a, b, sizes, s))
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    gate, up = gm.grouped_pair(lhs, rhs, 2 * rhs, sizes)
+    assert np.array_equal(gate, _ragged_dot(lhs, rhs, sizes))
+    assert np.array_equal(up, _ragged_dot(lhs, 2 * rhs, sizes))
+    assert pk.FALLBACKS[("moe_gmm", reason)] == before + 3
+    assert gm.GMM_CALLS == took
 
 
 @pytest.mark.parametrize("shape,kernel,want", [

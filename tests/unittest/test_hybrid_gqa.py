@@ -486,15 +486,18 @@ def test_a_window_kernels_forward_runs_once_a_layer(gradient_sites, kernel,
 def test_a_share_of_a_quarter_leaves_no_cond_in_the_step(gradient_sites):
     """The bucket is everything that could land (``share_bucket_rows``
     returns its worst case), so the program holds the sorted path alone:
-    twelve grouped products a layer with nothing kept, ten with the gate's
-    and the up's forward kept (seven ``moe_gmm``: three forward, the down
-    product rebuilt, three cotangents; three ``moe_tgmm``), none routed to
-    XLA, and no ``cond``."""
+    eleven grouped products a layer with nothing kept, nine with the gate's
+    and the up's forward kept (five ``moe_gmm``: three forward, the down
+    product rebuilt without its scale, the hidden's cotangent; one
+    ``moe_gmm_pair``: the input's cotangent through gate and up; three
+    ``moe_tgmm``), none routed to XLA, and no ``cond``."""
     kept, bare = gradient_sites
     assert "cond" not in kept and "cond" not in bare
     assert "ragged_dot_general" not in kept and "ragged_dot_general" not in bare
-    assert (kept["moe_gmm"], kept["moe_tgmm"]) == (4 * 7, 4 * 3)
-    assert (bare["moe_gmm"], bare["moe_tgmm"]) == (4 * 9, 4 * 3)
+    assert (kept["moe_gmm"], kept["moe_gmm_pair"], kept["moe_tgmm"]) == (
+        4 * 5, 4, 4 * 3)
+    assert (bare["moe_gmm"], bare["moe_gmm_pair"], bare["moe_tgmm"]) == (
+        4 * 7, 4, 4 * 3)
     assert kept["sort"] == 4 and bare["sort"] == 8
 
 

@@ -368,11 +368,11 @@ def test_grouped_products_run_over_the_whole_bucket(ref, monkeypatch, case):
     seen = []
     grouped = gm.grouped_matmul
 
-    def watched(a, b, sizes):  # traced inside ``lax.cond``
+    def watched(a, b, sizes, **scale):  # traced inside ``lax.cond``
         jax.debug.callback(
             lambda sizes, n_rows=a.shape[0]: seen.append(
                 (n_rows, np.asarray(sizes))), sizes)
-        return grouped(a, b, sizes)
+        return grouped(a, b, sizes, **scale)
 
     monkeypatch.setattr(gm, "grouped_matmul", watched)
     _, counts = moe.moe_share_ffn(p, x, sz["top_k"], sz["held"],
@@ -383,6 +383,66 @@ def test_grouped_products_run_over_the_whole_bucket(ref, monkeypatch, case):
     for n_rows, sizes in seen:
         assert n_rows == rows == int(sizes.sum())
         assert np.array_equal(sizes[:-1], counts[:-1])
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+def test_empty_rows_carry_large_tokens_under_a_zero_weight(path, score,
+                                                           monkeypatch):
+    """No mask over the gathered rows: the rows a batch leaves empty hold
+    the tokens of the argsort's tail and meet a zero weight. With those
+    tokens ten thousand times the others, the layer's value and every
+    gradient are the dense path's (``every_token``), on the small tokens to
+    the small tokens' own scale: nothing of an empty row leaks."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setenv("MXNET_PALLAS", "1" if path == "kernels" else "0")
+    N, d, ff, E, held, top_k = 256, 128, 128, 16, (2, 14), 4
+    n = held[1] - held[0]
+    p = moe.init_share_params(jax.random.PRNGKey(21), E, held, d, ff,
+                              shared=0, score=score)
+    # the router reads eight channels: the others grow without moving a choice
+    p["router"] = p["router"].at[8:].set(0.0)
+    x = jax.random.normal(jax.random.PRNGKey(22), (N, d))
+    rows = moe.share_bucket_rows(N, E, held, top_k)
+    assert rows == N * top_k  # the sorted path alone, no ``lax.cond``
+    idx, _ = moe.route_top_k(p, x, top_k, score=score)
+    local = np.asarray(idx) - held[0]
+    key = np.where((local >= 0) & (local < n), local, n).reshape(-1)
+    landed = int((key < n).sum())
+    fill = np.unique(np.argsort(key, kind="stable")[landed:rows] // top_k)
+    assert 128 < landed < rows and 0 < len(fill) < N
+    x = x.at[fill, 8:].multiply(1e4)
+    small = np.setdiff1d(np.arange(N), fill)
+    weight = jax.random.normal(jax.random.PRNGKey(23), (N, d))
+
+    def value_and_grads():
+        def loss(x, p):
+            y, counts = moe.moe_share_ffn(p, x, top_k, held, score=score)
+            return jnp.sum(y * weight), (y, counts)
+
+        (_, (y, counts)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(x, p)
+        assert int(jnp.sum(counts)) == landed
+        return y, grads
+
+    took = dict(gm.GMM_CALLS)
+    got, got_g = value_and_grads()
+    assert (gm.GMM_CALLS != took) == (path == "kernels")
+    with monkeypatch.context() as mp:  # a bucket under the load: every_token
+        mp.setattr(moe, "share_bucket_rows", lambda *a: 128)
+        want, want_g = value_and_grads()
+    for a, b in [(got, want), (got_g[0], want_g[0])]:
+        close(a, b)
+        close(a[small], b[small])
+    assert float(jnp.max(jnp.abs(want[small]))) < 1e-3 * float(
+        jnp.max(jnp.abs(want)))
+    for a, b in zip(jax.tree.leaves(got_g[1]), jax.tree.leaves(want_g[1])):
+        close(a, b)
 
 
 def test_shares_add_up_to_the_uncut_layer(ref):

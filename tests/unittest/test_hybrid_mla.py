@@ -662,7 +662,8 @@ def test_two_losses_keep_no_logits_and_one_loss_keeps_what_it_kept(
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert sites[kernel] == 3, kernel
     assert "cond" not in sites and "ragged_dot_general" not in sites
-    assert sites["moe_gmm"] == 2 * 7 and sites["moe_tgmm"] == 2 * 3
+    assert (sites["moe_gmm"], sites["moe_gmm_pair"], sites["moe_tgmm"]) == (
+        2 * 5, 2, 2 * 3)
 
 
 def test_kept_without_moe_hidden_changes_no_value(monkeypatch):

@@ -3,7 +3,8 @@
 
     python tools/gmm_probe.py [--shapes 65536x2304x896x16,...]
         [--plans default,512x1152x896,...] [--kinds gmm,gmm_t,tgmm]
-        [--yardsticks ragged_dot,megablox] [--dtype bfloat16] [--iters 20]
+        [--yardsticks ragged_dot,megablox] [--dtype bfloat16]
+        [--results float32,bfloat16] [--scale] [--pair] [--iters 20]
         [--tag NAME] [--out chiprun_out/gmm_probe.jsonl]
 
 For every shape ``MxKxNxG`` (rows, the right operand's two widths, groups)
@@ -11,7 +12,13 @@ it runs the three products a layer's gradient needs: ``gmm`` ([M, K] x
 [G, K, N]), ``gmm_t`` ([M, N] x [G, K, N]^T, the input's cotangent) and
 ``tgmm`` ([M, K]^T x [M, N] per group, the weights' gradient), each at
 every ``tm x tk x tn`` of ``--plans`` (``default`` = what
-``grouped_matmul._plan`` picks), ``--iters`` times back to back between two
+``grouped_matmul._plan`` picks for that result's type) and with its result
+in every type of ``--results`` (the layer's forward products write
+float32, its cotangents and weights' gradients the operands' type).
+``--scale`` adds ``gmm`` with a row scale in its store (the down product's
+forward); ``--pair`` adds ``gmm_t`` as ``moe_gmm_pair``, two products
+summed into one result (the input's cotangent through gate and up: twice
+the products, counted). Each runs ``--iters`` times back to back between two
 fences on the host's clock (one kernel a call and nothing else on the
 device, so that is its device time to a few microseconds), and prints
 milliseconds a call and TFLOP/s over the 2 M K N products. Yardsticks at
@@ -22,6 +29,7 @@ rows in the last as ``moe_share_ffn`` sends its empty rows. No chip:
 exit 2, nothing printed.
 """
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -39,6 +47,9 @@ def main(argv=None):
     ap.add_argument("--kinds", default="gmm,gmm_t,tgmm")
     ap.add_argument("--yardsticks", default="")
     ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--results", default="float32")
+    ap.add_argument("--scale", action="store_true")
+    ap.add_argument("--pair", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=os.path.join(
@@ -92,33 +103,50 @@ def main(argv=None):
         lhs = jax.random.normal(keys[0], (m, k), jnp.float32).astype(dtype)
         rhs = jax.random.normal(keys[1], (g, k, n), jnp.float32).astype(dtype)
         out = jax.random.normal(keys[2], (m, n), jnp.float32).astype(dtype)
+        scale = jax.random.uniform(keys[2], (m,), jnp.float32)
         operands = {"gmm": (lhs, rhs), "gmm_t": (out, rhs),
-                    "tgmm": (lhs, out)}
-        for kind in args.kinds.split(","):
-            kernel = "moe_tgmm" if kind == "tgmm" else "moe_gmm"
-            wide = (m, n, k) if kind == "gmm_t" else (m, k, n)
+                    "tgmm": (lhs, out), "gmm_scaled": (lhs, rhs, scale),
+                    "gmm_t_pair": (out, rhs, out, rhs)}
+        kinds = args.kinds.split(",")
+        kinds += ["gmm_scaled"] * args.scale + ["gmm_t_pair"] * args.pair
+        for kind, result in itertools.product(kinds,
+                                              args.results.split(",")):
+            kernel = {"tgmm": "moe_tgmm", "gmm_t_pair": "moe_gmm_pair"}.get(
+                kind, "moe_gmm")
+            back = kind.startswith("gmm_t")
+            wide = (m, n, k) if back else (m, k, n)
+            result = jnp.dtype(result)
             for plan in args.plans.split(","):
                 if plan == "default":
-                    tiles, refusal = gm._plan(*wide, g, dtype.itemsize,
-                                              kernel)
+                    tiles, refusal = gm._plan(
+                        *wide, g, dtype.itemsize, kernel,
+                        out_itemsize=result.itemsize,
+                        scaled=kind == "gmm_scaled")
                 else:
                     tiles, refusal = tuple(
                         int(x) for x in plan.split("x")), None
                 row = {"tag": args.tag, "shape": shape, "kind": kind,
                        "plan": tiles, "dtype": dtype.name,
+                       "result": result.name,
                        "device_kind": dev.device_kind}
-                flop = 2.0 * m * k * n
+                flop = 2.0 * m * k * n * (2 if kind == "gmm_t_pair" else 1)
                 if refusal is not None:
                     report(dict(row, error=refusal), flop)
                     continue
                 if any(w % t for w, t in zip(wide, tiles)):
                     continue  # another kind's plan
 
-                def run(a, b, kind=kind, kernel=kernel, tiles=tiles):
-                    return gm._product(kernel, a, b, sizes, tiles,
-                                       transposed=kind == "gmm_t")
+                def run(*ops, kind=kind, kernel=kernel, tiles=tiles,
+                        back=back, result=result):
+                    if kind == "gmm_scaled":
+                        return gm._product(kernel, ops[:2], sizes, tiles,
+                                           result, scale=ops[2])
+                    return gm._product(kernel, ops, sizes, tiles, result,
+                                       transposed=back)
 
                 report(row, flop, jax.jit(run), *operands[kind])
+                if kind in ("gmm_scaled", "gmm_t_pair"):
+                    continue  # the yardsticks have no such product
                 for yard in filter(None, args.yardsticks.split(",")):
                     report(dict(row, yardstick=yard), flop,
                            jax.jit(_yardstick(yard, kind, tiles, sizes)),
