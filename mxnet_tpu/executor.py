@@ -607,7 +607,12 @@ class Executor:
             ins = [jax.device_put(x, dev) for x in ins]
         sl = self._node_aux.get(id(n))
         aux_in = [aux_store[j] for j in range(sl[0], sl[1])] if sl else []
-        outs, n_aux = n.op.apply(n.params, ins, aux_in, is_train, node_rng)
+        # the node's lowering under its operator's type and its own name
+        # (``sym.BatchNorm/bn0``): what mx.profiler.scope_map reads off the
+        # compiled program, forward and backward
+        with jax.named_scope("sym.%s" % n.op.name), jax.named_scope(n.name):
+            outs, n_aux = n.op.apply(n.params, ins, aux_in, is_train,
+                                     node_rng)
         for i, o in enumerate(outs):
             env[(id(n), i)] = o
         if sl:

@@ -141,9 +141,10 @@ def _buffer_batch(data_batch, input_names):
 def _scan_flush(trainer, buf, epoch, nbatch0, guardian=None):
     """Dispatch one K-batch chunk; returns the pending record drained
     after the NEXT chunk is in flight (shared by FeedForward's
-    _train_scanned and Module._try_scanned_fit). mxtel: the "chunk"
+    _train_scanned and Module._try_scanned_fit). mxtel: the "fit.chunk"
     span covers staging + dispatch (the async device work completes
-    later — the drain's metric fence is its clock). The trainer's
+    later — the drain's metric fence is its clock) and carries the
+    update count the chunk starts at as its step number. The trainer's
     guardian verdicts for the chunk ride the pending record.
 
     Guardian snapshots are captured HERE, before the dispatch mutates
@@ -153,7 +154,7 @@ def _scan_flush(trainer, buf, epoch, nbatch0, guardian=None):
     verification passes (commit_snapshot). Snapshotting at drain time
     instead would capture state the in-flight chunk has already
     advanced (and possibly poisoned) past the verified steps."""
-    with _tel.span("chunk"):
+    with _tel.span("fit.chunk", step=trainer.optimizer.num_update):
         snap = None
         if guardian is not None and guardian.snapshot_due():
             snap = trainer.snapshot_state()
@@ -194,6 +195,16 @@ def _scan_drain(pending, eval_metric, label_names, batch_end_callback,
     labels."""
     if pending is None:
         return "ok"
+    with _tel.span("fit.metric"):
+        return _scan_drain_chunk(pending, eval_metric, label_names,
+                                 batch_end_callback, nbatch_base, guardian)
+
+
+def _scan_drain_chunk(pending, eval_metric, label_names, batch_end_callback,
+                      nbatch_base, guardian):
+    """:func:`_scan_drain`'s work, under its ``fit.metric`` span: the pull
+    of the chunk's outputs (the fence), the metric's updates and, each
+    under a ``fit.callbacks`` span of its own, the per-batch callbacks."""
     outs, flags, snap, bufs, epoch, nbatch0, prof_ctx = pending
     if guardian is not None:
         # the snapshot captured at this chunk's flush is the PREVIOUS
@@ -213,9 +224,11 @@ def _scan_drain(pending, eval_metric, label_names, batch_end_callback,
         td = time.monotonic()
     if (type(eval_metric) is metric_mod.Accuracy and len(outs) == 1
             and getattr(outs[0], "ndim", 0) == 3):
+        import jax
         import jax.numpy as jnp
 
-        host_outs = [_np.asarray(jnp.argmax(outs[0], axis=-1))]
+        with jax.named_scope("metric"):
+            host_outs = [_np.asarray(jnp.argmax(outs[0], axis=-1))]
     else:
         host_outs = [_np.asarray(o) for o in outs]  # one D2H per head
     from .analysis import compile_verify as _cv
@@ -242,12 +255,32 @@ def _scan_drain(pending, eval_metric, label_names, batch_end_callback,
         if losses is not None:
             losses.append(guardian.metric_step_loss())
         if batch_end_callback is not None:
-            _multiple_callbacks(batch_end_callback, BatchEndParam(
-                epoch=epoch, nbatch=nbatch0 + k + nbatch_base,
-                eval_metric=eval_metric, locals=locals()))
+            with _tel.span("fit.callbacks"):
+                _multiple_callbacks(batch_end_callback, BatchEndParam(
+                    epoch=epoch, nbatch=nbatch0 + k + nbatch_base,
+                    eval_metric=eval_metric, locals=locals()))
     if guardian is not None:
         return guardian.drain_chunk(flags, losses)
     return "ok"
+
+
+def _fed(data_iter):
+    """``data_iter`` for the scanned loops: itself with telemetry off,
+    else its batches each drawn under a ``fit.feed`` span (an idle chip
+    while the iterator works is then named in a capture)."""
+    if not _tel.ENABLED:
+        return data_iter
+
+    def spanned():
+        batches = iter(data_iter)
+        while True:
+            with _tel.span("fit.feed"):
+                batch = next(batches, None)
+            if batch is None:
+                return
+            yield batch
+
+    return spanned()
 
 
 def _train_scanned(trainer, symbol, ctx0, param_names, aux_names, arg_params,
@@ -289,7 +322,7 @@ def _train_scanned(trainer, symbol, ctx0, param_names, aux_names, arg_params,
         buf = []
         while True:
             do_reset = True
-            for data_batch in train_data:
+            for data_batch in _fed(train_data):
                 buf.append(_buffer_batch(data_batch, input_names))
                 nbatch += 1
                 if len(buf) == K:
@@ -492,6 +525,8 @@ def _train_multi_device(symbol, ctx, arg_names, param_names, aux_names, arg_para
         """One optimizer step (mxtel: wrapped in a "batch" span nested
         under the epoch span; step walltime and samples/sec feed the
         train.* metrics)."""
+        import jax
+
         with _tel.span("batch"):
             step_tic = time.monotonic() if _tel.ENABLED else 0.0
             # mxprof (MXNET_PROF=1): fenced sub-phase stamps — host
@@ -536,30 +571,36 @@ def _train_multi_device(symbol, ctx, arg_names, param_names, aux_names, arg_para
                             bur()
                 prof_t["device"] = time.monotonic() - t2
 
+            # the update's and the metric's eager operations under the
+            # scopes the scanned loop gives them (mx.profiler.scope_map)
             def _do_update():
-                if update_on_kvstore:
-                    _update_params_on_kvstore(
-                        executor_manager.param_arrays,
-                        executor_manager.grad_arrays, kvstore)
-                else:
-                    _update_params(
-                        executor_manager.param_arrays,
-                        executor_manager.grad_arrays,
-                        updater=updater, num_device=len(ctx),
-                        kvstore=kvstore)
+                with jax.named_scope("optimizer"):
+                    if update_on_kvstore:
+                        _update_params_on_kvstore(
+                            executor_manager.param_arrays,
+                            executor_manager.grad_arrays, kvstore)
+                    else:
+                        _update_params(
+                            executor_manager.param_arrays,
+                            executor_manager.grad_arrays,
+                            updater=updater, num_device=len(ctx),
+                            kvstore=kvstore)
+
+            def _do_metric():
+                with jax.named_scope("metric"):
+                    executor_manager.update_metric(
+                        eval_metric, data_batch.label)
 
             if guard is None:
                 _timed(_do_update, "update")
                 if monitor is not None:
                     monitor.toc_print()
-                _timed(lambda: executor_manager.update_metric(
-                    eval_metric, data_batch.label), "d2h")
+                _timed(_do_metric, "d2h")
             else:
                 # metric BEFORE the guarded update: outputs don't
                 # depend on it, and the guardian's loss feed reads this
                 # batch's metric delta for the z-score channel
-                _timed(lambda: executor_manager.update_metric(
-                    eval_metric, data_batch.label), "d2h")
+                _timed(_do_metric, "d2h")
                 action = _timed(lambda: guard.guard_batch(
                     _do_update,
                     grad_arrays_fn=lambda: [

@@ -288,10 +288,13 @@ def _mm(x, w, dtype):
 
 
 def _rms_norm(x, w, eps):
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+    with jax.named_scope("norm"):
+        return x * lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
 def _short_conv(x, taps):
@@ -322,8 +325,9 @@ def kda_layer(x, p, cfg: HybridConfig):
     # and its way back to the channels are float32 products with it, so
     # that q and k stay laid out as the projections left them (a sum over
     # the last axis of [B, T, H, D] makes XLA re-tile the tensor twice)
-    member = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
-        jnp.float32)
+    with jax.named_scope("kda"):
+        member = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
+            jnp.float32)
     hi = lax.Precision.HIGHEST
 
     def unit(t):
@@ -493,23 +497,34 @@ def gqa_layer(x, p, cfg: HybridConfig, kind):
 
 
 def _attention_half(x, norm, p, kind, cfg):
+    import jax
+
     h = _rms_norm(x, norm, cfg.rms_eps)
     if kind in ("swa", "full"):
-        return x + gqa_layer(h, p, cfg, kind)
-    return x + (kda_layer if kind == "kda" else mla_layer)(h, p, cfg)
+        y = gqa_layer(h, p, cfg, kind)
+    else:
+        y = (kda_layer if kind == "kda" else mla_layer)(h, p, cfg)
+    with jax.named_scope("residual"):
+        return x + y
 
 
 def _mlp_half(x, norm, p, mlp, cfg):
+    import jax
+
     from ..parallel import moe
 
     h = _rms_norm(x, norm, cfg.rms_eps)
     if mlp == "dense":
-        return x + moe.gated_mlp(h, p, cfg.dtype), None
-    B, T, d = h.shape
-    y, counts = moe.moe_share_ffn(
-        p, h.reshape(B * T, d), cfg.experts_per_token, cfg.experts_held,
-        cfg.route_scale, cfg.renormalize, cfg.dtype, cfg.router)
-    return x + y.reshape(B, T, d), counts
+        with jax.named_scope("mlp.dense"):
+            y, counts = moe.gated_mlp(h, p, cfg.dtype), None
+    else:
+        B, T, d = h.shape
+        y, counts = moe.moe_share_ffn(
+            p, h.reshape(B * T, d), cfg.experts_per_token, cfg.experts_held,
+            cfg.route_scale, cfg.renormalize, cfg.dtype, cfg.router)
+        y = y.reshape(B, T, d)
+    with jax.named_scope("residual"):
+        return x + y, counts
 
 
 #: what ``forward`` keeps of a half's forward pass for its backward pass, by
@@ -550,7 +565,8 @@ def _run(params, tokens, cfg: HybridConfig, next_tokens=None):
             counts.append(n)
         return x
 
-    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     for lp, kind, mlp in zip(params["layers"], cfg.attention, cfg.mlp):
         x = block(x, lp, kind, mlp)
     h = _rms_norm(x, params["norm_f"], cfg.rms_eps)
@@ -558,8 +574,9 @@ def _run(params, tokens, cfg: HybridConfig, next_tokens=None):
     if next_tokens is not None:
         with jax.named_scope("mtp"):
             mp = params["mtp"]
-            e = jnp.take(params["embed"], next_tokens, axis=0).astype(
-                jnp.float32)
+            with jax.named_scope("embed"):
+                e = jnp.take(params["embed"], next_tokens, axis=0).astype(
+                    jnp.float32)
             z = _mm(jnp.concatenate(
                 [_rms_norm(e, mp["enorm"], cfg.rms_eps),
                  _rms_norm(h, mp["hnorm"], cfg.rms_eps)], axis=-1),
@@ -615,17 +632,22 @@ def forward(params, tokens, cfg: HybridConfig):
     (None: ``KEPT``), and that cell's driver names ``("flash",
     "moe_sort")``: ten ``moe_gmm`` calls and the bucket's gather are
     rebuilt a step."""
+    import jax
+
     x, _, counts = _run(params, tokens, cfg)
     counts = _stack_counts(counts, cfg)
-    return _mm(x, params["lm_head"], cfg.dtype), counts
+    with jax.named_scope("head"):
+        return _mm(x, params["lm_head"], cfg.dtype), counts
 
 
 def _stack_counts(counts, cfg):
+    import jax
     import jax.numpy as jnp
 
     lo, hi = cfg.experts_held
-    return jnp.stack(counts) if counts else jnp.zeros((0, hi - lo),
-                                                      jnp.int32)
+    with jax.named_scope("moe.route"):
+        return jnp.stack(counts) if counts else jnp.zeros((0, hi - lo),
+                                                          jnp.int32)
 
 
 def _head_loss(h, head, targets, weights, dtype):
@@ -636,9 +658,12 @@ def _head_loss(h, head, targets, weights, dtype):
     import jax
     import jax.numpy as jnp
 
-    logp = jax.nn.log_softmax(_mm(h, head, dtype), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.sum(nll * weights) / jnp.sum(weights)
+    with jax.named_scope("head"):
+        logits = _mm(h, head, dtype)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll * weights) / jnp.sum(weights)
 
 
 def losses(params, batch, cfg: HybridConfig):
@@ -659,14 +684,20 @@ def losses(params, batch, cfg: HybridConfig):
 
     tokens = batch["tokens"]
     B, T = tokens.shape[0], tokens.shape[1] - 1
-    h, z, counts = _run(params, tokens[:, :-1], cfg, tokens[:, 1:])
+    with jax.named_scope("embed"):  # the batch's slicing
+        inputs, next_tokens = tokens[:, :-1], tokens[:, 1:]
+    h, z, counts = _run(params, inputs, cfg, next_tokens)
     head_loss = jax.checkpoint(
         functools.partial(_head_loss, dtype=cfg.dtype))
-    main = head_loss(h, params["lm_head"], tokens[:, 1:],
-                     jnp.ones((B, T), jnp.float32))
-    after_next = jnp.pad(tokens[:, 2:], ((0, 0), (0, 1)))
-    mtp = head_loss(z, params["lm_head"], after_next, jnp.broadcast_to(
-        (jnp.arange(T) < T - 1).astype(jnp.float32), (B, T)))
+    with jax.named_scope("loss"):
+        targets, weights = tokens[:, 1:], jnp.ones((B, T), jnp.float32)
+    main = head_loss(h, params["lm_head"], targets, weights)
+    with jax.named_scope("mtp"):
+        with jax.named_scope("loss"):
+            after_next = jnp.pad(tokens[:, 2:], ((0, 0), (0, 1)))
+            weights = jnp.broadcast_to(
+                (jnp.arange(T) < T - 1).astype(jnp.float32), (B, T))
+        mtp = head_loss(z, params["lm_head"], after_next, weights)
     return main, mtp, _stack_counts(counts, cfg)
 
 
@@ -682,12 +713,17 @@ def loss_fn(cfg: HybridConfig):
         del rng
         if cfg.mtp_modules:
             main, mtp, counts = losses(params, batch, cfg)
-            return main + cfg.mtp_weight * mtp, counts
+            with jax.named_scope("loss"):
+                return main + cfg.mtp_weight * mtp, counts
         tokens = batch["tokens"]
-        logits, counts = forward(params, tokens[:, :-1], cfg)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
-        return jnp.mean(nll), counts
+        with jax.named_scope("embed"):  # the batch's slicing
+            inputs = tokens[:, :-1]
+        logits, counts = forward(params, inputs, cfg)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, tokens[:, 1:, None], axis=-1)[..., 0]
+            return jnp.mean(nll), counts
 
     return f
 

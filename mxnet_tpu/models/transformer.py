@@ -94,13 +94,15 @@ def param_partition_specs(cfg: TransformerConfig):
 
 
 def _layer_norm(x, p, eps=1e-5):
+    import jax
     import jax.numpy as jnp
 
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    out = (xf - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        out = (xf - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
+        return out.astype(x.dtype)
 
 
 def _attention(q, k, v, causal=True):
@@ -142,13 +144,19 @@ def _sharded_attention(mesh, cfg):
 def forward(params, tokens, cfg: TransformerConfig, mesh=None):
     """tokens [B, T] int32 -> logits [B, T, vocab]. Under a mesh, pass
     it: attention then runs per shard (``_sharded_attention``), or as
-    ring attention with ``cfg.use_ring_attention``."""
+    ring attention with ``cfg.use_ring_attention``.
+
+    Every equation lies under a ``jax.named_scope`` that names the layer's
+    part (``embed``, ``norm``, ``attn`` with ``attn.qkv`` / ``attn.out``
+    around the projections, ``mlp``, ``head``): what
+    ``mx.profiler.scope_map`` reads off the compiled step."""
     import jax
     import jax.numpy as jnp
 
     B, T = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = x + params["pos_embed"][:T][None].astype(x.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x = x + params["pos_embed"][:T][None].astype(x.dtype)
 
     if cfg.use_ring_attention and mesh is not None:
         from ..parallel.ring_attention import make_ring_attention
@@ -162,21 +170,25 @@ def forward(params, tokens, cfg: TransformerConfig, mesh=None):
     H, D = cfg.num_heads, cfg.head_dim
     for lp in params["layers"]:
         h = _layer_norm(x, lp["ln1"])
-        qkv = jnp.einsum("btd,de->bte", h, lp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("attn"):
+            with jax.named_scope("attn.qkv"):
+                qkv = jnp.einsum("btd,de->bte", h, lp["wqkv"])
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(t):
-            return t.reshape(B, T, H, D).transpose(0, 2, 1, 3)
+            def heads(t):
+                return t.reshape(B, T, H, D).transpose(0, 2, 1, 3)
 
-        o = attn_fn(heads(q), heads(k), heads(v))
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
-        x = x + jnp.einsum("btd,de->bte", o, lp["wo"])
+            o = attn_fn(heads(q), heads(k), heads(v))
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("btd,de->bte", o, lp["wo"])
         h = _layer_norm(x, lp["ln2"])
-        ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
-        x = x + jnp.einsum("btf,fd->btd", ff, lp["w2"])
+        with jax.named_scope("mlp"):
+            ff = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"]))
+            x = x + jnp.einsum("btf,fd->btd", ff, lp["w2"])
     x = _layer_norm(x, params["ln_f"])
-    logits = jnp.einsum("btd,vd->btv", x, params["embed"])
-    return logits
+    with jax.named_scope("head"):
+        return jnp.einsum("btd,vd->btv", x, params["embed"])
 
 
 def loss_fn(cfg: TransformerConfig, mesh=None):
@@ -189,10 +201,14 @@ def loss_fn(cfg: TransformerConfig, mesh=None):
     def f(params, batch, rng):
         del rng
         tokens = batch["tokens"]
-        logits = forward(params, tokens[:, :-1], cfg, mesh=mesh)
-        targets = tokens[:, 1:]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        with jax.named_scope("embed"):  # the batch's slicing
+            inputs = tokens[:, :-1]
+        logits = forward(params, inputs, cfg, mesh=mesh)
+        with jax.named_scope("loss"):
+            targets = tokens[:, 1:]
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1)[..., 0]
+            return jnp.mean(nll)
 
     return f

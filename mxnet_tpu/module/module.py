@@ -439,7 +439,7 @@ class Module(BaseModule):
         import time as _time
 
         from ..base import MXNetError
-        from ..model import (_buffer_batch, _desc_name, _desc_shape,
+        from ..model import (_buffer_batch, _desc_name, _desc_shape, _fed,
                              _multiple_callbacks, _scan_drain, _scan_flush,
                              _scan_k)
         from ..parallel.fit_trainer import make_fit_trainer, supports_optimizer
@@ -496,7 +496,7 @@ class Module(BaseModule):
                 pending = None
                 buf = []
                 nbatch = 0
-                for data_batch in train_data:
+                for data_batch in _fed(train_data):
                     buf.append(_buffer_batch(data_batch, input_names))
                     nbatch += 1
                     if len(buf) == K:

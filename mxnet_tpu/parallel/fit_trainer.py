@@ -36,6 +36,8 @@ from __future__ import annotations
 
 import numpy as _np
 
+from .. import profiler as _profiler
+from .. import telemetry as _tel
 from ..base import MXNetError
 
 # exactly these classes (not subclasses: a subclass may override update
@@ -255,12 +257,18 @@ class FitTrainer:
         max_norm = self._guard_max_norm
         inject = self._inject
 
+        # every equation under a named scope (mx.profiler.scope_map reads
+        # them off the compiled loop): the Symbol's nodes under their
+        # operators' types (executor.py), the rest here; the scan's own
+        # slicing of the staged batches has none
         def step(params, opt_states, aux, batch, lr_t, t_t, rng, mult):
             def f(p):
-                vals = [
-                    (cast_data(batch[n]) if n in batch else cast_param(p[n]))
-                    for n in self._arg_names
-                ]
+                with jax.named_scope("cast"):
+                    vals = [
+                        (cast_data(batch[n]) if n in batch
+                         else cast_param(p[n]))
+                        for n in self._arg_names
+                    ]
                 outs, new_aux = self._run(vals, aux, rng, is_train=True)
                 # inexact heads only get cotangents; aux is state, not a
                 # differentiable output
@@ -269,34 +277,39 @@ class FitTrainer:
                 return flt, (outs, new_aux)
 
             flt, vjp_fn, (outs, new_aux) = jax.vjp(f, params, has_aux=True)
-            head_grads = [jnp.ones(o.shape, o.dtype) for o in flt]
+            with jax.named_scope("loss"):
+                head_grads = [jnp.ones(o.shape, o.dtype) for o in flt]
             (grads,) = vjp_fn(head_grads)
-            grads = {k: v.astype(jnp.float32) for k, v in grads.items()}
-            if inject:  # chaos multiplier (1.0 when this step drew no fault)
-                grads = {k: v * mult for k, v in grads.items()}
+            with jax.named_scope("optimizer"):
+                grads = {k: v.astype(jnp.float32) for k, v in grads.items()}
             flags = None
-            if guard_on:
-                gsq = sum(jnp.sum(jnp.square(g)) for g in grads.values())
-                ok = jnp.array(True)
-                for g in grads.values():
-                    ok = ok & jnp.all(jnp.isfinite(g))
-                if max_norm > 0.0:
-                    ok = ok & (gsq <= jnp.float32(max_norm) ** 2)
-            new_params, new_states = self._traced_update(
-                params, opt_states, grads, lr_t, t_t)
+            with jax.named_scope("guardian"):
+                if inject:  # chaos multiplier (1.0: this step drew no fault)
+                    grads = {k: v * mult for k, v in grads.items()}
+                if guard_on:
+                    gsq = sum(jnp.sum(jnp.square(g)) for g in grads.values())
+                    ok = jnp.array(True)
+                    for g in grads.values():
+                        ok = ok & jnp.all(jnp.isfinite(g))
+                    if max_norm > 0.0:
+                        ok = ok & (gsq <= jnp.float32(max_norm) ** 2)
+            with jax.named_scope("optimizer"):
+                new_params, new_states = self._traced_update(
+                    params, opt_states, grads, lr_t, t_t)
             if guard_on:
                 def sel(new, old):
                     return jnp.where(ok, new, old)
 
-                new_params = {k: sel(v, params[k])
-                              for k, v in new_params.items()}
-                new_states = [
-                    [None if l is None else sel(l, o)
-                     for l, o in zip(ns, os_)]
-                    for ns, os_ in zip(new_states, opt_states)
-                ]
-                new_aux = [sel(a, b) for a, b in zip(new_aux, aux)]
-                flags = (ok, jnp.sqrt(gsq))
+                with jax.named_scope("guardian"):
+                    new_params = {k: sel(v, params[k])
+                                  for k, v in new_params.items()}
+                    new_states = [
+                        [None if l is None else sel(l, o)
+                         for l, o in zip(ns, os_)]
+                        for ns, os_ in zip(new_states, opt_states)
+                    ]
+                    new_aux = [sel(a, b) for a, b in zip(new_aux, aux)]
+                    flags = (ok, jnp.sqrt(gsq))
             return new_params, new_states, new_aux, outs, flags
 
         def loop(params, opt_states, aux, batches, lrs, ts, rngs, mults):
@@ -395,8 +408,6 @@ class FitTrainer:
             self._jit_cache[K] = _cv.wrap(
                 "fit_trainer.loop|K=%d" % K, self._make_loop(K),
                 budget=1, group="train.fit_loop")
-            from .. import telemetry as _tel
-
             if _tel.ENABLED:
                 # the scanned loop is a jit build like any executor
                 # program — the compile layer's cache-hit counters say
@@ -454,6 +465,15 @@ class FitTrainer:
                 self._prof_keys[K] = _prof.program_key_for(
                     pkey, graph_key=ghash)
         self.last_program_key = self._prof_keys.get(K)
+        if _tel.ENABLED:
+            # a capture through mx.profiler gets the loop's scope map: the
+            # executable itself where mxprof's attribution holds it, else
+            # the jitted loop and the shapes it runs on, lowered at stop
+            from ..analysis import compile_verify as _cv
+
+            _profiler.note_program(
+                _cv.unwrap(self._jit_cache[K]), self.params, self.opt_states,
+                self.aux, batches, lrs, ts, rngs, mults)
         (self.params, self.opt_states, self.aux, stacked,
          self._last_flags) = self._jit_cache[K](
             self.params, self.opt_states, self.aux, batches, lrs, ts, rngs,

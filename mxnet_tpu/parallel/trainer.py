@@ -15,6 +15,10 @@ all-reduce of SURVEY §2.7, now riding ICI).
 """
 from __future__ import annotations
 
+import itertools
+
+from .. import profiler as _profiler
+from .. import telemetry as _tel
 from ..base import MXNetError
 
 
@@ -67,8 +71,9 @@ def make_train_step(loss_fn, optimizer=None, mesh=None, param_spec=None,
             )
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if has_aux:
             return params, opt_state, loss, aux
         return params, opt_state, loss
@@ -87,8 +92,19 @@ def make_train_step(loss_fn, optimizer=None, mesh=None, param_spec=None,
 
         batch_spec = NamedSharding(mesh, P(mesh.axis_names[0]))
 
+    steps = itertools.count()
+
     def step_fn(params, opt_state, batch, rng):
-        return jitted(params, opt_state, _put_batch(batch, batch_spec), rng)
+        batch = _put_batch(batch, batch_spec)
+        if not _tel.ENABLED:
+            return jitted(params, opt_state, batch, rng)
+        # telemetry on: the step under a ``train.step`` span that a
+        # running capture sees with the step's number, and the program
+        # handed to a capture through mx.profiler for its scope map
+        _profiler.note_program(_cv.unwrap(jitted), params, opt_state, batch,
+                               rng)
+        with _tel.span("train.step", step=next(steps)):
+            return jitted(params, opt_state, batch, rng)
 
     # the jitted program itself, for callers that lower it ahead of time
     # to read its HLO or its memory analysis (chip_smoke.py)

@@ -138,7 +138,7 @@ def epoch_rows(merged):
         epochs = sorted((s for s in spans if s["name"] == "epoch"),
                         key=lambda s: s["t_aligned"])
         waits = [s for s in spans if s["name"] in WAIT_SPANS]
-        batches = [s for s in spans if s["name"] in ("batch", "chunk")]
+        batches = [s for s in spans if s["name"] in ("batch", "fit.chunk")]
         for i, ep in enumerate(epochs):
             lo, hi = ep["t_aligned"], ep["t_aligned"] + ep["dur"]
             wait = sum(s["dur"] for s in waits
@@ -256,7 +256,7 @@ def cross_rank_rows(merged):
             "clock_samples": info["clock_samples"],
             "spans": len(spans),
             "batches": sum(1 for s in spans
-                           if s["name"] in ("batch", "chunk")),
+                           if s["name"] in ("batch", "fit.chunk")),
             "epochs": sum(1 for s in spans if s["name"] == "epoch"),
             "wait_s": sum(s["dur"] for s in spans
                           if s["name"] in WAIT_SPANS),

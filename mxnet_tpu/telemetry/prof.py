@@ -68,7 +68,7 @@ __all__ = [
     "graph_cost", "attribute_jit", "program_records",
     "note_step", "step_summary",
     "peak_flops", "hbm_gbps", "hbm_stats", "derived", "snapshot",
-    "PEAKS", "ROOFLINE_IMG_S",
+    "PEAKS",
     "PHASES",
 ]
 
@@ -82,10 +82,6 @@ log = logging.getLogger("mxnet_tpu.prof")
 PEAKS = {
     "TPU v5 lite": (197e12, 819.0),
 }
-#: ResNet-50 bs=128 bf16 HBM roofline on one v5e chip: ~190 MB of
-#: activation traffic per image at 819 GB/s ≈ 3,400 img/s at perfect
-#: overlap (docs/perf_analysis.md "Roofline").
-ROOFLINE_IMG_S = 3400.0
 
 #: the fenced sub-phases a step decomposes into (note_step keys)
 PHASES = ("host", "dispatch", "device", "d2h", "update")
@@ -664,7 +660,6 @@ def derived():
     out = {
         "peak_flops": peak,
         "hbm_gbps": hbm,
-        "roofline_img_s": ROOFLINE_IMG_S,
         "device_secs": dev_secs,
         "mfu": None,
         "roofline_pct": None,

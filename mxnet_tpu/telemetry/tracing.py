@@ -14,9 +14,12 @@ dispatching side reads :func:`current_span` and passes it as
 hand-off would misattribute unrelated threads' work the moment two jobs
 share a pool.
 
-Spans also forward into :func:`mxnet_tpu.profiler.scope` while the
-profiler is capturing, so the same names land in the xplane timeline —
-mxtel is the always-on record, xplane stays the deep-dive view.
+Spans also forward into :func:`mxnet_tpu.profiler.scope`, so the same
+names land in the xplane timeline of whoever is capturing — a capture
+through ``mx.profiler``, the benchmark's own ``jax.profiler.start_trace``
+or TensorBoard's capture dialog alike. mxtel is the always-on record,
+xplane stays the deep-dive view; ``profiler.scope_times`` names the
+device's idle gaps by these events.
 
 Every span belongs to a **trace**: root spans mint a process-unique
 ``trace`` id, children inherit it through the nesting chain, and
@@ -99,11 +102,12 @@ def wire_context():
 
 
 class _Span:
-    __slots__ = ("name", "id", "parent", "trace", "remote_parent",
+    __slots__ = ("name", "id", "parent", "trace", "remote_parent", "step",
                  "_t0", "_wall", "_prof")
 
-    def __init__(self, name, parent, wire=None):
+    def __init__(self, name, parent, wire=None, step=None):
         self.name = name
+        self.step = step
         self.id = next(_ids)
         self.parent = parent
         self.trace = None
@@ -130,14 +134,14 @@ class _Span:
         if self.trace is None:
             self.trace = mint_trace()
         stack.append(self.id)
-        # forward into the xplane timeline only while a capture runs —
-        # TraceAnnotation costs a jax call per span otherwise. The
-        # sys.modules probe (not an import) keeps light processes — the
-        # standalone elastic coordinator — from paying the full package
-        # import just because telemetry is on.
+        # forward into the xplane timeline, whoever started the capture
+        # (with none running a TraceAnnotation is one C++ check). Only
+        # where the package is loaded: the sys.modules probe (not an
+        # import) keeps light processes, the standalone elastic
+        # coordinator, from paying the full import because telemetry is on
         _profiler = sys.modules.get("mxnet_tpu.profiler")
-        if _profiler is not None and _profiler.state() == "run":
-            self._prof = _profiler.scope(self.name)
+        if _profiler is not None:
+            self._prof = _profiler.scope(self.name, self.step)
             self._prof.__enter__()
         self._wall = time.time()
         self._t0 = time.monotonic()
@@ -165,6 +169,8 @@ class _Span:
         }
         if self.remote_parent is not None:
             rec["remote_parent"] = self.remote_parent
+        if self.step is not None:
+            rec["step"] = self.step
         with _lock:
             _open.pop(self.id, None)
             _tail.append(rec)
@@ -182,18 +188,20 @@ class _Span:
         return False
 
 
-def span(name, parent=None, wire=None):
+def span(name, parent=None, wire=None, step=None):
     """Open a named span. A context manager; cheap no-op when telemetry
     is off. ``parent`` overrides the thread-local nesting (cross-thread
     propagation); ``wire`` adopts a remote caller's trace context (a
     :func:`wire_context` dict that crossed an RPC boundary) — the
     span's trace id and remote parent come from the caller's process,
-    so merged timelines keep the causal chain."""
+    so merged timelines keep the causal chain. ``step``: the training
+    step's (or chunk's) number, which the capture's event then carries
+    (a ``StepTraceAnnotation``) and the journal record too."""
     from . import ENABLED
 
     if not ENABLED:
         return _NULL
-    return _Span(name, parent, wire=wire)
+    return _Span(name, parent, wire=wire, step=step)
 
 
 def event(name, t=None, dur=0.0, trace=None, parent=None, **fields):

@@ -30,11 +30,19 @@ section is prepended: per-rank step-time / barrier-wait table plus the
 straggler attribution, sharing tools/trace_merge.py's merge machinery
 (clock offsets from coordinator-RPC clock records).
 
+``--xplane <dir>`` renders a profiler capture instead of (or after) a
+journal: device time by named scope and pass, the step's slow runs and
+the idle gaps by host span (``mx.profiler.scope_times``,
+docs/how_to/profiling.md). The directory is what
+``mx.profiler.profiler_set_state("run")`` ... ``("stop")`` wrote: the trace
+and, beside it, ``scopes.json``.
+
 Usage::
 
     python tools/telemetry_report.py run.jsonl
     python tools/telemetry_report.py run.jsonl --top 20
     python tools/telemetry_report.py run-{0,1,2,3}.jsonl   # cross-rank
+    python tools/telemetry_report.py --xplane /tmp/traces  # by scope
 """
 from __future__ import annotations
 
@@ -512,15 +520,116 @@ def cross_rank_section(journals):
     return lines
 
 
+def load_profiler_module():
+    """``mxnet_tpu/profiler.py`` by file path (it imports nothing of the
+    package; reading a capture needs jax's ``ProfileData`` alone)."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "mxnet_tpu", "profiler.py")
+    if os.path.exists(path):
+        spec = importlib.util.spec_from_file_location("_mx_profiler", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    from mxnet_tpu import profiler as mod  # installed wheel
+
+    return mod
+
+
+def scope_section(table, top=10, title="", depth=None):
+    """The by-scope view of ``mx.profiler.scope_times``' result: a row a
+    (scope, pass) with its share of the busy time, the ``top`` operations,
+    the step's slow runs and the idle gaps by host span. Milliseconds a
+    run of the step where the capture holds one. ``depth``: rows summed
+    by the first ``depth`` components of the scope's path
+    (``sym.BatchNorm/bn0`` -> ``sym.BatchNorm`` at 1)."""
+    by_scope = table["by_scope"]
+    if depth:
+        summed = {}
+        for scope, which, secs, calls in by_scope:
+            row = summed.setdefault(
+                ("/".join(scope.split("/")[:depth]), which), [0.0, 0.0])
+            row[0] += secs
+            row[1] += calls
+        by_scope = sorted(([s, p, v[0], v[1]] for (s, p), v
+                           in summed.items()), key=lambda r: -r[2])
+    step = table.get("step") or {}
+    runs = max(len(step.get("runs_s", ())), 1)
+    busy = table["busy_s"] or 1.0
+    lines = ["== device time by scope%s =="
+             % (" (%s)" % title if title else "")]
+    lines.append(
+        "  busy %.4f s of a %.4f s window; unscoped %.2f %%, rebuilt "
+        "%.2f %%, no map %.2f %%" % (
+            table["busy_s"], table["window_s"],
+            100 * table["unscoped_share"], 100 * table["rebuilt_share"],
+            100 * table["unmapped_share"]))
+    if step:
+        lines.append("  step: %d runs of %s, median %.3f ms (%.3f - %.3f)"
+                     % (runs, step["program"], 1e3 * step["median_s"],
+                        1e3 * min(step["runs_s"]), 1e3 * max(step["runs_s"])))
+    lines.append("  %-44s %-9s %10s %8s %10s" % (
+        "scope", "pass", "ms/run", "share", "calls/run"))
+    for scope, which, secs, calls in by_scope:
+        lines.append("  %-44s %-9s %10.3f %7.2f%% %10.1f" % (
+            scope, which, 1e3 * secs / runs, 100 * secs / busy,
+            calls / runs))
+    total = sum(r[2] for r in by_scope)
+    lines.append("  %-44s %-9s %10.3f %7.2f%%" % (
+        "(sum)", "", 1e3 * total / runs, 100 * total / busy))
+    lines.append("  -- the %d longest operations --" % top)
+    for scope, which, label, secs, calls in table["by_op"][:top]:
+        lines.append("  %-34s %-9s %-34s %9.3f %8.1f" % (
+            scope, which, label, 1e3 * secs / runs, calls / runs))
+    slow_runs = step.get("slow_runs", ())
+    for slow in slow_runs[:top]:
+        lines.append("  slow run %d: %.3f ms; grew: %s" % (
+            slow["run"], 1e3 * slow["seconds"], ", ".join(
+                "%s %s +%.3f ms" % (s, p, 1e3 * d)
+                for s, p, d in slow["grew"][:5]) or "nothing on the device"))
+    if len(slow_runs) > top:
+        lines.append("  ... and %d more slow runs" % (len(slow_runs) - top))
+    gaps = table.get("gaps") or {}
+    if gaps:
+        lines.append("  -- idle %.6f s between operations, by host span --"
+                     % gaps["idle_s"])
+        for name, secs, count in gaps["by_span"][:top]:
+            lines.append("  %-44s %12.6f s %8d gaps" % (name, secs, count))
+        for secs, name, before, after in gaps.get("longest", ())[:5]:
+            lines.append("  longest: %.6f s under %s, after %s, before %s"
+                         % (secs, name, before, after))
+    return lines
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="render an mxtel run journal (JSONL)")
-    ap.add_argument("journals", nargs="+", metavar="journal",
+    ap.add_argument("journals", nargs="*", metavar="journal",
                     help="path(s) written via MXNET_TELEMETRY_JOURNAL — "
                          "several journals add the cross-rank section")
     ap.add_argument("--top", type=int, default=10,
                     help="span rows in the top-spans table (default 10)")
+    ap.add_argument("--xplane", metavar="DIR",
+                    help="a profiler capture's directory (the trace and the "
+                         "scopes.json beside it): device time by scope")
+    ap.add_argument("--json", metavar="OUT",
+                    help="with --xplane: also write the table's data here")
+    ap.add_argument("--depth", type=int,
+                    help="with --xplane: sum rows by the first N components "
+                         "of a scope's path")
     args = ap.parse_args(argv)
+    if args.xplane:
+        table = load_profiler_module().scope_times(args.xplane)
+        if args.json:
+            with open(args.json, "w") as f:
+                json.dump(table, f)
+        print("\n".join(scope_section(table, args.top, args.xplane,
+                                      args.depth)))
+        if not args.journals:
+            return 0
+    elif not args.journals:
+        ap.error("a journal or --xplane <dir>")
     # single-rank body from the first NON-empty journal: in a chaos run
     # one rank's journal may be empty (SIGKILLed before its first
     # flush) and the cross-rank view over the healthy journals is
