@@ -35,17 +35,17 @@ written ONCE, in the type its consumer reads: the forward products
 float32; the hidden's cotangent and the weights' gradients in the
 operands' type (float32 accumulation, one rounding at the store: the bits
 ``astype`` on a float32 result gives); the input's cotangent through gate
-and up in the type the input arrived in (float32 rows: the scatter-add
+and up in the type the input arrived in (float32 rows: ``moe_combine``
 back to the tokens reads float32), so that no XLA pass over ``[rows, d]``
 exists only to rescale, round, widen or add what a kernel has just
 written.
 
 :func:`grouped_matmul` and :func:`grouped_pair` are the entry points, each
-a ``jax.custom_vjp`` whose residuals are its operands. Kernels off (the CPU
-default), a width that is no multiple of 128 or tiles that do not fit:
-``lax.ragged_dot``, counted in ``pallas_kernels.FALLBACKS`` under
-``moe_gmm``. ``MXNET_PALLAS`` and interpret mode govern these kernels as
-they govern the others.
+a ``jax.custom_vjp`` whose residuals are its operands; :func:`combine` and
+:func:`take_rows` sum rows back to their tokens by ``moe_combine``. Kernels
+off (the CPU default), a width that is no multiple of 128 or tiles that do
+not fit: ``lax.ragged_dot`` / the scatter-add, counted in ``FALLBACKS``
+under ``moe_gmm`` / ``moe_combine``; ``MXNET_PALLAS`` governs them all.
 """
 from __future__ import annotations
 
@@ -54,11 +54,11 @@ import functools
 from .. import telemetry as _tel
 from . import pallas_kernels as _pk
 
-__all__ = ["grouped_matmul", "grouped_pair", "GMM_CALLS"]
+__all__ = ["grouped_matmul", "grouped_pair", "combine", "take_rows",
+           "GMM_CALLS"]
 
-#: (kernel, operand type, result type, whether the store scales its rows,
-#: (tm, tk, tn)) -> number of call sites that took the kernel, filled while
-#: tracing like ``pallas_kernels.FLASH_CALLS``
+#: (kernel, operand type, result type, whether the store scales, tiles) ->
+#: call sites that took the kernel, filled while tracing (FLASH_CALLS')
 GMM_CALLS = {}
 
 _NN = (((1,), (0,)), ((), ()))  # [m, k] x [k, n] -> [m, n]
@@ -533,3 +533,187 @@ def grouped_pair(lhs, rhs_a, rhs_b, group_sizes):
     pair = jax.custom_vjp(run)
     pair.defvjp(fwd, bwd)
     return pair(lhs, rhs_a, rhs_b, group_sizes)
+
+
+#: the most scoped VMEM a ``moe_combine`` call may name: three quarters of
+#: the 128 MiB a v5e core has. The kernel holds a slab of the whole [n, d]
+#: sum while it runs, and the whole width fits at the three hybrid cells'
+#: shapes (81 MiB at [8192, 2304]), where a slab of a third takes twice the
+#: time (``_combine_plan``)
+_COMBINE_CAP = 96 * 1024 * 1024
+#: bucket rows a step of the kernel's row loop adds, unrolled
+_UNROLL = 8
+
+
+def _combine_vmem(n, tb, dc):
+    """Bytes of scoped VMEM ``moe_combine`` asks for at ``tb`` bucket rows
+    a block and ``dc`` columns a slab: the ``[n, dc]`` float32 sum of the
+    slab and the rows' ``[tb, dc]`` block twice over for the pipeline."""
+    return 4 * dc * (n + 2 * tb)
+
+
+def _combine_plan(n, d, rows, dtype):
+    """``((tb, dc), vmem_limit, refusal)`` of ``moe_combine`` summing
+    ``rows`` bucket rows of width ``d`` back to ``n`` tokens: blocks of
+    ``tb`` rows and the widest slab of ``dc`` columns whose sum fits
+    ``_COMBINE_CAP``, under a limit the call names where it passes Mosaic's
+    default ``_VMEM_LIMIT`` (``_combine_vmem`` is Mosaic's own total to the
+    MiB). Every slab walks every row, and the walk is the scalar unit's
+    work a row (its token's address), so a slab of a third takes twice as
+    long as the whole width. Ms a call on one v5e (``tools/gmm_probe.py
+    --combine``; my chip runs, PR 39; evenly routed / every token on the
+    first experts): at the Mellum2 cell's 65,536 rows of 2304 summed to
+    8,192 tokens, 512 x 2304 1.00 / 0.98 (676 GB/s), 256 x 2304 1.03 /
+    1.01, 512 x 1152 1.55 / 1.54, 512 x 768 2.07 / 1.84, and XLA's
+    scatter-add 6.44; at the GLM cell's 32,768 of 2048: 512 x 2048 0.54 /
+    0.52, 512 x 1024 0.78 / 0.72, the scatter-add 2.66; at the Kimi cell's
+    16,384 of 2304: 512 x 2304 0.37 / 0.37, 512 x 768 0.65 / 0.58, the
+    scatter-add 2.06. Or why the sum goes to XLA (a ``FALLBACKS``
+    reason)."""
+    import jax.numpy as jnp
+
+    if not _pk.enabled():
+        return None, None, "disabled"
+    tb = next((t for t in (512, 256, 128, 64, 32, 16, 8) if rows % t == 0),
+              None)
+    if jnp.dtype(dtype) != jnp.float32 or d % 128 or n % 8 or tb is None:
+        return None, None, "untileable"
+    mib = 1024 * 1024
+    for dc in _divisors(d):
+        need = _combine_vmem(n, tb, dc)
+        if need <= _pk._VMEM_LIMIT:
+            return (tb, dc), None, None
+        if need <= _COMBINE_CAP:
+            return (tb, dc), -(-need // mib) * mib, None
+    return None, None, "vmem"
+
+
+def _combine_kernel(tok, rows_ref, out_hbm, acc, done, *, tb, dc):
+    """One block of ``tb`` bucket rows of ``moe_combine`` in one column
+    slab: each row added to its token's row of the slab's float32 sum,
+    which stays in VMEM while every block of the bucket passes (zeros at
+    the first, written to the result's columns by one DMA at the last)."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slab, block = pl.program_id(0), pl.program_id(1)
+    n = acc.shape[0]
+    chunk = next(c for c in (512, 256, 128, 64, 32, 16, 8) if n % c == 0)
+
+    @pl.when(block == 0)
+    def _zeros():
+        def clear(c, carry):
+            acc[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :] = (
+                jnp.zeros((chunk, dc), jnp.float32))
+            return carry
+
+        lax.fori_loop(0, n // chunk, clear, 0)
+
+    def add(i, carry):
+        for u in range(_UNROLL):
+            r = i * _UNROLL + u
+            t = tok[block * tb + r]
+            acc[pl.ds(t, 1), :] += rows_ref[pl.ds(r, 1), :]
+        return carry
+
+    lax.fori_loop(0, tb // _UNROLL, add, 0)
+
+    @pl.when(block == pl.num_programs(1) - 1)
+    def _write():
+        copy = pltpu.make_async_copy(
+            acc, out_hbm.at[:, pl.ds(pl.multiple_of(slab * dc, 128), dc)],
+            done)
+        copy.start()
+        copy.wait()
+
+
+@functools.lru_cache(maxsize=None)
+def _combine_call(n, d, rows, plan, limit, interpret):
+    """``moe_combine`` at one setting, jitted over (rows [rows, d] float32,
+    their tokens [rows] int32)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tb, dc = plan
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=limit)}
+    call = pl.pallas_call(
+        functools.partial(_combine_kernel, tb=tb, dc=dc),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(d // dc, rows // tb),
+            in_specs=[pl.BlockSpec((tb, dc), lambda s, b, tok: (b, s))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((n, dc), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        interpret=interpret, name="moe_combine", **params)
+    return jax.jit(lambda rows_, tok: call(tok, rows_))
+
+
+def _combined(rows, tok, n, plan, limit):
+    """``moe_combine`` of ``rows`` back to ``n`` tokens at ``plan``,
+    counted."""
+    import jax.numpy as jnp
+
+    _took_kernel("moe_combine", rows.dtype, jnp.float32, False, plan)
+    return _combine_call(n, rows.shape[1], rows.shape[0], plan, limit,
+                         _pk._interpret())(rows, tok)
+
+
+def _combine_how(n, d, rows, dtype):
+    """``_combine_plan``'s plan and limit, having counted a refusal in
+    ``FALLBACKS`` (the plan is then None)."""
+    plan, limit, refusal = _combine_plan(n, d, rows, dtype)
+    if refusal is not None:
+        _pk._fallback("moe_combine", refusal, (n, d, rows))
+    return plan, limit
+
+
+def combine(rows, tok, n):
+    """``rows [R, d]`` summed back to the ``n`` tokens ``tok [R]`` names ->
+    ``[n, d]``: the value of ``zeros.at[tok].add(rows)``, every row read
+    once and every token's row written once. The ``moe_combine`` kernel
+    (:func:`_combine_plan` sizes it from ``(n, d, R)``); its transpose,
+    under a ``jax.custom_vjp``, is the gather ``g[tok]``. Kernels off, a
+    type other than float32 or a width that is no multiple of 128: the
+    scatter-add itself, counted in ``pallas_kernels.FALLBACKS`` under
+    ``moe_combine``."""
+    import jax
+    import jax.numpy as jnp
+
+    plan, limit = _combine_how(n, rows.shape[1], rows.shape[0], rows.dtype)
+    if plan is None:
+        return jnp.zeros((n, rows.shape[1]), rows.dtype).at[tok].add(rows)
+
+    @jax.custom_vjp
+    def run(rows, tok):
+        return _combined(rows, tok, n, plan, limit)
+
+    run.defvjp(lambda rows, tok: (_combined(rows, tok, n, plan, limit), tok),
+               lambda tok, g: (g[tok], None))
+    return run(rows, tok)
+
+
+def take_rows(x, tok):
+    """``x[tok]`` (``x [n, d]``, ``tok [R]``) whose transpose, the rows'
+    cotangent summed back to the tokens, is the ``moe_combine`` kernel in
+    place of XLA's scatter-add (:func:`combine`'s transpose pair)."""
+    import jax
+
+    n, d = x.shape
+    plan, limit = _combine_how(n, d, tok.shape[0], x.dtype)
+    if plan is None:
+        return x[tok]
+
+    @jax.custom_vjp
+    def run(x, tok):
+        return x[tok]
+
+    run.defvjp(lambda x, tok: (x[tok], tok),
+               lambda tok, g: (_combined(g, tok, n, plan, limit), None))
+    return run(x, tok)

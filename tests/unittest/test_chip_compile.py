@@ -411,3 +411,71 @@ def test_grouped_vmem_rule_refuses_what_the_compiler_refuses(
                     False, False)
     with pytest.raises(Exception, match="Scoped allocation with size " + size):
         call.lower(sizes, *operands).compile()
+
+
+#: tokens, width and bucket rows of the three hybrid cells' sums back to
+#: the tokens
+COMBINE_CELLS = {"mellum2": (8192, 2304, 65536),
+                 "kimi": (8192, 2304, 16384),
+                 "glm": (8192, 2048, 32768)}
+
+
+@pytest.mark.parametrize("cell", sorted(COMBINE_CELLS))
+def test_combine_compiles(one_chip, cell):
+    """``moe_combine`` at the three cells' shapes, as ``combine``'s forward
+    and as ``take_rows``' transpose, under the limit its plan names (the
+    whole width's sum in VMEM, past Mosaic's default)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    n, d, rows = COMBINE_CELLS[cell]
+    f32 = jnp.float32
+    shapes = (jax.ShapeDtypeStruct((rows, d), f32, sharding=one_chip),
+              jax.ShapeDtypeStruct((n, d), f32, sharding=one_chip),
+              jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip))
+
+    def loss(values, x, tok):
+        return (jnp.sum(gm.combine(values, tok, n))
+                + jnp.sum(gm.take_rows(x, tok) * values))
+
+    routed = dict(pk.FALLBACKS)
+    calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1)), *shapes)
+    assert len(calls) == 2 and all("moe_combine" in ln for ln in calls)
+    assert gm._combine_plan(n, d, rows, f32)[1] > pk._VMEM_LIMIT
+    assert pk.FALLBACKS == routed
+
+
+def test_a_mellum2_layer_sums_back_by_the_kernel(one_chip):
+    """One Mellum2 expert layer's value and gradient as the cell holds it
+    (checkpointed, ``moe_sort`` and ``moe_hidden`` kept), compiled: the
+    forward's sum back to the tokens and the input's cotangent's are the
+    two ``moe_combine`` calls, and no scatter over [8192, 2304] is left
+    (XLA's sort, permute and sorted scatter of ISSUE 39)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import moe
+
+    held, top_k = (0, 16), 8
+    params = jax.eval_shape(lambda key: moe.init_share_params(
+        key, 64, held, 2304, 896, 0, score="softmax"),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((8192, 2304), jnp.float32, sharding=one_chip)
+    layer = jax.checkpoint(
+        lambda p, x: moe.moe_share_ffn(p, x, top_k, held, dtype="bfloat16",
+                                       score="softmax")[0],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            "moe_sort", "moe_hidden"))
+    text = jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(layer(p, x) ** 2), argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    lines = text.splitlines()
+    assert sum("tpu_custom_call" in ln and "moe_combine" in ln
+               for ln in lines) == 2
+    assert not [ln for ln in lines
+                if " scatter(" in ln and "f32[8192,2304]" in ln]

@@ -2,7 +2,8 @@
 interpret mode against ``lax.ragged_dot``, values and both gradients; a
 result in the operands' type, the rows' scale in the store and the paired
 cotangent against the float32 results they replace; the visit list they
-walk; the plan and what it routes to XLA."""
+walk; the plan and what it routes to XLA; ``moe_combine`` and its
+transpose pair against the scatter-add and the gather they replace."""
 import numpy as np
 import pytest
 
@@ -243,7 +244,8 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
     whose consumer, the scatter-add back to the float32 tokens, reads
     float32. In the operands' type: the hidden's cotangent (the SiLU's
     gradient rounds it) and every weights' gradient. No ``moe_gmm`` result
-    on a cotangent path is float32."""
+    on a cotangent path is float32. Both sums back to the tokens, the
+    forward's and the input's cotangent's, are ``moe_combine``."""
     import jax
     import jax.numpy as jnp
 
@@ -268,6 +270,7 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
         return gm._plan(m, k, n, g, 2, kernel, **how)[0]
 
     down = tiles(ff, d, scaled=True)
+    combine = gm._combine_plan(tokens, d, m, jnp.float32)[0]
     new = _new_calls(took)
     # counted per trace: twice where the sorted path is a ``lax.cond``'s
     # branch (the Kimi cell's bucket is smaller than all that could land)
@@ -282,7 +285,9 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
         ("moe_tgmm", bf, bf, False,
          tiles(d, ff, "moe_tgmm", out_itemsize=2)): 2,
         ("moe_tgmm", bf, bf, False,
-         tiles(ff, d, "moe_tgmm", out_itemsize=2)): 1}
+         tiles(ff, d, "moe_tgmm", out_itemsize=2)): 1,
+        # the forward's sum back to the tokens and the input's cotangent's
+        ("moe_combine", f32, f32, False, combine): 2}
     assert pk.FALLBACKS == routed
 
 
@@ -502,3 +507,205 @@ def test_no_tiles_fit_is_a_refusal(monkeypatch):
 
     monkeypatch.setattr(pk, "_VMEM_LIMIT", 64 * 1024)
     assert gm._plan(M, K, N, 4, 4) == (None, "vmem")
+
+
+#: how the expert layer's bucket is filled: name -> (tokens' top k expert
+#: ids [tokens, top_k] from a RandomState, bucket rows), for 256 tokens of
+#: top 4 over 8 experts of which experts 0-3 are held, as
+#: ``moe_share_ffn`` sorts them (held first, by expert; the rows a batch
+#: leaves empty carry the sort's tail)
+ROUTINGS = {
+    # expert 1 gets nothing: an empty group in the middle
+    "empty_middle": (lambda rng: np.stack([rng.permutation(
+        [0, 2, 3, 4, 5, 6, 7])[:4] for _ in range(256)]), 1024),
+    # every token on experts 0-3: all its top 4 rows held
+    "all_held": (lambda rng: np.tile(np.arange(4), (256, 1)), 1024),
+    # one expert of the held takes every token, the rest lie elsewhere:
+    # three quarters of the bucket is the zero-weight tail
+    "one_expert": (lambda rng: np.tile([2, 4, 5, 6], (256, 1)), 1024),
+    # a bucket smaller than the assignments (the Kimi cell's shape): a
+    # token has 0-4 rows in it
+    "smaller_bucket": (lambda rng: np.stack([rng.permutation(8)[:4]
+                                             for _ in range(256)]), 512),
+}
+
+
+def _bucket(routing, d=256, seed=0):
+    """(rows [R, d] float32, tok [R], tokens) as ``moe_share_ffn`` makes
+    them for ``routing``; rows past the landed ones are zero, as the down
+    product's store writes them under a zero weight."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    pick, rows = ROUTINGS[routing]
+    idx = pick(rng)
+    key = np.where(idx < 4, idx, 4).reshape(-1)
+    sel = np.argsort(key, kind="stable")[:rows]
+    landed = int(np.sum(key < 4))
+    values = rng.randn(rows, d).astype(np.float32)
+    values[landed:] = 0.0
+    return (jnp.asarray(values), jnp.asarray(sel // 4, jnp.int32),
+            idx.shape[0])
+
+
+def _scatter_add(rows, tok, n):
+    import jax.numpy as jnp
+
+    return jnp.zeros((n, rows.shape[1]), rows.dtype).at[tok].add(rows)
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_the_combine_pair_is_the_scatter_add_and_the_gather(routing):
+    """``combine`` gives the value of ``zeros.at[tok].add(rows)`` and its
+    rows' cotangent is the gather ``g[tok]``; ``take_rows`` gives ``x[tok]``
+    and its cotangent the scatter-add of the rows' cotangent; both by the
+    kernel, counted, nothing routed to XLA."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rows, tok, n = _bucket(routing)
+    routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
+    g = jax.random.normal(jax.random.PRNGKey(7), (n, rows.shape[1]))
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n), rows)
+    want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
+    _close(np.asarray(got), np.asarray(want), 1e-6)
+    assert np.array_equal(back(g)[0], want_back(g)[0])
+    x = jax.random.normal(jax.random.PRNGKey(8), (n, rows.shape[1]))
+    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok), x)
+    assert np.array_equal(took_x, x[tok])
+    _close(np.asarray(back_x(rows)[0]), np.asarray(want), 1e-6)
+    # a token none of whose rows is in the bucket gets zeros
+    empty = np.setdiff1d(np.arange(n), np.asarray(tok))
+    assert not np.asarray(got)[empty].any()
+    plan = gm._combine_plan(n, rows.shape[1], rows.shape[0],
+                            jnp.float32)[0]
+    assert _new_calls(took) == {
+        ("moe_combine", "float32", "float32", False, plan): 2}
+    assert pk.FALLBACKS == routed
+
+
+def test_inside_a_cond_the_combine_runs_as_outside():
+    """The Kimi cell's bucket is smaller than every assignment, so its
+    sorted path is a ``lax.cond``'s branch."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    rows, tok, n = _bucket("smaller_bucket")
+    weight = jax.random.normal(jax.random.PRNGKey(9), (n, rows.shape[1]))
+
+    def loss(combine, take):
+        def of(r, x):
+            y = lax.cond(jnp.sum(tok) >= 0,
+                         lambda: combine(r + take(x) * r),
+                         lambda: jnp.zeros((n, r.shape[1])))
+            return jnp.sum(y * weight)
+        return of
+
+    kernel = loss(lambda r: gm.combine(r, tok, n),
+                  lambda x: gm.take_rows(x, tok))
+    xla = loss(lambda r: _scatter_add(r, tok, n), lambda x: x[tok])
+    x = jax.random.normal(jax.random.PRNGKey(10), (n, rows.shape[1]))
+    took = dict(gm.GMM_CALLS)
+    got = jax.jit(jax.value_and_grad(kernel, argnums=(0, 1)))(rows, x)
+    want = jax.value_and_grad(xla, argnums=(0, 1))(rows, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(np.asarray(a), np.asarray(b), 1e-5)
+    assert gm.GMM_CALLS != took
+
+
+@pytest.mark.parametrize("routing", ["empty_middle", "one_expert"])
+def test_the_combines_grid_and_row_reads_do_not_follow_the_routing(
+        routing, monkeypatch):
+    """One shape, two routings: the same grid of (column slabs, row
+    blocks), each step one [tb, dc] block of rows, so every row of the
+    bucket is read once a slab, landed or not, whoever it belongs to; the
+    sum the scatter-add's in every slab (VMEM made scarce here, so that a
+    slab is a third of the width)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_VMEM_LIMIT", 1024 * 1024)
+    monkeypatch.setattr(gm, "_COMBINE_CAP", 1024 * 1024)
+    rows, tok, n = _bucket(routing, d=384)
+    (tb, dc), limit, _ = gm._combine_plan(n, 384, rows.shape[0], jnp.float32)
+    assert (tb, dc) == (512, 128)
+    call = gm._combine_call(n, 384, rows.shape[0], (tb, dc), limit, True)
+    text = str(jax.make_jaxpr(call)(rows, tok))
+    grid = tuple(int(g) for g in re.search(
+        r"grid=\((\d+), (\d+)\)", text).groups())
+    assert "block_shape=(Blocked(block_size=%d), Blocked(block_size=%d))" % (
+        tb, dc) in text
+    assert grid == (3, 2)  # three slabs of the 384 columns, two row blocks
+    assert grid[0] * grid[1] * tb == 3 * rows.shape[0]  # rows read
+    _close(np.asarray(call(rows, tok)),
+           np.asarray(_scatter_add(rows, tok, n)), 1e-6)
+
+
+@pytest.mark.parametrize("switch,d,dtype,reason", [
+    ("0", 256, "float32", "disabled"),
+    ("1", 96, "float32", "untileable"),    # no multiple of 128 wide
+    ("1", 256, "bfloat16", "untileable"),  # the kernel sums float32 rows
+])
+def test_what_the_combine_cannot_take_is_the_scatter_add_counted(
+        switch, d, dtype, reason, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("MXNET_PALLAS", switch)
+    rows, tok, n = _bucket("empty_middle", d=d)
+    rows = rows.astype(dtype)
+    before = pk.FALLBACKS.get(("moe_combine", reason), 0)
+    took = dict(gm.GMM_CALLS)
+    g = jnp.ones((n, d), dtype)
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n), rows)
+    want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
+    assert np.array_equal(got, want)
+    assert np.array_equal(back(g)[0], want_back(g)[0])
+    x = jnp.ones((n, d), dtype)
+    assert np.array_equal(jax.vjp(lambda x: gm.take_rows(x, tok), x)[1](
+        rows)[0], want)
+    assert pk.FALLBACKS[("moe_combine", reason)] == before + 2
+    assert gm.GMM_CALLS == took
+
+
+@pytest.mark.parametrize("shape,want", [
+    # the three hybrid cells: tokens, width, bucket rows
+    ((8192, 2304, 65536), ((512, 2304), 81)),   # Mellum2
+    ((8192, 2048, 32768), ((512, 2048), 72)),   # GLM
+    ((8192, 2304, 16384), ((512, 2304), 81)),   # Kimi
+])
+def test_the_combine_plan_at_the_benchmark_cells_shapes(shape, want):
+    """The whole width in one slab under a limit the call names in whole
+    MiB: what ``_combine_plan``'s docstring measured; a cap too small for
+    any slab is a refusal."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    plan, limit, refusal = gm._combine_plan(*shape, jnp.float32)
+    assert refusal is None and (plan, limit // (1024 * 1024)) == want
+    assert gm._combine_vmem(shape[0], *plan) <= limit <= gm._COMBINE_CAP
+
+
+def test_no_slab_fits_is_a_refusal(monkeypatch):
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_COMBINE_CAP", 32 * 1024 * 1024)
+    assert gm._combine_plan(65536, 2304, 65536, jnp.float32) == (
+        None, None, "vmem")

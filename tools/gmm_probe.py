@@ -6,6 +6,8 @@
         [--yardsticks ragged_dot,megablox] [--dtype bfloat16]
         [--results float32,bfloat16] [--scale] [--pair] [--iters 20]
         [--tag NAME] [--out chiprun_out/gmm_probe.jsonl]
+        [--combine 8192x2304x8x64x16,...] [--combine-plans default,...]
+        [--routings even,one]
 
 For every shape ``MxKxNxG`` (rows, the right operand's two widths, groups)
 it runs the three products a layer's gradient needs: ``gmm`` ([M, K] x
@@ -25,8 +27,17 @@ milliseconds a call and TFLOP/s over the 2 M K N products. Yardsticks at
 the same operands: ``lax.ragged_dot`` (XLA's own kernel) and
 ``jax.experimental.pallas.ops.tpu.megablox`` at the plan's tiling. Rows
 are split unevenly over the groups, one group empty, two fifths of the
-rows in the last as ``moe_share_ffn`` sends its empty rows. No chip:
-exit 2, nothing printed.
+rows in the last as ``moe_share_ffn`` sends its empty rows.
+
+``--combine`` times ``moe_combine`` alone: for every ``NxDxKxExH``
+(tokens, width, top k, experts, held) the bucket of ``share_bucket_rows``
+rows that ``moe_share_ffn`` sorts under each routing of ``--routings``
+(``even``: each token's top k drawn at random from all the experts;
+``one``: every token's top k the first k experts, all held), its float32
+rows summed back to the tokens at every ``TBxDC`` of ``--combine-plans``
+(``default`` = ``_combine_plan``'s), with XLA's scatter-add
+(``zeros.at[tok].add``) as the yardstick; GB/s over the rows read and
+the tokens' rows written. No chip: exit 2, nothing printed.
 """
 import argparse
 import itertools
@@ -51,6 +62,9 @@ def main(argv=None):
     ap.add_argument("--scale", action="store_true")
     ap.add_argument("--pair", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--combine", default="")
+    ap.add_argument("--combine-plans", default="default")
+    ap.add_argument("--routings", default="even,one")
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=os.path.join(
         REPO, "chiprun_out", "gmm_probe.jsonl"))
@@ -83,7 +97,10 @@ def main(argv=None):
         try:
             if fn is not None:
                 row["ms"] = timed(fn, *operands)
-                row["tflops"] = flop / row["ms"] / 1e9
+                if row.get("kind") == "combine":  # bytes, not products
+                    row["gbps"] = flop / row["ms"] / 1e6
+                else:
+                    row["tflops"] = flop / row["ms"] / 1e9
         except Exception as e:  # a refused plan must not end the sweep
             row["error"] = "%s: %s" % (type(e).__name__, str(e)[-300:])
         rows.append(row)
@@ -91,7 +108,7 @@ def main(argv=None):
             f.write(json.dumps(row) + "\n")
         print(json.dumps(row), flush=True)
 
-    for shape in args.shapes.split(","):
+    for shape in filter(None, args.shapes.split(",")):
         m, k, n, g = (int(x) for x in shape.split("x"))
         rng = np.random.RandomState(0)
         share = rng.dirichlet(np.ones(g) * 0.5)
@@ -151,7 +168,59 @@ def main(argv=None):
                     report(dict(row, yardstick=yard), flop,
                            jax.jit(_yardstick(yard, kind, tiles, sizes)),
                            *operands[kind])
+    for shape in filter(None, args.combine.split(",")):
+        for routing in args.routings.split(","):
+            for row, moved, fn, operands in _combines(
+                    shape, routing, args.combine_plans.split(",")):
+                report(dict(row, tag=args.tag, device_kind=dev.device_kind),
+                       moved, fn, *operands)
     return 0 if all("error" not in row for row in rows) else 1
+
+
+def _combines(shape, routing, plans):
+    """(row, bytes moved, function, operands) of each plan of
+    ``moe_combine`` and of the scatter-add at one shape and routing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.parallel import moe
+
+    n, d, top_k, experts, held = (int(x) for x in shape.split("x"))
+    rows = moe.share_bucket_rows(n, experts, (0, held), top_k)
+    rng = np.random.RandomState(0)
+    if routing == "one":
+        idx = np.tile(np.arange(top_k), (n, 1))
+    else:
+        idx = np.argsort(rng.rand(n, experts), axis=1)[:, :top_k]
+    key = np.where(idx < held, idx, held).reshape(-1)
+    tok = jnp.asarray(np.argsort(key, kind="stable")[:rows] // top_k,
+                      jnp.int32)
+    values = jax.random.normal(jax.random.PRNGKey(0), (rows, d), jnp.float32)
+    moved = 4.0 * d * (rows + n)
+    row = {"shape": shape, "kind": "combine", "routing": routing,
+           "rows": rows}
+    for plan in plans:
+        if plan == "default":
+            tiles, limit, refusal = gm._combine_plan(n, d, rows, jnp.float32)
+        else:
+            tiles, refusal = tuple(int(x) for x in plan.split("x")), None
+            if rows % tiles[0] or d % tiles[1]:
+                continue  # another shape's plan
+            mib = 1024 * 1024
+            need = gm._combine_vmem(n, *tiles)
+            limit = (-(-need // mib) * mib if need > gm._pk._VMEM_LIMIT
+                     else None)
+        if refusal is not None:
+            yield dict(row, plan=plan, error=refusal), moved, None, ()
+            continue
+        yield (dict(row, plan=tiles, vmem_limit=limit), moved,
+               gm._combine_call(n, d, rows, tiles, limit, False),
+               (values, tok))
+    yield (dict(row, yardstick="scatter_add"), moved,
+           jax.jit(lambda v, t: jnp.zeros((n, d), v.dtype).at[t].add(v)),
+           (values, tok))
 
 
 def _yardstick(yard, kind, tiles, sizes):
