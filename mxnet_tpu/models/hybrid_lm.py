@@ -10,10 +10,13 @@ told, layer by layer, which attention and which MLP a block has:
   and norm of its own (``q_lora_rank``), softmax through
   ``ops.pallas_kernels.flash_attention``),
   ``"swa"`` or ``"full"`` (grouped-query softmax attention under rotary
-  positions: ``num_heads`` query heads read ``num_kv_heads`` key/value
-  heads of ``head_dim``; ``"swa"`` sees its last ``window`` keys and turns
-  by the plain rotation, ``"full"`` sees every earlier key and turns by
-  YaRN's; both through the same kernels);
+  positions: ``num_heads`` query heads (``swa_heads`` in a window layer
+  where set) read ``num_kv_heads`` key/value heads of ``head_dim``;
+  ``"swa"`` sees its last ``window`` keys and turns whole by the plain
+  rotation, ``"full"`` sees every earlier key and turns by YaRN's, at a
+  base of its own and over a head's first ``full_rotary_dim`` channels
+  where set; optionally a sigmoid gate on the output, ``attn_gate``; both
+  through the same kernels);
 * ``mlp[i]`` is ``"dense"`` (a gated SiLU MLP) or ``"moe"`` (one chip's
   share of a sparse expert layer, ``parallel.moe.moe_share_ffn``: the
   router (``router``: ``"sigmoid"`` with a score-correction bias, or
@@ -27,7 +30,8 @@ Pre-norm residual blocks with RMS norm and an untied head. Position: the
 attention beside linear attention) and otherwise turns the
 ``qk_rope_dim``-wide part of each query head and the one shared key part
 by the plain rotation; the ``"swa"`` and ``"full"`` layers rotate q and k
-whole (:func:`rope_inv_freq`). ``mtp_modules`` 1 adds a multi-token-
+whole or their first ``rotary_dim(kind)`` channels (:func:`rope_inv_freq`).
+``mtp_modules`` 1 adds a multi-token-
 prediction module trained with the model (DeepSeek-V3's, arXiv:2412.19437
 section 2.2): one more block over the main model's normed last hidden
 state and the NEXT token's embedding, through the shared embedding and
@@ -36,7 +40,9 @@ head, whose cross-entropy on the token after next joins the loss at
 kda, NoPE mla, sigmoid router, a shared expert), Mellum2-12B-A2.5B (swa,
 full, softmax router, no shared expert) and GLM-4.7-Flash (rotated mla
 with a query latent in every layer, 256-wide keys and values, sigmoid
-router, a shared expert, one prediction module);
+router, a shared expert, one prediction module) and Laguna-XS.2 (swa and
+full with 64 and 48 query heads, YaRN on half of each full head, a gated
+output, a dense first layer, a sigmoid router and a shared expert);
 ``docs/how_to/hybrid_lm.md`` has the config keys.
 
 Precision: parameters are float32 masters; every matrix product takes its
@@ -90,6 +96,15 @@ class HybridConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_attention_factor: float = 1.0
+    # where the two kinds differ, 0 taking the value above: the swa layers'
+    # query heads, the full layers' rotation base and the channels of a
+    # full head that turn (its first ones; the rest pass through)
+    swa_heads: int = 0
+    full_rope_theta: float = 0.0
+    full_rotary_dim: int = 0
+    # a sigmoid gate on the attention output of the swa / full layers,
+    # elementwise over its heads' channels, from the block's normed input
+    attn_gate: bool = False
     # mlps
     d_ff: int = 2048
     moe_d_ff: int = 256
@@ -122,9 +137,15 @@ class HybridConfig:
             raise ValueError("unknown layer kinds %s" % sorted(bad))
         if self.router not in ("sigmoid", "softmax"):
             raise ValueError("unknown router %r" % (self.router,))
-        if self.num_heads % self.kv_heads or self.head_dim % 2:
-            raise ValueError("%d query heads over %d key/value heads of %d"
-                             % (self.num_heads, self.kv_heads, self.head_dim))
+        for kind in ("swa", "full"):
+            if self.heads(kind) % self.kv_heads or self.head_dim % 2:
+                raise ValueError(
+                    "%d %s query heads over %d key/value heads of %d" % (
+                        self.heads(kind), kind, self.kv_heads, self.head_dim))
+            width = self.rotary_dim(kind)
+            if width % 2 or not 0 < width <= self.head_dim:
+                raise ValueError("%s turns %d of %d channels"
+                                 % (kind, width, self.head_dim))
         if self.mla_rope_theta and self.qk_rope_dim % 2:
             raise ValueError("a rotated part of %d" % self.qk_rope_dim)
         if self.mtp_modules not in (0, 1):
@@ -141,6 +162,17 @@ class HybridConfig:
     @property
     def kv_heads(self):
         return self.num_kv_heads or self.num_heads
+
+    def heads(self, kind):
+        """Query heads of a ``"swa"`` / ``"full"`` layer."""
+        return (kind == "swa" and self.swa_heads) or self.num_heads
+
+    def rope_base(self, kind):
+        return (kind == "full" and self.full_rope_theta) or self.rope_theta
+
+    def rotary_dim(self, kind):
+        """The channels of a head that turn: its first ones."""
+        return (kind == "full" and self.full_rotary_dim) or self.head_dim
 
     @property
     def moe_layers(self):
@@ -208,16 +240,20 @@ def init_params(cfg: HybridConfig, key):
             "wo": dense((H * cfg.v_head_dim, d)),
         }
 
-    def gqa():
-        q, kv = cfg.num_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
-        return {"wq": dense((d, q)), "wk": dense((d, kv)),
-                "wv": dense((d, kv)), "wo": dense((q, d))}
+    def gqa(kind):
+        q, kv = cfg.heads(kind) * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        p = {"wq": dense((d, q)), "wk": dense((d, kv)),
+             "wv": dense((d, kv)), "wo": dense((q, d))}
+        if cfg.attn_gate:
+            p["wg"] = dense((d, q))
+        return p
 
     def mlp():
         return {"w_gate": dense((d, cfg.d_ff)), "w_up": dense((d, cfg.d_ff)),
                 "w_down": dense((cfg.d_ff, d))}
 
-    attn = {"kda": kda, "mla": mla, "swa": gqa, "full": gqa}
+    attn = {"kda": kda, "mla": mla, "swa": functools.partial(gqa, "swa"),
+            "full": functools.partial(gqa, "full")}
 
     def block(kind, m):
         return {
@@ -260,6 +296,8 @@ def param_partition_specs(cfg: HybridConfig):
     mla.update({"wq_a": rep, "q_norm": rep, "wq_b": col} if cfg.q_lora_rank
                else {"wq": col})
     gqa = {"wq": col, "wk": col, "wv": col, "wo": row}
+    if cfg.attn_gate:
+        gqa["wg"] = col
     attn = {"kda": kda, "mla": mla, "swa": gqa, "full": gqa}
     dense = {"w_gate": col, "w_up": col, "w_down": row}
     experts = moe.share_partition_specs(bool(cfg.num_shared_experts),
@@ -421,32 +459,36 @@ def _rope_table(T, inv_freq, factor=1.0):
 
 
 def rope_inv_freq(cfg: HybridConfig, kind):
-    """``(inv_freq [head_dim / 2] float32, factor)`` of a layer kind's
-    rotation: channel ``m`` and ``m + head_dim / 2`` turn by ``pos *
-    inv_freq[m]``, and cos and sin are multiplied by ``factor``.
+    """``(inv_freq [r / 2] float32, factor)`` of a layer kind's rotation
+    over the ``r = cfg.rotary_dim(kind)`` channels of a head that turn (the
+    first ``r``; the rest pass through): channel ``m`` and ``m + r / 2``
+    turn by ``pos * inv_freq[m]``, and cos and sin are multiplied by
+    ``factor``. ``theta`` is the kind's base, ``cfg.rope_base(kind)``.
 
-    ``"swa"``: the plain rotation, ``theta ** (-2 m / head_dim)``, factor 1.
+    ``"swa"``: the plain rotation, ``theta ** (-2 m / r)``, factor 1.
     ``"full"``: YaRN (arXiv:2309.00071) as the published configurations'
-    library computes it, static at every length: channels that turn more
-    than ``beta_fast`` times over the original length keep their frequency,
-    those that turn less than ``beta_slow`` times have it divided by
-    ``yarn_factor``, a linear ramp between the two; both cos and sin carry
-    ``yarn_attention_factor`` (so the scores carry its square)."""
+    library computes it, static at every length, over the ``r`` channels
+    that turn (its ramp is placed by ``r``, not by ``head_dim``): channels
+    that turn more than ``beta_fast`` times over the original length keep
+    their frequency, those that turn less than ``beta_slow`` times have it
+    divided by ``yarn_factor``, a linear ramp between the two; both cos and
+    sin carry ``yarn_attention_factor`` (so the scores carry its square)."""
     import math
 
     import numpy as np
 
-    half = cfg.head_dim // 2
-    plain = _plain_inv_freq(cfg.rope_theta, cfg.head_dim)
+    width, theta = cfg.rotary_dim(kind), cfg.rope_base(kind)
+    half = width // 2
+    plain = _plain_inv_freq(theta, width)
     if kind == "swa" or cfg.yarn_factor == 1.0:
         return plain.astype(np.float32), 1.0
 
     def turns_at(turns):  # the channel that turns ``turns`` times
-        return cfg.head_dim * math.log(cfg.yarn_original_length / (
-            turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+        return width * math.log(cfg.yarn_original_length / (
+            turns * 2 * math.pi)) / (2 * math.log(theta))
 
     low = max(math.floor(turns_at(cfg.yarn_beta_fast)), 0)
-    high = min(math.ceil(turns_at(cfg.yarn_beta_slow)), cfg.head_dim - 1)
+    high = min(math.ceil(turns_at(cfg.yarn_beta_slow)), width - 1)
     ramp = np.clip((np.arange(half, dtype=np.float64) - low)
                    / max(high - low, 1e-3), 0.0, 1.0)
     inv_freq = plain * (1.0 - ramp) + plain / cfg.yarn_factor * ramp
@@ -463,20 +505,35 @@ def _rotate(x, cos, sin):
     return x * cos[:, None, :] + turned * sin[:, None, :]
 
 
+def _rotate_first(x, cos, sin):
+    """:func:`_rotate` over the first ``cos.shape[-1]`` channels of x; the
+    rest pass through."""
+    import jax.numpy as jnp
+
+    r = cos.shape[-1]
+    if r == x.shape[-1]:
+        return _rotate(x, cos, sin)
+    return jnp.concatenate([_rotate(x[..., :r], cos, sin), x[..., r:]],
+                           axis=-1)
+
+
 def gqa_layer(x, p, cfg: HybridConfig, kind):
     """x [B, T, d] (normed, float32) -> grouped-query softmax attention
     under rotary positions: ``kind`` ``"swa"`` (the last ``cfg.window``
-    keys, the plain rotation) or ``"full"`` (every earlier key, YaRN). q
-    and k are projected with ``cfg.dtype`` operands into float32, rotated
-    in float32 and cast once for the kernels; ``num_heads / kv_heads``
-    query heads read one key/value head, unrepeated."""
+    keys, the plain rotation) or ``"full"`` (every earlier key, YaRN), each
+    with its own query heads, base and turned channels. q and k are
+    projected with ``cfg.dtype`` operands into float32, rotated in float32
+    and cast once for the kernels; ``cfg.heads(kind) / kv_heads`` query
+    heads read one key/value head, unrepeated. With ``cfg.attn_gate`` the
+    heads' output is multiplied, channel by channel, by ``sigmoid(x wg)``
+    (float32) before ``wo``."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.pallas_kernels import flash_attention
 
     B, T, _ = x.shape
-    H, G, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    H, G, D = cfg.heads(kind), cfg.kv_heads, cfg.head_dim
     dtype = jnp.dtype(cfg.dtype)
     mm = functools.partial(_mm, dtype=dtype)
     with jax.named_scope("attn." + kind):
@@ -485,7 +542,7 @@ def gqa_layer(x, p, cfg: HybridConfig, kind):
         v = mm(x, p["wv"]).reshape(B, T, G, D)
         with jax.named_scope("rope"):
             cos, sin = _rope_table(T, *rope_inv_freq(cfg, kind))
-            q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+            q, k = _rotate_first(q, cos, sin), _rotate_first(k, cos, sin)
 
         def heads(t):
             return t.astype(dtype).transpose(0, 2, 1, 3)
@@ -493,7 +550,11 @@ def gqa_layer(x, p, cfg: HybridConfig, kind):
         o = flash_attention(heads(q), heads(k), heads(v), causal=True,
                             scale=D ** -0.5,
                             window=cfg.window if kind == "swa" else None)
-        return mm(o.transpose(0, 2, 1, 3).reshape(B, T, H * D), p["wo"])
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+        if cfg.attn_gate:
+            with jax.named_scope("attn.gate"):
+                o = o * jax.nn.sigmoid(mm(x, p["wg"]))
+        return mm(o, p["wo"])
 
 
 def _attention_half(x, norm, p, kind, cfg):
