@@ -802,12 +802,16 @@ def record_losses(main, mtp):
 def record_routing(counts, tokens, cfg: HybridConfig):
     """Telemetry of routing counts read back on the host (``counts``
     [steps, moe layers, held experts], ``tokens`` routed a step): counters
-    ``moe.assignments_total`` / ``moe.assignments_held_total`` and the gauge
-    ``moe.held_load_max_over_mean`` (the busiest held expert's assignments
-    over the mean). Returns that ratio, or None with nothing counted."""
+    ``moe.assignments_total`` / ``moe.assignments_held_total`` and the
+    gauges ``moe.held_load_max_over_mean`` (the busiest held expert's
+    assignments over the mean) and ``moe.bucket_landed_share`` (a layer's
+    landed rows over its sorted bucket's, the mean over steps and layers:
+    the share of the bucket the grouped products visit, which a step's time
+    follows). Returns the ratio, or None with nothing counted."""
     import numpy as np
 
     from .. import telemetry as _tel
+    from ..parallel import moe
 
     counts = np.asarray(counts, np.float64)
     if counts.size == 0 or counts.sum() == 0:
@@ -820,4 +824,9 @@ def record_routing(counts, tokens, cfg: HybridConfig):
             int(steps * tokens * cfg.experts_per_token * cfg.moe_blocks))
         _tel.counter("moe.assignments_held_total").inc(int(counts.sum()))
         _tel.gauge("moe.held_load_max_over_mean").set(ratio)
+        bucket = moe.share_bucket_rows(tokens, cfg.num_experts,
+                                       cfg.experts_held,
+                                       cfg.experts_per_token)
+        _tel.gauge("moe.bucket_landed_share").set(
+            float(counts.sum(axis=-1).mean()) / bucket)
     return ratio

@@ -28,8 +28,15 @@ All walk a list of VISITS, (row tile, group) pairs that come in by scalar
 prefetch beside the groups' offsets: a row tile that a group boundary cuts
 is visited once per group under a row mask, an empty group once with
 nothing in the mask (so ``moe_tgmm`` writes its block as zeros). The list
-has the static worst-case length, ``m // tm + g - 1``, the surplus visits
-masked whole: the same grid whatever the routing. Operands go to the MXU
+has the static worst-case length, ``m // tm + g - 1``: the same grid
+whatever the routing. The WORK follows the routing: the groups may end
+before the last row (the landed count), the real visits end at the tile
+that holds the last grouped row, and the surplus visits repeat it, so they
+fetch and write nothing, and do nothing. No kernel reads a row past the
+groups' end: the products do not store it (and never write the tiles
+after the one the end cuts), ``moe_tgmm`` zeroes such rows of its
+operands before it contracts over them, and ``moe_combine`` stops at a
+count. Operands go to the MXU
 in the type they arrive in and accumulation is float32. A result is
 written ONCE, in the type its consumer reads: the forward products
 float32; the hidden's cotangent and the weights' gradients in the
@@ -193,37 +200,64 @@ def _visits(group_sizes, m, tm):
     """The kernels' scalar operands: the groups' offsets [g + 1], then the
     group and the row tile of each of the ``m // tm + g - 1`` visits, and
     how many of them are real [1]. A group is visited once per row tile it
-    has rows in, an empty one once (at the tile its neighbours meet in);
-    tiles never go back. The surplus visits repeat the last real one and
-    are masked whole by their number."""
+    has rows in, an empty one once (at the tile its neighbours meet in, or
+    at the tile of the last grouped row where that is earlier); tiles never
+    go back, and no real visit goes past the tile that holds the groups'
+    last row. The surplus visits repeat the last real one."""
     import jax.numpy as jnp
 
     i32 = jnp.int32
     g = group_sizes.shape[0]
-    tiles = m // tm
     ends = jnp.minimum(jnp.cumsum(group_sizes.astype(i32)), m)
     starts = jnp.concatenate([jnp.zeros(1, i32), ends[:-1]])
-    first = jnp.minimum(starts // tm, tiles - 1)
+    first = jnp.minimum(starts // tm, jnp.maximum(ends[-1] - 1, 0) // tm)
     last = jnp.where(ends > starts, (ends - 1) // tm, first)
     upto = jnp.cumsum(last - first + 1)  # visits of the groups up to here
-    at = jnp.minimum(jnp.arange(tiles + g - 1, dtype=i32), upto[-1] - 1)
+    at = jnp.minimum(jnp.arange(m // tm + g - 1, dtype=i32), upto[-1] - 1)
     group = jnp.sum(at[:, None] >= upto[None, :], axis=1).astype(i32)
     tile = last[group] - (upto[group] - 1 - at)
     offsets = jnp.concatenate([jnp.zeros(1, i32), ends])
     return offsets, group, tile, upto[-1:]
 
 
-def _rows_of_visit(offsets, groups, tiles, real, v, shape, tm):
+def _rows_of_visit(offsets, groups, tiles, v, shape, tm):
     """Which rows of visit ``v``'s tile belong to its group: a mask of
-    ``shape`` = [tm, width]; nothing of a surplus visit."""
+    ``shape`` = [tm, width]."""
     import jax.numpy as jnp
     from jax import lax
 
     group = groups[v]
     row = tiles[v] * tm + lax.broadcasted_iota(jnp.int32, shape, 0)
-    lo = offsets[group]
-    hi = jnp.where(v < real[0], offsets[group + 1], lo)
-    return (row >= lo) & (row < hi)
+    return (row >= offsets[group]) & (row < offsets[group + 1])
+
+
+#: rows a step of ``_clear_past``'s loop sets: a bfloat16 sublane tile
+_CLEAR = 16
+
+
+def _clear_past(ref, tile, offsets):
+    """Rows of ``ref``'s [tm, width] block of row tile ``tile`` at and past
+    the groups' end set to zero where the block lies, ``_CLEAR`` rows a
+    step from the step that holds the end; nothing where the tile ends
+    before it. A few small steps at the tile the end cuts, where a mask of
+    the whole block would ask ``moe_tgmm``'s body for a block more of
+    VMEM."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    tm, width = ref.shape
+    start, end = tile * tm, offsets[offsets.shape[0] - 1]
+
+    def clear(i, carry):
+        at = pl.multiple_of(i * _CLEAR, _CLEAR)
+        row = start + at + lax.broadcasted_iota(jnp.int32, (_CLEAR, 1), 0)
+        ref[pl.ds(at, _CLEAR), :] = jnp.where(
+            row < end, ref[pl.ds(at, _CLEAR), :], 0)
+        return carry
+
+    lax.fori_loop(jnp.clip(end - start, 0, tm) // _CLEAR, tm // _CLEAR,
+                  clear, 0)
 
 
 def _column(row_ref):
@@ -248,7 +282,7 @@ def _gmm_kernel(offsets, groups, tiles, real, *refs, tm, k_steps, dims,
     """One visit's [tm, tn] block of ``moe_gmm`` (``pairs`` of operands:
     their products summed), ``tk`` of the contraction a grid step; the rows
     of the visit's group are stored at the last, under their scale where
-    there is one, in the result's type."""
+    there is one, in the result's type. A surplus visit does nothing."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -256,67 +290,74 @@ def _gmm_kernel(offsets, groups, tiles, real, *refs, tm, k_steps, dims,
     scale_ref = rest[0] if scaled else None
     out_ref, *acc_ref = rest[scaled:]
     v, at = pl.program_id(1), pl.program_id(2)
-    part = _pk._dot(operands[0][...], operands[1][...], dims)
-    for a_ref, b_ref in zip(operands[2::2], operands[3::2]):
-        part += _pk._dot(a_ref[...], b_ref[...], dims)
 
     def store(total):
         if scaled:
             total = total * _column(scale_ref)
-        mine = _rows_of_visit(offsets, groups, tiles, real, v,
-                              out_ref.shape, tm)
+        mine = _rows_of_visit(offsets, groups, tiles, v, out_ref.shape, tm)
         out_ref[...] = jnp.where(mine, total.astype(out_ref.dtype),
                                  out_ref[...])
 
-    if k_steps == 1:
-        store(part)
-        return
-    acc, = acc_ref
+    @pl.when(v < real[0])
+    def _visit():
+        part = _pk._dot(operands[0][...], operands[1][...], dims)
+        for a_ref, b_ref in zip(operands[2::2], operands[3::2]):
+            part += _pk._dot(a_ref[...], b_ref[...], dims)
+        if k_steps == 1:
+            store(part)
+            return
+        acc, = acc_ref
 
-    @pl.when(at == 0)
-    def _first():
-        acc[...] = part
+        @pl.when(at == 0)
+        def _first():
+            acc[...] = part
 
-    @pl.when(at > 0)
-    def _add():
-        acc[...] += part
+        @pl.when(at > 0)
+        def _add():
+            acc[...] += part
 
-    @pl.when(at == k_steps - 1)
-    def _last():
-        store(acc[...])
+        @pl.when(at == k_steps - 1)
+        def _last():
+            store(acc[...])
 
 
 def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
                  *acc_ref, tm, visits):
     """One visit's part of a group's [tk, tn] block of ``moe_tgmm``: zeros
     at the group's first visit, then the product of the visit's rows, the
-    narrower operand masked to them. A float32 result is summed where it
-    lies; a narrower one in the float32 scratch block, rounded into the
-    result at the group's last visit (the surplus visits are the last
-    group's, so that is the grid's last step there)."""
+    narrower operand masked to them, the wider one's rows past the groups'
+    end set to zero where it lies (a zero in the narrower one does not hold
+    a row that is not finite out of the sum). A float32 result is summed
+    where it lies; a narrower one in the float32 scratch block, rounded
+    into the result at the group's last real visit. A surplus visit does
+    nothing."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     v = pl.program_id(2)
     acc = acc_ref[0] if acc_ref else out_ref
 
-    @pl.when((v == 0) | (groups[jnp.maximum(v - 1, 0)] != groups[v]))
-    def _first():
-        acc[...] = jnp.zeros_like(acc)
+    @pl.when(v < real[0])
+    def _visit():
+        @pl.when((v == 0) | (groups[jnp.maximum(v - 1, 0)] != groups[v]))
+        def _first():
+            acc[...] = jnp.zeros_like(acc)
 
-    a, b = lhs_ref[...], rhs_ref[...]
-    if a.shape[1] <= b.shape[1]:
-        a = jnp.where(_rows_of_visit(offsets, groups, tiles, real, v,
-                                     a.shape, tm), a, 0)
-    else:
-        b = jnp.where(_rows_of_visit(offsets, groups, tiles, real, v,
-                                     b.shape, tm), b, 0)
-    acc[...] += _pk._dot(a, b, _TN)
-    if acc_ref:
-        @pl.when((v == visits - 1)
-                 | (groups[jnp.minimum(v + 1, visits - 1)] != groups[v]))
-        def _last():
-            out_ref[...] = acc[...].astype(out_ref.dtype)
+        narrow, wide = ((lhs_ref, rhs_ref) if lhs_ref.shape[1]
+                        <= rhs_ref.shape[1] else (rhs_ref, lhs_ref))
+        _clear_past(wide, tiles[v], offsets)
+        a, b = lhs_ref[...], rhs_ref[...]
+        mine = _rows_of_visit(offsets, groups, tiles, v, narrow.shape, tm)
+        if narrow is lhs_ref:
+            a = jnp.where(mine, a, 0)
+        else:
+            b = jnp.where(mine, b, 0)
+        acc[...] += _pk._dot(a, b, _TN)
+        if acc_ref:
+            @pl.when((v == real[0] - 1)
+                     | (groups[jnp.minimum(v + 1, visits - 1)] != groups[v]))
+            def _last():
+                out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -408,6 +449,15 @@ def _product(kernel, operands, group_sizes, plan, out_dtype,
                  transposed, scaled, _pk._interpret())(group_sizes, *operands)
 
 
+def _every_row(group_sizes, m):
+    """``group_sizes`` with the rows past their end put in the last group:
+    what ``lax.ragged_dot`` needs to write every row of ``m``."""
+    import jax.numpy as jnp
+
+    return group_sizes.at[-1].add(
+        jnp.maximum(m - jnp.sum(group_sizes), 0).astype(group_sizes.dtype))
+
+
 def _weights_gradient(lhs, g_out, group_sizes, plan):
     """``lhs^T . g_out`` per group, in the operands' type."""
     return _product("moe_tgmm", (lhs, g_out), group_sizes, plan, lhs.dtype)
@@ -416,10 +466,16 @@ def _weights_gradient(lhs, g_out, group_sizes, plan):
 def grouped_matmul(lhs, rhs, group_sizes, row_scale=None):
     """``lhs [m, k]`` times ``rhs [g, k, n]`` by groups of rows ->
     ``[m, n]`` float32: the first ``group_sizes[0]`` rows meet ``rhs[0]``,
-    the next ``group_sizes[1]`` rows ``rhs[1]``, and so on.
-    ``sum(group_sizes) == m`` is the caller's to keep (``moe_share_ffn``
-    does: its empty rows ride in the last group); rows past the last group
-    are never written. The operands go to the MXU in their common type,
+    the next ``group_sizes[1]`` rows ``rhs[1]``, and so on, up to the
+    groups' end, ``sum(group_sizes) <= m`` (``moe_share_ffn``'s landed
+    count). Rows past the groups' end are UNSPECIFIED, in the value and in
+    ``lhs``'s and ``row_scale``'s cotangents. The kernels do not store
+    them (the tile the end cuts keeps what its block held there, nothing is
+    written after it) and read none of them, so nothing in ``lhs``'s or the cotangent's rows
+    past the end reaches ``rhs``'s gradient, not even a NaN; the fallback
+    (``lax.ragged_dot``, which on the chip leaves a row outside every group
+    unwritten) computes them in the last group, where they count. The
+    operands go to the MXU in their common type,
     accumulation is float32. ``row_scale [m]`` (float32): each row of the
     float32 total is multiplied by its scale before it is written, the
     bits of ``grouped_matmul(lhs, rhs, group_sizes) * row_scale[:, None]``
@@ -451,7 +507,7 @@ def grouped_matmul(lhs, rhs, group_sizes, row_scale=None):
     refusal = next((why for _, why in plans if why is not None), None)
     if refusal is not None:
         _pk._fallback("moe_gmm", refusal, (m, k, n, g))
-        out = lax.ragged_dot(lhs, rhs, group_sizes,
+        out = lax.ragged_dot(lhs, rhs, _every_row(group_sizes, m),
                              preferred_element_type=jnp.float32)
         return out if row_scale is None else out * row_scale[:, None]
     forward, to_lhs, to_rhs = (plan for plan, _ in plans)
@@ -493,9 +549,12 @@ def grouped_pair(lhs, rhs_a, rhs_b, group_sizes):
     it is rounded to the weights' type here, kept so, and its cotangent
     is written in the type it ARRIVED in, which is the type the cotangent's
     consumer reads (the scatter-add back to the tokens takes float32: a
-    bfloat16 result would be one more pass that widens it). Where the
-    pair's blocks fit no plan (or the kernels take nothing), two
-    :func:`grouped_matmul` calls."""
+    bfloat16 result would be one more pass that widens it). The groups may
+    end before the last row, as :func:`grouped_matmul`'s: rows past their
+    end are unspecified in both values and in ``lhs``'s cotangent, and
+    nothing of them reaches a weights' gradient. Where the pair's blocks
+    fit no plan (or the kernels take nothing), two :func:`grouped_matmul`
+    calls."""
     import jax
     import jax.numpy as jnp
 
@@ -588,11 +647,13 @@ def _combine_plan(n, d, rows, dtype):
     return None, None, "vmem"
 
 
-def _combine_kernel(tok, rows_ref, out_hbm, acc, done, *, tb, dc):
+def _combine_kernel(tok, count, rows_ref, out_hbm, acc, done, *, tb, dc):
     """One block of ``tb`` bucket rows of ``moe_combine`` in one column
-    slab: each row added to its token's row of the slab's float32 sum,
-    which stays in VMEM while every block of the bucket passes (zeros at
-    the first, written to the result's columns by one DMA at the last)."""
+    slab: each of its rows below ``count`` added to its token's row of the
+    slab's float32 sum, which stays in VMEM while the blocks pass (zeros at
+    the first, written to the result's columns by one DMA at the last). A
+    block past the count adds nothing; its index map holds the last block
+    that has a row below the count, so nothing is fetched for it."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -611,14 +672,20 @@ def _combine_kernel(tok, rows_ref, out_hbm, acc, done, *, tb, dc):
 
         lax.fori_loop(0, n // chunk, clear, 0)
 
-    def add(i, carry):
-        for u in range(_UNROLL):
-            r = i * _UNROLL + u
-            t = tok[block * tb + r]
-            acc[pl.ds(t, 1), :] += rows_ref[pl.ds(r, 1), :]
+    def add(r, carry):
+        t = tok[block * tb + r]
+        acc[pl.ds(t, 1), :] += rows_ref[pl.ds(r, 1), :]
         return carry
 
-    lax.fori_loop(0, tb // _UNROLL, add, 0)
+    def add_unrolled(i, carry):
+        for u in range(_UNROLL):
+            add(i * _UNROLL + u, carry)
+        return carry
+
+    here = jnp.clip(count[0] - block * tb, 0, tb)
+    whole = here // _UNROLL
+    lax.fori_loop(0, whole, add_unrolled, 0)
+    lax.fori_loop(whole * _UNROLL, here, add, 0)
 
     @pl.when(block == pl.num_programs(1) - 1)
     def _write():
@@ -632,7 +699,7 @@ def _combine_kernel(tok, rows_ref, out_hbm, acc, done, *, tb, dc):
 @functools.lru_cache(maxsize=None)
 def _combine_call(n, d, rows, plan, limit, interpret):
     """``moe_combine`` at one setting, jitted over (rows [rows, d] float32,
-    their tokens [rows] int32)."""
+    their tokens [rows] int32, how many of the rows to sum [1] int32)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -646,23 +713,32 @@ def _combine_call(n, d, rows, plan, limit, interpret):
         functools.partial(_combine_kernel, tb=tb, dc=dc),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(d // dc, rows // tb),
-            in_specs=[pl.BlockSpec((tb, dc), lambda s, b, tok: (b, s))],
+            num_scalar_prefetch=2, grid=(d // dc, rows // tb),
+            # a block past the count holds the block of row count - 1
+            in_specs=[pl.BlockSpec((tb, dc), lambda s, b, tok, count: (
+                jnp.minimum(b, jnp.maximum(count[0] - 1, 0) // tb), s))],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.VMEM((n, dc), jnp.float32),
                             pltpu.SemaphoreType.DMA(())]),
         interpret=interpret, name="moe_combine", **params)
-    return jax.jit(lambda rows_, tok: call(tok, rows_))
+    return jax.jit(lambda rows_, tok, count: call(tok, count, rows_))
 
 
-def _combined(rows, tok, n, plan, limit):
-    """``moe_combine`` of ``rows`` back to ``n`` tokens at ``plan``,
-    counted."""
+def _summed(rows, tok, n, count, plan, limit):
+    """The first ``count`` of ``rows`` summed back to their ``n`` tokens:
+    ``moe_combine`` at ``plan``, counted, or with no plan the scatter-add,
+    the rows past the count sent out of range and dropped (none of them is
+    read)."""
     import jax.numpy as jnp
 
+    R = rows.shape[0]
+    if plan is None:
+        tok = jnp.where(jnp.arange(R) < count, tok, n)
+        return jnp.zeros((n, rows.shape[1]), rows.dtype).at[tok].add(
+            rows, mode="drop")
     _took_kernel("moe_combine", rows.dtype, jnp.float32, False, plan)
-    return _combine_call(n, rows.shape[1], rows.shape[0], plan, limit,
-                         _pk._interpret())(rows, tok)
+    return _combine_call(n, rows.shape[1], R, plan, limit, _pk._interpret())(
+        rows, tok, jnp.full((1,), count, jnp.int32))
 
 
 def _combine_how(n, d, rows, dtype):
@@ -674,46 +750,51 @@ def _combine_how(n, d, rows, dtype):
     return plan, limit
 
 
-def combine(rows, tok, n):
-    """``rows [R, d]`` summed back to the ``n`` tokens ``tok [R]`` names ->
-    ``[n, d]``: the value of ``zeros.at[tok].add(rows)``, every row read
-    once and every token's row written once. The ``moe_combine`` kernel
-    (:func:`_combine_plan` sizes it from ``(n, d, R)``); its transpose,
-    under a ``jax.custom_vjp``, is the gather ``g[tok]``. Kernels off, a
-    type other than float32 or a width that is no multiple of 128: the
-    scatter-add itself, counted in ``pallas_kernels.FALLBACKS`` under
+def combine(rows, tok, n, count):
+    """The first ``count`` (an int32 scalar) of ``rows [R, d]`` summed back
+    to the ``n`` tokens ``tok [R]`` names -> ``[n, d]``: the value of
+    ``zeros.at[tok[:count]].add(rows[:count])``, each of those rows read
+    once and every token's row written once. The rows past the count are
+    not read and may hold anything, NaN included (``moe_share_ffn``'s
+    landed count: the rows past it are what the grouped products leave
+    unspecified). The ``moe_combine``
+    kernel (:func:`_combine_plan` sizes it from ``(n, d, R)``); its
+    transpose, under a ``jax.custom_vjp``, is the gather ``g[tok]`` (in the
+    rows past the count too, whose cotangent is then unspecified). Kernels
+    off, a type other than float32 or a width that is no multiple of 128:
+    the scatter-add itself, counted in ``pallas_kernels.FALLBACKS`` under
     ``moe_combine``."""
     import jax
-    import jax.numpy as jnp
 
     plan, limit = _combine_how(n, rows.shape[1], rows.shape[0], rows.dtype)
     if plan is None:
-        return jnp.zeros((n, rows.shape[1]), rows.dtype).at[tok].add(rows)
+        return _summed(rows, tok, n, count, None, None)
 
-    @jax.custom_vjp
-    def run(rows, tok):
-        return _combined(rows, tok, n, plan, limit)
+    def summed(rows, tok, count):
+        return _summed(rows, tok, n, count, plan, limit)
 
-    run.defvjp(lambda rows, tok: (_combined(rows, tok, n, plan, limit), tok),
-               lambda tok, g: (g[tok], None))
-    return run(rows, tok)
+    run = jax.custom_vjp(summed)
+    run.defvjp(lambda rows, tok, count: (summed(rows, tok, count), tok),
+               lambda tok, g: (g[tok], None, None))
+    return run(rows, tok, count)
 
 
-def take_rows(x, tok):
+def take_rows(x, tok, count):
     """``x[tok]`` (``x [n, d]``, ``tok [R]``) whose transpose, the rows'
     cotangent summed back to the tokens, is the ``moe_combine`` kernel in
-    place of XLA's scatter-add (:func:`combine`'s transpose pair)."""
+    place of XLA's scatter-add (:func:`combine`'s transpose pair): it sums
+    the first ``count`` rows' cotangents alone and reads none of the others
+    (kernel or scatter-add); the gather itself is of every row."""
     import jax
 
     n, d = x.shape
     plan, limit = _combine_how(n, d, tok.shape[0], x.dtype)
-    if plan is None:
-        return x[tok]
 
     @jax.custom_vjp
-    def run(x, tok):
+    def run(x, tok, count):
         return x[tok]
 
-    run.defvjp(lambda x, tok: (x[tok], tok),
-               lambda tok, g: (_combined(g, tok, n, plan, limit), None))
-    return run(x, tok)
+    run.defvjp(lambda x, tok, count: (x[tok], (tok, count)),
+               lambda kept, g: (_summed(g, kept[0], n, kept[1], plan, limit),
+                                None, None))
+    return run(x, tok, count)

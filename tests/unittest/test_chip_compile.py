@@ -287,10 +287,11 @@ def test_kda_kernels_compile(one_chip):
 
 
 #: rows, the model's width, an expert's width, experts held: the sorted
-#: bucket of the benchmark's three hybrid cells
+#: bucket of the benchmark's four MoE cells
 GROUPED_CELLS = {"mellum2": (65536, 2304, 896, 16),
                  "kimi": (16384, 2304, 1024, 8),
-                 "glm": (32768, 2048, 1536, 8)}
+                 "glm": (32768, 2048, 1536, 8),
+                 "laguna": (65536, 2048, 512, 32)}
 
 
 def _grouped_operands(one_chip, m, k, n, g):
@@ -305,7 +306,7 @@ def _grouped_operands(one_chip, m, k, n, g):
 @pytest.mark.parametrize("product", ["up", "down", "pair"])
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
 def test_grouped_products_compile(one_chip, cell, product):
-    """The expert layer's grouped products at the three cells' shapes, each
+    """The expert layer's grouped products at the four cells' shapes, each
     in all its directions at the tiles ``_plan`` picks, counted, nothing
     routed to XLA. ``up``: gate or up alone ([rows, d] x [g, d, f]);
     ``down`` ([rows, f] x [g, f, d]) with the routing weight in its store,
@@ -413,18 +414,20 @@ def test_grouped_vmem_rule_refuses_what_the_compiler_refuses(
         call.lower(sizes, *operands).compile()
 
 
-#: tokens, width and bucket rows of the three hybrid cells' sums back to
-#: the tokens
+#: tokens, width and bucket rows of the four MoE cells' sums back to the
+#: tokens
 COMBINE_CELLS = {"mellum2": (8192, 2304, 65536),
                  "kimi": (8192, 2304, 16384),
-                 "glm": (8192, 2048, 32768)}
+                 "glm": (8192, 2048, 32768),
+                 "laguna": (8192, 2048, 65536)}
 
 
 @pytest.mark.parametrize("cell", sorted(COMBINE_CELLS))
 def test_combine_compiles(one_chip, cell):
-    """``moe_combine`` at the three cells' shapes, as ``combine``'s forward
-    and as ``take_rows``' transpose, under the limit its plan names (the
-    whole width's sum in VMEM, past Mosaic's default)."""
+    """``moe_combine`` at the four cells' shapes, as ``combine``'s forward
+    and as ``take_rows``' transpose, each stopping at a landed count, under
+    the limit its plan names (the whole width's sum in VMEM, past Mosaic's
+    default)."""
     import jax
     import jax.numpy as jnp
 
@@ -435,11 +438,12 @@ def test_combine_compiles(one_chip, cell):
     f32 = jnp.float32
     shapes = (jax.ShapeDtypeStruct((rows, d), f32, sharding=one_chip),
               jax.ShapeDtypeStruct((n, d), f32, sharding=one_chip),
-              jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip))
+              jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+              jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
 
-    def loss(values, x, tok):
-        return (jnp.sum(gm.combine(values, tok, n))
-                + jnp.sum(gm.take_rows(x, tok) * values))
+    def loss(values, x, tok, landed):
+        return (jnp.sum(gm.combine(values, tok, n, landed))
+                + jnp.sum(gm.take_rows(x, tok, landed) * values))
 
     routed = dict(pk.FALLBACKS)
     calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1)), *shapes)

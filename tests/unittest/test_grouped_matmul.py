@@ -105,18 +105,26 @@ def test_tiles_that_step_through_both_widths(layout, monkeypatch):
         _close(a, b, 1e-5)
 
 
+@pytest.mark.parametrize("end", ["every_row", "landed", "none"])
 @pytest.mark.parametrize("tm", [128, 256])
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_every_row_is_visited_once_and_the_list_is_static(layout, tm):
+def test_every_row_is_visited_once_and_the_list_is_static(layout, tm, end):
     """The visit list: ``m // tm + g - 1`` long whatever the sizes, tiles
     never going back, every group at least once, and the masks of the real
-    visits cover each row exactly once, under its own group."""
+    visits cover each grouped row exactly once, under its own group. Where
+    the groups end before the last row (``landed``: the 640 rows of the
+    layout in 768; ``none``: nothing lands), the real visits end at the
+    tile of the last grouped row (tile 0 when there is none) and cover no
+    row past it; the surplus visits repeat that tile and group."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import grouped_matmul as gm
 
     sizes = np.asarray(LAYOUTS[layout])
-    sizes[-1] += 768 - M  # six tiles of 128, three of 256
+    if end == "every_row":
+        sizes[-1] += 768 - M  # six tiles of 128, three of 256
+    elif end == "none":
+        sizes[:] = 0
     offsets, groups, tiles, real = (np.asarray(x) for x in gm._visits(
         jnp.asarray(sizes, jnp.int32), 768, tm))
     assert len(groups) == len(tiles) == 768 // tm + len(sizes) - 1
@@ -126,13 +134,16 @@ def test_every_row_is_visited_once_and_the_list_is_static(layout, tm):
     assert set(groups[:real]) == set(range(len(sizes)))
     assert (groups[real:] == groups[real - 1]).all()
     assert (tiles[real:] == tiles[real - 1]).all()
+    assert tiles[real - 1] == max(int(sizes.sum()) - 1, 0) // tm
     owner = np.full(768, -1)
     for v in range(real):
         row = tiles[v] * tm + np.arange(tm)
         mine = (row >= offsets[groups[v]]) & (row < offsets[groups[v] + 1])
         assert (owner[row[mine]] == -1).all()
         owner[row[mine]] = groups[v]
-    assert np.array_equal(owner, np.repeat(np.arange(len(sizes)), sizes))
+    want = np.full(768, -1)
+    want[:sizes.sum()] = np.repeat(np.arange(len(sizes)), sizes)
+    assert np.array_equal(owner, want)
 
 
 def test_inside_a_cond_the_kernels_run_as_outside():
@@ -220,19 +231,20 @@ def test_gmm_calls_records_plan_types_and_scale_of_each_direction(entry):
         jax.make_jaxpr(jax.grad(lambda a, b, s: jnp.sum(gm.grouped_matmul(
             a, b, sizes, row_scale=s if scaled else None) * weight),
             argnums=(0, 1, 2)))(lhs, rhs, scale)
-        want = {("moe_gmm", bf, f32, scaled, (128, K, N)): 1,   # forward
-                ("moe_gmm", bf, bf, False, (128, N, K)): 1,     # d lhs
-                ("moe_tgmm", bf, bf, False, (128, K, N)): 1}    # d rhs
+        want = {("moe_gmm", bf, f32, scaled, (128, K, N)): 1,  # fwd
+                ("moe_gmm", bf, bf, False, (128, N, K)): 1,    # d lhs
+                ("moe_tgmm", bf, bf, False, (128, K, N)): 1}   # d rhs
         if scaled:  # the unscaled product, rebuilt for the scale's cotangent
             want[("moe_gmm", bf, f32, False, (128, K, N))] = 1
     assert _new_calls(took) == want
 
 
 #: tokens, width, an expert's width, experts, held, top k, router, shared
-#: experts: the expert layer of the benchmark's three hybrid cells
+#: experts: the expert layer of the benchmark's four MoE cells
 CELLS = {"mellum2": (8192, 2304, 896, 64, (0, 16), 8, "softmax", 0),
          "glm": (8192, 2048, 1536, 64, (0, 8), 4, "sigmoid", 1),
-         "kimi": (8192, 2304, 1024, 256, (0, 8), 8, "sigmoid", 1)}
+         "kimi": (8192, 2304, 1024, 256, (0, 8), 8, "sigmoid", 1),
+         "laguna": (8192, 2048, 512, 256, (0, 32), 8, "sigmoid", 1)}
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -279,7 +291,7 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
     assert {key: n / traces for key, n in new.items()} == {
         ("moe_gmm", bf, f32, False, tiles(d, ff)): 2,       # gate, up
         ("moe_gmm", bf, f32, True, down): 1,                # down
-        ("moe_gmm", bf, f32, False, down): 1,               # down, rebuilt
+        ("moe_gmm", bf, f32, False, down): 1,         # down, rebuilt
         ("moe_gmm", bf, bf, False, tiles(d, ff, out_itemsize=2)): 1,
         ("moe_gmm_pair", bf, f32, False, tiles(ff, d, "moe_gmm_pair")): 1,
         ("moe_tgmm", bf, bf, False,
@@ -289,6 +301,67 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
         # the forward's sum back to the tokens and the input's cotangent's
         ("moe_combine", f32, f32, False, combine): 2}
     assert pk.FALLBACKS == routed
+
+
+#: how many of a token's top 4 of 16 experts land on the 4 held: name ->
+#: (tokens whose four all land, tokens with one landing) of 256
+SHARE_FILLS = {"nothing_lands": (0, 0), "typical": (40, 100),
+               "full_bucket": (256, 0)}
+
+
+@pytest.mark.parametrize("fill", sorted(SHARE_FILLS))
+def test_the_layer_with_kernels_is_the_layer_without(fill, monkeypatch):
+    """``moe_share_ffn`` (4 of 16 experts held, top 4, a bucket of every
+    assignment that could land) with the kernels on, stopping at the landed
+    rows, and off, ``lax.ragged_dot`` and the scatter-add over the whole
+    bucket: the same counts, and the value and every gradient alike, where
+    nothing lands, at a typical fill and with the bucket full."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.parallel import moe
+
+    tokens, d, ff, experts, held, top_k = 256, 128, 128, 16, (0, 4), 4
+    assert moe.share_bucket_rows(tokens, experts, held, top_k) == (
+        tokens * top_k)
+    params = moe.init_share_params(jax.random.PRNGKey(11), experts, held, d,
+                                   ff)
+    params["router"] = jnp.eye(d, experts)  # the first 16 channels route
+    params["router_bias"] = jnp.zeros(experts)
+    every, one = SHARE_FILLS[fill]
+    rng = np.random.RandomState(12)
+    logits = np.full((tokens, experts), -3.0, np.float32)
+    for t in range(tokens):
+        chosen = (np.arange(4) if t < every else
+                  np.concatenate([[t % 4], 4 + rng.permutation(12)[:3]])
+                  if t < every + one else 4 + rng.permutation(12)[:4])
+        logits[t, chosen] = 3.0 + rng.rand(4)
+    x = jax.random.normal(jax.random.PRNGKey(13), (tokens, d))
+    x = x.at[:, :experts].set(jnp.asarray(logits))
+    weight = jax.random.normal(jax.random.PRNGKey(14), (tokens, d))
+
+    def run(switch):
+        monkeypatch.setenv("MXNET_PALLAS", switch)
+
+        def loss(p, x):
+            y, counts = moe.moe_share_ffn(p, x, top_k, held)
+            return jnp.sum(y * weight), (y, counts)
+
+        (_, (y, counts)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(params, x)
+        return y, counts, grads
+
+    took = dict(gm.GMM_CALLS)
+    y, counts, grads = run("1")
+    assert gm.GMM_CALLS != took
+    want_y, want_counts, want_grads = run("0")
+    assert np.array_equal(counts, want_counts)
+    assert int(np.sum(counts)) == 4 * every + one
+    _close(np.asarray(y), np.asarray(want_y), 1e-5)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert np.isfinite(np.asarray(got)).all()
+        _close(np.asarray(got), np.asarray(want), 1e-4)
 
 
 #: a plan of one step over the contraction and one that steps through both
@@ -331,6 +404,81 @@ def test_a_bfloat16_result_is_the_float32_result_rounded(layout, kind, plan):
     assert np.array_equal(np.asarray(wide.astype(jnp.bfloat16), np.float32),
                           np.asarray(narrow, np.float32))
     assert np.asarray(narrow, np.float32).any()
+
+
+#: groups that end before the last row: name -> sizes (they add up to less
+#: than M), the rows past their end as ``moe_share_ffn`` leaves them
+LANDED = {
+    "cut_inside_a_tile": [100, 0, 77, 130],  # ends at 307, in tile 2 of 128
+    "on_a_tile_edge": [128, 0, 0, 256],      # ends at 384
+    "one_row": [0, 1, 0, 0],
+    "nothing": [0, 0, 0, 0],
+}
+
+
+def _past_the_end(x, end, fill):
+    """``x`` with its rows from ``end`` on set to ``fill``."""
+    import jax.numpy as jnp
+
+    past = (jnp.arange(x.shape[0]) >= end).reshape((-1,) + (1,) * (x.ndim - 1))
+    return jnp.where(past, jnp.asarray(fill, x.dtype), x)
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+@pytest.mark.parametrize("entry", ["plain", "scaled", "pair"])
+@pytest.mark.parametrize("layout", sorted(LANDED))
+def test_rows_past_the_groups_end_are_never_read(layout, entry, plan,
+                                                 monkeypatch):
+    """Groups that end at a landed count, with every operand's rows past it
+    (the left operand, the cotangent, the row scale) NaN: the value and the
+    left operand's (and the scale's) cotangent on the landed rows, and
+    every weights' gradient, are those of the rule before, the rest of the
+    rows in the last group under zeros, and every weights' gradient is
+    finite: no kernel read a row past the count."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    if PLANS[plan]:
+        monkeypatch.setattr(gm, "_plan",
+                            lambda *a, **kw: (PLANS[plan], None))
+    sizes = jnp.asarray(LANDED[layout], jnp.int32)
+    end = int(sizes.sum())
+    padded = sizes.at[-1].add(M - end)
+    lhs, rhs, weight = _operands("bfloat16", n=256)
+    lhs = lhs.astype(jnp.float32) if entry == "pair" else lhs
+    scale = jax.random.uniform(jax.random.PRNGKey(5), (M,), jnp.float32)
+
+    def products(a, b, s, sizes):
+        if entry == "pair":
+            gate, up = gm.grouped_pair(a, b, jnp.flip(b, 0) * 0.5, sizes)
+            return gate + 2 * up
+        return gm.grouped_matmul(a, b, sizes,
+                                 row_scale=s if entry == "scaled" else None)
+
+    def value_and_grads(fill, sizes):
+        def loss(a, b, s):
+            out = products(a, b, s, sizes)
+            return jnp.sum(out * _past_the_end(weight, end, fill)), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                             has_aux=True)(
+            _past_the_end(lhs, end, fill), rhs,
+            _past_the_end(scale, end, fill))
+        return [np.asarray(x, np.float64) for x in (out,) + grads]
+
+    routed = dict(pk.FALLBACKS)
+    got = value_and_grads(np.nan, sizes)
+    want = value_and_grads(0.0, padded)
+    assert pk.FALLBACKS == routed
+    (out, d_lhs, d_rhs, d_scale), (w_out, w_lhs, w_rhs, w_scale) = got, want
+    assert np.array_equal(out[:end], w_out[:end])
+    assert np.array_equal(d_lhs[:end], w_lhs[:end])
+    assert np.isfinite(d_rhs).all() and np.array_equal(d_rhs, w_rhs)
+    if entry == "scaled":
+        assert np.array_equal(d_scale[:end], w_scale[:end])
 
 
 def _scaled_ragged_dot(a, b, sizes, scale):
@@ -531,9 +679,10 @@ ROUTINGS = {
 
 
 def _bucket(routing, d=256, seed=0):
-    """(rows [R, d] float32, tok [R], tokens) as ``moe_share_ffn`` makes
-    them for ``routing``; rows past the landed ones are zero, as the down
-    product's store writes them under a zero weight."""
+    """(rows [R, d] float32, tok [R], tokens, the landed count) as
+    ``moe_share_ffn`` makes them for ``routing``; rows past the landed ones
+    are zero, as the fallback's down product writes them under a zero
+    weight."""
     import jax.numpy as jnp
 
     rng = np.random.RandomState(seed)
@@ -545,7 +694,7 @@ def _bucket(routing, d=256, seed=0):
     values = rng.randn(rows, d).astype(np.float32)
     values[landed:] = 0.0
     return (jnp.asarray(values), jnp.asarray(sel // 4, jnp.int32),
-            idx.shape[0])
+            idx.shape[0], landed)
 
 
 def _scatter_add(rows, tok, n):
@@ -556,25 +705,27 @@ def _scatter_add(rows, tok, n):
 
 @pytest.mark.parametrize("routing", sorted(ROUTINGS))
 def test_the_combine_pair_is_the_scatter_add_and_the_gather(routing):
-    """``combine`` gives the value of ``zeros.at[tok].add(rows)`` and its
-    rows' cotangent is the gather ``g[tok]``; ``take_rows`` gives ``x[tok]``
-    and its cotangent the scatter-add of the rows' cotangent; both by the
-    kernel, counted, nothing routed to XLA."""
+    """Counting every row: ``combine`` gives the value of
+    ``zeros.at[tok].add(rows)`` and its rows' cotangent is the gather
+    ``g[tok]``; ``take_rows`` gives ``x[tok]`` and its cotangent the
+    scatter-add of the rows' cotangent; both by the kernel, counted,
+    nothing routed to XLA."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    rows, tok, n = _bucket(routing)
+    rows, tok, n, _ = _bucket(routing)
+    every = jnp.int32(rows.shape[0])
     routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
     g = jax.random.normal(jax.random.PRNGKey(7), (n, rows.shape[1]))
-    got, back = jax.vjp(lambda r: gm.combine(r, tok, n), rows)
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n, every), rows)
     want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
     _close(np.asarray(got), np.asarray(want), 1e-6)
     assert np.array_equal(back(g)[0], want_back(g)[0])
     x = jax.random.normal(jax.random.PRNGKey(8), (n, rows.shape[1]))
-    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok), x)
+    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, every), x)
     assert np.array_equal(took_x, x[tok])
     _close(np.asarray(back_x(rows)[0]), np.asarray(want), 1e-6)
     # a token none of whose rows is in the bucket gets zeros
@@ -587,6 +738,64 @@ def test_the_combine_pair_is_the_scatter_add_and_the_gather(routing):
     assert pk.FALLBACKS == routed
 
 
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_the_combine_pair_stops_at_the_landed_count(routing):
+    """Given the landed count, with the rows past it NaN: ``combine`` is the
+    scatter-add of the landed rows and its cotangent on them the gather;
+    ``take_rows`` gathers every row and its cotangent is the scatter-add of
+    the landed rows' cotangents. Both by the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rows, tok, n, landed = _bucket(routing)
+    nan = _past_the_end(rows, landed, np.nan)
+    count = jnp.int32(landed)
+    routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n, count), nan)
+    want = _scatter_add(rows[:landed], tok[:landed], n)
+    _close(np.asarray(got), np.asarray(want), 1e-6)
+    g = jax.random.normal(jax.random.PRNGKey(7), (n, rows.shape[1]))
+    assert np.array_equal(back(g)[0][:landed], g[tok][:landed])
+    x = jax.random.normal(jax.random.PRNGKey(8), (n, rows.shape[1]))
+    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, count), x)
+    assert np.array_equal(took_x, x[tok])
+    _close(np.asarray(back_x(nan)[0]), np.asarray(want), 1e-6)
+    plan = gm._combine_plan(n, rows.shape[1], rows.shape[0],
+                            jnp.float32)[0]
+    assert _new_calls(took) == {
+        ("moe_combine", "float32", "float32", False, plan): 2}
+    assert pk.FALLBACKS == routed
+
+
+@pytest.mark.parametrize("routing", ["empty_middle", "all_held"])
+def test_off_the_kernel_the_landed_count_still_holds(routing, monkeypatch):
+    """Kernels off: the scatter-add, counted, reads no row past the count
+    either (they are sent out of range and dropped), in ``combine`` and in
+    ``take_rows``' transpose."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("MXNET_PALLAS", "0")
+    rows, tok, n, landed = _bucket(routing)
+    nan = _past_the_end(rows, landed, np.nan)
+    before = pk.FALLBACKS.get(("moe_combine", "disabled"), 0)
+    took = dict(gm.GMM_CALLS)
+    want = _scatter_add(rows[:landed], tok[:landed], n)
+    assert np.array_equal(gm.combine(nan, tok, n, jnp.int32(landed)), want)
+    x = jnp.ones((n, rows.shape[1]))
+    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, landed), x)
+    assert np.array_equal(took_x, x[tok])
+    assert np.array_equal(back_x(nan)[0], want)
+    assert pk.FALLBACKS[("moe_combine", "disabled")] == before + 2
+    assert gm.GMM_CALLS == took
+
+
 def test_inside_a_cond_the_combine_runs_as_outside():
     """The Kimi cell's bucket is smaller than every assignment, so its
     sorted path is a ``lax.cond``'s branch."""
@@ -596,7 +805,7 @@ def test_inside_a_cond_the_combine_runs_as_outside():
 
     from mxnet_tpu.ops import grouped_matmul as gm
 
-    rows, tok, n = _bucket("smaller_bucket")
+    rows, tok, n, _ = _bucket("smaller_bucket")
     weight = jax.random.normal(jax.random.PRNGKey(9), (n, rows.shape[1]))
 
     def loss(combine, take):
@@ -607,8 +816,9 @@ def test_inside_a_cond_the_combine_runs_as_outside():
             return jnp.sum(y * weight)
         return of
 
-    kernel = loss(lambda r: gm.combine(r, tok, n),
-                  lambda x: gm.take_rows(x, tok))
+    every = jnp.int32(rows.shape[0])
+    kernel = loss(lambda r: gm.combine(r, tok, n, every),
+                  lambda x: gm.take_rows(x, tok, every))
     xla = loss(lambda r: _scatter_add(r, tok, n), lambda x: x[tok])
     x = jax.random.normal(jax.random.PRNGKey(10), (n, rows.shape[1]))
     took = dict(gm.GMM_CALLS)
@@ -619,14 +829,41 @@ def test_inside_a_cond_the_combine_runs_as_outside():
     assert gm.GMM_CALLS != took
 
 
-@pytest.mark.parametrize("routing", ["empty_middle", "one_expert"])
-def test_the_combines_grid_and_row_reads_do_not_follow_the_routing(
+def _row_blocks_read(call, rows, tok, count):
+    """The row block a ``moe_combine`` call's index map gives each step of
+    its grid, slab by slab: the ``pallas_call``'s own map, evaluated."""
+    import jax
+    from jax._src.state.discharge import discharge_state
+
+    def found(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for param in eqn.params.values():
+                inner = getattr(param, "jaxpr", None)
+                if inner is not None and found(inner) is not None:
+                    return found(inner)
+        return None
+
+    mapping = found(jax.make_jaxpr(call)(rows, tok, count).jaxpr).params[
+        "grid_mapping"]
+    index_map = mapping.block_mappings[0].index_map_jaxpr
+    jaxpr, consts = discharge_state(index_map.jaxpr, index_map.consts)
+    slabs, blocks = mapping.grid
+    return [[int(jax.core.eval_jaxpr(jaxpr, consts, s, b, tok, count)[0])
+             for b in range(blocks)] for s in range(slabs)]
+
+
+@pytest.mark.parametrize("routing", ["empty_middle", "one_expert",
+                                     "all_held"])
+def test_the_combines_grid_is_static_and_it_reads_the_landed_blocks(
         routing, monkeypatch):
-    """One shape, two routings: the same grid of (column slabs, row
-    blocks), each step one [tb, dc] block of rows, so every row of the
-    bucket is read once a slab, landed or not, whoever it belongs to; the
-    sum the scatter-add's in every slab (VMEM made scarce here, so that a
-    slab is a third of the width)."""
+    """One shape, three routings: the same grid of (column slabs, row
+    blocks), but the row blocks an index map fetches stop at the block of
+    the last landed row (each later step holds it, so nothing more is
+    read), and the rows past the count, NaN here, are not added: the sum
+    is the scatter-add of the landed rows in every slab (VMEM made scarce
+    here, so that a slab is a third of the width; blocks of 128 rows)."""
     import re
 
     import jax
@@ -637,19 +874,26 @@ def test_the_combines_grid_and_row_reads_do_not_follow_the_routing(
 
     monkeypatch.setattr(pk, "_VMEM_LIMIT", 1024 * 1024)
     monkeypatch.setattr(gm, "_COMBINE_CAP", 1024 * 1024)
-    rows, tok, n = _bucket(routing, d=384)
+    rows, tok, n, landed = _bucket(routing, d=384)
     (tb, dc), limit, _ = gm._combine_plan(n, 384, rows.shape[0], jnp.float32)
     assert (tb, dc) == (512, 128)
+    tb = 128
     call = gm._combine_call(n, 384, rows.shape[0], (tb, dc), limit, True)
-    text = str(jax.make_jaxpr(call)(rows, tok))
+    count = jnp.full((1,), landed, jnp.int32)
+    text = str(jax.make_jaxpr(call)(rows, tok, count))
     grid = tuple(int(g) for g in re.search(
         r"grid=\((\d+), (\d+)\)", text).groups())
     assert "block_shape=(Blocked(block_size=%d), Blocked(block_size=%d))" % (
         tb, dc) in text
-    assert grid == (3, 2)  # three slabs of the 384 columns, two row blocks
-    assert grid[0] * grid[1] * tb == 3 * rows.shape[0]  # rows read
-    _close(np.asarray(call(rows, tok)),
-           np.asarray(_scatter_add(rows, tok, n)), 1e-6)
+    assert grid == (3, 8)  # three slabs of the 384 columns, eight blocks
+    read = _row_blocks_read(call, rows, tok, count)
+    last = (landed - 1) // tb
+    assert read == [[min(b, last) for b in range(8)]] * 3
+    assert 0 < landed <= rows.shape[0] and (
+        (last + 1) * tb < rows.shape[0]) == (routing != "all_held")
+    nan = _past_the_end(rows, landed, np.nan)
+    _close(np.asarray(call(nan, tok, count)),
+           np.asarray(_scatter_add(rows[:landed], tok[:landed], n)), 1e-6)
 
 
 @pytest.mark.parametrize("switch,d,dtype,reason", [
@@ -666,18 +910,19 @@ def test_what_the_combine_cannot_take_is_the_scatter_add_counted(
     from mxnet_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setenv("MXNET_PALLAS", switch)
-    rows, tok, n = _bucket("empty_middle", d=d)
+    rows, tok, n, _ = _bucket("empty_middle", d=d)
     rows = rows.astype(dtype)
     before = pk.FALLBACKS.get(("moe_combine", reason), 0)
     took = dict(gm.GMM_CALLS)
     g = jnp.ones((n, d), dtype)
-    got, back = jax.vjp(lambda r: gm.combine(r, tok, n), rows)
+    every = jnp.int32(rows.shape[0])
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n, every), rows)
     want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
     assert np.array_equal(got, want)
     assert np.array_equal(back(g)[0], want_back(g)[0])
     x = jnp.ones((n, d), dtype)
-    assert np.array_equal(jax.vjp(lambda x: gm.take_rows(x, tok), x)[1](
-        rows)[0], want)
+    assert np.array_equal(jax.vjp(lambda x: gm.take_rows(x, tok, every),
+                                  x)[1](rows)[0], want)
     assert pk.FALLBACKS[("moe_combine", reason)] == before + 2
     assert gm.GMM_CALLS == took
 

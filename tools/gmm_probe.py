@@ -8,6 +8,8 @@
         [--tag NAME] [--out chiprun_out/gmm_probe.jsonl]
         [--combine 8192x2304x8x64x16,...] [--combine-plans default,...]
         [--routings even,one]
+        [--landed 0.125,0.25,...] [--rule landed|pad]
+        [--layers mellum2,glm,kimi,laguna] [--root DIR]
 
 For every shape ``MxKxNxG`` (rows, the right operand's two widths, groups)
 it runs the three products a layer's gradient needs: ``gmm`` ([M, K] x
@@ -37,9 +39,24 @@ rows that ``moe_share_ffn`` sorts under each routing of ``--routings``
 rows summed back to the tokens at every ``TBxDC`` of ``--combine-plans``
 (``default`` = ``_combine_plan``'s), with XLA's scatter-add
 (``zeros.at[tok].add``) as the yardstick; GB/s over the rows read and
-the tokens' rows written. No chip: exit 2, nothing printed.
+the tokens' rows written.
+
+``--landed`` gives each share of the rows that land (``moe_share_ffn``'s
+landed count over its bucket's rows): the groups of every product above
+then add up to that share of ``M`` (``--rule landed``, the groups end at
+the landed rows) or the rest of the rows ride in the last group (``--rule
+pad``: the rule before the kernels stopped at the landed rows), and for
+every cell of ``--layers`` one expert layer's value and gradient
+(``moe_share_ffn`` at the cell's shapes, checkpointed with ``moe_sort``
+and ``moe_hidden`` kept) is timed with a routing that lands that share of
+its bucket, the products and sums inside it by the rule of the tree it
+runs on. ``--root`` takes ``mxnet_tpu`` from another checkout (a parent
+commit unpacked under ``.parent/``; run it with ``--rule pad``), so both
+sides are read the same way in one chip call. No chip: exit 2, nothing
+printed.
 """
 import argparse
+import functools
 import itertools
 import json
 import os
@@ -65,12 +82,16 @@ def main(argv=None):
     ap.add_argument("--combine", default="")
     ap.add_argument("--combine-plans", default="default")
     ap.add_argument("--routings", default="even,one")
+    ap.add_argument("--landed", default="")
+    ap.add_argument("--rule", default="landed", choices=("landed", "pad"))
+    ap.add_argument("--layers", default="")
+    ap.add_argument("--root", default=REPO)
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=os.path.join(
         REPO, "chiprun_out", "gmm_probe.jsonl"))
     args = ap.parse_args(argv)
 
-    sys.path.insert(0, REPO)
+    sys.path.insert(0, os.path.abspath(args.root))
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -108,13 +129,17 @@ def main(argv=None):
             f.write(json.dumps(row) + "\n")
         print(json.dumps(row), flush=True)
 
-    for shape in filter(None, args.shapes.split(",")):
+    landed = [float(x) for x in filter(None, args.landed.split(","))]
+    for shape, fill in itertools.product(
+            filter(None, args.shapes.split(",")), landed or [None]):
         m, k, n, g = (int(x) for x in shape.split("x"))
         rng = np.random.RandomState(0)
         share = rng.dirichlet(np.ones(g) * 0.5)
         share[g // 2] = 0.0
-        sizes = np.floor(share / share.sum() * 0.6 * m).astype(np.int32)
-        sizes[-1] += m - sizes.sum()
+        rows_in = m if fill is None else int(fill * m)
+        sizes = np.floor(share / share.sum() * 0.6 * rows_in).astype(np.int32)
+        sizes[-1] += (m if fill is None or args.rule == "pad" else rows_in) \
+            - sizes.sum()
         sizes = jnp.asarray(sizes)
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         lhs = jax.random.normal(keys[0], (m, k), jnp.float32).astype(dtype)
@@ -146,6 +171,8 @@ def main(argv=None):
                        "plan": tiles, "dtype": dtype.name,
                        "result": result.name,
                        "device_kind": dev.device_kind}
+                if fill is not None:
+                    row.update(landed=fill, rule=args.rule)
                 flop = 2.0 * m * k * n * (2 if kind == "gmm_t_pair" else 1)
                 if refusal is not None:
                     report(dict(row, error=refusal), flop)
@@ -174,7 +201,77 @@ def main(argv=None):
                     shape, routing, args.combine_plans.split(",")):
                 report(dict(row, tag=args.tag, device_kind=dev.device_kind),
                        moved, fn, *operands)
+    for cell, fill in itertools.product(
+            filter(None, args.layers.split(",")), landed):
+        row, flop, fn, operands = _layer(cell, fill)
+        report(dict(row, tag=args.tag, rule=args.rule,
+                    device_kind=dev.device_kind), flop, fn, *operands)
     return 0 if all("error" not in row for row in rows) else 1
+
+
+#: tokens, width, an expert's width, top k, experts, held, router, shared
+#: experts: the expert layer of the benchmark's four MoE cells
+LAYERS = {"mellum2": (8192, 2304, 896, 8, 64, 16, "softmax", 0),
+          "glm": (8192, 2048, 1536, 4, 64, 8, "sigmoid", 1),
+          "kimi": (8192, 2304, 1024, 8, 256, 8, "sigmoid", 1),
+          "laguna": (8192, 2048, 512, 8, 256, 32, "sigmoid", 1)}
+
+
+def _layer(cell, fill):
+    """(row, the grouped products' flops at the landed rows, function,
+    operands) of one expert layer's value and gradient at ``cell``'s
+    shapes with ``fill`` of its bucket landed: the router reads the first
+    ``experts`` channels as logits, so that the tokens that land choose
+    ``top_k`` held experts each (in turn) and the others ``top_k`` experts
+    held elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.parallel import moe
+
+    tokens, d, ff, top_k, experts, held, score, shared = LAYERS[cell]
+    bucket = moe.share_bucket_rows(tokens, experts, (0, held), top_k)
+    landing = int(fill * bucket) // top_k
+    params = moe.init_share_params(jax.random.PRNGKey(0), experts,
+                                   (0, held), d, ff, shared, score=score)
+    params["router"] = jnp.eye(d, experts, dtype=jnp.float32)
+    if "router_bias" in params:
+        params["router_bias"] = jnp.zeros_like(params["router_bias"])
+    logits = np.full((tokens, experts), -4.0, np.float32)
+    for t in range(tokens):
+        if t < landing:
+            chosen = (t + np.arange(top_k)) % held
+        else:
+            chosen = held + (t + np.arange(top_k)) % (experts - held)
+        logits[t, chosen] = 4.0 - 0.01 * np.arange(top_k)
+    x = jax.random.normal(jax.random.PRNGKey(1), (tokens, d), jnp.float32)
+    x = x.at[:, :experts].set(jnp.asarray(logits))
+    # three forward products, the down product rebuilt unscaled for the
+    # routing weight's cotangent, and two products in each one's backward
+    flop = 2.0 * landing * top_k * d * ff * 10
+    row = {"layer": cell, "kind": "layer", "landed": fill,
+           "landed_rows": landing * top_k, "bucket": bucket}
+    return row, flop, _layer_step(cell), (params, x)
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_step(cell):
+    """The jitted value and gradient of ``cell``'s expert layer: one
+    compile for every landed share."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import moe
+
+    _, _, _, top_k, _, held, score, _ = LAYERS[cell]
+    layer = jax.checkpoint(
+        lambda p, x: moe.moe_share_ffn(p, x, top_k, (0, held),
+                                       dtype="bfloat16", score=score)[0],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            "moe_sort", "moe_hidden"))
+    return jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(layer(p, x) ** 2), argnums=(0, 1)))
 
 
 def _combines(shape, routing, plans):
@@ -215,8 +312,10 @@ def _combines(shape, routing, plans):
         if refusal is not None:
             yield dict(row, plan=plan, error=refusal), moved, None, ()
             continue
+        call = gm._combine_call(n, d, rows, tiles, limit, False)
         yield (dict(row, plan=tiles, vmem_limit=limit), moved,
-               gm._combine_call(n, d, rows, tiles, limit, False),
+               lambda v, t, call=call: call(
+                   v, t, jnp.full((1,), rows, jnp.int32)),
                (values, tok))
     yield (dict(row, yardstick="scatter_add"), moved,
            jax.jit(lambda v, t: jnp.zeros((n, d), v.dtype).at[t].add(v)),
