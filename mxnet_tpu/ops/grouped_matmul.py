@@ -1,4 +1,6 @@
-"""Grouped matrix products as Pallas kernels: ``moe_gmm`` and ``moe_tgmm``.
+"""The expert layer's passes over its sorted bucket as Pallas kernels:
+the grouped products ``moe_gmm`` and ``moe_tgmm``, the gather ``moe_take``
+and the sum back to the tokens ``moe_combine``.
 
 The expert layer of a sparse model (``parallel/moe.py: moe_share_ffn``)
 multiplies rows sorted by expert with that expert's matrix:
@@ -14,7 +16,13 @@ here, with tiles sized to the shape by :func:`_plan`:
   operand read transposed ``[m, n] x [g, k, n]^T -> [m, k]`` (the input's
   cotangent: through the index map and the product's dimensions, the
   weights are never transposed in memory); its store can multiply each
-  row by a float32 scale (the routing weight of the down product);
+  row by a float32 scale (the routing weight of the down product); what a
+  call does around its products (``GMM_CALLS``' fifth field): its left
+  operand formed as ``silu(gate) * up`` from two float32 blocks as they
+  are loaded (``SWIGLU``), the rebuilt down product's store writing the
+  routing weight's cotangent in place of the product
+  (``SCALE_COTANGENT``), the hidden cotangent's store writing gate's and
+  up's through the SiLU (``SWIGLU_COTANGENT``);
 * ``moe_gmm_pair``: the same body over two pairs of operands,
   ``a1 . b1^T + a2 . b2^T`` summed in float32 and written once (the
   input's cotangent through gate and up, whose weights are never
@@ -22,7 +30,13 @@ here, with tiles sized to the shape by :func:`_plan`:
 * ``moe_tgmm``: ``lhs^T [k, m] x [m, n]`` per group ``-> [g, k, n]`` (the
   weights' gradient), accumulated in VMEM over the row tiles of one group:
   in the result's own block where the result is float32, else in a
-  float32 scratch block that is rounded into it at the group's last visit.
+  float32 scratch block that is rounded into it at the group's last visit
+  (``SWIGLU`` too: the down weights' gradient);
+* ``moe_take``: ``x[tok]``, the bucket's rows gathered from the tokens'
+  (forward, and the output's cotangent for the backward), the tokens' rows
+  held in VMEM, the rows past a count never written;
+* ``moe_combine``: the bucket's rows summed back to the tokens, the sum
+  held in VMEM, the rows past a count never read.
 
 All walk a list of VISITS, (row tile, group) pairs that come in by scalar
 prefetch beside the groups' offsets: a row tile that a group boundary cuts
@@ -35,9 +49,9 @@ that holds the last grouped row, and the surplus visits repeat it, so they
 fetch and write nothing, and do nothing. No kernel reads a row past the
 groups' end: the products do not store it (and never write the tiles
 after the one the end cuts), ``moe_tgmm`` zeroes such rows of its
-operands before it contracts over them, and ``moe_combine`` stops at a
-count. Operands go to the MXU
-in the type they arrive in and accumulation is float32. A result is
+operands before it contracts over them, and ``moe_take`` and
+``moe_combine`` stop at a count. Operands go to the MXU in the right
+operand's type and accumulation is float32. A result is
 written ONCE, in the type its consumer reads: the forward products
 float32; the hidden's cotangent and the weights' gradients in the
 operands' type (float32 accumulation, one rounding at the store: the bits
@@ -47,12 +61,16 @@ back to the tokens reads float32), so that no XLA pass over ``[rows, d]``
 exists only to rescale, round, widen or add what a kernel has just
 written.
 
-:func:`grouped_matmul` and :func:`grouped_pair` are the entry points, each
-a ``jax.custom_vjp`` whose residuals are its operands; :func:`combine` and
-:func:`take_rows` sum rows back to their tokens by ``moe_combine``. Kernels
-off (the CPU default), a width that is no multiple of 128 or tiles that do
-not fit: ``lax.ragged_dot`` / the scatter-add, counted in ``FALLBACKS``
-under ``moe_gmm`` / ``moe_combine``; ``MXNET_PALLAS`` governs them all.
+:func:`expert_mlp` is the layer's entry point: the gather, gate and up,
+the SiLU and the down product under ONE ``jax.custom_vjp``, so that no
+pass over the bucket is XLA's and none goes past the landed rows;
+:func:`grouped_matmul` is one product alone, a ``jax.custom_vjp`` whose
+residuals are its operands; :func:`combine` is the ``moe_combine`` sum,
+whose transpose is the ``moe_take`` gather. Kernels off (the CPU default),
+a width that is no multiple of 128 or tiles that do not fit:
+``lax.ragged_dot``, XLA's gather, SiLU and scatter-add, each refusal
+counted once in ``FALLBACKS`` under ``moe_gmm`` / ``moe_take`` /
+``moe_combine``; ``MXNET_PALLAS`` governs them all.
 """
 from __future__ import annotations
 
@@ -61,12 +79,26 @@ import functools
 from .. import telemetry as _tel
 from . import pallas_kernels as _pk
 
-__all__ = ["grouped_matmul", "grouped_pair", "combine", "take_rows",
-           "GMM_CALLS"]
+__all__ = ["grouped_matmul", "expert_mlp", "combine", "GMM_CALLS"]
 
-#: (kernel, operand type, result type, whether the store scales, tiles) ->
-#: call sites that took the kernel, filled while tracing (FLASH_CALLS')
+#: (kernel, operand type, result type, whether the store scales, what the
+#: call does around its products or None, tiles) -> call sites that took
+#: the kernel, filled while tracing (FLASH_CALLS')
 GMM_CALLS = {}
+
+#: what a call does around its products (``GMM_CALLS``' fifth field, the
+#: names joined by "+"). ``SWIGLU``: the left operand is ``silu(gate) *
+#: up``, formed in the operands' type from a float32 block of each as it
+#: is loaded, so that an expert's hidden is never stored;
+#: ``SCALE_COTANGENT`` (the down product rebuilt in its backward pass):
+#: the product is not stored, the store writes the cotangent times the
+#: rows' scale in the operands' type and each row's ``sum(g * product)``,
+#: the routing weight's cotangent; ``SWIGLU_COTANGENT`` (the hidden's
+#: cotangent): the store writes gate's and up's cotangents through
+#: ``silu(gate) * up``, from their float32 blocks, in place of the product
+SWIGLU = "swiglu"
+SCALE_COTANGENT = "scale_cotangent"
+SWIGLU_COTANGENT = "swiglu_cotangent"
 
 _NN = (((1,), (0,)), ((), ()))  # [m, k] x [k, n] -> [m, n]
 _NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k] -> [m, n]
@@ -78,19 +110,20 @@ def _pairs(kernel):
     return 2 if kernel == "moe_gmm_pair" else 1
 
 
-def _took_kernel(kernel, dtype, out_dtype, scaled, tiles):
+def _took_kernel(kernel, dtype, out_dtype, scaled, tiles, fused=None):
     import jax.numpy as jnp
 
     key = (kernel, jnp.dtype(dtype).name, jnp.dtype(out_dtype).name,
-           scaled, tiles)
+           scaled, fused, tiles)
     GMM_CALLS[key] = GMM_CALLS.get(key, 0) + 1
     if _tel.ENABLED:
-        _tel.counter("pallas.kernel_total.%s.%s.%s%s" % (
-            key[:3] + (".scaled" if scaled else "",))).inc()
+        _tel.counter("pallas.kernel_total.%s.%s.%s%s%s" % (
+            key[:3] + (".scaled" if scaled else "",
+                       "." + fused.replace("+", ".") if fused else ""))).inc()
 
 
 def _vmem(kernel, tm, tk, tn, itemsize, k_steps=1, out_itemsize=4,
-          scaled=False):
+          scaled=False, epilogue=None, swiglu=False):
     """Bytes of scoped VMEM a kernel asks for at tiles ``(tm, tk, tn)``:
     every operand and result block twice over for the pipeline, then the
     body's own. ``moe_gmm`` (``moe_gmm_pair``: two of each operand block):
@@ -100,25 +133,46 @@ def _vmem(kernel, tm, tk, tn, itemsize, k_steps=1, out_itemsize=4,
     first float32 product; else the product goes to the result's block as
     it is made, a few hundred bytes a row in flight (more where it is
     rounded on the way); the rows' scale comes as a [1, tm] float32 block
-    and is turned into a column 128 rows at a time.
+    and is turned into a column 128 rows at a time. A ``swiglu`` left
+    operand is two float32 blocks, gate and up, twice over, and the SiLU
+    in the operands' type through ``_WIDE`` bytes an element of the tile
+    in flight. The
+    ``SCALE_COTANGENT`` epilogue: the float32 cotangent's [tm, tn] block
+    twice over and the row sums' [1, tm] block (eight sublanes) twice over;
+    its products in flight fit in what the plain store is counted
+    (Mosaic's totals for a v5e at the four MoE cells' shapes lie 5.3 - 7.4
+    bytes a block element over the scaled store's, against the 8 counted).
+    The ``SWIGLU_COTANGENT`` epilogue: gate's and up's float32 blocks
+    twice over, the second result's block twice over, and ``_SWIGLU_OWN``
+    bytes a block element of the SiLU's gradient in flight.
     ``moe_tgmm``: the left operand's tile transposed, the narrower
     operand's masked copy with its float32 form, and for a result narrower
     than float32 the float32 scratch block it is rounded from (with the
-    narrower result's blocks, what the float32 result's two take). Fitted
-    to what the chip's compiler asks for at the three benchmark cells'
+    narrower result's blocks, what the float32 result's two take); a
+    ``swiglu`` left operand as in ``moe_gmm``.
+    Fitted to what the chip's compiler asks for at the benchmark cells'
     shapes, read stage by stage under a rising limit: 0 - 2 % over it at
     sixty settings, never under (AOT, PR 37; test_chip_compile.py holds
-    the shapes)."""
+    the shapes); the ``SWIGLU`` forms and ``SWIGLU_COTANGENT`` 10 - 30 %
+    over it at the four MoE cells' plans and the next larger tiles, never
+    under (compiled for a v5e)."""
+    left = 8 if swiglu else itemsize
+    wide = _WIDE * tm * tk if swiglu else 0
     if kernel == "moe_tgmm":
         scratch = tk * tn * 4 if out_itemsize < 4 else 0
-        return (2 * tm * (tk + tn) * itemsize + 2 * tk * tn * out_itemsize
-                + scratch + tm * tk * itemsize
-                + tm * min(tk, tn) * (4 + itemsize))
+        return (2 * tm * (tk * left + tn * itemsize)
+                + 2 * tk * tn * out_itemsize + scratch + tm * tk * itemsize
+                + tm * min(tk, tn) * (4 + itemsize) + wide)
     pairs = _pairs(kernel)
-    blocks = (2 * pairs * (tm * tk + tk * tn) * itemsize
+    blocks = (2 * pairs * (tm * tk * left + tk * tn * itemsize)
               + 2 * tm * tn * out_itemsize
               + (tm * 576 + 128 * 1024 if scaled else 0))
-    own = tm * tk * itemsize + (256 * tm if pairs > 1 else 0)
+    own = tm * tk * itemsize + (256 * tm if pairs > 1 else 0) + wide
+    if epilogue == SCALE_COTANGENT:
+        blocks += 8 * tm * tn + 64 * tm
+    elif epilogue == SWIGLU_COTANGENT:
+        blocks += 16 * tm * tn + 2 * tm * tn * out_itemsize
+        own += _SWIGLU_OWN * tm * tn
     if k_steps > 1:
         own += 8 * tm * tn
     elif pairs > 1:
@@ -128,18 +182,23 @@ def _vmem(kernel, tm, tk, tn, itemsize, k_steps=1, out_itemsize=4,
     return blocks + own
 
 
+#: bytes of VMEM an element of a ``SWIGLU`` tile asks for in flight, and
+#: an element of the ``SWIGLU_COTANGENT`` store
+_WIDE, _SWIGLU_OWN = 8, 16
+
+
 def _divisors(width):
     """The multiples of 128 that divide ``width``, largest first."""
     return [d for d in range(width, 0, -128) if width % d == 0]
 
 
-def _plan(m, k, n, g, itemsize, kernel="moe_gmm", out_itemsize=4,
-          scaled=False):
+def _plan(m, k, n, g, itemsize, kernel="moe_gmm", **how):
     """``((tm, tk, tn), refusal)``: the tiles a product of ``m`` rows,
     contraction ``k`` and result width ``n`` over ``g`` groups runs at, and
     why it would NOT take the kernel (a ``FALLBACKS`` reason) or None when
-    it will. ``out_itemsize``: the item size of the result's type;
-    ``scaled``: the store multiplies by a row scale. The only place that
+    it will. ``how``: what :func:`_vmem` counts beside the tiles (the
+    result's item size, a row scale, what the call does around its
+    products). The only place that
     knows shapes: of the tiles that fit ``_VMEM_LIMIT``, those that move
     the fewest bytes between HBM and VMEM, the larger tiles on a tie. A
     ``moe_gmm`` reads the left operand once per column tile, and the right
@@ -181,7 +240,7 @@ def _plan(m, k, n, g, itemsize, kernel="moe_gmm", out_itemsize=4,
         for tn in _divisors(n):
             for tk in _divisors(k):
                 if _vmem(kernel, tm, tk, tn, itemsize, k // tk,
-                         out_itemsize, scaled) > _pk._VMEM_LIMIT:
+                         **how) > _pk._VMEM_LIMIT:
                     continue
                 if kernel == "moe_tgmm":
                     moved = m * (k * (n // tn) + n * (k // tk))
@@ -220,14 +279,14 @@ def _visits(group_sizes, m, tm):
     return offsets, group, tile, upto[-1:]
 
 
-def _rows_of_visit(offsets, groups, tiles, v, shape, tm):
+def _rows_of_visit(offsets, groups, tiles, v, shape, tm, axis=0):
     """Which rows of visit ``v``'s tile belong to its group: a mask of
-    ``shape`` = [tm, width]."""
+    ``shape`` = [tm, width], or [1, tm] with the rows along ``axis`` 1."""
     import jax.numpy as jnp
     from jax import lax
 
     group = groups[v]
-    row = tiles[v] * tm + lax.broadcasted_iota(jnp.int32, shape, 0)
+    row = tiles[v] * tm + lax.broadcasted_iota(jnp.int32, shape, axis)
     return (row >= offsets[group]) & (row < offsets[group + 1])
 
 
@@ -277,32 +336,88 @@ def _column(row_ref):
         axis=0)
 
 
+def _row(column):
+    """A ``[tm, 1]`` float32 column as a ``[1, tm]`` row, 128 rows at a
+    time: :func:`_column` the other way round, the column spread across a
+    square, masked to its diagonal and summed down the sublanes (exact)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    diagonal = (lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+                == lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(diagonal, column[at:at + 128, :], 0.0), axis=0,
+                 keepdims=True) for at in range(0, column.shape[0], 128)],
+        axis=1)
+
+
+def _swiglu(gate_ref, up_ref, dtype):
+    """``silu(gate) * up`` of two float32 blocks, in ``dtype``."""
+    import jax
+
+    gate = gate_ref[...]
+    return (gate * jax.nn.sigmoid(gate) * up_ref[...]).astype(dtype)
+
+
 def _gmm_kernel(offsets, groups, tiles, real, *refs, tm, k_steps, dims,
-                pairs, scaled):
+                pairs, scaled, swiglu, epilogue, fed):
     """One visit's [tm, tn] block of ``moe_gmm`` (``pairs`` of operands:
-    their products summed), ``tk`` of the contraction a grid step; the rows
-    of the visit's group are stored at the last, under their scale where
-    there is one, in the result's type. A surplus visit does nothing."""
+    their products summed), ``tk`` of the contraction a grid step, each
+    left operand in the right one's type ``fed`` (``swiglu``: formed from
+    a gate and an up block); the rows of the visit's group are stored at
+    the last, under their scale where there is one, in the result's type.
+    The ``SCALE_COTANGENT`` epilogue stores no product: the float32
+    cotangent block ``g`` times the rows' scale, in the result's type, and
+    each row's ``sum(g * total)`` over the tile's columns to a [1, tm]
+    float32 block of partial sums. The ``SWIGLU_COTANGENT`` epilogue reads
+    the product as the hidden's cotangent and stores gate's and up's
+    through ``silu(gate) * up``, from their float32 blocks. A surplus visit
+    does nothing."""
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    operands, rest = refs[:2 * pairs], refs[2 * pairs:]
+    per = 3 if swiglu else 2
+    operands, rest = refs[:per * pairs], refs[per * pairs:]
+    taken = {None: 0, SCALE_COTANGENT: 1, SWIGLU_COTANGENT: 2}[epilogue]
+    beside, rest = rest[:taken], rest[taken:]
     scale_ref = rest[0] if scaled else None
-    out_ref, *acc_ref = rest[scaled:]
+    rest = rest[scaled:]
+    outs, acc_ref = rest[:1 + bool(epilogue)], rest[1 + bool(epilogue):]
     v, at = pl.program_id(1), pl.program_id(2)
 
     def store(total):
-        if scaled:
-            total = total * _column(scale_ref)
-        mine = _rows_of_visit(offsets, groups, tiles, v, out_ref.shape, tm)
-        out_ref[...] = jnp.where(mine, total.astype(out_ref.dtype),
-                                 out_ref[...])
+        mine = _rows_of_visit(offsets, groups, tiles, v, outs[0].shape, tm)
+
+        def put(ref, value):
+            ref[...] = jnp.where(mine, value.astype(ref.dtype), ref[...])
+
+        if epilogue == SCALE_COTANGENT:
+            g, sums = beside[0][...], outs[1]
+            put(outs[0], g * _column(scale_ref))
+            row = _rows_of_visit(offsets, groups, tiles, v, sums.shape, tm,
+                                 axis=1)
+            sums[...] = jnp.where(
+                row, _row(jnp.sum(g * total, axis=1, keepdims=True)),
+                sums[...])
+        elif epilogue == SWIGLU_COTANGENT:
+            gate, up = beside[0][...], beside[1][...]
+            sig = jax.nn.sigmoid(gate)
+            put(outs[0], total * up * (sig * (1.0 + gate * (1.0 - sig))))
+            put(outs[1], total * (gate * sig))
+        else:
+            put(outs[0], total * _column(scale_ref) if scaled else total)
+
+    def product(refs_):
+        left = (_swiglu(*refs_[:2], fed) if swiglu
+                else refs_[0][...].astype(fed))
+        return _pk._dot(left, refs_[-1][...], dims)
 
     @pl.when(v < real[0])
     def _visit():
-        part = _pk._dot(operands[0][...], operands[1][...], dims)
-        for a_ref, b_ref in zip(operands[2::2], operands[3::2]):
-            part += _pk._dot(a_ref[...], b_ref[...], dims)
+        part = product(operands[:per])
+        for first in range(per, per * pairs, per):
+            part += product(operands[first:first + per])
         if k_steps == 1:
             store(part)
             return
@@ -321,19 +436,22 @@ def _gmm_kernel(offsets, groups, tiles, real, *refs, tm, k_steps, dims,
             store(acc[...])
 
 
-def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
-                 *acc_ref, tm, visits):
+def _tgmm_kernel(offsets, groups, tiles, real, *refs, tm, visits, swiglu,
+                 fed):
     """One visit's part of a group's [tk, tn] block of ``moe_tgmm``: zeros
-    at the group's first visit, then the product of the visit's rows, the
-    narrower operand masked to them, the wider one's rows past the groups'
-    end set to zero where it lies (a zero in the narrower one does not hold
-    a row that is not finite out of the sum). A float32 result is summed
-    where it lies; a narrower one in the float32 scratch block, rounded
-    into the result at the group's last real visit. A surplus visit does
-    nothing."""
+    at the group's first visit, then the product of the visit's rows in
+    ``fed`` (``swiglu``: the left operand formed from a gate and an up
+    block), the narrower operand masked to them, the wider one's rows past
+    the groups' end set to zero where it lies (a zero in the narrower one
+    does not hold a row that is not finite out of the sum). A float32
+    result is summed where it lies; a narrower one in the float32 scratch
+    block, rounded into the result at the group's last real visit. A
+    surplus visit does nothing."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    lhs_refs, (rhs_ref, out_ref, *acc_ref) = (refs[:1 + swiglu],
+                                              refs[1 + swiglu:])
     v = pl.program_id(2)
     acc = acc_ref[0] if acc_ref else out_ref
 
@@ -343,12 +461,15 @@ def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
         def _first():
             acc[...] = jnp.zeros_like(acc)
 
-        narrow, wide = ((lhs_ref, rhs_ref) if lhs_ref.shape[1]
-                        <= rhs_ref.shape[1] else (rhs_ref, lhs_ref))
-        _clear_past(wide, tiles[v], offsets)
-        a, b = lhs_ref[...], rhs_ref[...]
-        mine = _rows_of_visit(offsets, groups, tiles, v, narrow.shape, tm)
-        if narrow is lhs_ref:
+        lhs_narrow = lhs_refs[0].shape[1] <= rhs_ref.shape[1]
+        for wide in ((rhs_ref,) if lhs_narrow else lhs_refs):
+            _clear_past(wide, tiles[v], offsets)
+        a = (_swiglu(*lhs_refs, fed) if swiglu
+             else lhs_refs[0][...].astype(fed))
+        b = rhs_ref[...].astype(fed)
+        mine = _rows_of_visit(offsets, groups, tiles, v,
+                              (a if lhs_narrow else b).shape, tm)
+        if lhs_narrow:
             a = jnp.where(mine, a, 0)
         else:
             b = jnp.where(mine, b, 0)
@@ -362,16 +483,23 @@ def _tgmm_kernel(offsets, groups, tiles, real, lhs_ref, rhs_ref, out_ref,
 
 @functools.lru_cache(maxsize=None)
 def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
-          interpret):
+          interpret, epilogue=None, swiglu=False):
     """One of the kernels at one setting, jitted over (group sizes,
     operands...) with its list of visits. Cached, so that a model's layers
     share it (``pallas_kernels._flash_call``): the list's dozen small
     operations were a second of tracing a step with 40 call sites
-    otherwise. ``m, k, n``: rows, contraction and result width of a
-    ``moe_gmm`` (``transposed``: the right operand is [g, n, k];
-    ``moe_gmm_pair``: lhs, rhs, lhs, rhs, each pair contracting ``k``;
-    ``scaled``: a last operand [1, m] float32); of a ``moe_tgmm`` the rows
-    and the widths of its two operands. ``out_dtype``: the result's."""
+    otherwise. ``dtype``: the type the products are fed. ``m, k, n``: rows,
+    contraction and result width of a ``moe_gmm`` (``transposed``: the
+    right operand is [g, n, k]; ``moe_gmm_pair``: lhs, rhs, lhs, rhs, each
+    pair contracting ``k``; ``swiglu``: the left operand is a float32 gate
+    and up, [m, k] each; then with the ``SCALE_COTANGENT`` epilogue a
+    float32 cotangent [m, n], with ``SWIGLU_COTANGENT`` a float32 gate and
+    up [m, n]; ``scaled``: a last operand [1, m] float32); of a
+    ``moe_tgmm`` the rows and the widths of its two operands (``swiglu``:
+    the left one a gate and an up). ``out_dtype``: the result's; with
+    ``SCALE_COTANGENT`` a second result, the rows' partial sums [n // tn,
+    1, m] float32, with ``SWIGLU_COTANGENT`` two results [m, n], gate's and
+    up's."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -380,11 +508,14 @@ def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
     tm, tk, tn = plan
     visits = m // tm + g - 1
     out_dtype = jnp.dtype(out_dtype)
+    lefts = 1 + swiglu
     if kernel == "moe_tgmm":
-        body = functools.partial(_tgmm_kernel, tm=tm, visits=visits)
+        body = functools.partial(_tgmm_kernel, tm=tm, visits=visits,
+                                 swiglu=swiglu, fed=jnp.dtype(dtype))
         grid = (n // tn, k // tk, visits)
         in_specs = [
-            pl.BlockSpec((tm, tk), lambda j, i, v, o, gr, t, r: (t[v], i)),
+            pl.BlockSpec((tm, tk), lambda j, i, v, o, gr, t, r: (t[v], i))
+        ] * lefts + [
             pl.BlockSpec((tm, tn), lambda j, i, v, o, gr, t, r: (t[v], j))]
         out_specs = pl.BlockSpec(
             (None, tk, tn), lambda j, i, v, o, gr, t, r: (gr[v], i, j))
@@ -396,6 +527,7 @@ def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
         pairs = _pairs(kernel)
         body = functools.partial(
             _gmm_kernel, tm=tm, k_steps=k_steps, pairs=pairs, scaled=scaled,
+            swiglu=swiglu, epilogue=epilogue, fed=jnp.dtype(dtype),
             dims=_NT if transposed else _NN)
         grid = (n // tn, visits, k_steps)
         if transposed:
@@ -404,15 +536,24 @@ def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
         else:
             rhs = pl.BlockSpec(
                 (None, tk, tn), lambda j, v, i, o, gr, t, r: (gr[v], i, j))
-        in_specs = [
-            pl.BlockSpec((tm, tk), lambda j, v, i, o, gr, t, r: (t[v], i)),
-            rhs] * pairs
+        in_specs = ([
+            pl.BlockSpec((tm, tk), lambda j, v, i, o, gr, t, r: (t[v], i))
+        ] * lefts + [rhs]) * pairs
+        block = pl.BlockSpec((tm, tn), lambda j, v, i, o, gr, t, r: (t[v], j))
+        in_specs += [block] * {None: 0, SCALE_COTANGENT: 1,
+                               SWIGLU_COTANGENT: 2}[epilogue]
         if scaled:
             in_specs.append(pl.BlockSpec(
                 (1, tm), lambda j, v, i, o, gr, t, r: (0, t[v])))
-        out_specs = pl.BlockSpec(
-            (tm, tn), lambda j, v, i, o, gr, t, r: (t[v], j))
+        out_specs = block
         out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
+        if epilogue == SCALE_COTANGENT:
+            out_specs = [block, pl.BlockSpec(
+                (None, 1, tm), lambda j, v, i, o, gr, t, r: (j, 0, t[v]))]
+            out_shape = [out_shape, jax.ShapeDtypeStruct(
+                (n // tn, 1, m), jnp.float32)]
+        elif epilogue == SWIGLU_COTANGENT:
+            out_specs, out_shape = [block] * 2, [out_shape] * 2
         scratch = [pltpu.VMEM((tm, tn), jnp.float32)] * (k_steps > 1)
     params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"))}
@@ -427,35 +568,53 @@ def _call(kernel, dtype, out_dtype, m, k, n, g, plan, transposed, scaled,
 
 
 def _product(kernel, operands, group_sizes, plan, out_dtype,
-             transposed=False, scale=None):
-    """``kernel`` over ``operands`` (lhs, rhs; a pair: lhs, rhs, lhs, rhs)
-    at ``plan``, its result in ``out_dtype``, its rows times ``scale`` [m]
-    where there is one; counted."""
+             transposed=False, scale=None, swiglu=False, epilogue=None,
+             beside=()):
+    """``kernel`` over ``operands`` (lhs, rhs; a pair: lhs, rhs, lhs, rhs;
+    ``swiglu``: gate, up, rhs) at ``plan``, fed the right operand's type
+    (``moe_tgmm``: the narrower operand's), its result in ``out_dtype``,
+    its rows times ``scale`` [m] where there is one; counted. ``epilogue``
+    in place of the plain store, reading ``beside``: ``SCALE_COTANGENT``
+    the float32 cotangent [m, n] of the scaled product -> ``(cotangent *
+    scale`` in ``out_dtype``, the scale's cotangent [m] float32);
+    ``SWIGLU_COTANGENT`` the float32 gate and up [m, n] -> their
+    cotangents ``(g_gate, g_up)`` in ``out_dtype``."""
     import jax.numpy as jnp
 
-    lhs, rhs = operands[:2]
+    lhs, rhs = operands[0], operands[1 + swiglu]
     m, k = lhs.shape
     g = group_sizes.shape[0]
     if kernel == "moe_tgmm":
         n = rhs.shape[1]
+        fed = min(lhs.dtype, rhs.dtype, key=lambda t: t.itemsize)
     else:
         n = rhs.shape[1] if transposed else rhs.shape[2]
+        fed = rhs.dtype
+    operands += tuple(x.astype(jnp.float32) for x in beside)
     scaled = scale is not None
     if scaled:
         operands += (scale.astype(jnp.float32)[None, :],)
     out_dtype = jnp.dtype(out_dtype)
-    _took_kernel(kernel, lhs.dtype, out_dtype, scaled, plan)
-    return _call(kernel, lhs.dtype.name, out_dtype.name, m, k, n, g, plan,
-                 transposed, scaled, _pk._interpret())(group_sizes, *operands)
+    fused = "+".join(name for name in (SWIGLU if swiglu else None, epilogue)
+                     if name) or None
+    _took_kernel(kernel, fed, out_dtype, scaled, plan, fused)
+    out = _call(kernel, fed.name, out_dtype.name, m, k, n, g, plan,
+                transposed, scaled, _pk._interpret(), epilogue, swiglu)(
+                    group_sizes, *operands)
+    if epilogue == SCALE_COTANGENT:
+        scaled_cotangent, partial = out
+        return scaled_cotangent, jnp.sum(partial, axis=(0, 1))
+    return tuple(out) if epilogue else out
 
 
-def _every_row(group_sizes, m):
-    """``group_sizes`` with the rows past their end put in the last group:
-    what ``lax.ragged_dot`` needs to write every row of ``m``."""
+def _landed_rows(group_sizes, m):
+    """A ``[m, 1]`` mask of the rows inside the groups: off the kernels,
+    the rows past their end (which a kernel upstream may have left
+    unwritten) are read as zeros and written as zeros, so nothing in them
+    reaches a value or a gradient."""
     import jax.numpy as jnp
 
-    return group_sizes.at[-1].add(
-        jnp.maximum(m - jnp.sum(group_sizes), 0).astype(group_sizes.dtype))
+    return (jnp.arange(m) < jnp.sum(group_sizes))[:, None]
 
 
 def _weights_gradient(lhs, g_out, group_sizes, plan):
@@ -463,135 +622,179 @@ def _weights_gradient(lhs, g_out, group_sizes, plan):
     return _product("moe_tgmm", (lhs, g_out), group_sizes, plan, lhs.dtype)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, row_scale=None):
+def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs [m, k]`` times ``rhs [g, k, n]`` by groups of rows ->
     ``[m, n]`` float32: the first ``group_sizes[0]`` rows meet ``rhs[0]``,
     the next ``group_sizes[1]`` rows ``rhs[1]``, and so on, up to the
-    groups' end, ``sum(group_sizes) <= m`` (``moe_share_ffn``'s landed
-    count). Rows past the groups' end are UNSPECIFIED, in the value and in
-    ``lhs``'s and ``row_scale``'s cotangents. The kernels do not store
-    them (the tile the end cuts keeps what its block held there, nothing is
-    written after it) and read none of them, so nothing in ``lhs``'s or the cotangent's rows
-    past the end reaches ``rhs``'s gradient, not even a NaN; the fallback
-    (``lax.ragged_dot``, which on the chip leaves a row outside every group
-    unwritten) computes them in the last group, where they count. The
-    operands go to the MXU in their common type,
-    accumulation is float32. ``row_scale [m]`` (float32): each row of the
-    float32 total is multiplied by its scale before it is written, the
-    bits of ``grouped_matmul(lhs, rhs, group_sizes) * row_scale[:, None]``
-    without that pass over ``[m, n]``.
+    groups' end, ``sum(group_sizes) <= m``. Rows past the groups' end are
+    UNSPECIFIED, in the value and in ``lhs``'s cotangent. The kernels do
+    not store them (the tile the end cuts keeps what its block held there,
+    nothing is written after it) and read none of them, so nothing in
+    ``lhs``'s or the cotangent's rows past the end reaches ``rhs``'s
+    gradient, not even a NaN; the fallback (``lax.ragged_dot``, which on
+    the chip leaves a row outside every group unwritten) reads them as
+    zeros and writes zeros there. The operands go to the MXU in their
+    common type, accumulation is float32.
 
     The product and its gradients run as the ``moe_gmm`` / ``moe_tgmm``
     kernels (:func:`_plan` sizes their tiles from the shapes), under one
     ``jax.custom_vjp`` that keeps its operands and nothing the kernels
     make: the cotangents of ``lhs`` and ``rhs`` are written in the
-    operands' type by the kernels (``g * row_scale`` rounded to it first,
-    where there is a scale); the scale's is ``sum_c g[r, c] * (lhs .
-    rhs)[r, c]`` over the unscaled product, rebuilt by the forward kernel.
-    Routed to ``lax.ragged_dot`` (the scale a plain multiply), and counted
-    in ``pallas_kernels.FALLBACKS`` under ``moe_gmm``, when the kernels are
-    disabled, a dimension is no multiple of 128, or no tiles fit the
-    scoped VMEM. Every call site that takes a kernel is counted in
+    operands' type by the kernels. Routed to ``lax.ragged_dot``, and
+    counted in ``pallas_kernels.FALLBACKS`` under ``moe_gmm``, when the
+    kernels are disabled, a dimension is no multiple of 128, or no tiles
+    fit the scoped VMEM. Every call site that takes a kernel is counted in
     ``GMM_CALLS`` with its types and tiles."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     dtype = jnp.result_type(lhs, rhs)
     lhs, rhs = lhs.astype(dtype), rhs.astype(dtype)
     (m, k), (g, _, n) = lhs.shape, rhs.shape
     size = dtype.itemsize
-    plans = (_plan(m, k, n, g, size, scaled=row_scale is not None),
+    plans = (_plan(m, k, n, g, size),
              _plan(m, n, k, g, size, out_itemsize=size),
              _plan(m, k, n, g, size, "moe_tgmm", out_itemsize=size))
     refusal = next((why for _, why in plans if why is not None), None)
     if refusal is not None:
         _pk._fallback("moe_gmm", refusal, (m, k, n, g))
-        out = lax.ragged_dot(lhs, rhs, _every_row(group_sizes, m),
-                             preferred_element_type=jnp.float32)
-        return out if row_scale is None else out * row_scale[:, None]
+        return _ragged_dot(lhs, rhs, group_sizes)
     forward, to_lhs, to_rhs = (plan for plan, _ in plans)
 
-    def run(lhs, rhs, group_sizes, row_scale):
+    def run(lhs, rhs, group_sizes):
         return _product("moe_gmm", (lhs, rhs), group_sizes, forward,
-                        jnp.float32, scale=row_scale)
+                        jnp.float32)
 
     def fwd(*operands):
         return run(*operands), operands
 
     def bwd(kept, g_out):
-        lhs, rhs, group_sizes, row_scale = kept
-        g_scale = None
-        if row_scale is not None:
-            unscaled = run(lhs, rhs, group_sizes, None)
-            g_scale = jnp.sum(g_out * unscaled, axis=1).astype(
-                row_scale.dtype)
-            g_out = g_out * row_scale[:, None]
+        lhs, rhs, group_sizes = kept
         g_out = g_out.astype(dtype)
         g_lhs = _product("moe_gmm", (g_out, rhs), group_sizes, to_lhs, dtype,
                          transposed=True)
-        return (g_lhs, _weights_gradient(lhs, g_out, group_sizes, to_rhs),
-                None, g_scale)
+        return g_lhs, _weights_gradient(lhs, g_out, group_sizes, to_rhs), None
 
     product = jax.custom_vjp(run)
     product.defvjp(fwd, bwd)
-    return product(lhs, rhs, group_sizes, row_scale)
+    return product(lhs, rhs, group_sizes)
 
 
-def grouped_pair(lhs, rhs_a, rhs_b, group_sizes):
-    """``(grouped_matmul(lhs, rhs_a), grouped_matmul(lhs, rhs_b))``, both
-    float32, under ONE ``jax.custom_vjp``: the two forward products as they
-    are, and ``lhs``'s cotangent ``g_a . rhs_a^T + g_b . rhs_b^T`` as one
-    ``moe_gmm_pair`` call that sums both products in float32 and writes
-    the sum once, where two calls write two ``[m, k]`` results that are
-    each rounded and then added. The operands' type is the weights';
-    ``lhs`` may arrive wider (the gathered rows of a float32 activation):
-    it is rounded to the weights' type here, kept so, and its cotangent
-    is written in the type it ARRIVED in, which is the type the cotangent's
-    consumer reads (the scatter-add back to the tokens takes float32: a
-    bfloat16 result would be one more pass that widens it). The groups may
-    end before the last row, as :func:`grouped_matmul`'s: rows past their
-    end are unspecified in both values and in ``lhs``'s cotangent, and
-    nothing of them reaches a weights' gradient. Where the pair's blocks
-    fit no plan (or the kernels take nothing), two :func:`grouped_matmul`
-    calls."""
+def _ragged_dot(lhs, rhs, group_sizes):
+    """``lax.ragged_dot`` into float32, the rows past the groups' end read
+    and written as zeros (``_landed_rows``): the products' fallback."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    live = _landed_rows(group_sizes, lhs.shape[0])
+    return jnp.where(live, lax.ragged_dot(
+        jnp.where(live, lhs, 0), rhs, group_sizes,
+        preferred_element_type=jnp.float32), 0.0)
+
+
+def expert_mlp(x, tok, count, w_gate, w_up, w_down, group_sizes,
+               row_scale, keep=None):
+    """The held experts' gated MLPs over the sorted bucket, up to the sum
+    back to the tokens -> ``[R, d]`` float32: the value of ``rows =
+    x[tok]``, ``gate, up = rows . w_gate, rows . w_up`` and ``(silu(gate)
+    * up) . w_down`` times ``row_scale``, each product by groups of rows
+    (:func:`grouped_matmul`'s), under ONE ``jax.custom_vjp``, so that no
+    pass over the bucket's rows is XLA's and none goes past the landed
+    rows: the ``moe_take`` kernel gathers the first ``count`` rows in the
+    weights' type; the down product forms its operand ``silu(gate) * up``
+    from the float32 gate and up as it loads them (``SWIGLU``), so the
+    hidden is never stored, and so does its rebuilt twin for the routing
+    weight's cotangent (``SCALE_COTANGENT``) and the down weights'
+    gradient; the hidden's cotangent is never stored either: its product's
+    store writes gate's and up's cotangents through the SiLU, in the
+    weights' type (``SWIGLU_COTANGENT``), which ``moe_gmm_pair`` and
+    ``moe_tgmm`` read; ``x``'s cotangent is summed back to its rows by
+    ``moe_combine``. ``keep(gate, up)`` names the two float32 values a
+    checkpoint policy may keep (``ops/remat.py: offer``), inside the
+    forward rule, where the backward pass reads them. The rows past the
+    count are unspecified in the value, and nothing of them reaches a
+    cotangent. The weights' type is the operands'; accumulation is
+    float32. Where a plan refuses (or the kernels take nothing): that
+    formula in XLA (the gather of every row, ``lax.ragged_dot`` with the
+    rows past the count read and written as zeros, the SiLU), the refusal
+    counted once in ``FALLBACKS`` under ``moe_gmm``."""
     import jax
     import jax.numpy as jnp
 
-    dtype, arrived = jnp.result_type(rhs_a, rhs_b), lhs.dtype
-    rhs_a, rhs_b = rhs_a.astype(dtype), rhs_b.astype(dtype)
-    (m, k), (g, _, n) = lhs.shape, rhs_a.shape
-    size = dtype.itemsize
-    plans = (_plan(m, k, n, g, size),
-             _plan(m, n, k, g, size, "moe_gmm_pair",
-                   out_itemsize=arrived.itemsize),
-             _plan(m, k, n, g, size, "moe_tgmm", out_itemsize=size))
-    if any(why for _, why in plans):
-        lhs = lhs.astype(dtype)
-        return (grouped_matmul(lhs, rhs_a, group_sizes),
-                grouped_matmul(lhs, rhs_b, group_sizes))
-    forward, to_lhs, to_rhs = (plan for plan, _ in plans)
+    keep = keep or (lambda *values: values)
+    dtype = jnp.result_type(w_gate, w_up, w_down)
+    w_gate, w_up, w_down = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    (n, d), R, (g, _, f) = x.shape, tok.shape[0], w_gate.shape
+    size, f32 = dtype.itemsize, jnp.float32
+    plans = (_plan(R, d, f, g, size),
+             _plan(R, f, d, g, size, scaled=True, swiglu=True),
+             _plan(R, f, d, g, size, out_itemsize=size, scaled=True,
+                   swiglu=True, epilogue=SCALE_COTANGENT),
+             _plan(R, d, f, g, size, out_itemsize=size,
+                   epilogue=SWIGLU_COTANGENT),
+             _plan(R, f, d, g, size, "moe_tgmm", out_itemsize=size,
+                   swiglu=True),
+             _plan(R, f, d, g, size, "moe_gmm_pair",
+                   out_itemsize=x.dtype.itemsize),
+             _plan(R, d, f, g, size, "moe_tgmm", out_itemsize=size))
+    refusal = next((why for _, why in plans if why is not None), None)
+    if refusal is not None:
+        _pk._fallback("moe_gmm", refusal, (R, d, f, g))
+        rows = x[tok].astype(dtype)
+        gate, up = keep(*(_ragged_dot(rows, w, group_sizes)
+                          for w in (w_gate, w_up)))
+        hidden = (jax.nn.silu(gate) * up).astype(dtype)
+        return _ragged_dot(hidden, w_down, group_sizes) * row_scale[:, None]
+    (up_plan, down, rebuilt, to_hidden, to_down, to_rows,
+     to_gate_up) = (plan for plan, _ in plans)
+    take = _take_how(n, d, R, x.dtype, dtype)
+    back = _combine_how(n, d, R, x.dtype)
 
-    def run(lhs, rhs_a, rhs_b, group_sizes):
-        return tuple(_product("moe_gmm", (lhs.astype(dtype), rhs),
-                              group_sizes, forward, jnp.float32)
-                     for rhs in (rhs_a, rhs_b))
+    def hidden(x, tok, count, w_gate, w_up, group_sizes):
+        rows = _take(x, tok, count, dtype, *take)
+        return (rows,) + tuple(
+            _product("moe_gmm", (rows, w), group_sizes, up_plan, f32)
+            for w in (w_gate, w_up))
 
-    def fwd(lhs, *rest):
-        lhs = lhs.astype(dtype)
-        return run(lhs, *rest), (lhs,) + rest
+    def down_product(gate, up, w_down, group_sizes, row_scale):
+        return _product("moe_gmm", (gate, up, w_down), group_sizes, down,
+                        f32, scale=row_scale, swiglu=True)
+
+    def run(x, tok, count, w_gate, w_up, w_down, group_sizes, row_scale):
+        _, gate, up = hidden(x, tok, count, w_gate, w_up, group_sizes)
+        return down_product(gate, up, w_down, group_sizes, row_scale)
+
+    def fwd(x, tok, count, w_gate, w_up, w_down, group_sizes, row_scale):
+        rows, gate, up = hidden(x, tok, count, w_gate, w_up, group_sizes)
+        out = down_product(gate, up, w_down, group_sizes, row_scale)
+        # named where the forward reads them no more: ``jax.checkpoint``
+        # copies a kept value that the forward goes on to read through a
+        # ``reduce_precision`` of its own, a pass over the bucket
+        return out, (rows, *keep(gate, up), tok, count, w_gate, w_up, w_down,
+                     group_sizes, row_scale)
 
     def bwd(kept, g_out):
-        lhs, rhs_a, rhs_b, group_sizes = kept
-        g_a, g_b = (g.astype(dtype) for g in g_out)
-        g_lhs = _product("moe_gmm_pair", (g_a, rhs_a, g_b, rhs_b),
-                         group_sizes, to_lhs, arrived, transposed=True)
-        return (g_lhs, _weights_gradient(lhs, g_a, group_sizes, to_rhs),
-                _weights_gradient(lhs, g_b, group_sizes, to_rhs), None)
+        (rows, gate, up, tok, count, w_gate, w_up, w_down, group_sizes,
+         row_scale) = kept
+        g_down, g_scale = _product(
+            "moe_gmm", (gate, up, w_down), group_sizes, rebuilt, dtype,
+            scale=row_scale, swiglu=True, epilogue=SCALE_COTANGENT,
+            beside=(g_out,))
+        g_gate, g_up = _product(
+            "moe_gmm", (g_down, w_down), group_sizes, to_hidden, dtype,
+            transposed=True, epilogue=SWIGLU_COTANGENT, beside=(gate, up))
+        g_rows = _product("moe_gmm_pair", (g_gate, w_gate, g_up, w_up),
+                          group_sizes, to_rows, x.dtype, transposed=True)
+        return (_summed(g_rows, tok, n, count, *back).astype(x.dtype), None,
+                None, _weights_gradient(rows, g_gate, group_sizes, to_gate_up),
+                _weights_gradient(rows, g_up, group_sizes, to_gate_up),
+                _product("moe_tgmm", (gate, up, g_down), group_sizes, to_down,
+                         dtype, swiglu=True),
+                None, g_scale.astype(row_scale.dtype))
 
-    pair = jax.custom_vjp(run)
-    pair.defvjp(fwd, bwd)
-    return pair(lhs, rhs_a, rhs_b, group_sizes)
+    mlp = jax.custom_vjp(run)
+    mlp.defvjp(fwd, bwd)
+    return mlp(x, tok, count, w_gate, w_up, w_down, group_sizes, row_scale)
 
 
 #: the most scoped VMEM a ``moe_combine`` call may name: three quarters of
@@ -609,6 +812,40 @@ def _combine_vmem(n, tb, dc):
     a block and ``dc`` columns a slab: the ``[n, dc]`` float32 sum of the
     slab and the rows' ``[tb, dc]`` block twice over for the pipeline."""
     return 4 * dc * (n + 2 * tb)
+
+
+def _take_vmem(n, tb, dc, out_itemsize):
+    """Bytes of scoped VMEM ``moe_take`` asks for: the ``[n, dc]`` float32
+    slab of the source, the result's ``[tb, dc]`` block twice over, and
+    where the result is narrower a float32 block the rows are gathered
+    into before they are rounded."""
+    return dc * (4 * n + 2 * tb * out_itemsize
+                 + (4 * tb if out_itemsize < 4 else 0))
+
+
+def _slab_plan(n, d, rows, dtype, vmem, least=8):
+    """``((tb, dc), vmem_limit, refusal)`` of a kernel that holds a float32
+    ``[n, dc]`` slab of ``n`` tokens' rows in VMEM while it walks ``rows``
+    bucket rows in blocks of ``tb`` (at least ``least``): the widest slab
+    whose ``vmem(tb, dc)`` fits ``_COMBINE_CAP``, under a limit the call
+    names where it passes Mosaic's default ``_VMEM_LIMIT``. Or why the
+    rows go to XLA (a ``FALLBACKS`` reason)."""
+    import jax.numpy as jnp
+
+    if not _pk.enabled():
+        return None, None, "disabled"
+    tb = next((t for t in (512, 256, 128, 64, 32, 16, 8)
+               if t >= least and rows % t == 0), None)
+    if jnp.dtype(dtype) != jnp.float32 or d % 128 or n % 8 or tb is None:
+        return None, None, "untileable"
+    mib = 1024 * 1024
+    for dc in _divisors(d):
+        need = vmem(tb, dc)
+        if need <= _pk._VMEM_LIMIT:
+            return (tb, dc), None, None
+        if need <= _COMBINE_CAP:
+            return (tb, dc), -(-need // mib) * mib, None
+    return None, None, "vmem"
 
 
 def _combine_plan(n, d, rows, dtype):
@@ -629,22 +866,20 @@ def _combine_plan(n, d, rows, dtype):
     16,384 of 2304: 512 x 2304 0.37 / 0.37, 512 x 768 0.65 / 0.58, the
     scatter-add 2.06. Or why the sum goes to XLA (a ``FALLBACKS``
     reason)."""
+    return _slab_plan(n, d, rows, dtype,
+                      lambda tb, dc: _combine_vmem(n, tb, dc))
+
+
+def _take_plan(n, d, rows, dtype, out_dtype):
+    """``((tb, dc), vmem_limit, refusal)`` of ``moe_take`` gathering
+    ``rows`` bucket rows of width ``d`` from ``n`` float32 token rows into
+    ``out_dtype``, as :func:`_combine_plan` plans the sum back (blocks of
+    16 rows at the least, a bfloat16 sublane tile)."""
     import jax.numpy as jnp
 
-    if not _pk.enabled():
-        return None, None, "disabled"
-    tb = next((t for t in (512, 256, 128, 64, 32, 16, 8) if rows % t == 0),
-              None)
-    if jnp.dtype(dtype) != jnp.float32 or d % 128 or n % 8 or tb is None:
-        return None, None, "untileable"
-    mib = 1024 * 1024
-    for dc in _divisors(d):
-        need = _combine_vmem(n, tb, dc)
-        if need <= _pk._VMEM_LIMIT:
-            return (tb, dc), None, None
-        if need <= _COMBINE_CAP:
-            return (tb, dc), -(-need // mib) * mib, None
-    return None, None, "vmem"
+    size = jnp.dtype(out_dtype).itemsize
+    return _slab_plan(n, d, rows, dtype,
+                      lambda tb, dc: _take_vmem(n, tb, dc, size), least=16)
 
 
 def _combine_kernel(tok, count, rows_ref, out_hbm, acc, done, *, tb, dc):
@@ -724,6 +959,109 @@ def _combine_call(n, d, rows, plan, limit, interpret):
     return jax.jit(lambda rows_, tok, count: call(tok, count, rows_))
 
 
+def _take_kernel(tok, count, x_hbm, out_ref, held, done, *stage, tb, dc):
+    """One block of ``tb`` bucket rows of ``moe_take`` in one column slab:
+    each of its rows below ``count`` copied from its token's row of the
+    slab, which one DMA brings into VMEM at the slab's first block, then
+    rounded into the result's block where that is narrower (through the
+    float32 ``stage``). A block past the count copies nothing; its index
+    map holds the last block that has a row below the count, so nothing
+    is written back for it."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slab, block = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(block == 0)
+    def _load():
+        copy = pltpu.make_async_copy(
+            x_hbm.at[:, pl.ds(pl.multiple_of(slab * dc, 128), dc)], held,
+            done)
+        copy.start()
+        copy.wait()
+
+    rows_ref = stage[0] if stage else out_ref
+
+    def take(r, carry):
+        rows_ref[pl.ds(r, 1), :] = held[pl.ds(tok[block * tb + r], 1), :]
+        return carry
+
+    def take_unrolled(i, carry):
+        for u in range(_UNROLL):
+            take(i * _UNROLL + u, carry)
+        return carry
+
+    here = jnp.clip(count[0] - block * tb, 0, tb)
+    whole = here // _UNROLL
+    lax.fori_loop(0, whole, take_unrolled, 0)
+    lax.fori_loop(whole * _UNROLL, here, take, 0)
+    if stage:
+        def narrow(c, carry):
+            at = pl.multiple_of(c * 16, 16)
+            out_ref[pl.ds(at, 16), :] = stage[0][pl.ds(at, 16), :].astype(
+                out_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, (here + 15) // 16, narrow, 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _take_call(n, d, rows, out_dtype, plan, limit, interpret):
+    """``moe_take`` at one setting, jitted over (x [n, d] float32, the rows'
+    tokens [rows] int32, how many rows to gather [1] int32) -> [rows, d]
+    ``out_dtype``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tb, dc = plan
+    out_dtype = jnp.dtype(out_dtype)
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=limit)}
+    call = pl.pallas_call(
+        functools.partial(_take_kernel, tb=tb, dc=dc),
+        out_shape=jax.ShapeDtypeStruct((rows, d), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(d // dc, rows // tb),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            # a block past the count holds the block of row count - 1
+            out_specs=pl.BlockSpec((tb, dc), lambda s, b, tok, count: (
+                jnp.minimum(b, jnp.maximum(count[0] - 1, 0) // tb), s)),
+            scratch_shapes=[pltpu.VMEM((n, dc), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]
+            + [pltpu.VMEM((tb, dc), jnp.float32)] * (out_dtype.itemsize < 4)),
+        interpret=interpret, name="moe_take", **params)
+    return jax.jit(lambda x, tok, count: call(tok, count, x))
+
+
+def _take(x, tok, count, dtype, plan, limit):
+    """``x[tok]`` in ``dtype``, the rows from ``count`` on UNSPECIFIED:
+    ``moe_take`` at ``plan``, counted, or with no plan XLA's gather (of
+    every row)."""
+    import jax.numpy as jnp
+
+    if plan is None:
+        return x[tok].astype(dtype)
+    dtype = jnp.dtype(dtype)
+    _took_kernel("moe_take", x.dtype, dtype, False, plan)
+    return _take_call(x.shape[0], x.shape[1], tok.shape[0], dtype.name, plan,
+                      limit, _pk._interpret())(
+        x, tok, jnp.full((1,), count, jnp.int32))
+
+
+def _take_how(n, d, rows, dtype, out_dtype):
+    """``_take_plan``'s plan and limit, having counted a refusal in
+    ``FALLBACKS`` (the plan is then None)."""
+    plan, limit, refusal = _take_plan(n, d, rows, dtype, out_dtype)
+    if refusal is not None:
+        _pk._fallback("moe_take", refusal, (n, d, rows))
+    return plan, limit
+
+
 def _summed(rows, tok, n, count, plan, limit):
     """The first ``count`` of ``rows`` summed back to their ``n`` tokens:
     ``moe_combine`` at ``plan``, counted, or with no plan the scatter-add,
@@ -759,42 +1097,25 @@ def combine(rows, tok, n, count):
     landed count: the rows past it are what the grouped products leave
     unspecified). The ``moe_combine``
     kernel (:func:`_combine_plan` sizes it from ``(n, d, R)``); its
-    transpose, under a ``jax.custom_vjp``, is the gather ``g[tok]`` (in the
-    rows past the count too, whose cotangent is then unspecified). Kernels
-    off, a type other than float32 or a width that is no multiple of 128:
-    the scatter-add itself, counted in ``pallas_kernels.FALLBACKS`` under
+    transpose, under a ``jax.custom_vjp``, is the gather ``g[tok]`` by the
+    ``moe_take`` kernel, which writes the first ``count`` rows alone (the
+    cotangent of the rows past the count is unspecified). Kernels off, a
+    type other than float32 or a width that is no multiple of 128: the
+    scatter-add itself, counted in ``pallas_kernels.FALLBACKS`` under
     ``moe_combine``."""
     import jax
 
-    plan, limit = _combine_how(n, rows.shape[1], rows.shape[0], rows.dtype)
+    R, d = rows.shape
+    plan, limit = _combine_how(n, d, R, rows.dtype)
     if plan is None:
         return _summed(rows, tok, n, count, None, None)
+    take = _take_how(n, d, R, rows.dtype, rows.dtype)
 
     def summed(rows, tok, count):
         return _summed(rows, tok, n, count, plan, limit)
 
     run = jax.custom_vjp(summed)
-    run.defvjp(lambda rows, tok, count: (summed(rows, tok, count), tok),
-               lambda tok, g: (g[tok], None, None))
+    run.defvjp(lambda rows, tok, count: (summed(rows, tok, count),
+                                         (tok, count)),
+               lambda kept, g: (_take(g, *kept, g.dtype, *take), None, None))
     return run(rows, tok, count)
-
-
-def take_rows(x, tok, count):
-    """``x[tok]`` (``x [n, d]``, ``tok [R]``) whose transpose, the rows'
-    cotangent summed back to the tokens, is the ``moe_combine`` kernel in
-    place of XLA's scatter-add (:func:`combine`'s transpose pair): it sums
-    the first ``count`` rows' cotangents alone and reads none of the others
-    (kernel or scatter-add); the gather itself is of every row."""
-    import jax
-
-    n, d = x.shape
-    plan, limit = _combine_how(n, d, tok.shape[0], x.dtype)
-
-    @jax.custom_vjp
-    def run(x, tok, count):
-        return x[tok]
-
-    run.defvjp(lambda x, tok, count: (x[tok], (tok, count)),
-               lambda kept, g: (_summed(g, kept[0], n, kept[1], plan, limit),
-                                None, None))
-    return run(x, tok, count)

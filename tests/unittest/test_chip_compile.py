@@ -303,19 +303,23 @@ def _grouped_operands(one_chip, m, k, n, g):
             jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip))
 
 
-@pytest.mark.parametrize("product", ["up", "down", "pair"])
+@pytest.mark.parametrize("product", ["up", "down", "mlp"])
 @pytest.mark.parametrize("cell", sorted(GROUPED_CELLS))
 def test_grouped_products_compile(one_chip, cell, product):
     """The expert layer's grouped products at the four cells' shapes, each
     in all its directions at the tiles ``_plan`` picks, counted, nothing
-    routed to XLA. ``up``: gate or up alone ([rows, d] x [g, d, f]);
-    ``down`` ([rows, f] x [g, f, d]) with the routing weight in its store,
-    and rebuilt without it for the weight's cotangent; ``pair``: gate and
-    up under one rule over float32 rows, as ``moe_share_ffn`` hands them
-    over, the input's cotangent as ONE float32 ``moe_gmm_pair``. Forward
-    results float32; a bfloat16 input's cotangent (the right operand read
-    transposed) and the weights' gradient in bfloat16, rounded in the
-    kernels' stores."""
+    routed to XLA. ``up``: one product at gate's or up's shape ([rows, d]
+    x [g, d, f]); ``down``: one at the down product's ([rows, f] x [g, f,
+    d]); ``mlp``: ``expert_mlp``, as ``moe_share_ffn`` runs it: the
+    tokens' rows gathered, gate and up, the down product fed ``silu(gate)
+    * up`` as it loads them, the routing weight in its store, rebuilt with
+    the ``scale_cotangent`` epilogue for the weight's cotangent (its own
+    plan: the cotangent's float32 block beside the operands) and in the
+    down weights' gradient, the hidden's cotangent written as gate's and
+    up's through the SiLU, and the input's cotangent through gate and up
+    as ONE float32 ``moe_gmm_pair``. Forward results float32; a bfloat16
+    input's cotangent (the right operand read transposed) and the weights'
+    gradient in bfloat16, rounded in the kernels' stores."""
     import jax
     import jax.numpy as jnp
 
@@ -326,17 +330,16 @@ def test_grouped_products_compile(one_chip, cell, product):
     k, n = (f, d) if product == "down" else (d, f)
     lhs, rhs, sizes = _grouped_operands(one_chip, m, k, n, g)
     scale = jax.ShapeDtypeStruct((m,), jnp.float32, sharding=one_chip)
-    if product == "pair":
-        lhs = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    if product == "mlp":  # the tokens' rows; the bucket's are gathered
+        lhs = jax.ShapeDtypeStruct((8192, d), jnp.float32, sharding=one_chip)
+    tok = jnp.arange(m, dtype=jnp.int32) % 8192
 
     def loss(a, b, scale, sizes):
-        if product == "pair":
-            # two right operands that XLA cannot fold into one
-            gate, up = gm.grouped_pair(a, b, jnp.flip(b, 0), sizes)
-            out = gate + 2 * up  # and two cotangents
+        if product == "mlp":
+            out = gm.expert_mlp(a, tok, jnp.sum(sizes), b, jnp.flip(b, 0),
+                                jnp.swapaxes(b, 1, 2), sizes, scale)
         else:
-            out = gm.grouped_matmul(
-                a, b, sizes, row_scale=scale if product == "down" else None)
+            out = gm.grouped_matmul(a, b, sizes)
         return jnp.sum(out), out
 
     routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
@@ -345,22 +348,38 @@ def test_grouped_products_compile(one_chip, cell, product):
     new = {key: count - took.get(key, 0)
            for key, count in gm.GMM_CALLS.items() if count != took.get(key)}
     bf, f32 = "bfloat16", "float32"
-    forward = gm._plan(m, k, n, g, 2, scaled=product == "down")[0]
+    forward = gm._plan(m, k, n, g, 2)[0]
     to_rhs = gm._plan(m, k, n, g, 2, "moe_tgmm", out_itemsize=2)[0]
-    if product == "pair":
-        want = {("moe_gmm", bf, f32, False, forward): 2,
-                ("moe_gmm_pair", bf, f32, False,
+    if product == "mlp":
+        fused = gm.SWIGLU + "+" + gm.SCALE_COTANGENT
+        want = {("moe_gmm", bf, f32, False, None, forward): 2,
+                ("moe_gmm", bf, f32, True, gm.SWIGLU, gm._plan(
+                    m, n, k, g, 2, scaled=True, swiglu=True)[0]): 1,
+                ("moe_gmm", bf, bf, True, fused, gm._plan(
+                    m, n, k, g, 2, out_itemsize=2, scaled=True, swiglu=True,
+                    epilogue=gm.SCALE_COTANGENT)[0]): 1,
+                ("moe_gmm", bf, bf, False, gm.SWIGLU_COTANGENT, gm._plan(
+                    m, k, n, g, 2, out_itemsize=2,
+                    epilogue=gm.SWIGLU_COTANGENT)[0]): 1,
+                ("moe_gmm_pair", bf, f32, False, None,
                  gm._plan(m, n, k, g, 2, "moe_gmm_pair")[0]): 1,
-                ("moe_tgmm", bf, bf, False, to_rhs): 2}
+                ("moe_tgmm", bf, bf, False, None, to_rhs): 2,
+                ("moe_tgmm", bf, bf, False, gm.SWIGLU, gm._plan(
+                    m, n, k, g, 2, "moe_tgmm", out_itemsize=2,
+                    swiglu=True)[0]): 1,
+                ("moe_take", f32, bf, False, None, gm._take_plan(
+                    8192, d, m, jnp.float32, jnp.bfloat16)[0]): 1,
+                ("moe_combine", f32, f32, False, None, gm._combine_plan(
+                    8192, d, m, jnp.float32)[0]): 1}
     else:
-        want = {("moe_gmm", bf, f32, product == "down", forward): 1,
-                ("moe_gmm", bf, bf, False,
+        want = {("moe_gmm", bf, f32, False, None, forward): 1,
+                ("moe_gmm", bf, bf, False, None,
                  gm._plan(m, n, k, g, 2, out_itemsize=2)[0]): 1,
-                ("moe_tgmm", bf, bf, False, to_rhs): 1}
-        if product == "down":
-            want[("moe_gmm", bf, f32, False, forward)] = 1
+                ("moe_tgmm", bf, bf, False, None, to_rhs): 1}
     assert new == want
-    named = {kernel: sum(kernel in ln for ln in calls)
+    # by the instruction's own name: a line may take another kernel's
+    # result as an operand, and name it there
+    named = {kernel: sum(kernel in ln.split("=")[0] for ln in calls)
              for kernel in ("moe_gmm", "moe_gmm_pair", "moe_tgmm")}
     named["moe_gmm"] -= named["moe_gmm_pair"]  # a pair's line names both
     assert named == {kernel: sum(count for key, count in want.items()
@@ -424,10 +443,11 @@ COMBINE_CELLS = {"mellum2": (8192, 2304, 65536),
 
 @pytest.mark.parametrize("cell", sorted(COMBINE_CELLS))
 def test_combine_compiles(one_chip, cell):
-    """``moe_combine`` at the four cells' shapes, as ``combine``'s forward
-    and as ``take_rows``' transpose, each stopping at a landed count, under
-    the limit its plan names (the whole width's sum in VMEM, past Mosaic's
-    default)."""
+    """``moe_combine`` at the four cells' shapes, as ``combine``'s forward,
+    stopping at a landed count, under the limit its plan names (the whole
+    width's sum in VMEM, past Mosaic's default); ``moe_take`` as
+    ``combine``'s transpose, under the limit its plan names (the whole
+    width of the tokens' rows in VMEM)."""
     import jax
     import jax.numpy as jnp
 
@@ -441,14 +461,17 @@ def test_combine_compiles(one_chip, cell):
               jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
               jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
 
-    def loss(values, x, tok, landed):
-        return (jnp.sum(gm.combine(values, tok, n, landed))
-                + jnp.sum(gm.take_rows(x, tok, landed) * values))
+    def loss(values, weight, tok, landed):
+        return jnp.sum(gm.combine(values, tok, n, landed) * weight)
 
     routed = dict(pk.FALLBACKS)
-    calls = _kernels(jax.value_and_grad(loss, argnums=(0, 1)), *shapes)
-    assert len(calls) == 2 and all("moe_combine" in ln for ln in calls)
+    calls = _kernels(jax.value_and_grad(loss), *shapes)
+    assert len(calls) == 2
+    assert sum("moe_combine" in ln for ln in calls) == 1
+    assert sum("moe_take" in ln for ln in calls) == 1
     assert gm._combine_plan(n, d, rows, f32)[1] > pk._VMEM_LIMIT
+    for out in (f32, jnp.bfloat16):
+        assert gm._take_plan(n, d, rows, f32, out)[1] > pk._VMEM_LIMIT
     assert pk.FALLBACKS == routed
 
 
@@ -457,7 +480,11 @@ def test_a_mellum2_layer_sums_back_by_the_kernel(one_chip):
     (checkpointed, ``moe_sort`` and ``moe_hidden`` kept), compiled: the
     forward's sum back to the tokens and the input's cotangent's are the
     two ``moe_combine`` calls, and no scatter over [8192, 2304] is left
-    (XLA's sort, permute and sorted scatter of ISSUE 39)."""
+    (XLA's sort, permute and sorted scatter); the gathers are ``moe_take``
+    calls, and nothing over the whole bucket is XLA's: no gather of
+    [65536, 2304] rows, no reduction of the routing weight's cotangent
+    over [65536] rows and no fusion over the [65536, 896] hidden (the SiLU
+    and its gradient are in the products' loads and stores)."""
     import jax
     import jax.numpy as jnp
 
@@ -483,3 +510,9 @@ def test_a_mellum2_layer_sums_back_by_the_kernel(one_chip):
                for ln in lines) == 2
     assert not [ln for ln in lines
                 if " scatter(" in ln and "f32[8192,2304]" in ln]
+    assert sum("tpu_custom_call" in ln and "moe_take" in ln
+               for ln in lines) >= 2
+    assert not [ln for ln in lines if " gather(" in ln and "[65536,2304]" in ln]
+    assert not [ln for ln in lines if " reduce(" in ln and "f32[65536]" in ln]
+    assert not [ln for ln in lines if " fusion(" in ln
+                and "[65536,896]" in ln.split(" fusion(")[0]]

@@ -203,12 +203,16 @@ def _new_calls(took):
             if n != took.get(key, 0)}
 
 
-@pytest.mark.parametrize("entry", ["plain", "scaled", "pair"])
+@pytest.mark.parametrize("entry", ["plain", "mlp"])
 def test_gmm_calls_records_plan_types_and_scale_of_each_direction(entry):
     """The forward products write float32, every cotangent the operands'
-    type; only the down product's forward store scales (its rebuilt,
-    unscaled twin feeds the scale's cotangent); the pair's input cotangent
-    is ONE call over two right-hand blocks."""
+    type. ``mlp``: ``expert_mlp``'s down product's forward store scales,
+    fed ``silu(gate) * up`` as it loads them, and its rebuilt twin stores
+    no product: its ``scale_cotangent`` epilogue writes the cotangent times
+    the scale in the operands' type (and the scale's cotangent beside it);
+    the hidden's cotangent is written through the SiLU; the input's
+    cotangent through gate and up is ONE call over two right-hand
+    blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -219,23 +223,41 @@ def test_gmm_calls_records_plan_types_and_scale_of_each_direction(entry):
     scale = jnp.linspace(0.0, 1.0, M)
     bf, f32 = "bfloat16", "float32"
     took = dict(gm.GMM_CALLS)
-    if entry == "pair":
-        jax.make_jaxpr(jax.grad(lambda a, b: sum(
-            jnp.sum(out * weight) for out in gm.grouped_pair(a, b, b, sizes)),
-            argnums=(0, 1)))(lhs, rhs)
-        want = {("moe_gmm", bf, f32, False, (128, K, N)): 2,
-                ("moe_gmm_pair", bf, bf, False, (128, N, K)): 1,
-                ("moe_tgmm", bf, bf, False, (128, K, N)): 2}
+    if entry == "mlp":
+        x = lhs[:160].astype(jnp.float32)  # 160 tokens of width K
+        tok = jnp.arange(M, dtype=jnp.int32) % 160
+        jax.make_jaxpr(jax.grad(lambda x, b, s: jnp.sum(gm.expert_mlp(
+            x, tok, jnp.int32(M), b, jnp.flip(b, 0), jnp.swapaxes(b, 1, 2),
+            sizes, s)), argnums=(0, 1, 2)))(x, rhs, scale)
+
+        def tiles(k, n, kernel="moe_gmm", **how):
+            return gm._plan(M, k, n, 4, 2, kernel, **how)[0]
+
+        want = {("moe_gmm", bf, f32, False, None, tiles(K, N)): 2,
+                ("moe_gmm", bf, f32, True, gm.SWIGLU,
+                 tiles(N, K, scaled=True, swiglu=True)): 1,
+                ("moe_gmm", bf, bf, True, gm.SWIGLU + "+" + gm.SCALE_COTANGENT,
+                 tiles(N, K, out_itemsize=2, scaled=True, swiglu=True,
+                       epilogue=gm.SCALE_COTANGENT)): 1,
+                ("moe_gmm", bf, bf, False, gm.SWIGLU_COTANGENT,
+                 tiles(K, N, out_itemsize=2,
+                       epilogue=gm.SWIGLU_COTANGENT)): 1,
+                ("moe_gmm_pair", bf, f32, False, None,
+                 tiles(N, K, "moe_gmm_pair")): 1,
+                ("moe_tgmm", bf, bf, False, None,
+                 tiles(K, N, "moe_tgmm", out_itemsize=2)): 2,
+                ("moe_tgmm", bf, bf, False, gm.SWIGLU,
+                 tiles(N, K, "moe_tgmm", out_itemsize=2, swiglu=True)): 1,
+                ("moe_take", f32, bf, False, None,
+                 gm._take_plan(160, K, M, jnp.float32, bf)[0]): 1,
+                ("moe_combine", f32, f32, False, None,
+                 gm._combine_plan(160, K, M, jnp.float32)[0]): 1}
     else:
-        scaled = entry == "scaled"
-        jax.make_jaxpr(jax.grad(lambda a, b, s: jnp.sum(gm.grouped_matmul(
-            a, b, sizes, row_scale=s if scaled else None) * weight),
-            argnums=(0, 1, 2)))(lhs, rhs, scale)
-        want = {("moe_gmm", bf, f32, scaled, (128, K, N)): 1,  # fwd
-                ("moe_gmm", bf, bf, False, (128, N, K)): 1,    # d lhs
-                ("moe_tgmm", bf, bf, False, (128, K, N)): 1}   # d rhs
-        if scaled:  # the unscaled product, rebuilt for the scale's cotangent
-            want[("moe_gmm", bf, f32, False, (128, K, N))] = 1
+        jax.make_jaxpr(jax.grad(lambda a, b: jnp.sum(gm.grouped_matmul(
+            a, b, sizes) * weight), argnums=(0, 1)))(lhs, rhs)
+        want = {("moe_gmm", bf, f32, False, None, (128, K, N)): 1,  # fwd
+                ("moe_gmm", bf, bf, False, None, (128, N, K)): 1,  # d lhs
+                ("moe_tgmm", bf, bf, False, None, (128, K, N)): 1}  # d rhs
     assert _new_calls(took) == want
 
 
@@ -251,13 +273,18 @@ CELLS = {"mellum2": (8192, 2304, 896, 64, (0, 16), 8, "softmax", 0),
 def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
     """``moe_share_ffn``'s gradient traced (nothing runs) at a cell's
     shapes. Float32: the three forward products (the down product's with
-    its scale), the unscaled down product rebuilt for the routing weight's
-    cotangent, and the input's cotangent through gate and up, ONE pair
-    whose consumer, the scatter-add back to the float32 tokens, reads
-    float32. In the operands' type: the hidden's cotangent (the SiLU's
-    gradient rounds it) and every weights' gradient. No ``moe_gmm`` result
-    on a cotangent path is float32. Both sums back to the tokens, the
-    forward's and the input's cotangent's, are ``moe_combine``."""
+    its scale, fed ``silu(gate) * up`` as it loads gate and up: the hidden
+    is never stored), the hidden's cotangent written through the SiLU as
+    gate's and up's cotangents in the operands' type
+    (``swiglu_cotangent``), and the input's cotangent through gate and up,
+    ONE pair whose consumer, the sum back to the float32 tokens, reads
+    float32. In the operands' type: the down product rebuilt with the
+    ``scale_cotangent`` epilogue (the cotangent times the routing weight,
+    and the weight's cotangent beside it: no float32 product is stored)
+    and every weights' gradient. Both sums back to the tokens, the
+    forward's and the input's cotangent's, are ``moe_combine``; both
+    gathers, the tokens' rows in the operands' type and the output's
+    cotangent's rows in float32, are ``moe_take``."""
     import jax
     import jax.numpy as jnp
 
@@ -281,25 +308,36 @@ def test_a_traced_layer_writes_each_result_as_its_consumer_reads_it(cell):
     def tiles(k, n, kernel="moe_gmm", **how):
         return gm._plan(m, k, n, g, 2, kernel, **how)[0]
 
-    down = tiles(ff, d, scaled=True)
+    down = tiles(ff, d, scaled=True, swiglu=True)
+    rebuilt = tiles(ff, d, scaled=True, out_itemsize=2, swiglu=True,
+                    epilogue=gm.SCALE_COTANGENT)
     combine = gm._combine_plan(tokens, d, m, jnp.float32)[0]
     new = _new_calls(took)
     # counted per trace: twice where the sorted path is a ``lax.cond``'s
     # branch (the Kimi cell's bucket is smaller than all that could land)
-    traces = new[("moe_gmm", bf, f32, True, down)]
+    traces = new[("moe_gmm", bf, f32, True, gm.SWIGLU, down)]
     assert traces == (2 if cell == "kimi" else 1)
     assert {key: n / traces for key, n in new.items()} == {
-        ("moe_gmm", bf, f32, False, tiles(d, ff)): 2,       # gate, up
-        ("moe_gmm", bf, f32, True, down): 1,                # down
-        ("moe_gmm", bf, f32, False, down): 1,         # down, rebuilt
-        ("moe_gmm", bf, bf, False, tiles(d, ff, out_itemsize=2)): 1,
-        ("moe_gmm_pair", bf, f32, False, tiles(ff, d, "moe_gmm_pair")): 1,
-        ("moe_tgmm", bf, bf, False,
+        ("moe_gmm", bf, f32, False, None, tiles(d, ff)): 2,    # gate, up
+        ("moe_gmm", bf, f32, True, gm.SWIGLU, down): 1,        # down
+        ("moe_gmm", bf, bf, True, gm.SWIGLU + "+" + gm.SCALE_COTANGENT,
+         rebuilt): 1,
+        ("moe_gmm", bf, bf, False, gm.SWIGLU_COTANGENT,
+         tiles(d, ff, out_itemsize=2, epilogue=gm.SWIGLU_COTANGENT)): 1,
+        ("moe_gmm_pair", bf, f32, False, None,
+         tiles(ff, d, "moe_gmm_pair")): 1,
+        ("moe_tgmm", bf, bf, False, None,
          tiles(d, ff, "moe_tgmm", out_itemsize=2)): 2,
-        ("moe_tgmm", bf, bf, False,
-         tiles(ff, d, "moe_tgmm", out_itemsize=2)): 1,
+        ("moe_tgmm", bf, bf, False, gm.SWIGLU,
+         tiles(ff, d, "moe_tgmm", out_itemsize=2, swiglu=True)): 1,
         # the forward's sum back to the tokens and the input's cotangent's
-        ("moe_combine", f32, f32, False, combine): 2}
+        ("moe_combine", f32, f32, False, None, combine): 2,
+        # the tokens' rows gathered in the operands' type, and the
+        # output's cotangent gathered to the bucket's rows
+        ("moe_take", f32, bf, False, None,
+         gm._take_plan(tokens, d, m, jnp.float32, bf)[0]): 1,
+        ("moe_take", f32, f32, False, None,
+         gm._take_plan(tokens, d, m, jnp.float32, f32)[0]): 1}
     assert pk.FALLBACKS == routed
 
 
@@ -413,6 +451,7 @@ LANDED = {
     "on_a_tile_edge": [128, 0, 0, 256],      # ends at 384
     "one_row": [0, 1, 0, 0],
     "nothing": [0, 0, 0, 0],
+    "every_row": [100, 200, 77, 263],       # all 640: nothing past the end
 }
 
 
@@ -425,16 +464,14 @@ def _past_the_end(x, end, fill):
 
 
 @pytest.mark.parametrize("plan", sorted(PLANS))
-@pytest.mark.parametrize("entry", ["plain", "scaled", "pair"])
 @pytest.mark.parametrize("layout", sorted(LANDED))
-def test_rows_past_the_groups_end_are_never_read(layout, entry, plan,
-                                                 monkeypatch):
+def test_rows_past_the_groups_end_are_never_read(layout, plan, monkeypatch):
     """Groups that end at a landed count, with every operand's rows past it
-    (the left operand, the cotangent, the row scale) NaN: the value and the
-    left operand's (and the scale's) cotangent on the landed rows, and
-    every weights' gradient, are those of the rule before, the rest of the
-    rows in the last group under zeros, and every weights' gradient is
-    finite: no kernel read a row past the count."""
+    (the left operand, the cotangent) NaN: the value and the left operand's
+    cotangent on the landed rows, and the weights' gradient, are those of
+    the rule before, the rest of the rows in the last group under zeros,
+    and the weights' gradient is finite: no kernel read a row past the
+    count (``expert_mlp``'s passes: its own test below)."""
     import jax
     import jax.numpy as jnp
 
@@ -448,37 +485,99 @@ def test_rows_past_the_groups_end_are_never_read(layout, entry, plan,
     end = int(sizes.sum())
     padded = sizes.at[-1].add(M - end)
     lhs, rhs, weight = _operands("bfloat16", n=256)
-    lhs = lhs.astype(jnp.float32) if entry == "pair" else lhs
-    scale = jax.random.uniform(jax.random.PRNGKey(5), (M,), jnp.float32)
-
-    def products(a, b, s, sizes):
-        if entry == "pair":
-            gate, up = gm.grouped_pair(a, b, jnp.flip(b, 0) * 0.5, sizes)
-            return gate + 2 * up
-        return gm.grouped_matmul(a, b, sizes,
-                                 row_scale=s if entry == "scaled" else None)
 
     def value_and_grads(fill, sizes):
-        def loss(a, b, s):
-            out = products(a, b, s, sizes)
+        def loss(a, b):
+            out = gm.grouped_matmul(a, b, sizes)
             return jnp.sum(out * _past_the_end(weight, end, fill)), out
 
-        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
                                              has_aux=True)(
-            _past_the_end(lhs, end, fill), rhs,
-            _past_the_end(scale, end, fill))
+            _past_the_end(lhs, end, fill), rhs)
         return [np.asarray(x, np.float64) for x in (out,) + grads]
 
     routed = dict(pk.FALLBACKS)
     got = value_and_grads(np.nan, sizes)
     want = value_and_grads(0.0, padded)
     assert pk.FALLBACKS == routed
-    (out, d_lhs, d_rhs, d_scale), (w_out, w_lhs, w_rhs, w_scale) = got, want
+    (out, d_lhs, d_rhs), (w_out, w_lhs, w_rhs) = got, want
     assert np.array_equal(out[:end], w_out[:end])
     assert np.array_equal(d_lhs[:end], w_lhs[:end])
     assert np.isfinite(d_rhs).all() and np.array_equal(d_rhs, w_rhs)
-    if entry == "scaled":
-        assert np.array_equal(d_scale[:end], w_scale[:end])
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+@pytest.mark.parametrize("layout", sorted(LANDED))
+def test_the_expert_mlp_is_the_composition_it_replaces(layout, plan,
+                                                       monkeypatch):
+    """``expert_mlp``: the gathered rows, gate and up, ``silu(gate) * up``
+    and the down product under the rows' scale, against ``x[tok]`` and
+    ``lax.ragged_dot`` over the hidden rounded to the weights' type: the
+    value on the landed rows, and the cotangents of the tokens, the three
+    weights and the scale (float32 accumulation either way; the kernels'
+    SiLU may round a hidden element to its other bfloat16 neighbour, and
+    the hidden's cotangent is not rounded before the SiLU's gradient here,
+    hence the tolerances), with the scale and the output's cotangent NaN
+    past the
+    count: every gradient is finite, so nothing read a row past it. Every
+    pass by a kernel: the hidden formed on load, the rebuilt product's and
+    the hidden cotangent's epilogues, both gathers and the sum back."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    if PLANS[plan]:
+        monkeypatch.setattr(gm, "_plan",
+                            lambda *a, **kw: (PLANS[plan], None))
+    sizes = jnp.asarray(LANDED[layout], jnp.int32)
+    end, n = int(sizes.sum()), 160
+    padded = sizes.at[-1].add(M - end)
+    _, w_gate, weight = _operands("bfloat16", n=256)
+    w_up = jnp.flip(w_gate, axis=0) * 0.5
+    w_down = jnp.swapaxes(w_gate, 1, 2) * 0.25
+    keys = jax.random.split(jax.random.PRNGKey(17), 2)
+    x = jax.random.normal(keys[0], (n, K))
+    scale = jax.random.uniform(keys[1], (M,), jnp.float32)
+    tok = jnp.asarray(np.random.RandomState(16).randint(0, n, M), jnp.int32)
+
+    def fused(x, a, b, c, s, sizes):
+        return gm.expert_mlp(x, tok, jnp.int32(end), a, b, c, sizes, s)
+
+    def xla(x, a, b, c, s, sizes):
+        rows = x[tok].astype(a.dtype)
+        gate, up = _ragged_dot(rows, a, sizes), _ragged_dot(rows, b, sizes)
+        hidden = (jax.nn.silu(gate) * up).astype(c.dtype)
+        return _ragged_dot(hidden, c, sizes) * s[:, None]
+
+    def value_and_grads(mlp, fill, sizes):
+        def loss(x, a, b, c, s):
+            out = mlp(x, a, b, c, s, sizes)
+            return jnp.sum(out * _past_the_end(weight, end, fill)), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+                x, w_gate, w_up, w_down, _past_the_end(scale, end, fill))
+        return [np.asarray(v, np.float64) for v in (out,) + grads]
+
+    routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
+    got = value_and_grads(fused, np.nan, sizes)
+    assert pk.FALLBACKS == routed
+    new = _new_calls(took)
+    assert {key[4] for key in new} == {
+        None, gm.SWIGLU, gm.SWIGLU_COTANGENT,
+        gm.SWIGLU + "+" + gm.SCALE_COTANGENT}
+    assert {key[:3] for key in new if key[0] in ("moe_take", "moe_combine")
+            } == {("moe_take", "float32", "bfloat16"),
+                  ("moe_combine", "float32", "float32")}
+    want = value_and_grads(xla, 0.0, padded)
+    for a, b in zip(got[1:5], want[1:5]):  # the tokens' and weights'
+        assert np.isfinite(a).all()
+        _close(a, b, 1e-2)
+    if end:
+        _close(got[0][:end], want[0][:end], 1e-3)
+        _close(got[5][:end], want[5][:end], 1e-3)
 
 
 def _scaled_ragged_dot(a, b, sizes, scale):
@@ -489,41 +588,32 @@ def _scaled_ragged_dot(a, b, sizes, scale):
 @pytest.mark.parametrize("layout", ["tail_in_last", "empty_middle",
                                     "inside_tiles"])
 def test_the_rows_scale_in_the_store(layout, dtype, rel):
-    """``row_scale``: the bits of the unscaled product times the scale (the
-    same float32 multiply, made before the one write), zero rows under a
-    zero scale, and the gradients of all three operands as
-    ``lax.ragged_dot``'s under the plain multiply."""
+    """The rows' scale in ``moe_gmm``'s store (``expert_mlp``'s down
+    product): the bits of the unscaled product times the scale (the same
+    float32 multiply, made before the one write), zero rows under a zero
+    scale, and ``lax.ragged_dot``'s value under the plain multiply."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import grouped_matmul as gm
 
-    lhs, rhs, weight = _operands(dtype)
+    lhs, rhs, _ = _operands(dtype)
     sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
     scale = jax.random.uniform(jax.random.PRNGKey(5), (M,), jnp.float32)
     scale = jnp.where(jnp.arange(M) < M - 100, scale, 0.0)  # an empty tail
-
-    def value_and_grads(product):
-        def loss(a, b, s):
-            out = product(a, b, sizes, s)
-            return jnp.sum(out * weight), out
-
-        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
-                                             has_aux=True)(lhs, rhs, scale)
-        return [np.asarray(x, np.float64) for x in (out,) + grads]
-
-    got = value_and_grads(lambda a, b, sizes, s: gm.grouped_matmul(
-        a, b, sizes, row_scale=s))
-    plain = np.asarray(gm.grouped_matmul(lhs, rhs, sizes)
-                       * scale[:, None], np.float64)
-    assert np.array_equal(got[0], plain) and not got[0][M - 100:].any()
-    want = value_and_grads(_scaled_ragged_dot)
-    _close(got[0], want[0], 1e-5)
-    for a, b in zip(got[1:], want[1:]):
-        assert a.shape == b.shape
-        _close(a, b, rel)
-    # under a zero scale a row asks nothing of the left operand
-    assert not got[1][M - 100:].any()
+    size = jnp.dtype(dtype).itemsize
+    took = dict(gm.GMM_CALLS)
+    got = np.asarray(gm._product(
+        "moe_gmm", (lhs, rhs), sizes, gm._plan(M, K, N, 4, size,
+                                               scaled=True)[0],
+        jnp.float32, scale=scale), np.float64)
+    assert {key[3] for key in _new_calls(took)} == {True}
+    plain = np.asarray(gm._product(
+        "moe_gmm", (lhs, rhs), sizes, gm._plan(M, K, N, 4, size)[0],
+        jnp.float32) * scale[:, None], np.float64)
+    assert np.array_equal(got, plain) and not got[M - 100:].any()
+    _close(got, np.asarray(_scaled_ragged_dot(lhs, rhs, sizes, scale),
+                           np.float64), 1e-5)
 
 
 @pytest.mark.parametrize("arrived", ["bfloat16", "float32"])
@@ -531,70 +621,61 @@ def test_the_rows_scale_in_the_store(layout, dtype, rel):
 @pytest.mark.parametrize("layout", ["tail_in_last", "empty_middle",
                                     "inside_tiles"])
 def test_the_pairs_input_cotangent_is_the_float32_sum_written_once(
-        layout, plan, arrived, monkeypatch):
-    """``grouped_pair``: both values as two ``grouped_matmul`` calls give
-    them; both weights' gradients as the two calls'; the input's cotangent
-    the float32 sum of the two products in the type the input ARRIVED in:
-    for float32 rows that sum itself, for bfloat16 rows the sum rounded
-    once (to the last bit where the contraction is one step: the sum is
-    then the same float32 addition), nearer the float32 sum than two
-    roundings and an add are."""
-    import jax
+        layout, plan, arrived):
+    """``moe_gmm_pair`` (the input's cotangent through gate and up in
+    ``expert_mlp``): the float32 sum of the two products ``g_a . rhs_a^T +
+    g_b . rhs_b^T`` in the type the input ARRIVED in: for float32 rows that
+    sum itself, for bfloat16 rows the sum rounded once (to the last bit
+    where the contraction is one step: the sum is then the same float32
+    addition), nearer the float32 sum than two roundings and an add
+    are."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import grouped_matmul as gm
 
-    if PLANS[plan]:
-        monkeypatch.setattr(gm, "_plan",
-                            lambda *a, **kw: (PLANS[plan], None))
     bf = jnp.bfloat16
-    lhs, rhs_a, weight_a = _operands("bfloat16", n=256)
-    lhs = lhs.astype(arrived)  # values bfloat16 holds, so nothing is lost
+    _, rhs_a, weight_a = _operands("bfloat16", n=256)
     rhs_b = jnp.flip(rhs_a, axis=0) * 0.5
     weight_b = jnp.flip(weight_a, axis=1)
+    g_a, g_b = weight_a.astype(bf), weight_b.astype(bf)
     sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
-
-    def loss(products):
-        def of(a, b, c):
-            gate, up = products(a, b, c)
-            return jnp.sum(gate * weight_a) + jnp.sum(up * weight_b), (
-                gate, up)
-        return of
-
+    arrived = jnp.dtype(arrived)
+    tiles = PLANS[plan] or gm._plan(M, 256, K, 4, 2, "moe_gmm_pair",
+                                    out_itemsize=arrived.itemsize)[0]
+    assert (256 // tiles[1] > 1) == (plan == "stepped")
     took = dict(gm.GMM_CALLS)
-    (_, got), got_grads = jax.value_and_grad(
-        loss(lambda a, b, c: gm.grouped_pair(a, b, c, sizes)),
-        argnums=(0, 1, 2), has_aux=True)(lhs, rhs_a, rhs_b)
+    d_lhs = gm._product("moe_gmm_pair", (g_a, rhs_a, g_b, rhs_b), sizes,
+                        tiles, arrived, transposed=True)
     assert {key[2]: n for key, n in _new_calls(took).items()
-            if key[0] == "moe_gmm_pair"} == {arrived: 1}
-    (_, want), want_grads = jax.value_and_grad(
-        loss(lambda a, b, c: (gm.grouped_matmul(a.astype(bf), b, sizes),
-                              gm.grouped_matmul(a.astype(bf), c, sizes))),
-        argnums=(0, 1, 2), has_aux=True)(lhs, rhs_a, rhs_b)
-    for a, b in zip(got + got_grads[1:], want + want_grads[1:]):
-        assert a.dtype == b.dtype and np.array_equal(
-            np.asarray(a, np.float32), np.asarray(b, np.float32))
-    tiles = gm._plan(M, 256, K, 4, 2, out_itemsize=2)[0]
-    halves = [gm._product("moe_gmm", (g.astype(bf), rhs), sizes, tiles,
-                          jnp.float32, transposed=True)
-              for g, rhs in ((weight_a, rhs_a), (weight_b, rhs_b))]
+            if key[0] == "moe_gmm_pair"} == {arrived.name: 1}
+    assert d_lhs.dtype == arrived and d_lhs.shape == (M, K)
+    one = gm._plan(M, 256, K, 4, 2, out_itemsize=2)[0]
+    halves = [gm._product("moe_gmm", (g, rhs), sizes, one, jnp.float32,
+                          transposed=True)
+              for g, rhs in ((g_a, rhs_a), (g_b, rhs_b))]
     exact = np.asarray((halves[0] + halves[1]).astype(arrived), np.float32)
-    d_lhs = np.asarray(got_grads[0], np.float32)
-    assert got_grads[0].dtype == jnp.dtype(arrived)
+    d_lhs = np.asarray(d_lhs, np.float32)
     if plan == "one_step":
         assert np.array_equal(d_lhs, exact)
-    _close(d_lhs, exact, 1e-2 if arrived == "bfloat16" else 1e-5)
+    _close(d_lhs, exact, 1e-2 if arrived == bf else 1e-5)
     wide = np.asarray(halves[0] + halves[1], np.float64)
-    twice = np.asarray(want_grads[0], np.float64)
+    twice = np.asarray(halves[0].astype(bf) + halves[1].astype(bf),
+                       np.float64)
     assert np.abs(d_lhs - wide).sum() <= np.abs(twice - wide).sum()
 
 
+@pytest.mark.parametrize("layout", sorted(LANDED))
 @pytest.mark.parametrize("switch,reason", [("0", "disabled"),
                                            ("1", "untileable")])
 def test_scale_and_pair_off_the_kernels_are_ragged_dots(switch, reason,
-                                                        monkeypatch):
-    """The fallback keeps today's arithmetic: the scale a plain multiply,
-    the pair two products, each counted in ``FALLBACKS``."""
+                                                        layout, monkeypatch):
+    """``expert_mlp`` off the kernels is its formula in XLA: the gather of
+    every row, gate and up as two ``lax.ragged_dot``, the SiLU, the down
+    product's ``lax.ragged_dot`` and the scale a plain multiply; the value
+    on the landed rows and every gradient to the bit, the rows past the
+    count (scale and output cotangent NaN there) read as zeros, so every
+    gradient is finite; the refusal counted ONCE, under ``moe_gmm``, and no
+    kernel taken."""
     import jax
     import jax.numpy as jnp
 
@@ -602,26 +683,49 @@ def test_scale_and_pair_off_the_kernels_are_ragged_dots(switch, reason,
     from mxnet_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setenv("MXNET_PALLAS", switch)
-    n = N if reason == "disabled" else 96
-    lhs, rhs, weight = _operands("float32", n=n)
-    sizes = jnp.asarray(LAYOUTS["tail_in_last"], jnp.int32)
-    scale = jnp.linspace(0.0, 2.0, M)
+    f = N if reason == "disabled" else 96
+    sizes = jnp.asarray(LANDED[layout], jnp.int32)
+    end, n = int(sizes.sum()), 160
+    keys = jax.random.split(jax.random.PRNGKey(18), 5)
+    x = jax.random.normal(keys[0], (n, K))
+    w_gate, w_up = (jax.random.normal(k, (4, K, f)) for k in keys[1:3])
+    w_down = jax.random.normal(keys[3], (4, f, K))
+    scale = _past_the_end(jax.random.uniform(keys[4], (M,)), end, np.nan)
+    weight = _past_the_end(jnp.ones((M, K)), end, np.nan)
+    tok = jnp.asarray(np.random.RandomState(19).randint(0, n, M), jnp.int32)
+    live = (jnp.arange(M) < end)[:, None]
+
+    def xla(x, a, b, c, s):
+        rows = jnp.where(live, x[tok], 0.0)
+        gate, up = (jnp.where(live, _ragged_dot(rows, w, sizes), 0.0)
+                    for w in (a, b))
+        out = jnp.where(live, _ragged_dot(jax.nn.silu(gate) * up, c, sizes),
+                        0.0)
+        return out * s[:, None]
+
+    def value_and_grads(mlp):
+        def loss(*operands):
+            out = mlp(*operands)
+            return jnp.sum(jnp.where(live, out * weight, 0.0)), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+                x, w_gate, w_up, w_down, scale)
+        return [np.asarray(v) for v in (out,) + grads]
+
     before = pk.FALLBACKS.get(("moe_gmm", reason), 0)
-    took = dict(gm.GMM_CALLS)
-
-    def grads(product):
-        return jax.grad(lambda a, b, s: jnp.sum(product(a, b, s) * weight),
-                        argnums=(0, 1, 2))(lhs, rhs, scale)
-
-    got = grads(lambda a, b, s: gm.grouped_matmul(a, b, sizes, row_scale=s))
-    want = grads(lambda a, b, s: _scaled_ragged_dot(a, b, sizes, s))
-    for a, b in zip(got, want):
-        assert np.array_equal(a, b)
-    gate, up = gm.grouped_pair(lhs, rhs, 2 * rhs, sizes)
-    assert np.array_equal(gate, _ragged_dot(lhs, rhs, sizes))
-    assert np.array_equal(up, _ragged_dot(lhs, 2 * rhs, sizes))
-    assert pk.FALLBACKS[("moe_gmm", reason)] == before + 3
+    routed, took = dict(pk.FALLBACKS), dict(gm.GMM_CALLS)
+    got = value_and_grads(lambda x, a, b, c, s: gm.expert_mlp(
+        x, tok, jnp.int32(end), a, b, c, sizes, s))
+    assert pk.FALLBACKS[("moe_gmm", reason)] == before + 1
+    assert {key: n - routed.get(key, 0) for key, n in pk.FALLBACKS.items()
+            if n != routed.get(key, 0)} == {("moe_gmm", reason): 1}
     assert gm.GMM_CALLS == took
+    want = value_and_grads(xla)
+    assert np.array_equal(got[0][:end], want[0][:end])
+    for a, b in zip(got[1:5], want[1:5]):  # the tokens' and weights'
+        assert np.isfinite(a).all() and np.array_equal(a, b)
+    assert np.array_equal(got[5][:end], want[5][:end])
 
 
 @pytest.mark.parametrize("shape,kernel,want", [
@@ -707,9 +811,8 @@ def _scatter_add(rows, tok, n):
 def test_the_combine_pair_is_the_scatter_add_and_the_gather(routing):
     """Counting every row: ``combine`` gives the value of
     ``zeros.at[tok].add(rows)`` and its rows' cotangent is the gather
-    ``g[tok]``; ``take_rows`` gives ``x[tok]`` and its cotangent the
-    scatter-add of the rows' cotangent; both by the kernel, counted,
-    nothing routed to XLA."""
+    ``g[tok]``; the sum by ``moe_combine``, the gather by ``moe_take``,
+    counted, nothing routed to XLA."""
     import jax
     import jax.numpy as jnp
 
@@ -724,26 +827,24 @@ def test_the_combine_pair_is_the_scatter_add_and_the_gather(routing):
     want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
     _close(np.asarray(got), np.asarray(want), 1e-6)
     assert np.array_equal(back(g)[0], want_back(g)[0])
-    x = jax.random.normal(jax.random.PRNGKey(8), (n, rows.shape[1]))
-    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, every), x)
-    assert np.array_equal(took_x, x[tok])
-    _close(np.asarray(back_x(rows)[0]), np.asarray(want), 1e-6)
     # a token none of whose rows is in the bucket gets zeros
     empty = np.setdiff1d(np.arange(n), np.asarray(tok))
     assert not np.asarray(got)[empty].any()
     plan = gm._combine_plan(n, rows.shape[1], rows.shape[0],
                             jnp.float32)[0]
+    take = gm._take_plan(n, rows.shape[1], rows.shape[0], jnp.float32,
+                         jnp.float32)[0]
     assert _new_calls(took) == {
-        ("moe_combine", "float32", "float32", False, plan): 2}
+        ("moe_combine", "float32", "float32", False, None, plan): 1,
+        ("moe_take", "float32", "float32", False, None, take): 1}
     assert pk.FALLBACKS == routed
 
 
 @pytest.mark.parametrize("routing", sorted(ROUTINGS))
 def test_the_combine_pair_stops_at_the_landed_count(routing):
     """Given the landed count, with the rows past it NaN: ``combine`` is the
-    scatter-add of the landed rows and its cotangent on them the gather;
-    ``take_rows`` gathers every row and its cotangent is the scatter-add of
-    the landed rows' cotangents. Both by the kernel."""
+    scatter-add of the landed rows and its cotangent on them the gather
+    (the rest unspecified). Both by the kernels."""
     import jax
     import jax.numpy as jnp
 
@@ -759,22 +860,21 @@ def test_the_combine_pair_stops_at_the_landed_count(routing):
     _close(np.asarray(got), np.asarray(want), 1e-6)
     g = jax.random.normal(jax.random.PRNGKey(7), (n, rows.shape[1]))
     assert np.array_equal(back(g)[0][:landed], g[tok][:landed])
-    x = jax.random.normal(jax.random.PRNGKey(8), (n, rows.shape[1]))
-    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, count), x)
-    assert np.array_equal(took_x, x[tok])
-    _close(np.asarray(back_x(nan)[0]), np.asarray(want), 1e-6)
     plan = gm._combine_plan(n, rows.shape[1], rows.shape[0],
                             jnp.float32)[0]
+    take = gm._take_plan(n, rows.shape[1], rows.shape[0], jnp.float32,
+                         jnp.float32)[0]
     assert _new_calls(took) == {
-        ("moe_combine", "float32", "float32", False, plan): 2}
+        ("moe_combine", "float32", "float32", False, None, plan): 1,
+        ("moe_take", "float32", "float32", False, None, take): 1}
     assert pk.FALLBACKS == routed
 
 
 @pytest.mark.parametrize("routing", ["empty_middle", "all_held"])
 def test_off_the_kernel_the_landed_count_still_holds(routing, monkeypatch):
     """Kernels off: the scatter-add, counted, reads no row past the count
-    either (they are sent out of range and dropped), in ``combine`` and in
-    ``take_rows``' transpose."""
+    either (they are sent out of range and dropped), and its transpose is
+    the gather ``g[tok]``."""
     import jax
     import jax.numpy as jnp
 
@@ -787,12 +887,12 @@ def test_off_the_kernel_the_landed_count_still_holds(routing, monkeypatch):
     before = pk.FALLBACKS.get(("moe_combine", "disabled"), 0)
     took = dict(gm.GMM_CALLS)
     want = _scatter_add(rows[:landed], tok[:landed], n)
-    assert np.array_equal(gm.combine(nan, tok, n, jnp.int32(landed)), want)
-    x = jnp.ones((n, rows.shape[1]))
-    took_x, back_x = jax.vjp(lambda x: gm.take_rows(x, tok, landed), x)
-    assert np.array_equal(took_x, x[tok])
-    assert np.array_equal(back_x(nan)[0], want)
-    assert pk.FALLBACKS[("moe_combine", "disabled")] == before + 2
+    got, back = jax.vjp(lambda r: gm.combine(r, tok, n, jnp.int32(landed)),
+                        nan)
+    assert np.array_equal(got, want)
+    g = jax.random.normal(jax.random.PRNGKey(7), (n, rows.shape[1]))
+    assert np.array_equal(back(g)[0][:landed], g[tok][:landed])
+    assert pk.FALLBACKS[("moe_combine", "disabled")] == before + 1
     assert gm.GMM_CALLS == took
 
 
@@ -817,8 +917,7 @@ def test_inside_a_cond_the_combine_runs_as_outside():
         return of
 
     every = jnp.int32(rows.shape[0])
-    kernel = loss(lambda r: gm.combine(r, tok, n, every),
-                  lambda x: gm.take_rows(x, tok, every))
+    kernel = loss(lambda r: gm.combine(r, tok, n, every), lambda x: x[tok])
     xla = loss(lambda r: _scatter_add(r, tok, n), lambda x: x[tok])
     x = jax.random.normal(jax.random.PRNGKey(10), (n, rows.shape[1]))
     took = dict(gm.GMM_CALLS)
@@ -829,9 +928,10 @@ def test_inside_a_cond_the_combine_runs_as_outside():
     assert gm.GMM_CALLS != took
 
 
-def _row_blocks_read(call, rows, tok, count):
+def _row_blocks_read(call, rows, tok, count, which=0):
     """The row block a ``moe_combine`` call's index map gives each step of
-    its grid, slab by slab: the ``pallas_call``'s own map, evaluated."""
+    its grid, slab by slab (``which``: the block mapping, -1 the result's
+    of a ``moe_take`` call): the ``pallas_call``'s own map, evaluated."""
     import jax
     from jax._src.state.discharge import discharge_state
 
@@ -847,7 +947,7 @@ def _row_blocks_read(call, rows, tok, count):
 
     mapping = found(jax.make_jaxpr(call)(rows, tok, count).jaxpr).params[
         "grid_mapping"]
-    index_map = mapping.block_mappings[0].index_map_jaxpr
+    index_map = mapping.block_mappings[which].index_map_jaxpr
     jaxpr, consts = discharge_state(index_map.jaxpr, index_map.consts)
     slabs, blocks = mapping.grid
     return [[int(jax.core.eval_jaxpr(jaxpr, consts, s, b, tok, count)[0])
@@ -896,6 +996,43 @@ def test_the_combines_grid_is_static_and_it_reads_the_landed_blocks(
            np.asarray(_scatter_add(rows[:landed], tok[:landed], n)), 1e-6)
 
 
+#: landed counts of a 1024-row bucket in blocks of 128: name -> count
+TAKE_COUNTS = {"nothing": 0, "cuts_a_block": 300, "every_row": 1024}
+
+
+@pytest.mark.parametrize("out", ["float32", "bfloat16"])
+@pytest.mark.parametrize("count", sorted(TAKE_COUNTS))
+def test_the_take_kernel_writes_the_landed_rows_alone(count, out,
+                                                      monkeypatch):
+    """``moe_take``: the first ``count`` rows are ``x[tok]`` in the
+    result's type to the last bit (one rounding of the float32 row), in
+    every column slab; the grid is static, and the row blocks its result's
+    index map writes stop at the block of the last landed row (each later
+    step holds it, so nothing more is written back; VMEM made scarce here,
+    so that a slab is a third of the width; blocks of 128 rows)."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_VMEM_LIMIT", 1024 * 1024)
+    monkeypatch.setattr(gm, "_COMBINE_CAP", 1024 * 1024)
+    rows, tok, n, _ = _bucket("empty_middle", d=384)
+    R, landed = rows.shape[0], TAKE_COUNTS[count]
+    (tb, dc), limit, _ = gm._take_plan(n, 384, R, jnp.float32, out)
+    assert dc == 128
+    call = gm._take_call(n, 384, R, out, (128, dc), limit, True)
+    x = rows[:n] * 3.0 + 0.1
+    at = jnp.full((1,), landed, jnp.int32)
+    got = call(x, tok, at)
+    assert got.shape == (R, 384) and got.dtype == jnp.dtype(out)
+    assert np.array_equal(np.asarray(got[:landed], np.float32),
+                          np.asarray(x[tok][:landed].astype(out), np.float32))
+    last = max(landed - 1, 0) // 128
+    assert _row_blocks_read(call, x, tok, at, which=-1) == [
+        [min(b, last) for b in range(R // 128)]] * 3
+
+
 @pytest.mark.parametrize("switch,d,dtype,reason", [
     ("0", 256, "float32", "disabled"),
     ("1", 96, "float32", "untileable"),    # no multiple of 128 wide
@@ -920,10 +1057,7 @@ def test_what_the_combine_cannot_take_is_the_scatter_add_counted(
     want, want_back = jax.vjp(lambda r: _scatter_add(r, tok, n), rows)
     assert np.array_equal(got, want)
     assert np.array_equal(back(g)[0], want_back(g)[0])
-    x = jnp.ones((n, d), dtype)
-    assert np.array_equal(jax.vjp(lambda x: gm.take_rows(x, tok, every),
-                                  x)[1](rows)[0], want)
-    assert pk.FALLBACKS[("moe_combine", reason)] == before + 2
+    assert pk.FALLBACKS[("moe_combine", reason)] == before + 1
     assert gm.GMM_CALLS == took
 
 
