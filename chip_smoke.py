@@ -366,7 +366,7 @@ def phase_c(jax, size, seed, on_chip):
             if n != took.get(key, 0)}
     say("  flash kernels taken (kernel, operands, tiles visited/masked/"
         "square): %s", took)
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in ("flash_fwd", "flash_bwd_dqkv"):
         sites = {key: n for key, n in took.items() if key[0] == name}
         check(sum(sites.values()) == cfg.num_layers
               and all(operand == str(np.dtype(cfg.dtype))
@@ -381,7 +381,7 @@ def phase_c(jax, size, seed, on_chip):
             params, opt_state, batch, key).compile().as_text()
         calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
         found = {name: sum("/%s/" % name in ln for ln in calls)
-                 for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+                 for name in ("flash_fwd", "flash_bwd_dqkv")}
         say("  tpu_custom_call in the compiled step: %d %s", len(calls),
             found)
         # forward() unrolls its layers: one of each kernel per layer

@@ -247,7 +247,7 @@ def _took_kernel(kernel, dtype, tiles, vmem_limit=None):
         _tel.counter("pallas.kernel_total.%s.%s" % (kernel, operand)).inc()
 
 
-# The three kernels build their score tiles transposed, st[k, q]: k
+# The kernels build their score tiles transposed, st[k, q]: k
 # positions down the sublanes, q positions along the lanes. What is kept
 # per q position (running max and sum, lse, dcap) is then a lane-dense
 # row that broadcasts down the sublanes as it is stored, and the
@@ -439,8 +439,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                          dk_ref, dv_ref, *, scale, causal, block, step,
-                          n_steps, window=None):
+                          *refs, scale, causal, block, step, n_steps,
+                          window=None):
     """dK/dV for one block of ``block`` k positions: stream q/dO ``step``
     positions at a time, accumulate dO^T.p and q^T.ds (flash-attention
     backward, k side). Causal: the block's own ``block // step`` steps
@@ -450,11 +450,24 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
     that loop ends at the last step that holds a query which sees the
     block, and the steps the window's trailing edge crosses are masked
     (``_k_side_steps``). One q head's part: under grouped queries the
-    results are float32 and the group is summed outside."""
+    results are float32 and the group is summed outside.
+
+    ``refs`` is ``(dk_ref, dv_ref)``, or ``(dq_ref, dk_ref, dv_ref,
+    dq_acc)`` for ``flash_bwd_dqkv``, the whole backward in one kernel:
+    each visited tile also adds ds^T.k into its q rows of ``dq_acc``, a
+    float32 [tq, d] scratch that lives across the head's k blocks (the
+    grid runs them in order), zeroed at the first and written to the
+    head's resident dQ block at the last. dQ is one q head's own: under
+    grouped queries nothing is summed. It is [tq, d] and not [d, tq] as
+    the other accumulators: k^T.ds, which reads the k block transposed
+    where the scores read it as it is, stops the TPU compiler with an
+    internal error at d = 128 and 1024-row blocks (AOT)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
+    dq_ref, dk_ref, dv_ref, dq_acc = (
+        refs if len(refs) == 4 else (None,) + refs + (None,))
     op = _operand_dtype(q_ref.dtype)
     fold = _fold_scale(q_ref.dtype, scale)
     col0 = pl.program_id(1) * block
@@ -462,6 +475,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
     if fold:
         kblk = kblk * scale
     vblk = v_ref[0].astype(op)   # [block, dv]
+    if dq_ref is not None:
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def tile(q0, carry, hi=block, diagonal=False, edge=False):
         dk, dv = carry  # [d, block], [dv, block]
@@ -485,6 +502,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         dpt = _dot(vblk[:hi], do, _NT)
         dst = (pt * (dpt - dcap)).astype(op)
         dk_new = dk[:, :hi] + _dot(q, dst, _TT)
+        if dq_ref is not None:
+            dq_acc[lanes, :] += _dot(dst, kblk[:hi], _TN)
         return _put_lanes(dk, dk_new, 0, hi), _put_lanes(dv, dv_new, 0, hi)
 
     carry = (jnp.zeros(kblk.shape[::-1], jnp.float32),
@@ -503,12 +522,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
     # dk = scale * ds^T.q whether the scale went into k or into the scores
     dk_ref[0] = (dk * scale).T.astype(dk_ref.dtype)
     dv_ref[0] = dv.T.astype(dv_ref.dtype)
+    if dq_ref is not None:
+        @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+        def _():
+            # dq = scale * ds.k, the scale already in kblk where it was
+            # folded; out a step of q positions at a time, so that no
+            # second [tq, d] float32 value is held beside the accumulator
+            def put(j, carry):
+                rows = pl.ds(pl.multiple_of(j * step, step), step)
+                part = dq_acc[rows, :]
+                if not fold:
+                    part = part * scale
+                dq_ref[0, rows, :] = part.astype(dq_ref.dtype)
+                return carry
+
+            lax.fori_loop(0, n_steps, put, 0)
 
 
 @functools.lru_cache(maxsize=None)
 def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
                 interpret, group=1, window=None, vmem_limit=None):
-    """One of the three kernels at one setting, as a jitted pallas_call.
+    """One of the four kernels at one setting, as a jitted pallas_call.
     Cached, so that a model's layers share it: its body is then traced
     and lowered once a program and not once a call site (24 layers are
     72 sites; a warm gpt2-medium set-up spent 1.2 s more on them than
@@ -516,9 +550,12 @@ def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
 
     ``group`` q heads read one k/v head (``bh`` counts q heads; k and v
     come with ``bh // group``): the index maps send program ``i`` to k/v
-    head ``i // group``, nothing is copied. The dkv kernel then gives one
-    q head's part, in float32, for the caller to sum over the group. With
-    a ``window`` the call is named ``flash_win_*``, so that a trace tells
+    head ``i // group``, nothing is copied. The dkv and dqkv kernels then
+    give one q head's dk and dv, in float32, for the caller to sum over
+    the group. dqkv's dQ block is the head's whole [tq, d], resident
+    across the k blocks of the grid's second axis, which Mosaic runs in
+    order (its default, "arbitrary"), as the accumulator needs. With a
+    ``window`` the call is named ``flash_win_*``, so that a trace tells
     the windowed layers' device time from the others'. ``vmem_limit``
     (``_flash_plan`` names it where no block fits under the default) is
     handed to Mosaic as the call's scoped-VMEM limit; None passes no
@@ -546,8 +583,13 @@ def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
                             lambda i, j: (kv_head(i), j, 0))
 
     stats = pl.BlockSpec((1, 8, block), lambda i, j: (i, 0, j))
-    own, other = (tk, tq) if name == "flash_bwd_dkv" else (tq, tk)
+    k_side = name in ("flash_bwd_dkv", "flash_bwd_dqkv")
+    own, other = (tk, tq) if k_side else (tq, tk)
     part = dtype if group == 1 else jnp.float32  # one q head's dk and dv
+    k_side_in = [whole(tq, d), blocked_kv(d), blocked_kv(dv), whole(tq, dv),
+                 whole(8, tq), whole(8, tq)]
+    dkv_out = (jax.ShapeDtypeStruct((bh, tk, d), part),
+               jax.ShapeDtypeStruct((bh, tk, dv), part))
     body, in_specs, out_specs, out_shape = {
         "flash_fwd": (
             _flash_fwd_kernel,
@@ -562,20 +604,22 @@ def _flash_call(name, dtype, bh, tq, tk, d, dv, causal, scale, block, step,
             blocked(d),
             jax.ShapeDtypeStruct((bh, tq, d), dtype)),
         "flash_bwd_dkv": (
-            _flash_bwd_dkv_kernel,
-            [whole(tq, d), blocked_kv(d), blocked_kv(dv), whole(tq, dv),
-             whole(8, tq), whole(8, tq)],
-            (blocked(d), blocked(dv)),
-            (jax.ShapeDtypeStruct((bh, tk, d), part),
-             jax.ShapeDtypeStruct((bh, tk, dv), part))),
+            _flash_bwd_dkv_kernel, k_side_in, (blocked(d), blocked(dv)),
+            dkv_out),
+        "flash_bwd_dqkv": (
+            _flash_bwd_dkv_kernel, k_side_in,
+            (whole(tq, d), blocked(d), blocked(dv)),
+            (jax.ShapeDtypeStruct((bh, tq, d), dtype),) + dkv_out),
     }[name]
+    scratch = ([pltpu.VMEM((tq, d), jnp.float32)]
+               if name == "flash_bwd_dqkv" else [])
     params = {} if vmem_limit is None or interpret else {
         "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)}
     return jax.jit(pl.pallas_call(
         functools.partial(body, scale=scale, causal=causal, block=block,
                           step=step, n_steps=other // step, window=window),
         out_shape=out_shape, grid=(bh, own // block), in_specs=in_specs,
-        out_specs=out_specs, interpret=interpret,
+        out_specs=out_specs, scratch_shapes=scratch, interpret=interpret,
         name=_kernel_name(name, window), **params))
 
 
@@ -601,12 +645,17 @@ def _flash_attention_pallas(q, k, v, causal, scale, block, step, window=None,
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                block, step, window=None, vmem_limit=None):
-    """Blockwise backward: neither pass materialises the [T, T] score
-    matrix in HBM — the cliff the dense-vjp fallback hits at long T.
-    Under grouped queries dk and dv come from the kernel a q head at a
-    time in float32 and are summed over each group here, before their one
-    rounding to the operands' type."""
+                                block, step, window=None, vmem_limit=None,
+                                fused=True):
+    """Blockwise backward: no kernel materialises the [T, T] score matrix
+    in HBM — the cliff the dense-vjp fallback hits at long T. ``fused``:
+    one ``flash_bwd_dqkv`` call, which builds each tile's S and dP once
+    for all three gradients (5 products a tile); otherwise the
+    ``flash_bwd_dq`` + ``flash_bwd_dkv`` pair, which builds them in each
+    (7), where the plan keeps it (``_flash_plan``). Under grouped
+    queries dk and dv come from the kernel a q head at a time in float32
+    and are summed over each group here, before their one rounding to
+    the operands' type."""
     import jax.numpy as jnp
 
     b, h, tq, d = q.shape
@@ -620,19 +669,28 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
                     jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                             axis=-1).reshape(bh, 1, tq), (bh, 8, tq)))
     setting = (q.dtype.name, bh, tq, tk, d, dv, causal, scale)
-    _took_kernel(_kernel_name("flash_bwd_dq", window), q.dtype,
-                 _tile_counts(tq, tk, step, causal, window, block),
-                 vmem_limit)
-    dq = _flash_call("flash_bwd_dq", *setting, block, step,
-                     _interpret(), group, window, vmem_limit)(*operands)
     # the k side owns blocks of k and steps through q: the same two
     # sizes, fitted to the other length where the two differ
-    block, step, _ = _select_blocks(tk, tq, block, step)
-    _took_kernel(_kernel_name("flash_bwd_dkv", window), q.dtype,
-                 _tile_counts(tk, tq, step, causal, window, block,
-                              k_side=True), vmem_limit)
-    dk, dv_ = _flash_call("flash_bwd_dkv", *setting, block, step,
-                          _interpret(), group, window, vmem_limit)(*operands)
+    k_block, k_step, _ = _select_blocks(tk, tq, block, step)
+    k_tiles = _tile_counts(tk, tq, k_step, causal, window, k_block,
+                           k_side=True)
+    if fused:
+        _took_kernel(_kernel_name("flash_bwd_dqkv", window), q.dtype,
+                     k_tiles, vmem_limit)
+        dq, dk, dv_ = _flash_call(
+            "flash_bwd_dqkv", *setting, k_block, k_step, _interpret(), group,
+            window, vmem_limit)(*operands)
+    else:
+        _took_kernel(_kernel_name("flash_bwd_dq", window), q.dtype,
+                     _tile_counts(tq, tk, step, causal, window, block),
+                     vmem_limit)
+        dq = _flash_call("flash_bwd_dq", *setting, block, step,
+                         _interpret(), group, window, vmem_limit)(*operands)
+        _took_kernel(_kernel_name("flash_bwd_dkv", window), q.dtype,
+                     k_tiles, vmem_limit)
+        dk, dv_ = _flash_call("flash_bwd_dkv", *setting, k_block, k_step,
+                              _interpret(), group, window,
+                              vmem_limit)(*operands)
     if group > 1:
         dk, dv_ = (x.reshape(b, hkv, group, tk, -1).sum(axis=2).astype(
             q.dtype) for x in (dk, dv_))
@@ -727,79 +785,113 @@ def _select_blocks(tq, tk, block_q=None, block_k=None):
     return block_q, block_k, ok
 
 
-def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize, group=1):
-    """Bytes of scoped VMEM the hungriest of the three kernels asks for.
-    Every operand block is double-buffered by the pipeline, each a whole
-    number of 128-lane tiles wide in VMEM whatever d is (192 takes 256):
-    the full-length operands (K and V in fwd/dq, Q and dO in dkv), the
-    streamed in/out blocks, and the lse/dcap stats rows (full length in
-    dkv). On top sit the body's float32 [block_k, block_q] score tiles (2
-    in fwd, 2.75 in dq, 1.5 in dkv), the [d, block_q] accumulators, and in
-    dkv the block's own k and v. Fitted to the sizes the chip's compiler
-    reports when it refuses, at T=1k..32k, d=64..256, bf16 and f32, blocks
-    256..1024 (and d=192, dv=128 at T=8192; AOT, PR 30): it accepts nothing
-    of those that Mosaic refuses (test_chip_compile.py holds the shapes).
-    Under grouped queries (``group`` > 1) dkv's two result blocks are
-    float32. A window changes nothing: the kernels still hold the whole
-    of the other side's operands. Past the default limit, where
-    ``_flash_plan`` names the calls' own, it is what they ask for: at
-    T=8192, d = dv = 256, bf16 it reads 27.0 / 22.0 / 19.1 / 18.0 MiB at
-    1024x512 / 512x512 / 256x256 / 128x128 where Mosaic reports 21.00 /
-    19.00 / 18.00 / 17.50M for dkv, the hungriest (f32 at d = dv = 128 the
-    same four; AOT, PR 36): 3-29 % over, so the limit named is never
-    under what the compiler takes."""
+def _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize, group=1,
+                fused=True):
+    """Bytes of scoped VMEM the hungriest kernel of a call asks for: of
+    ``flash_fwd`` and ``flash_bwd_dqkv`` (``fused``), or of ``flash_fwd``
+    and the ``flash_bwd_dq`` + ``flash_bwd_dkv`` pair. Every operand block
+    is double-buffered by the pipeline, each a whole number of 128-lane
+    tiles wide in VMEM whatever d is (192 takes 256): the full-length
+    operands (K and V in fwd/dq, Q and dO in dkv), the streamed in/out
+    blocks, and the lse/dcap stats rows (full length in dkv). On top sit
+    the body's float32 [block_k, block_q] score tiles (2 in fwd, 2.75 in
+    dq, 1.5 in dkv), the [d, block_q] accumulators, and in dkv the block's
+    own k and v. dqkv is dkv with dQ on top: its float32 [tq, d]
+    accumulator, the head's whole dQ block (double-buffered, as every
+    result), and the fifth product's temporaries (0.75 of a score tile,
+    3.5 [block_k, d] and half a [block_q, d] in float32), fitted so that
+    it is under none of Mosaic's totals at T=1k..16k, d=64..256, bf16 and
+    f32, blocks 128..1024, grouped or not, under a window or not, and over
+    them by 0-0.7 MiB at the cells' shapes and near 16 MiB (the limit at
+    which each compiles; AOT). The pair's terms are fitted to the
+    sizes the chip's compiler reports when it refuses, at T=1k..32k,
+    d=64..256, bf16 and f32, blocks 256..1024 (and d=192, dv=128 at
+    T=8192; AOT): it accepts nothing of those that Mosaic refuses
+    (test_chip_compile.py holds the shapes). Under grouped queries
+    (``group`` > 1) dkv's two result blocks are float32. A window changes
+    nothing: the kernels still hold the whole of the other side's
+    operands. Past the default limit, where ``_flash_plan`` names the
+    calls' own, it is what they ask for: at T=8192, d = dv = 256, bf16 the
+    pair reads 27.0 / 22.0 / 19.1 / 18.0 MiB at 1024x512 / 512x512 /
+    256x256 / 128x128 where Mosaic reports 21.00 / 19.00 / 18.00 / 17.50M
+    for dkv, the hungriest (f32 at d = dv = 128 the same four; AOT):
+    3-29 % over, so the limit named is never under what the compiler
+    takes."""
     wide, wide_v = (-(-n // 128) * 128 for n in (d, dv))  # whole lane tiles
     io = (wide + wide_v) * itemsize
     tile = block_k * block_q * 4
     fwd = 2 * (tk + block_q) * io + 2 * 8 * block_q * 4 \
         + 2 * tile + 4 * dv * block_q
-    dq = 2 * (tk + block_q) * io + 2 * block_q * wide * itemsize \
-        + 2 * 2 * 8 * block_q * 4 + 2.75 * tile + 4 * d * block_q
     dkv = 2 * (tq + 2 * block_q) * io + 2 * 2 * 8 * tq * 4 \
         + 1.5 * tile + 4 * (d + dv) * block_q + block_q * io
     if group > 1:
         dkv += 2 * block_q * (wide + wide_v) * (4 - itemsize)
+    if fused:
+        # dQ's float32 [tq, d] accumulator and its double-buffered block,
+        # and what Mosaic takes beside dkv's for the fifth product
+        return max(fwd, dkv + 4 * tq * wide + 2 * tq * wide * itemsize
+                   + 0.75 * tile + 4 * (3.5 * block_k + 0.5 * block_q) * wide)
+    dq = 2 * (tk + block_q) * io + 2 * block_q * wide * itemsize \
+        + 2 * 2 * 8 * block_q * 4 + 2.75 * tile + 4 * d * block_q
     return max(fwd, dq, dkv)
 
 
 def _flash_plan(tq, tk, d, dv, block_q=None, block_k=None, itemsize=4,
                 group=1):
-    """``(block_q, block_k, refusal, vmem_limit)``: the blocks
+    """``(block_q, block_k, refusal, vmem_limit, fused)``: the blocks
     ``flash_attention`` runs these operands at, why it would NOT take the
-    Pallas kernels (a ``FALLBACKS`` reason) or None when it will, and the
+    Pallas kernels (a ``FALLBACKS`` reason) or None when it will, the
     scoped-VMEM limit the calls must ask Mosaic for, or None where its
-    default does: every gate the kernels apply — enablement, block-tiling
-    legality and the scoped-VMEM footprint. A ``block_q`` the caller did
-    not name is halved while the footprint overflows, so that long or wide
-    operands stay on the kernels at a smaller block.
+    default does, and whether the backward is the one ``flash_bwd_dqkv``
+    kernel (``_flash_vmem``'s ``fused``): every gate the kernels apply —
+    enablement, block-tiling legality and the scoped-VMEM footprint. A
+    ``block_q`` the caller did not name is halved while the footprint
+    overflows, so that long or wide operands stay on the kernels at a
+    smaller block.
 
     Where the operands fit under the default limit at NO block (the
     whole-length ones alone are past it: 256-wide keys and values at T =
     8192 in bfloat16 are 16.8 MB double-buffered) a smaller block buys
     nothing: the blocks stay as ``_select_blocks`` gives them and the
-    calls name their own limit, ``_flash_vmem``'s footprint with
-    ``_VMEM_MARGIN`` over it in whole MiB, refused (``"vmem"``) past
-    ``_VMEM_CAP``."""
+    calls name their own limit, the footprint with ``_VMEM_MARGIN`` over
+    it in whole MiB, refused (``"vmem"``) past ``_VMEM_CAP``.
+
+    A plan is made for each backward, the one ``flash_bwd_dqkv`` kernel
+    and the ``flash_bwd_dq`` + ``flash_bwd_dkv`` pair, and the one kernel
+    is taken unless only the pair fits (T = 16384 at 256-wide heads) or
+    the pair fits at larger blocks: the one kernel's dQ accumulator and
+    block are whole-length, so a smaller block frees less for it (f32 at
+    T = 4096 and 128-wide heads fits it at 128 x 128, the pair at 512 x
+    512), and 256 x 256 blocks cost over twice 1024 x 512
+    (``_select_blocks``). Such a call keeps the blocks and the backward
+    it had before the one kernel."""
     named = block_q is not None
     block_q, block_k, tiles = _select_blocks(tq, tk, block_q, block_k)
     if not enabled():
-        return block_q, block_k, "disabled", None
+        return block_q, block_k, "disabled", None, True
     if not tiles:
-        return block_q, block_k, "untileable", None
-    if _flash_vmem(tq, tk, d, dv, 128, 128, itemsize, group) > _VMEM_LIMIT:
-        mib = 1024 * 1024
-        limit = -int(-_VMEM_MARGIN * _flash_vmem(
-            tq, tk, d, dv, block_q, block_k, itemsize, group) // mib) * mib
-        if limit > _VMEM_CAP:
-            return block_q, block_k, "vmem", None
-        return block_q, block_k, None, limit
-    while _flash_vmem(tq, tk, d, dv, block_q, block_k, itemsize,
-                      group) > _VMEM_LIMIT:
-        smaller = _select_blocks(tq, tk, block_q // 2, block_k)
-        if named or not smaller[2] or smaller[0] >= block_q:
-            return block_q, block_k, "vmem", None
-        block_q, block_k, _ = smaller
-    return block_q, block_k, None, None
+        return block_q, block_k, "untileable", None, True
+
+    def plan(fused):
+        need = functools.partial(_flash_vmem, tq, tk, d, dv,
+                                 itemsize=itemsize, group=group, fused=fused)
+        if need(128, 128) > _VMEM_LIMIT:
+            mib = 1024 * 1024
+            limit = -int(-_VMEM_MARGIN * need(block_q, block_k) // mib) * mib
+            return (block_q, block_k, None, limit, fused) \
+                if limit <= _VMEM_CAP else None
+        bq, bk = block_q, block_k
+        while need(bq, bk) > _VMEM_LIMIT:
+            smaller = _select_blocks(tq, tk, bq // 2, bk)
+            if named or not smaller[2] or smaller[0] >= bq:
+                return None
+            bq, bk, _ = smaller
+        return bq, bk, None, None, fused
+
+    one, pair = plan(True), plan(False)
+    if one is not None and (pair is None or one[:2] >= pair[:2]):
+        return one
+    return pair or (block_q, block_k, "vmem", None, True)
 
 
 def flash_kernel_usable(tq, tk, d, dv, block_q=None, block_k=None,
@@ -822,14 +914,19 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
     maps, nothing is repeated in memory). ``window``: a causal query sees
     its last ``window`` keys, itself included (``q - window < k <= q``);
     the kernels then visit only the tiles that hold a visible key and are
-    named ``flash_win_fwd`` / ``flash_win_bwd_dq`` / ``flash_win_bwd_dkv``.
-    A window that reaches every key is no window.
+    named ``flash_win_fwd`` / ``flash_win_bwd_dqkv`` (``flash_win_bwd_dq``
+    / ``flash_win_bwd_dkv`` where the backward is the pair). A window that
+    reaches every key is no window.
 
     Forward AND backward run as Pallas kernels: the forward saves the
     per-row log-sum-exp, and the backward reconstructs attention weights
     blockwise from it (standard flash-attention backward), so the [T, T]
-    score matrix never exists in HBM in either direction. The kernels
-    run whenever the shapes tile.
+    score matrix never exists in HBM in either direction. The backward is
+    ONE kernel, ``flash_bwd_dqkv``, that builds each tile's scores and
+    dP once for dQ, dK and dV; where its dQ accumulator leaves it no
+    room beside the whole q side, or less than the pair ``flash_bwd_dq``
+    + ``flash_bwd_dkv`` has, the pair (``_flash_plan``). The kernels run
+    whenever the shapes tile.
 
     The products take their operands in the inputs' type: bfloat16 q, k,
     v (and the p and ds tiles rebuilt from them) go to the MXU as
@@ -875,7 +972,7 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
         if not causal or window < 1:
             raise ValueError("a window of %r, causal %r" % (window, causal))
         window = None if window >= tk else int(window)
-    block_q, block_k, refusal, vmem_limit = _flash_plan(
+    block_q, block_k, refusal, vmem_limit, fused = _flash_plan(
         tq, tk, q.shape[-1], v.shape[-1], block_q, block_k,
         q.dtype.itemsize, group)
     if q.ndim != 4:
@@ -901,7 +998,8 @@ def flash_attention(q, k, v, causal=True, scale=None, window=None,
 
     def bwd(res, g):
         return _flash_attention_bwd_pallas(*res, g, causal, scale, block_q,
-                                           block_k, window, vmem_limit)
+                                           block_k, window, vmem_limit,
+                                           fused)
 
     attn.defvjp(fwd, bwd)
     return attn(q, k, v)

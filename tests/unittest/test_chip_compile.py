@@ -90,8 +90,8 @@ def test_flash_backward_compiles(one_chip, shape):
 
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    assert len(calls) == 3
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    assert len(calls) == 2
+    for name in ("flash_fwd", "flash_bwd_dqkv"):
         assert sum("/%s/" % name in ln for ln in calls) == 1, name
 
 
@@ -100,13 +100,16 @@ def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
     and then failed in Mosaic ("Scoped allocation ... 18.06M and limit
     16.00M"); the rule routed them to XLA until PR 36 and now, since they
     fit under the default limit at no block, names the limit their calls
-    ask for, under which all three kernels compile; past the cap (T=32768)
-    they still go to XLA, and that is counted. f32 at T=4096 overflows at
-    the default 1024-wide block only (Mosaic: 16.76M in dq): the rule
-    halves the block and the kernels compile, no limit named. 256-wide
-    keys and values in bfloat16 at T=8192: Mosaic refuses the smallest
-    block under its default (17.50M in dkv), which is why the rule names a
-    limit there and shrinks nothing."""
+    ask for, under which both kernels compile; past the cap (T=32768)
+    they still go to XLA, and that is counted. f32 at T=4096 overflows
+    the default limit at 1024 x 512, 512 x 512 and 256 x 256 once the
+    one backward kernel holds dQ's accumulator (Mosaic: 25.95M, 19.96M,
+    16.85M; AOT), and the pair at 1024 x 512 only (16.76M in dq):
+    the plan keeps the pair at the larger 512 x 512 and its three
+    kernels compile, no limit named. 256-wide keys and values in bfloat16
+    at T=8192: Mosaic refuses the smallest block under its default
+    (17.50M in dkv alone), which is why the rule names a limit there and
+    shrinks nothing."""
     import jax
     import jax.numpy as jnp
 
@@ -121,7 +124,7 @@ def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
     before = pk.FALLBACKS.get(("flash_attention", "vmem"), 0)
     q = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.float32,
                              sharding=one_chip)
-    assert len(_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) == 3
+    assert len(_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) == 2
     assert pk.FALLBACKS.get(("flash_attention", "vmem"), 0) == before
     assert not pk.flash_kernel_usable(32768, 32768, 128, 128, itemsize=4)
     q = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.float32,
@@ -132,7 +135,7 @@ def test_flash_vmem_rule_refuses_what_the_compiler_refuses(one_chip):
     assert pk.FALLBACKS[("flash_attention", "vmem")] == before + 1
 
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4) == (
-        512, 512, None, None)
+        512, 512, None, None, False)
     q = jax.ShapeDtypeStruct((4, 8, 4096, 128), jnp.float32,
                              sharding=one_chip)
     assert len(_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) == 3
@@ -180,58 +183,12 @@ def test_rtc_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("heads,d,dv", [(2, 192, 128), (20, 256, 256)],
-                         ids=["kimi-192-128", "glm-256-256"])
-def test_latent_attention_shape_compiles(one_chip, heads, d, dv):
-    """The hybrid LMs' latent-attention calls at T = 8192. Kimi-Linear's:
-    192-wide keys, 128-wide values. A 192-wide block fills 256 lanes in
-    VMEM: the footprint rule knows, goes down to 256 x 256, and all three
-    kernels compile (at 512 x 512 Mosaic refused dq, 16.61M of 16.00M, and
-    at 512 x 256 dkv inside a whole step, 16.12M; AOT, PR 30).
-    GLM-4.7-Flash's: 20 heads of 256-wide keys AND values, which fit under
-    the default limit at no block (``_flash_vmem`` reads 18.0 MB at 128 x
-    128, Mosaic 17.50M): the plan keeps ``_select_blocks``' blocks, names
-    the limit, and the three kernels compile under it, counted with the
-    limit in their key (AOT, PR 36)."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops import pallas_kernels as pk
-
-    def loss(q, k, v):
-        return pk.flash_attention(q, k, v, causal=True).astype(
-            jnp.float32).sum()
-
-    plan = pk._flash_plan(8192, 8192, d, dv, itemsize=2)
-    if d == 192:
-        assert plan == (256, 256, None, None)
-    else:
-        assert plan[:3] == pk._select_blocks(8192, 8192)[:2] + (None,)
-        assert pk._VMEM_LIMIT < plan[3] <= pk._VMEM_CAP
-    routed, took = dict(pk.FALLBACKS), dict(pk.FLASH_CALLS)
-    q = jax.ShapeDtypeStruct((1, heads, 8192, d), jnp.bfloat16,
-                             sharding=one_chip)
-    v = jax.ShapeDtypeStruct((1, heads, 8192, dv), jnp.bfloat16,
-                             sharding=one_chip)
-    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
-    assert len(calls) == 3 and pk.FALLBACKS == routed
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert sum("/%s/" % name in ln for ln in calls) == 1, name
-    new = [key for key, n in pk.FLASH_CALLS.items() if n != took.get(key, 0)]
-    assert sorted(key[0] for key in new) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-    assert all(key[3:] == (() if plan[3] is None else (plan[3],))
-               for key in new)
-
-
-@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
-def test_grouped_query_shapes_compile(one_chip, window):
-    """The Mellum2 cell's attention calls: 32 query heads of 128 over 4
-    key/value heads at T = 8192, with the 1,024 window and without: the
-    forward and the three kernels of the gradient, under the window
-    kernels' own names, at the plan the footprint rule picks for a group
-    (512 x 512: at 1024 x 512 dkv's float32 per-head results took 16.69M
-    of 16.00M inside the whole step; AOT, PR 34)."""
+def _compiles_as_one_backward(one_chip, heads, kv_heads, d, dv, window):
+    """An LM cell's attention call at T = 8192, forward and backward: one
+    ``flash_fwd`` and ONE ``flash_bwd_dqkv`` call (``flash_win_*`` under a
+    window), counted with the limit the plan names, nothing routed to
+    XLA, the plan at ``_select_blocks``' blocks under a limit of its own
+    at or under ``_VMEM_CAP``."""
     import jax
     import jax.numpy as jnp
 
@@ -243,21 +200,83 @@ def test_grouped_query_shapes_compile(one_chip, window):
     def loss(q, k, v):
         return attend(q, k, v).astype(jnp.float32).sum()
 
-    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8) == (
-        512, 512, None, None)
-    routed = dict(pk.FALLBACKS)
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+    plan = pk._flash_plan(8192, 8192, d, dv, itemsize=2,
+                          group=heads // kv_heads)
+    assert plan[:3] == pk._select_blocks(8192, 8192)[:2] + (None,)
+    assert pk._VMEM_LIMIT < plan[3] <= pk._VMEM_CAP and plan[4]
+    routed, took = dict(pk.FALLBACKS), dict(pk.FLASH_CALLS)
+    q = jax.ShapeDtypeStruct((1, heads, 8192, d), jnp.bfloat16,
                              sharding=one_chip)
-    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+    k = jax.ShapeDtypeStruct((1, kv_heads, 8192, d), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, kv_heads, 8192, dv), jnp.bfloat16,
                              sharding=one_chip)
     prefix = "flash_" if window is None else "flash_win_"
-    calls = _kernels(attend, q, k, k)
+    calls = _kernels(attend, q, k, v)
     assert len(calls) == 1 and "/%sfwd/" % prefix in calls[0]
-    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
-    assert len(calls) == 3
-    for name in ("fwd", "bwd_dq", "bwd_dkv"):
+    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert len(calls) == 2 and pk.FALLBACKS == routed
+    for name in ("fwd", "bwd_dqkv"):
         assert sum("/%s%s/" % (prefix, name) in ln for ln in calls) == 1, name
-    assert pk.FALLBACKS == routed
+    new = [key for key, n in pk.FLASH_CALLS.items() if n != took.get(key, 0)]
+    assert sorted(key[0] for key in new) == [prefix + "bwd_dqkv",
+                                             prefix + "fwd"]
+    assert all(key[3:] == (plan[3],) for key in new)
+
+
+@pytest.mark.parametrize("heads,d,dv", [(32, 192, 128), (20, 256, 256)],
+                         ids=["kimi-192-128", "glm-256-256"])
+def test_latent_attention_shape_compiles(one_chip, heads, d, dv):
+    """The hybrid LMs' latent-attention calls at T = 8192: Kimi-Linear's
+    32 heads of 192-wide keys and 128-wide values (a 192-wide block fills
+    256 lanes in VMEM), GLM-4.7-Flash's 20 heads of 256-wide keys AND
+    values. GLM's fit under Mosaic's default 16 MiB at no block whatever
+    the backward (``_flash_vmem`` read 18.0 MB at 128 x 128 for the pair,
+    Mosaic 17.50M; AOT); Kimi's ran at 256 x 256 under it until
+    the backward held dQ's float32 [T, d] accumulator and block beside
+    the whole q side, and now names a limit at 1024 x 512 as GLM's does
+    (AOT)."""
+    _compiles_as_one_backward(one_chip, heads, heads, d, dv, None)
+
+
+@pytest.mark.parametrize("heads,kv_heads,window", [
+    (32, 4, None), (32, 4, 1024), (48, 8, None), (64, 8, 512)],
+    ids=["full", "window", "laguna-full", "laguna-window"])
+def test_grouped_query_shapes_compile(one_chip, heads, kv_heads, window):
+    """The grouped-query cells' attention calls at T = 8192, heads of 128:
+    Mellum2's 32 query heads over 4 key/value heads with the 1,024 window
+    and without, Laguna's 48 over 8 in its full layers and 64 over 8 under
+    its 512 window. They ran at 512 x 512 under the default limit (at
+    1024 x 512 dkv's float32 per-head results took 16.69M of 16.00M inside
+    the whole step; AOT); with dQ's accumulator and block in the
+    one backward kernel no block fits the default, so they run at 1024 x
+    512 under a limit of their own (AOT)."""
+    _compiles_as_one_backward(one_chip, heads, kv_heads, 128, 128, window)
+
+
+def test_flash_backward_pair_compiles_where_only_it_fits(one_chip):
+    """T = 16384 at 256-wide keys and values: the one backward kernel's
+    footprint is past ``_VMEM_CAP``, the pair's is not, and the pair
+    compiles under the limit the plan names, with the forward."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    plan = pk._flash_plan(16384, 16384, 256, 256, itemsize=2)
+    assert plan[2] is None and not plan[4]
+
+    def loss(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    routed = dict(pk.FALLBACKS)
+    q = jax.ShapeDtypeStruct((1, 2, 16384, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    calls = _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert len(calls) == 3 and pk.FALLBACKS == routed
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum("/%s/" % name in ln for ln in calls) == 1, name
 
 
 def test_kda_kernels_compile(one_chip):

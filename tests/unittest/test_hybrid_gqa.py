@@ -478,9 +478,8 @@ def test_a_window_kernels_forward_runs_once_a_layer(gradient_sites, kernel,
                                                     layers):
     kept, bare = gradient_sites
     assert kept[kernel] == layers and bare[kernel] == 2 * layers
-    for name in ("bwd_dq", "bwd_dkv"):
-        backward = kernel.replace("fwd", name)
-        assert kept[backward] == bare[backward] == layers
+    backward = kernel.replace("fwd", "bwd_dqkv")
+    assert kept[backward] == bare[backward] == layers
 
 
 def test_a_share_of_a_quarter_leaves_no_cond_in_the_step(gradient_sites):

@@ -659,7 +659,7 @@ def test_two_losses_keep_no_logits_and_one_loss_keeps_what_it_kept(
     sites = _primitives(jax.make_jaxpr(jax.grad(
         lambda p, b: hybrid_lm.loss_fn(cfg)(p, b, None)[0]))(
             params, batch).jaxpr, {})
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for kernel in ("flash_fwd", "flash_bwd_dqkv"):
         assert sites[kernel] == 3, kernel
     assert "cond" not in sites and "ragged_dot_general" not in sites
     assert (sites["moe_gmm"], sites["moe_gmm_pair"], sites["moe_tgmm"]) == (

@@ -4,6 +4,8 @@ Mirrors the reference's cuDNN-vs-plain consistency checks
 (tests/python/gpu/test_operator_gpu.py check_consistency): the Pallas fast
 path must agree with the plain XLA implementation.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -56,10 +58,14 @@ def test_flash_attention_grad_matches_reference():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
 
 
-def test_flash_attention_bwd_kernel_parity_multiblock():
-    """The Pallas backward (dq + dkv kernels, round 4) must match the
-    dense vjp across block boundaries, both causal and not, with
-    non-uniform head gradients (exercises the lse/D reconstruction)."""
+@pytest.mark.parametrize("backward", ["dqkv", "pair"])
+def test_flash_attention_bwd_kernel_parity_multiblock(monkeypatch, backward):
+    """The Pallas backward must match the dense vjp across block
+    boundaries, both causal and not, with non-uniform head gradients
+    (exercises the lse/D reconstruction): the one ``flash_bwd_dqkv``
+    kernel, and the ``flash_bwd_dq`` + ``flash_bwd_dkv`` pair, which the
+    plan keeps where only the pair's footprint is under ``_VMEM_CAP``
+    (here a cap set between the two)."""
     import jax
     import jax.numpy as jnp
 
@@ -67,6 +73,17 @@ def test_flash_attention_bwd_kernel_parity_multiblock():
 
     rng = np.random.RandomState(5)
     b, h, t, d = 2, 2, 256, 32
+    if backward == "pair":
+        mib = 2 ** 20
+        limits = [-int(-pk._VMEM_MARGIN * pk._flash_vmem(
+            t, t, d, d, 128, 128, 4, fused=fused) // mib) * mib
+            for fused in (True, False)]
+        assert limits[0] > limits[1]
+        monkeypatch.setattr(pk, "_VMEM_LIMIT", 0)
+        monkeypatch.setattr(pk, "_VMEM_CAP", limits[1])
+    assert pk._flash_plan(t, t, d, d, 128, 128)[2:] == (
+        None, None if backward == "dqkv" else limits[1], backward == "dqkv")
+    took = dict(pk.FLASH_CALLS)
     q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
     k = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
@@ -86,6 +103,10 @@ def test_flash_attention_bwd_kernel_parity_multiblock():
         for a, b_ in zip(pull_f(g), pull_r(g)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=3e-4)
+    names = {key[0] for key, n in pk.FLASH_CALLS.items()
+             if n != took.get(key, 0)}
+    assert names == ({"flash_fwd", "flash_bwd_dqkv"} if backward == "dqkv"
+                     else {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
 
 
 def test_flash_attention_block_divisor_shrink(monkeypatch):
@@ -164,17 +185,26 @@ def test_flash_plan_halves_the_default_block(monkeypatch):
     """A block_q the caller did not name is halved while the operands
     overflow the scoped VMEM; a named one is refused while a smaller block
     would fit; operands that fit at no block stay on the kernels under a
-    limit of their own (the next test), and go to XLA past the cap."""
+    limit of their own (the next test), and go to XLA past the cap. The
+    backward is the pair where it fits at larger blocks than the one
+    kernel."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
-    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2) == (
-        1024, 512, None, None)
+    assert pk._flash_plan(1024, 1024, 128, 128, itemsize=2) == (
+        1024, 512, None, None, True)
+    # the one backward kernel fits these at smaller blocks than the pair
+    # alone (its dQ accumulator does not shrink with the block), so the
+    # backward is the pair, at the blocks it has had
+    assert pk._flash_plan(4096, 4096, 128, 128, itemsize=2) == (
+        1024, 512, None, None, False)
+    assert pk._flash_plan(2048, 2048, 128, 128, itemsize=4) == (
+        1024, 512, None, None, False)
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4) == (
-        512, 512, None, None)
+        512, 512, None, None, False)
     assert pk._flash_plan(4096, 4096, 128, 128, block_q=1024,
                           itemsize=4)[2] == "vmem"
-    assert pk._flash_plan(32768, 32768, 128, 128, itemsize=4)[2:] == (
+    assert pk._flash_plan(32768, 32768, 128, 128, itemsize=4)[2:4] == (
         "vmem", None)
     monkeypatch.setenv("MXNET_FLASH_BLOCK_Q", "1024")  # a probe is a name
     assert pk._flash_plan(4096, 4096, 128, 128, itemsize=4)[2] == "vmem"
@@ -185,9 +215,12 @@ def test_flash_plan_names_a_limit_where_no_block_fits(monkeypatch):
     attention): the whole-length operands alone are past Mosaic's default
     16 MiB at every block, so the plan keeps ``_select_blocks``' blocks and
     names the scoped VMEM the calls ask for: the footprint and a quarter,
-    in whole MiB, under the cap. Where a block fits under the default the
-    plan is what it was and names none: Kimi-Linear's 192/128 head, the
-    dense LM's and Mellum2's grouped heads."""
+    in whole MiB, under the cap. Since the backward is one kernel that
+    holds dQ's [T, d] accumulator and block beside the whole q side,
+    Kimi-Linear's 192/128 head and the grouped heads of Mellum2 and
+    Laguna at T = 8192 fit at no block either, and run at the same
+    blocks under a limit of their own; the dense LM at T = 1024 names
+    none."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "_interpret", lambda: False)
@@ -195,8 +228,10 @@ def test_flash_plan_names_a_limit_where_no_block_fits(monkeypatch):
     for block in (1024, 512, 256, 128):
         assert pk._flash_vmem(8192, 8192, 256, 256, block, min(block, 512),
                               2) > pk._VMEM_LIMIT
-    bq, bk, refusal, limit = pk._flash_plan(8192, 8192, 256, 256, itemsize=2)
-    assert (bq, bk, refusal) == pk._select_blocks(8192, 8192)[:2] + (None,)
+    bq, bk, refusal, limit, fused = pk._flash_plan(8192, 8192, 256, 256,
+                                                   itemsize=2)
+    assert (bq, bk, refusal, fused) == pk._select_blocks(8192, 8192)[:2] + (
+        None, True)
     need = pk._flash_vmem(8192, 8192, 256, 256, bq, bk, 2)
     assert pk._VMEM_MARGIN * need <= limit < pk._VMEM_MARGIN * need + 2 ** 20
     assert limit % 2 ** 20 == 0 and pk._VMEM_LIMIT < limit <= pk._VMEM_CAP
@@ -206,13 +241,13 @@ def test_flash_plan_names_a_limit_where_no_block_fits(monkeypatch):
     assert pk.flash_kernel_usable(8192, 8192, 256, 256, itemsize=2)
     # float32 at T = 8192 and 128-wide heads went to XLA until PR 36
     assert pk._flash_plan(8192, 8192, 128, 128, itemsize=4)[2] is None
-    # as before: a block fits, no limit is named
-    assert pk._flash_plan(8192, 8192, 192, 128, itemsize=2) == (
-        256, 256, None, None)
+    for d, dv, group in ((192, 128, 1), (128, 128, 8), (128, 128, 6)):
+        plan = pk._flash_plan(8192, 8192, d, dv, itemsize=2, group=group)
+        assert plan[:3] == (1024, 512, None) and plan[4], (d, dv, group)
+        assert pk._VMEM_LIMIT < plan[3] <= pk._VMEM_CAP
+    # a block fits, no limit is named
     assert pk._flash_plan(1024, 1024, 64, 64, itemsize=2) == (
-        1024, 512, None, None)
-    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8) == (
-        512, 512, None, None)
+        1024, 512, None, None, True)
 
 
 #: (T, block_q, block_k): one tile; several tiles with block_q != block_k;
@@ -319,10 +354,11 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_flash_products_take_the_inputs_type(dtype):
-    """bfloat16 q, k, v: every product of the three kernels is fed
+    """bfloat16 q, k, v: every product of the two kernels is fed
     bfloat16 and accumulates in float32; float32 inputs keep float32
-    products. Read from the kernels' jaxprs: 2 + 3 + 4 products, each
-    once in the loop's tile body and once in the diagonal step's."""
+    products. Read from the kernels' jaxprs: 2 + 5 products, each once in
+    the loop's tile body and once in the diagonal step's: the backward
+    builds a tile's S and dP once for dQ, dK and dV."""
     import jax
     import jax.numpy as jnp
 
@@ -340,7 +376,7 @@ def test_flash_products_take_the_inputs_type(dtype):
                              if e.primitive.name == "dot_general"]
         for eqn in _eqns(outer.jaxpr) if eqn.primitive.name == "pallas_call"}
     assert {name: len(dots) for name, dots in kernels.items()} == {
-        "flash_fwd": 4, "flash_bwd_dq": 6, "flash_bwd_dkv": 8}
+        "flash_fwd": 4, "flash_bwd_dqkv": 10}
     for name, dots in kernels.items():
         for eqn in dots:
             operands = {str(var.aval.dtype) for var in eqn.invars}
@@ -368,7 +404,7 @@ def test_flash_calls_are_counted():
                 for key, n in pk.FLASH_CALLS.items()
                 if n != before.get(key, 0)}
 
-    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    kernels = ("flash_fwd", "flash_bwd_dqkv")
     # the benchmark cell's attention: as it runs (1024 x 512), at 256 x 256
     assert took((8, 16, 1024, 64), jnp.bfloat16, causal=True) == {
         (name, "bfloat16", (3, 2, 4)): 1 for name in kernels}
@@ -406,7 +442,7 @@ FLASH_WINDOWS = {"none": None, "step_multiple": 256, "ragged": 100,
 
 
 @pytest.mark.parametrize("window", sorted(FLASH_WINDOWS))
-@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("group", [1, 6, 8])
 def test_flash_grouped_queries_and_window(group, window):
     """Forward and all three gradients against the dense reference, several
     blocks long: ``group`` query heads read one key/value head (dk and dv
@@ -418,7 +454,7 @@ def test_flash_grouped_queries_and_window(group, window):
     from mxnet_tpu.ops import pallas_kernels as pk
 
     w = FLASH_WINDOWS[window]
-    t, d, hkv = 512, 64, 1 if group == 8 else 2
+    t, d, hkv = 512, 64, 2 if group == 1 else 1
     rng = np.random.RandomState(5)
     q, g = (jnp.asarray(rng.randn(1, hkv * group, t, d), jnp.float32)
             for _ in range(2))
@@ -443,7 +479,7 @@ def test_flash_grouped_queries_and_window(group, window):
              if n != took.get(key, 0)}
     windowed = w is not None and w < t
     assert names == {("flash_win_" if windowed else "flash_") + side
-                     for side in ("fwd", "bwd_dq", "bwd_dkv")}
+                     for side in ("fwd", "bwd_dqkv")}
     assert [a.shape for a in got] == [q.shape, q.shape, k.shape, v.shape]
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
@@ -533,8 +569,9 @@ def test_flash_tile_counts_with_a_window_match_the_mask(t, block, step,
 
 def test_flash_window_calls_are_counted_with_what_they_visit():
     """A traced call under a window is counted under the window kernels'
-    names with the tiles their loops visit: at the benchmark cell's shapes
-    45 of the 136 tiles under the diagonal."""
+    names with the tiles their loops visit and the limit the calls name:
+    at the benchmark cell's shapes, 1024 x 512 blocks, 52 of the 136 tiles
+    under the diagonal (45 at 512 x 512)."""
     import jax
     import jax.numpy as jnp
 
@@ -549,42 +586,141 @@ def test_flash_window_calls_are_counted_with_what_they_visit():
         argnums=(0, 1, 2)), q, k, k)
     new = {key: n - before.get(key, 0) for key, n in pk.FLASH_CALLS.items()
            if n != before.get(key, 0)}
-    assert pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8)[:2] == (
-        512, 512)
-    assert new == {(name, "bfloat16", (45, 30, 256)): 1 for name in (
-        "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv")}
+    plan = pk._flash_plan(8192, 8192, 128, 128, itemsize=2, group=8)
+    assert plan[:2] == (1024, 512)
+    assert new == {(name, "bfloat16", (52, 44, 256), plan[3]): 1
+                   for name in ("flash_win_fwd", "flash_win_bwd_dqkv")}
+    assert pk._tile_counts(8192, 8192, 512, True, 1024, 512) == (
+        45, 30, 256)
     assert pk._tile_counts(8192, 8192, 512, True)[0] == 136
 
 
-def test_flash_causal_over_unequal_lengths_goes_to_xla():
+@pytest.mark.parametrize("tq,tk", [(128, 256), (384, 128)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_causal_over_unequal_lengths_goes_to_xla(dtype, tq, tk):
     """The causal kernels unroll each block's own diagonal steps, which
-    exist only where tq == tk: a causal rectangle is routed, and counted."""
+    exist only where tq == tk: a causal rectangle is routed, and counted.
+    Without the mask a rectangle either way stays on the kernels, forward
+    and backward (the k-side kernel fits the two blocks to the other
+    length), against the dense reference in float32."""
+    import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
     rng = np.random.RandomState(12)
-    q = jnp.asarray(rng.randn(1, 1, 128, 32), jnp.float32)
-    k = jnp.asarray(rng.randn(1, 1, 256, 32), jnp.float32)
+    q = jnp.asarray(rng.randn(1, 1, tq, 32), dtype)
+    k = jnp.asarray(rng.randn(1, 1, tk, 32), dtype)
     before = pk.FALLBACKS.get(("flash_attention", "causal_rectangle"), 0)
     out = pk.flash_attention(q, k, k, causal=True)
     assert pk.FALLBACKS[("flash_attention", "causal_rectangle")] == before + 1
     ref = pk._attention_reference(q, k, k, True, 32 ** -0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-    # without the mask the rectangle stays on the kernels, forward and
-    # backward (the dkv kernel fits the two blocks to the other length)
-    import jax
-
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=1e-5)
     routed = dict(pk.FALLBACKS)
     out, pull = jax.vjp(
         lambda q, k, v: pk.flash_attention(q, k, v, causal=False), q, k, k)
+    got = (out,) + pull(out)
     assert pk.FALLBACKS == routed
-    ref, pull_ref = jax.vjp(
+    f32 = [x.astype(jnp.float32) for x in (q, k, k, out)]
+    want, pull_ref = jax.vjp(
         lambda q, k, v: pk._attention_reference(q, k, v, False, 32 ** -0.5),
-        q, k, k)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-    for a, b in zip(pull(out), pull_ref(out)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
+        *f32[:3])
+    want = (want,) + pull_ref(f32[3])
+    for i, (a, b) in enumerate(zip(got, want)):
+        tol = FLASH_TOLERANCE[dtype][i > 0]
+        if dtype == "bfloat16":
+            tol *= float(np.abs(b).max())
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("t,d,dtype", [(16384, 256, "bfloat16"),
+                                        (4096, 128, "float32")],
+                         ids=["only_the_pair_fits", "the_pair_fits_larger"])
+def test_flash_backward_keeps_the_pair_where_only_it_fits(t, d, dtype):
+    """T = 16384 at 256-wide keys and values: the one backward kernel's
+    footprint (dQ's float32 [T, d] accumulator and its block on top of
+    the whole q side) is past ``_VMEM_CAP`` and the pair's is not, so the
+    backward stays on ``flash_bwd_dq`` + ``flash_bwd_dkv`` under a limit
+    of their own, and nothing goes to XLA's dense path. f32 at T = 4096
+    and 128-wide heads: the one kernel fits the default limit at 128 x
+    128 alone, the pair at 512 x 512, so the backward is the pair at the
+    larger blocks, under no limit of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    itemsize = jnp.dtype(dtype).itemsize
+    plan = pk._flash_plan(t, t, d, d, itemsize=itemsize)
+    assert plan[2] is None and not plan[4]
+    fused = functools.partial(pk._flash_vmem, t, t, d, d, itemsize=itemsize)
+    if t == 16384:
+        assert pk._VMEM_LIMIT < plan[3] <= pk._VMEM_CAP
+        assert pk._VMEM_MARGIN * fused(*plan[:2]) > pk._VMEM_CAP
+    else:
+        assert plan[:4] == (512, 512, None, None)
+        assert fused(256, 256) > pk._VMEM_LIMIT >= fused(128, 128)
+    routed, before = dict(pk.FALLBACKS), dict(pk.FLASH_CALLS)
+    x = jax.ShapeDtypeStruct((1, 2, t, d), dtype)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: pk.flash_attention(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+    new = {key: n - before.get(key, 0) for key, n in pk.FLASH_CALLS.items()
+           if n != before.get(key, 0)}
+    key = (dtype, pk._tile_counts(t, t, plan[1], True)) + (
+        () if plan[3] is None else (plan[3],))
+    assert new == {(name,) + key: 1 for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert pk.FALLBACKS == routed
+
+
+def test_flash_kernel_names_are_read_by_the_benchmark():
+    """Every name ``_flash_call`` gives is read by the benchmark's readers
+    as they stand: ``flash_roofline`` (its ``KERNELS`` substrings) counts
+    the kernels outside a window and none inside one, and
+    ``window_attn_roofline`` (its ``flash_win_`` filter) counts the window
+    kernels and no other. A name neither reads would drop that kernel's
+    time from its roofline."""
+    import importlib.util
+    import os
+    import sys
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark")
+    sys.path.insert(0, bench)  # the readers import ``flops`` beside them
+    try:
+        readers = {}
+        for name in ("flash_roofline", "window_attn_roofline"):
+            spec = importlib.util.spec_from_file_location(
+                name, os.path.join(bench, "layer_metrics", name + ".py"))
+            readers[name] = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(readers[name])
+    finally:
+        sys.path.remove(bench)
+
+    class Reference:  # the work both readers ask a configuration for
+        attention_work = window_attention_work = staticmethod(
+            lambda config, traffic: (1e9, 1e6))
+
+    run = {"reference": Reference, "config": {}, "traffic": {},
+           "peaks": {"bf16_flops_per_s": 1e14, "hbm_bytes_per_s": 1e12}}
+
+    def read(reader, op):
+        trace = {"ops": {op + "_bf16_20_8192_256_": 1.0},
+                 "module_runs": {"step": 1}}
+        return readers[reader].compute(trace, {}, run) is not None
+
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_dqkv")
+    for name in names:
+        for window in (None, 512):
+            kernel = pk._kernel_name(name, window)
+            assert read("flash_roofline", kernel) == (window is None), kernel
+            assert read("window_attn_roofline", kernel) == (
+                window is not None), kernel
+    assert not read("flash_roofline", "fusion")
 
 
 def test_flash_attention_fallback_odd_shapes():

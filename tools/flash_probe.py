@@ -1,16 +1,19 @@
 #!/usr/bin/env python3
-"""Device time of the three flash-attention kernels alone, on the chip.
+"""Device time of the flash-attention kernels alone, on the chip.
 
     python tools/flash_probe.py [--root DIR] [--shapes 8x16x1024x64,...]
         [--blocks default,256x256,...] [--dtype bfloat16] [--iters 10]
         [--tag NAME] [--out chiprun_out/flash_probe.jsonl]
 
-For every shape ``BxHxTxD`` and every ``block_q x block_k`` (``default`` =
-what ``_select_blocks`` picks) it jits ``jax.grad`` of causal
+For every shape ``BxHxTxD[xDV][/HKV][@W]`` (values ``DV`` wide, ``HKV``
+key/value heads, a window of ``W``) and every ``block_q x block_k``
+(``default`` = what ``_flash_plan`` picks; a named pair may still be
+given a VMEM limit of its own) it jits ``jax.grad`` of causal
 ``flash_attention``, runs it ``--iters`` times under the profiler and sums
-the device time of ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv``
-from the trace (``benchmark/trace_reduce.py``, as ``flash_roofline`` does
-for a cell): milliseconds a call, and at the cell's own shape
+the device time of each kernel from the trace
+(``benchmark/trace_reduce.py``, as ``flash_roofline`` does for a cell):
+milliseconds a call under the kernel's name, a window's kernels under
+their names without ``win_``, and at the cell's own shape
 (``8x16x1024x64``) times 24 layers the milliseconds of a gpt2-medium step.
 ``--root`` takes ``mxnet_tpu`` from another checkout (a parent commit
 unpacked under ``.parent/``), so both sides of a comparison are read the
@@ -25,7 +28,15 @@ import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+#: every name ``_flash_call`` gives, ``flash_win_`` read as ``flash_``; an
+#: operation counts under the longest that it contains
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_dqkv")
+
+
+def kernel_of(op):
+    """The kernel an operation of the trace is, or None."""
+    op = op.replace("flash_win_", "flash_")
+    return max((k for k in KERNELS if k in op), key=len, default=None)
 
 
 def main(argv=None):
@@ -54,22 +65,30 @@ def main(argv=None):
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     rows = []
     for shape in args.shapes.split(","):
-        b, h, t, d = (int(x) for x in shape.split("x"))
+        shape_, _, window = shape.partition("@")
+        shape_, _, hkv = shape_.partition("/")
+        b, h, t, d, *dv = (int(x) for x in shape_.split("x"))
+        dv, hkv = (dv or [d])[0], int(hkv or h)
+        window = int(window) if window else None
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(kk, (b, h, t, d), jnp.float32)
-                   .astype(args.dtype) for kk in keys)
+        q, k, v = (jax.random.normal(kk, dims, jnp.float32).astype(args.dtype)
+                   for kk, dims in zip(keys, ((b, h, t, d), (b, hkv, t, d),
+                                              (b, hkv, t, dv))))
         for blocks in args.blocks.split(","):
             bq, bk = (None, None) if blocks == "default" else (
                 int(x) for x in blocks.split("x"))
 
             def loss(q, k, v):
                 return pk.flash_attention(
-                    q, k, v, causal=True, block_q=bq, block_k=bk
-                ).astype(jnp.float32).sum()
+                    q, k, v, causal=True, window=window, block_q=bq,
+                    block_k=bk).astype(jnp.float32).sum()
 
+            plan = pk._flash_plan(t, t, d, dv, bq, bk,
+                                  jnp.dtype(args.dtype).itemsize, h // hkv)
             row = {"tag": args.tag, "shape": shape, "dtype": args.dtype,
-                   "blocks": "x".join(str(x) for x in pk._select_blocks(
-                       t, t, bq, bk)[:2]),
+                   "blocks": "%dx%d" % plan[:2], "vmem_limit": plan[3],
+                   # one backward kernel (a tree from before it: the pair)
+                   "fused": len(plan) > 4 and plan[4],
                    "device_kind": dev.device_kind}
             try:
                 grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
@@ -82,11 +101,13 @@ def main(argv=None):
                 jax.profiler.stop_trace()
                 ops = trace_reduce.reduce_dir(trace_dir)["ops"]
                 shutil.rmtree(trace_dir, ignore_errors=True)
-                for kernel in KERNELS:
-                    row[kernel + "_ms"] = 1e3 / args.iters * sum(
-                        sec for name, sec in ops.items()
-                        if kernel in name)
-                row["sum_ms"] = sum(row[kernel + "_ms"] for kernel in KERNELS)
+                for name, sec in ops.items():
+                    kernel = kernel_of(name)
+                    if kernel is not None:
+                        row[kernel + "_ms"] = row.get(
+                            kernel + "_ms", 0.0) + 1e3 / args.iters * sec
+                row["sum_ms"] = sum(row.get(kernel + "_ms", 0.0)
+                                    for kernel in KERNELS)
                 row["fallbacks"] = {"%s.%s" % key: n
                                     for key, n in pk.FALLBACKS.items()}
             except Exception as e:  # a refused block must not end the sweep
